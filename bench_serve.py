@@ -98,8 +98,10 @@ schema/contract as bench.py — the flagship quantized line LAST):
 
 ``--smoke``: tiny CPU config — always runnable (CI leg, rc 0; gather
 reference attention keeps it fast, kernel parity is the test suite's
-job). Off-TPU without ``--smoke`` each leg emits a structured ``error``
-line instead of crashing (driver contract, like bench_flash_ab).
+job); its lines name the CPU. Without ``--smoke`` the script needs a TPU
+and exits non-zero naming the platform it found. A leg that raises prints
+its traceback and a structured ``error`` line, the remaining legs still
+run, and the exit code is non-zero.
 """
 from __future__ import annotations
 
@@ -1169,9 +1171,15 @@ def main():
     import paddle_tpu  # noqa: F401  (framework config)
     import jax
 
-    # serving path: 32-bit index types, same policy as bench.py
-    jax.config.update("jax_enable_x64", False)
+    from paddle_tpu.framework.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     on_tpu = jax.devices()[0].platform == "tpu"
+    if not (on_tpu or smoke):
+        raise SystemExit(
+            f"bench_serve.py needs a TPU: jax found platform "
+            f"{jax.devices()[0].platform!r} "
+            f"({jax.devices()[0].device_kind}). --smoke is the CPU leg.")
 
     # round 16: --legs=a,b,c runs (and emits) only the named legs — the
     # tier-1 smoke gate selects its gated subset instead of paying every
@@ -1206,7 +1214,6 @@ def main():
     label = (f"smoke bs{shape['batch']}" if smoke
              else f"gpt3-125m bs{shape['batch']}")
     chip = (jax.devices()[0].device_kind if on_tpu else "cpu")
-    runnable = on_tpu or smoke
     use_kernel = None if on_tpu else False
 
     # round-10 quantized A/B (fp unified vs int8-weights vs int8-weights +
@@ -1338,12 +1345,8 @@ def main():
                 + (f" prompt{ab_shape['prompt']}+{ab_kw['steps']}x"
                    f"{ab_kw['windows']} steps, {chip}) [{name}]"))
 
+    failed = []
     for name, over in legs:
-        if not runnable:
-            print(_error_line(
-                "backend_unavailable: paged decode needs a TPU chip, or "
-                "--smoke for the interpret leg", metric=metric_for(name)))
-            continue
         try:
             if name == "unified-async":
                 sync_out, async_out = bench_serving_ab(
@@ -1500,10 +1503,14 @@ def main():
                                     **{k: v for k, v in shape.items()
                                        if k != "steps"}, **over)
                 results[name] = dict(metric=metric_for(name), **out)
-        except Exception as e:  # one failed leg must not kill the others
+        except Exception as e:
+            # the remaining legs still run; the run fails at the end
+            import traceback
+
+            traceback.print_exc(file=sys.stderr)
             print(_error_line(f"{type(e).__name__}: {e}"[:200],
                               metric=metric_for(name)))
-            continue
+            failed.append(name)
 
     # line order = leg order, flagship (quantized unified) LAST.
     # vs_baseline: unified-step over the legacy two-jit path (the round-9
@@ -1580,15 +1587,9 @@ def main():
     # mega step + single-dispatch draft chain vs the per-op partner on
     # continuous-arrival prefill+decode traffic (self-baselined)
     _emit("unified-mega-mixed", None)
+    if failed:
+        raise SystemExit(f"bench_serve.py: legs failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":
-    try:
-        main()
-    except Exception as e:  # last line must stay parseable for the driver
-        import sys
-        import traceback
-
-        traceback.print_exc(file=sys.stderr)
-        print(_error_line(f"{type(e).__name__}: {e}"[:200]))
-        sys.exit(0)
+    main()

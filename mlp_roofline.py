@@ -90,7 +90,7 @@ def main():
     # 2*BSH; gelu bwd reads the saved w1-output 4*BSH... counted at bf16.
     bsh = B * S * H * 2  # bytes
     min_bytes = (2 + 3 + 2) * bsh + 2 * 4 * bsh  # LN legs + gelu-grad read/write
-    chip, _ = _chip_peak(jax, on_tpu)
+    chip, _ = _chip_peak(jax.devices()[0].device_kind)
     bw = HBM_GBPS.get(chip, 819e9)
     roofline_ms = min_bytes / bw * 1e3
 

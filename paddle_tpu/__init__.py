@@ -18,10 +18,6 @@ import jax as _jax
 _jax.config.update("jax_enable_x64", True)
 _jax.config.update("jax_default_matmul_precision", "highest")
 
-from . import _jax_compat as _jc  # newer-jax spellings on older releases
-
-_jc.install()
-
 from . import framework
 from .framework import (  # dtypes & device & rng
     CPUPlace,

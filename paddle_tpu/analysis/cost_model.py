@@ -280,17 +280,17 @@ def static_hbm_report(closed, n_param_leaves: int, pool_avals, *,
                         if a.shape and a.shape[0] == num_layers))
     wb = (layer_bytes / mp + repl_bytes) / max(batch, 1)
 
-    # KV term off the pool invar geometry (pools [L, pages, page, heads,
-    # hd]; scale planes [L, pages, page, heads] fp32)
+    # KV term off the pool invar geometry (pools [L, pages, heads, page,
+    # hd]; scale planes [L, pages, heads, page] fp32)
     kv = 0.0
     for a in pool_avals:
         if a is None:
             continue
+        heads = a.shape[2]
         if len(a.shape) == 5:
-            _, _, _, heads, hd = a.shape
-            kv += num_layers * avg_ctx * heads * hd * a.dtype.itemsize / mp
+            kv += (num_layers * avg_ctx * heads * a.shape[4]
+                   * a.dtype.itemsize / mp)
         elif len(a.shape) == 4:
-            heads = a.shape[-1]
             kv += num_layers * avg_ctx * heads * a.dtype.itemsize / mp
 
     act = (HBM_ROUNDTRIPS * num_layers

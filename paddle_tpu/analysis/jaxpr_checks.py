@@ -67,11 +67,11 @@ DOT_OPERAND_BYTES = 1 << 20  # JX002: only large operands are worth a report
 
 
 def _jaxprs_in(val):
-    import jax
+    from jax.extend import core as jex_core
 
-    if isinstance(val, jax.core.ClosedJaxpr):
+    if isinstance(val, jex_core.ClosedJaxpr):
         yield val.jaxpr
-    elif isinstance(val, jax.core.Jaxpr):
+    elif isinstance(val, jex_core.Jaxpr):
         yield val
     elif isinstance(val, (list, tuple)):
         for v in val:
@@ -94,15 +94,27 @@ def _aval_bytes(aval) -> int:
         return 0
 
 
-def trace_callable(fn, *args, mesh=None, **kwargs):
-    """Abstract-trace ``fn`` to a ClosedJaxpr (no FLOPs run). ``mesh``
-    supplies the sharding context the spmd paths need for bare
-    PartitionSpec constraints."""
+def _mesh_scope(fn, mesh=None):
+    """``(traceable, context)`` for abstract-tracing ``fn``. The spmd train
+    step enters its mesh on every call, which jax refuses under a trace
+    (``set_mesh`` is only legal outside ``jit``): its inner jit (``fn.jit``)
+    is traced instead, with the mesh (``fn.mesh``) entered out here."""
     import contextlib
 
     import jax
 
+    mesh = mesh if mesh is not None else getattr(fn, "mesh", None)
     ctx = jax.set_mesh(mesh) if mesh is not None else contextlib.nullcontext()
+    return getattr(fn, "jit", fn), ctx
+
+
+def trace_callable(fn, *args, mesh=None, **kwargs):
+    """Abstract-trace ``fn`` to a ClosedJaxpr (no FLOPs run). ``mesh``
+    supplies the sharding context the spmd paths need for bare
+    PartitionSpec constraints."""
+    import jax
+
+    fn, ctx = _mesh_scope(fn, mesh)
     with ctx:
         return jax.make_jaxpr(fn, **kwargs)(*args)
 
@@ -223,7 +235,9 @@ def check_donation(fn, args, donate_argnums, target: str) -> list[Finding]:
     output, or XLA cannot alias it and the donation is silently wasted."""
     import jax
 
-    out_shape = jax.eval_shape(fn, *args)
+    fn, ctx = _mesh_scope(fn)
+    with ctx:
+        out_shape = jax.eval_shape(fn, *args)
     out_leaves = jax.tree.leaves(out_shape)
     avail = Counter((tuple(o.shape), str(o.dtype)) for o in out_leaves)
     findings: list[Finding] = []

@@ -29,6 +29,8 @@ tile is HBM-resident by design; ``gelu(y2 @ w1)`` coming back at
 """
 from __future__ import annotations
 
+import math
+
 from .cost_model import find_layer_scan
 from .findings import Finding, rule
 from .jaxpr_checks import _aval_bytes, _jaxprs_in, iter_eqns
@@ -43,10 +45,7 @@ LIVE_BUFFERS = 2
 def _block_bytes(bm) -> int:
     """One operand's block window bytes: BlockSpec block shape (squeezed /
     ``Mapped`` dims count 1) x the operand dtype."""
-    n = 1
-    for d in bm.block_shape:
-        n *= d if isinstance(d, int) else 1
-    return n * bm.array_shape_dtype.dtype.itemsize
+    return math.prod(bm.ref_aval.shape) * bm.array_aval.dtype.itemsize
 
 
 def pallas_footprints(closed) -> list[dict]:
@@ -64,7 +63,8 @@ def pallas_footprints(closed) -> list[dict]:
             scratch = sum(_aval_bytes(v.aval)
                           for v in inner.invars[-n_scratch:])
         out.append({
-            "kernel": eqn.params["name_and_src_info"].name,
+            "kernel": (eqn.params["name"]
+                       or eqn.params["jaxpr"].debug_info.func_name),
             "grid": tuple(int(g) for g in gm.grid),
             "block_bytes": blocks,
             "scratch_bytes": scratch,

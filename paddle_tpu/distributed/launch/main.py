@@ -45,6 +45,24 @@ def _parse_args(argv=None):
     return parser.parse_args(argv)
 
 
+def _refuse_shared_chips(nproc_per_node: int) -> None:
+    """A chip belongs to one process, and this launcher hands no worker its
+    own chips: several workers on one TPU host would all reach for the same
+    ones and fail or hang. The host is told by the chips' device nodes — the
+    launcher itself must stay off JAX."""
+    import glob
+
+    if nproc_per_node <= 1 or os.environ.get("JAX_PLATFORMS") == "cpu":
+        return
+    if glob.glob("/dev/accel*") or glob.glob("/dev/vfio/[0-9]*"):
+        raise SystemExit(
+            f"--nproc_per_node {nproc_per_node} on a TPU host: a chip "
+            "belongs to one process and this launcher does not divide the "
+            "host's chips between workers. Run one process per host (it "
+            "drives every local chip), or set JAX_PLATFORMS=cpu for CPU "
+            "workers.")
+
+
 class Pod:
     """The local worker group: spawn, watch, restart (build_pod parity)."""
 
@@ -324,6 +342,7 @@ def launch(argv=None) -> int:
     failing it.
     """
     args = _parse_args(argv)
+    _refuse_shared_chips(args.nproc_per_node)
     if args.auto_tuner_json:
         return _launch_auto_tuner(args)
     spec = str(args.nnodes)

@@ -263,7 +263,7 @@ def paged_attention(q, k_pages, v_pages, page_table, seq_lens, scale=None,
     decode step, vLLM page-table layout). One query token per sequence:
     ``q`` [b, num_q_heads, head_dim] attends its slot's cached prefix read
     through ``page_table`` [b, pages_per_slot] from the page pools
-    [num_pages, page_size, kv_heads, head_dim]; ``seq_lens`` [b] are the
+    [num_pages, kv_heads, page_size, head_dim]; ``seq_lens`` [b] are the
     ragged context lengths (0 = empty slot -> zero output). Pallas kernel
     on TPU (``use_kernel=True`` forces interpret mode off-TPU), jnp gather
     reference elsewhere. Decode-only: not differentiable."""
@@ -287,7 +287,7 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, kv_lens, q_lens,
     its chunk, attending its whole paged context of ``kv_lens`` tokens
     (chunk included; its K/V must already be written). Rows past
     ``q_lens`` are unspecified. With ``k_scales``/``v_scales``
-    ([num_pages, page_size, kv_heads]) the page pools are int8 (round-10
+    ([num_pages, kv_heads, page_size]) the page pools are int8 (round-10
     quantized KV cache) and dequantize inside the kernel's page loop.
     Pallas kernel on TPU (``use_kernel=True`` forces interpret mode
     off-TPU), jnp gather reference elsewhere. Decode-only: not

@@ -2,11 +2,14 @@
 
 Reference shape: the vLLM-style block manager behind the reference's
 ``block_multihead_attention`` serving path, TPU-native: the cache is a POOL
-of fixed-size pages ``[num_layers, num_pages, page_size, kv_heads,
+of fixed-size pages ``[num_layers, num_pages, kv_heads, page_size,
 head_dim]`` (one stacked array per K and V so the decode jit sees ONE
-pytree leaf each), and each admitted sequence owns a list of pages through
-a per-slot page table. Admission/eviction move pages between the free list
-and slots without copying K/V — fragmentation-free continuous batching.
+pytree leaf each; HEAD-MAJOR inside a page, so the attention kernels'
+per-(page, head) block is a contiguous ``[page_size, head_dim]`` tile —
+the (8, 128) block rule Mosaic enforces on the last two dims), and each
+admitted sequence owns a list of pages through a per-slot page table.
+Admission/eviction move pages between the free list and slots without
+copying K/V — fragmentation-free continuous batching.
 
 Split of responsibilities:
 
@@ -130,7 +133,7 @@ def kv_cache_quantized(kv_cache_dtype) -> bool:
 def paged_write_tokens(pages, tok, page_table, positions, page_size):
     """Write ONE token per slot into the page pool (the decode-step write).
 
-    pages: [num_pages, page_size, kv_heads, head_dim]; tok: [batch,
+    pages: [num_pages, kv_heads, page_size, head_dim]; tok: [batch,
     kv_heads, head_dim]; page_table: [batch, pages_per_slot] int32;
     positions: [batch] int32 write position per slot (< 0 = inactive slot,
     dropped). Returns the updated pool.
@@ -141,13 +144,13 @@ def paged_write_tokens(pages, tok, page_table, positions, page_size):
     pg = page_table[jnp.arange(b), pos // page_size]
     # inactive slots and unallocated (-1) entries route out of bounds
     pg = jnp.where((positions >= 0) & (pg >= 0), pg, num_pages)
-    return pages.at[pg, pos % page_size].set(tok, mode="drop")
+    return pages.at[pg, :, pos % page_size].set(tok, mode="drop")
 
 
 def paged_write_prefill(pages, seq, pages_for_slot, length, page_size):
     """Scatter one slot's prompt K/V into its pages (copy-on-prefill).
 
-    pages: [num_pages, page_size, kv_heads, head_dim]; seq: [s_pad,
+    pages: [num_pages, kv_heads, page_size, head_dim]; seq: [s_pad,
     kv_heads, head_dim] (positions >= length are padding and dropped);
     pages_for_slot: [pages_per_slot] int32 (-1 unallocated); length: scalar.
     """
@@ -157,7 +160,7 @@ def paged_write_prefill(pages, seq, pages_for_slot, length, page_size):
     pg = pages_for_slot[jnp.minimum(i // page_size,
                                     pages_for_slot.shape[0] - 1)]
     pg = jnp.where((i < length) & (pg >= 0), pg, num_pages)
-    return pages.at[pg, i % page_size].set(seq, mode="drop")
+    return pages.at[pg, :, i % page_size].set(seq, mode="drop")
 
 
 def _packed_dest(page_table, tok_slot, tok_pos, page_size, num_pages):
@@ -180,14 +183,14 @@ def paged_write_packed(pages, toks, page_table, tok_slot, tok_pos,
     unified-step write: the step's dense dims run over the flat token
     budget, each token carrying its owning slot + absolute position).
 
-    pages: [num_pages, page_size, kv_heads, head_dim]; toks: [budget,
+    pages: [num_pages, kv_heads, page_size, head_dim]; toks: [budget,
     kv_heads, head_dim]; page_table: [batch, pages_per_slot] int32;
     tok_slot: [budget] int32 owning slot (< 0 = padding, dropped);
     tok_pos: [budget] int32 absolute write position. Returns the pool.
     """
     pg, row = _packed_dest(page_table, tok_slot, tok_pos, page_size,
                            pages.shape[0])
-    return pages.at[pg, row].set(toks, mode="drop")
+    return pages.at[pg, :, row].set(toks, mode="drop")
 
 
 def paged_write_packed_quant(pages, scales, toks, page_table, tok_slot,
@@ -196,8 +199,8 @@ def paged_write_packed_quant(pages, scales, toks, page_table, tok_slot,
     (:func:`paged_write_packed`) with a per-token-per-head symmetric int8
     quantization fused in front of the scatter.
 
-    pages: [num_pages, page_size, kv_heads, head_dim] **int8**; scales:
-    [num_pages, page_size, kv_heads] fp32 (the per-page scale plane — page
+    pages: [num_pages, kv_heads, page_size, head_dim] **int8**; scales:
+    [num_pages, kv_heads, page_size] fp32 (the per-page scale plane — page
     granularity keeps it travelling with the page through CoW copies,
     prefix sharing and eviction); toks: [budget, kv_heads, head_dim] float.
     Each token row quantizes against its own per-head absmax
@@ -210,8 +213,8 @@ def paged_write_packed_quant(pages, scales, toks, page_table, tok_slot,
     absmax = jnp.max(jnp.abs(tf), axis=-1)           # [budget, kv_heads]
     s = jnp.maximum(absmax, 1e-8) / 127.0
     q = jnp.clip(jnp.round(tf / s[..., None]), -127, 127).astype(jnp.int8)
-    pages = pages.at[pg, row].set(q, mode="drop")
-    scales = scales.at[pg, row].set(s.astype(scales.dtype), mode="drop")
+    pages = pages.at[pg, :, row].set(q, mode="drop")
+    scales = scales.at[pg, :, row].set(s.astype(scales.dtype), mode="drop")
     return pages, scales
 
 
@@ -232,16 +235,17 @@ def paged_write_packed_prequant(pages, scales, q_toks, s_toks, page_table,
     """
     pg, row = _packed_dest(page_table, tok_slot, tok_pos, page_size,
                            pages.shape[0])
-    pages = pages.at[pg, row].set(q_toks.astype(pages.dtype), mode="drop")
-    scales = scales.at[pg, row].set(s_toks.astype(scales.dtype),
-                                    mode="drop")
+    pages = pages.at[pg, :, row].set(q_toks.astype(pages.dtype),
+                                     mode="drop")
+    scales = scales.at[pg, :, row].set(s_toks.astype(scales.dtype),
+                                       mode="drop")
     return pages, scales
 
 
 def paged_copy_pages(pages, src, dst):
     """Copy-on-write page copies, traced into the unified step.
 
-    pages: [num_layers, num_pages, page_size, kv_heads, head_dim] (the
+    pages: [num_layers, num_pages, kv_heads, page_size, head_dim] (the
     stacked pool as the jits see it); src/dst: [batch] int32 pool indices,
     ``dst == num_pages`` (the host's no-op sentinel) drops the copy. Each
     active lane duplicates one page across every layer.
@@ -255,14 +259,18 @@ def batched_import_rows(pages, vals, pg, row):
     """Land one restore round's token rows in ONE scatter — the round-21
     batched import/restore write (tpulint flagship: ``serving-tiered``).
 
-    pages: ``[L, P, page_size, kv_heads, head_dim]`` (or a 4-D scale
-    plane ``[L, P, page_size, kv_heads]``); vals: ``[L, R, kv_heads,
-    head_dim]`` (resp. ``[L, R, kv_heads]``) — flat row ``r`` lands at
-    ``pages[:, pg[r], row[r]]``. Padding rows carry ``pg == P`` (the
-    out-of-bounds sentinel) and drop, so one power-of-two-padded trace
-    serves every restore round of that width.
+    pages: ``[L, P, kv_heads, page_size, head_dim]`` (or a 4-D scale
+    plane ``[L, P, kv_heads, page_size]``); vals: ``[L, R, kv_heads,
+    head_dim]`` (resp. ``[L, R, kv_heads]``, the token-row-major payload
+    layout) — flat row ``r`` lands at ``pages[:, pg[r], :, row[r]]``.
+    Padding rows carry ``pg == P`` (the out-of-bounds sentinel) and drop,
+    so one power-of-two-padded trace serves every restore round of that
+    width.
     """
-    return pages.at[:, pg, row].set(vals, mode="drop")
+    # pg/row are split by the head slice, so the indexed view leads with
+    # the row axis: [R, L, kv_heads(, head_dim)]
+    return pages.at[:, pg, :, row].set(jnp.moveaxis(vals, 1, 0),
+                                       mode="drop")
 
 
 #: the jitted batched-import entry point: the pool argument is DONATED —
@@ -271,6 +279,12 @@ def batched_import_rows(pages, vals, pg, row):
 #: and the 4-D scale planes each trace once per padded row width.
 _batched_import_rows_jit = jax.jit(batched_import_rows,
                                    donate_argnums=(0,))
+
+
+#: payload plane name -> the manager attribute holding that pool (the scale
+#: planes exist on int8 pools only)
+_PLANE_ATTRS = {"k": "k_pages", "v": "v_pages",
+                "ks": "k_scales", "vs": "v_scales"}
 
 
 def _payload_crc(planes: dict) -> int:
@@ -321,10 +335,10 @@ class KVCacheManager:
         self.max_batch = int(max_batch)
         self.max_seq_len = int(max_seq_len)
         self.pages_per_slot = math.ceil(self.max_seq_len / self.page_size)
-        shape = (num_layers, self.num_pages, self.page_size,
-                 num_kv_heads, head_dim)
+        shape = (num_layers, self.num_pages, num_kv_heads,
+                 self.page_size, head_dim)
         # int8 KV (round 10): pages store int8 with a per-page fp32 scale
-        # plane [L, P, page_size, kv_heads] — the scale travels WITH its
+        # plane [L, P, kv_heads, page_size] — the scale travels WITH its
         # page (CoW copies, prefix sharing, eviction all stay page-local).
         # ``dtype`` remains the COMPUTE dtype (page-size autotune key).
         self.quantize_kv = bool(quantize_kv)
@@ -332,8 +346,7 @@ class KVCacheManager:
         self.k_pages = jnp.zeros(shape, pool_dtype)
         self.v_pages = jnp.zeros(shape, pool_dtype)
         if self.quantize_kv:
-            sshape = (num_layers, self.num_pages, self.page_size,
-                      num_kv_heads)
+            sshape = shape[:4]
             self.k_scales = jnp.zeros(sshape, jnp.float32)
             self.v_scales = jnp.zeros(sshape, jnp.float32)
         else:
@@ -347,11 +360,11 @@ class KVCacheManager:
             import jax
             from jax.sharding import NamedSharding, PartitionSpec as P
 
-            kv_sh = NamedSharding(mesh, P(None, None, None, "mp", None))
+            kv_sh = NamedSharding(mesh, P(None, None, "mp", None, None))
             self.k_pages = jax.device_put(self.k_pages, kv_sh)
             self.v_pages = jax.device_put(self.v_pages, kv_sh)
             if self.quantize_kv:
-                sc_sh = NamedSharding(mesh, P(None, None, None, "mp"))
+                sc_sh = NamedSharding(mesh, P(None, None, "mp", None))
                 self.k_scales = jax.device_put(self.k_scales, sc_sh)
                 self.v_scales = jax.device_put(self.v_scales, sc_sh)
         # host-side bookkeeping (numpy; uploaded per step as small arrays).
@@ -1108,17 +1121,22 @@ class KVCacheManager:
         self._release_page(page)
         self._note_occupancy()
 
+    def _planes(self) -> dict:
+        """Payload plane name -> this manager's pool array."""
+        return {name: getattr(self, attr)
+                for name, attr in _PLANE_ATTRS.items()
+                if self.quantize_kv or name in ("k", "v")}
+
     def read_page_payload(self, page: int, ntok: int) -> dict:
         """One page's transferable payload: the first ``ntok`` token
         rows of every layer's K/V (+ the int8 scale planes when the
         pool is quantized) as host numpy arrays — exactly the bytes a
         decode replica needs to serve this page bit-identically."""
-        out = {"k": np.asarray(self.k_pages[:, page, :ntok]),
-               "v": np.asarray(self.v_pages[:, page, :ntok])}
-        if self.quantize_kv:
-            out["ks"] = np.asarray(self.k_scales[:, page, :ntok])
-            out["vs"] = np.asarray(self.v_scales[:, page, :ntok])
-        return out
+        # pool pages are head-major; the payload (wire + host tier
+        # contract) is token-row-major [L, ntok, kv_heads(, head_dim)]
+        return {name: np.ascontiguousarray(
+                    np.asarray(pool[:, page, :, :ntok]).swapaxes(1, 2))
+                for name, pool in self._planes().items()}
 
     def import_prefix_page(self, key: bytes, ntok: int, payload: dict):
         """Land one transferred page: allocate a pool page, write the
@@ -1154,13 +1172,10 @@ class KVCacheManager:
             return None
         page = self._free_pages.pop()
         self._refcount[page] = 0
-        self.k_pages = self.k_pages.at[:, page, :ntok].set(payload["k"])
-        self.v_pages = self.v_pages.at[:, page, :ntok].set(payload["v"])
-        if self.quantize_kv:
-            self.k_scales = self.k_scales.at[:, page, :ntok].set(
-                payload["ks"])
-            self.v_scales = self.v_scales.at[:, page, :ntok].set(
-                payload["vs"])
+        for name, pool in self._planes().items():
+            # token-row-major payload -> the pool's head-major page
+            setattr(self, _PLANE_ATTRS[name], pool.at[
+                :, page, :, :ntok].set(payload[name].swapaxes(1, 2)))
         self._page_key[page] = key
         self._prefix_pages[key] = page
         self._page_ntok[page] = int(ntok)
@@ -1273,13 +1288,9 @@ class KVCacheManager:
             off += ntok
         pg = jnp.asarray(pg)
         row = jnp.asarray(row)
-        for name, pool_attr in (("k", "k_pages"), ("v", "v_pages"),
-                                ("ks", "k_scales"), ("vs", "v_scales")):
-            if name not in vals:
-                continue
-            setattr(self, pool_attr, _batched_import_rows_jit(
-                getattr(self, pool_attr), jnp.asarray(vals[name]), pg,
-                row))
+        for name, pool in self._planes().items():
+            setattr(self, _PLANE_ATTRS[name], _batched_import_rows_jit(
+                pool, jnp.asarray(vals[name]), pg, row))
             self._m_restore_scatters.inc()
 
     def discard_imported_prefix(self, keys) -> int:

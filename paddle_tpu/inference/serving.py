@@ -575,6 +575,15 @@ class ServingPredictor:
         # (no per-step upload, the in-jit where() degenerates to identity)
         self._no_feedback = jnp.zeros((self.token_budget,), jnp.int32)
         self._zero_prev = jnp.zeros((self.max_batch,), jnp.int32)
+        if self.mesh is not None:
+            # the carry comes back replicated over the mesh; its first-step
+            # stand-in must be placed the same way, or the second call shows
+            # jit a new input sharding and the step is traced twice
+            import jax
+            from jax.sharding import NamedSharding, PartitionSpec
+
+            self._zero_prev = jax.device_put(
+                self._zero_prev, NamedSharding(self.mesh, PartitionSpec()))
         self._carry = None       # device next_toks of the LAST dispatch
         # per-lane base PRNG keys ([b, 2], content-cached upload: rows
         # only change on admission) — the in-jit fold keys row j by

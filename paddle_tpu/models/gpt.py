@@ -17,6 +17,7 @@ from __future__ import annotations
 import math
 from dataclasses import dataclass
 
+from ..framework.jit32 import jit32
 from ..framework.param_attr import ParamAttr
 from ..nn import Layer, functional as F
 from ..nn.initializer import Normal
@@ -716,14 +717,14 @@ def _mesh_mp(mesh):
 
 
 # KV pool / scale-plane PartitionSpecs under the serving mesh: pools are
-# [L, num_pages, page_size, kv_heads, head_dim] (scales drop the trailing
+# [L, num_pages, kv_heads, page_size, head_dim] (scales drop the trailing
 # head_dim) — the HEAD axis shards, so every chip owns its heads' pages
 # (and their scales) end to end: quantize-on-write, CoW copies and prefix
 # reuse all stay chip-local, zero KV bytes cross the interconnect.
 def _kv_specs():
     from jax.sharding import PartitionSpec as P
 
-    return P(None, None, None, "mp", None), P(None, None, None, "mp")
+    return P(None, None, "mp", None, None), P(None, None, "mp", None)
 
 
 def build_prefill(config: GPTConfig, page_size: int,
@@ -823,7 +824,7 @@ def build_prefill(config: GPTConfig, page_size: int,
 
     # donate the pools like the decode step: every admission threads the
     # full cache through this jit, and an un-donated scatter would copy it
-    jitted = jax.jit(prefill, donate_argnums=(3, 4))
+    jitted = jit32(prefill, donate_argnums=(3, 4))
     # one executable per prompt-length bucket: the counter makes the
     # bucketed-prefill compile count visible (bench_serve prefill_retraces)
     jitted.trace_count = trace_count
@@ -919,7 +920,7 @@ def build_decode_step(config: GPTConfig, page_size: int,
 
     # donate the page pools: the step rewrites them, and double-buffering
     # the cache (the biggest serving allocation) would halve capacity
-    jitted = jax.jit(step, donate_argnums=(3, 4))
+    jitted = jit32(step, donate_argnums=(3, 4))
     jitted.trace_count = trace_count
     return jitted
 
@@ -1389,8 +1390,8 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
                     v_scales)
         return next_toks, logits, k_pages, v_pages
 
-    jitted = jax.jit(step,
-                     donate_argnums=tuple(range(n_lead, n_lead + n_pool)))
+    jitted = jit32(step,
+                   donate_argnums=tuple(range(n_lead, n_lead + n_pool)))
     jitted.trace_count = trace_count
     return jitted
 
@@ -1804,7 +1805,7 @@ def build_draft_chain(config: GPTConfig, draft_layers: int, page_size: int,
         with jax.default_matmul_precision("default"):
             return body(*args)
 
-    jitted = jax.jit(chain, donate_argnums=tuple(range(4, 4 + n_pool)))
+    jitted = jit32(chain, donate_argnums=tuple(range(4, 4 + n_pool)))
     jitted.trace_count = trace_count
     return jitted
 

@@ -34,6 +34,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..framework.jit32 import jit32
 from .gpt import GPTConfig
 
 
@@ -287,19 +288,21 @@ def _block(p, x, config: GPTConfig, mesh: Mesh, dp_axis="dp"):
     else:
         use_flash = config.force_flash  # interpret-mode kernel for CPU tests
     if use_flash:
-        # fused Pallas kernel: no S x S residuals in fwd or bwd. Under TP the
-        # kernel runs per-device via shard_map over the mp-sharded head dim
-        # (and dp-sharded batch): heads are embarrassingly parallel in flash
-        # attention, so no collectives are needed inside the region —
-        # reference never runs flash under mp>1 shards a head *across*
-        # devices either (mp_layers.py splits by whole heads).
+        # fused Pallas kernel: no S x S residuals in fwd or bwd. On a mesh
+        # the kernel runs per-device via shard_map: heads (mp) and batch
+        # (dp) are embarrassingly parallel in flash attention, so no
+        # collectives are needed inside the region — reference never shards
+        # a head *across* devices either (mp_layers.py splits by whole
+        # heads).
         from ..ops.pallas.flash_attention import flash_attention
 
         qh = q.reshape(mb, s, nh, hd)
         kh = k.reshape(mb, s, nh, hd)
         vh = v.reshape(mb, s, nh, hd)
-        sharded_dp = dp_axis is not None and mesh.shape["dp"] > 1
-        if mesh.shape["mp"] > 1 or sharded_dp:
+        if math.prod(mesh.shape.values()) > 1:
+            # manual over EVERY mesh axis (Mosaic calls cannot be
+            # partitioned automatically): under pp the stage dim arrives
+            # through ``_pipeline``'s vmap(spmd_axis_name="pp")
             spec = P(dp_axis, None, "mp", None)
 
             def local_flash(qs, ks, vs):
@@ -309,7 +312,6 @@ def _block(p, x, config: GPTConfig, mesh: Mesh, dp_axis="dp"):
                 local_flash,
                 in_specs=(spec, spec, spec),
                 out_specs=spec,
-                axis_names={"mp"} | ({"dp"} if sharded_dp else set()),
                 check_vma=False,
             )(qh, kh, vh)
         else:
@@ -484,7 +486,8 @@ def _pipeline(stages, mbs, mesh: Mesh, config: GPTConfig, dp_axis="dp"):
     last = num_stages - 1
     cs = _mk_cs(mesh)
 
-    stage_v = jax.vmap(lambda p, x: _stage_fn(p, x, config, mesh, dp_axis))
+    stage_v = jax.vmap(lambda p, x: _stage_fn(p, x, config, mesh, dp_axis),
+                       spmd_axis_name="pp")
 
     def step(carry, t):
         # inject microbatch t into stage 0 (clipped past the schedule; the
@@ -590,8 +593,13 @@ def build_spmd_train_step(
     momentum: float = 0.9,
     zero_stage: int = 0,
     comm_quant=None,
+    dtype=jnp.float32,
 ):
     """Returns (jitted step, params, opt_state, example (ids, labels)).
+
+    ``dtype`` is the parameter / momentum dtype (:func:`init_params`):
+    fp32 by default; GPT-760M at bs8 seq1024 needs ``jnp.bfloat16`` state
+    to fit a 16 GB chip (the loss math is fp32 either way).
 
     The step is jit-compiled over the mesh with full in/out shardings and
     donated state: ``step(params, momentum, ids, labels) -> (params, momentum,
@@ -633,7 +641,7 @@ def build_spmd_train_step(
             raise ValueError(
                 "fused_mlp has no MoE path — the fused MLP kernels are "
                 "dense-only (disable fused_mlp for moe_experts > 0)")
-    params = init_params(config, mesh)
+    params = init_params(config, mesh, dtype=dtype)
     if zero_stage:
         p_shard, m_shard = zero_shardings(params, mesh, zero_stage)
     else:
@@ -675,7 +683,7 @@ def build_spmd_train_step(
         params2 = jax.tree.map(lambda p, m: p - lr * m, params, mom2)
         return params2, mom2, loss
 
-    jitted_inner = jax.jit(
+    jitted_inner = jit32(
         step,
         in_shardings=(p_shard, m_shard, data_shard, data_shard),
         out_shardings=(p_shard, m_shard, NamedSharding(mesh, P())),
@@ -732,7 +740,10 @@ def build_spmd_train_step(
         _m_wire.inc(wire_per_step)
         return out
 
-    jitted.lower = lambda *a: jitted_inner.lower(*a)
+    jitted.lower = jitted_inner.lower
+    # abstract tracers (analysis/jaxpr_checks) cannot call ``jitted`` itself:
+    # jax refuses ``set_mesh`` under a trace
+    jitted.jit, jitted.mesh = jitted_inner, mesh
     rng = np.random.RandomState(0)
     ids = jnp.asarray(rng.randint(0, config.vocab_size, (batch_size, seq_len)), jnp.int32)
     labels = jnp.asarray(rng.randint(0, config.vocab_size, (batch_size, seq_len)), jnp.int32)

@@ -99,10 +99,7 @@ def scaled_dot_product_attention(
 def _kernel_backend_ok() -> bool:
     import jax as _jax
 
-    try:
-        return _jax.devices()[0].platform == "tpu"
-    except RuntimeError:
-        return False
+    return _jax.devices()[0].platform == "tpu"
 
 
 # Below this sequence length the fused XLA softmax-attention beats the

@@ -85,19 +85,15 @@ def x64_off():
     Only engaged when lowering for TPU: in interpret mode (CPU tests) the
     int64 scalars are harmless, and flipping the x64 config mid-trace
     poisons the surrounding jit's lowering (i32/i64 operand mismatches in
-    the emitted calls). Version-tolerant: ``jax.enable_x64`` on current
-    jax, the experimental spelling on older releases."""
+    the emitted calls). Under the compiled hot paths (``framework.jit32``)
+    the whole trace is already 32-bit and this is a no-op."""
     import contextlib
 
     import jax
 
     if jax.default_backend() != "tpu":
         return contextlib.nullcontext()
-    if hasattr(jax, "enable_x64"):
-        return jax.enable_x64(False)
-    from jax.experimental import disable_x64
-
-    return disable_x64()
+    return jax.enable_x64(False)
 
 
 def lookup(sig: str):

@@ -46,6 +46,13 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+# stable pallas_call names: they survive into the compiled HLO and the
+# device trace, so a check ("did the flash kernel run?") or a trace
+# reduction finds the kernels after a refactor
+FWD_KERNEL_NAME = "flash_attention_fwd"
+BWD_KERNEL_NAME = "flash_attention_bwd"
+
+
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
@@ -406,12 +413,13 @@ def _flash_fwd_impl(q, k, v, mask, lens, scale, causal, hq, blocks=None):
                 out_specs=out_specs)
             out, lse = pl.pallas_call(
                 kern, grid_spec=grid_spec, out_shape=out_shape,
-                interpret=_interpret(),
+                interpret=_interpret(), name=FWD_KERNEL_NAME,
             )(lens.astype(jnp.int32), *args)
         else:
             out, lse = pl.pallas_call(
                 kern, grid=grid, in_specs=in_specs, out_specs=out_specs,
                 out_shape=out_shape, interpret=_interpret(),
+                name=FWD_KERNEL_NAME,
             )(*args)
     return out, lse
 
@@ -549,13 +557,13 @@ def flash_bwd_impl(q, k, v, g, lse, delta, scale, causal,
                 in_specs=in_specs, out_specs=out_specs)
             dq, dk, dv = pl.pallas_call(
                 kern, grid_spec=grid_spec, out_shape=out_shape,
-                interpret=_interpret(),
+                interpret=_interpret(), name=BWD_KERNEL_NAME,
             )(lens.astype(jnp.int32), *args)
         else:
             dq, dk, dv = pl.pallas_call(
                 kern, grid=(bhq, seq_k // bkb), in_specs=in_specs,
                 out_specs=out_specs, out_shape=out_shape,
-                interpret=_interpret(),
+                interpret=_interpret(), name=BWD_KERNEL_NAME,
             )(*args)
     if group > 1:
         bkv = k.shape[0]

@@ -53,6 +53,11 @@ def _interpret() -> bool:
 # comfortably inside VMEM. Autotune overrides per shape signature.
 LN_ROWS = 512
 GELU_ROWS = 256
+# The gelu backward's Mosaic working set is ~24 B per block element (three
+# double-buffered 2-byte bands + fp32 temporaries: a [256, 6144] bf16 block
+# asks 38 MB when compiled for a v5e). Blocks are halved until they fit 3/4
+# of the 16 MiB scoped-VMEM default.
+_GELU_BLOCK_ELEMS = (12 << 20) // 24
 
 _K0 = 0.7978845608028654  # sqrt(2/pi)
 _A = 0.044715
@@ -71,9 +76,11 @@ def _sig(kind, rows, h, dtype, which) -> str:
 
 def _rows_for(kind, rows, h, dtype, which="fwd") -> int:
     hit = _atc.lookup(_sig(kind, rows, h, dtype, which))
-    if hit:
-        return _pick_rows(hit[0], rows)
-    return _pick_rows(LN_ROWS if kind == "ln" else GELU_ROWS, rows)
+    pref = hit[0] if hit else (LN_ROWS if kind == "ln" else GELU_ROWS)
+    if kind == "gelu":
+        while pref * h > _GELU_BLOCK_ELEMS and pref > 8:
+            pref //= 2
+    return _pick_rows(pref, rows)
 
 
 def _shape_ok(rows: int, h: int, dtype) -> bool:
@@ -158,6 +165,14 @@ def _vec_spec(h):
     return pl.BlockSpec((1, h), lambda i: (0, 0))
 
 
+def _part_spec(h):
+    """One [1, h] partial-sum row per program of a [nblk, 1, h] output: a
+    [1, h] block over [nblk, h] breaks Mosaic's (8, 128) rule on the
+    second-minor dim; with the unit dim the block's last two dims equal
+    the array's."""
+    return pl.BlockSpec((None, 1, h), lambda i: (i, 0, 0))
+
+
 def _stat_spec(br):
     """[1, rows] fp32 per-row statistics, one [1, br] band per program."""
     return pl.BlockSpec((1, br), lambda i: (0, i))
@@ -201,12 +216,11 @@ def _ln_bwd_impl(dy, dso, s, mean, rstd, g, x_dtype, eps):
                 + [_stat_spec(br), _stat_spec(br), _vec_spec(h)])
     args = ([dy, dso, s] if has_dso else [dy, s]) + [mean, rstd,
                                                      g.reshape(1, h)]
-    part_spec = pl.BlockSpec((1, h), lambda i: (i, 0))
-    out_specs = _row_specs(h, br, 1) + [part_spec, part_spec]
+    out_specs = _row_specs(h, br, 1) + [_part_spec(h), _part_spec(h)]
     out_shape = [
         jax.ShapeDtypeStruct((rows, h), x_dtype),
-        jax.ShapeDtypeStruct((nblk, h), jnp.float32),
-        jax.ShapeDtypeStruct((nblk, h), jnp.float32),
+        jax.ShapeDtypeStruct((nblk, 1, h), jnp.float32),
+        jax.ShapeDtypeStruct((nblk, 1, h), jnp.float32),
     ]
     kern = functools.partial(_ln_bwd_kernel, has_dso=has_dso)
     with _atc.x64_off():
@@ -214,7 +228,7 @@ def _ln_bwd_impl(dy, dso, s, mean, rstd, g, x_dtype, eps):
             kern, grid=grid, in_specs=in_specs, out_specs=out_specs,
             out_shape=out_shape, interpret=_interpret(),
         )(*args)
-    return dx, dg_part.sum(axis=0), db_part.sum(axis=0)
+    return dx, dg_part.sum(axis=(0, 1)), db_part.sum(axis=(0, 1))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -337,8 +351,8 @@ def _gelu_bwd_impl(dy, x, b):
     out_specs = _row_specs(h, br, 1)
     out_shape = [jax.ShapeDtypeStruct((rows, h), x.dtype)]
     if has_bias:
-        out_specs.append(pl.BlockSpec((1, h), lambda i: (i, 0)))
-        out_shape.append(jax.ShapeDtypeStruct((nblk, h), jnp.float32))
+        out_specs.append(_part_spec(h))
+        out_shape.append(jax.ShapeDtypeStruct((nblk, 1, h), jnp.float32))
     kern = functools.partial(_gelu_bwd_kernel, has_bias=has_bias)
     with _atc.x64_off():
         outs = pl.pallas_call(
@@ -347,7 +361,7 @@ def _gelu_bwd_impl(dy, x, b):
         )(*args)
     if has_bias:
         dx, db_part = outs
-        return dx, db_part.sum(axis=0)
+        return dx, db_part.sum(axis=(0, 1))
     return outs[0], None
 
 

@@ -392,7 +392,7 @@ def _fwd_impl(x2, weights, scales3d, group_offsets, k, bits, group_size):
     out_shape = jax.ShapeDtypeStruct((mp, n), jnp.float32)
     o_spec = pl.BlockSpec((bm, bn), lambda i, j, kk, g: (i, j))
     x_spec = pl.BlockSpec((bm, bk), lambda i, j, kk, g: (i, kk))
-    semantics = pltpu.TPUCompilerParams(
+    semantics = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))
     if bits == 0:
         grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -479,7 +479,7 @@ def _bwd_dx_impl(dy, weights, scales3d, group_offsets, k, bits, group_size,
     out_shape = jax.ShapeDtypeStruct((mp, k), jnp.float32)
     dx_spec = pl.BlockSpec((bm, bk), lambda i, kk, j, g: (i, kk))
     dy_spec = pl.BlockSpec((bm, bn), lambda i, kk, j, g: (i, j))
-    semantics = pltpu.TPUCompilerParams(
+    semantics = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))
     if bits == 0:
         grid_spec = pltpu.PrefetchScalarGridSpec(

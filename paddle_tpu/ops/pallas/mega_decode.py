@@ -228,8 +228,9 @@ def _mega_attn_kernel(ctx_ref, qlen_ref, pt_ref, *refs, page_size, scale,
     Stage schedule (all state VMEM-resident across the grid):
     - ``j == 0``: LN1 + this head's QKV column tiles -> q rows saved, the
       new K/V rows quantized inline (int8 KV) and emitted;
-    - every ``j``: one pool page through the online softmax (int8 pages
-      dequantize against their [page_size, 1] scale column on the way in);
+    - every ``j``: one pool page through the online softmax (int8 pages'
+      per-token scales fold into the dots' fp32 sides as [1, page_size]
+      rows — the ragged_paged_attention spelling);
     - ``j == last``: the lane's own new tokens as an in-register causal
       block, then this head's rows of the output GEMM accumulate into the
       cross-head ``yacc`` block;
@@ -311,9 +312,11 @@ def _mega_attn_kernel(ctx_ref, qlen_ref, pt_ref, *refs, page_size, scale,
         k = k_ref[...]
         v = v_ref[...]
         if kv_quant:
-            k = (k.astype(jnp.float32) * ks_ref[...]).astype(q.dtype)
-            v = (v.astype(jnp.float32) * vs_ref[...]).astype(q.dtype)
+            k = k.astype(q.dtype)
+            v = v.astype(q.dtype)
         s = _dotf32(q, k, ((1,), (1,))) * scale          # [C8, ps] f32
+        if kv_quant:
+            s = s * ks_ref[pl.ds(hh, 1), :]
         col = j * page_size + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 1)
         s = jnp.where(col < ctx, s, NEG_INF)
@@ -324,7 +327,8 @@ def _mega_attn_kernel(ctx_ref, qlen_ref, pt_ref, *refs, page_size, scale,
         p = jnp.exp(s - m_next)
         l_next = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
         l_safe = jnp.where(l_next == 0.0, 1.0, l_next)
-        pv = _dotf32(p.astype(v.dtype), v, ((1,), (0,)))
+        pw = p * vs_ref[pl.ds(hh, 1), :] if kv_quant else p
+        pv = _dotf32(pw.astype(v.dtype), v, ((1,), (0,)))
         o_ref[...] = ((o_ref[...] * (l_prev * alpha) + pv) / l_safe
                       ).astype(o_ref.dtype)
         m_ref[...] = m_next
@@ -430,7 +434,7 @@ def mega_attn_layer(xb, p, k_pages, v_pages, page_table, ctx_lens, q_lens,
             eps=eps, k_scales=k_scales, v_scales=v_scales,
             head_major=head_major, fuse_epilogue=fuse_epilogue)
     b, chunk, h = xb.shape
-    num_pages, page_size, hkv, hd = k_pages.shape
+    num_pages, hkv, page_size, hd = k_pages.shape
     # group-1 attention per shard: q heads == kv heads. The pool's head
     # axis is authoritative — under the mp mesh it carries this shard's
     # LOCAL heads while xb keeps the full (replicated) hidden width.
@@ -464,12 +468,14 @@ def mega_attn_layer(xb, p, k_pages, v_pages, page_table, ctx_lens, q_lens,
         page = pt_ref[bi, jnp.minimum(jnp.int32(j), last)]
         return jnp.clip(page, 0, num_pages - 1)
 
-    kv_spec = pl.BlockSpec((None, page_size, None, hd),
+    kv_spec = pl.BlockSpec((None, None, page_size, hd),
                            lambda bi, hh, j, *r: (kv_page(bi, hh, j, *r),
-                                                  0, hh, 0))
-    sc_spec = pl.BlockSpec((None, page_size, 1),
+                                                  hh, 0, 0))
+    # all local heads' scale rows of the page (the plane's full last two
+    # dims); the kernel slices its own head's [1, page_size] row
+    sc_spec = pl.BlockSpec((None, hkv, page_size),
                            lambda bi, hh, j, *r: (kv_page(bi, hh, j, *r),
-                                                  0, hh))
+                                                  0, 0))
     head_rows = pl.BlockSpec((None, c8, hd),
                              lambda bi, hh, j, *_: (bi, 0, 0))
 
@@ -549,7 +555,7 @@ def mega_attn_layer(xb, p, k_pages, v_pages, page_table, ctx_lens, q_lens,
     with _atc.x64_off():
         outs = pl.pallas_call(
             kern, grid_spec=grid_spec, out_shape=out_shape,
-            compiler_params=pltpu.TPUCompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary", "arbitrary")),
             interpret=_interpret(),
         )(ctx_lens.astype(jnp.int32), q_lens.astype(jnp.int32),
@@ -578,10 +584,11 @@ def mega_attn_layer_reference(xb, p, k_pages, v_pages, page_table,
     exact stage order — the numerical golden AND the non-TPU fallback.
     ``fuse_epilogue=False`` mirrors the kernel's mp spelling: the return
     is the pre-psum output-GEMM partial (no residual/bias/LN2)."""
+    from .paged_attention import gather_pages
     from .quant_matmul import dequantize_weight
 
     b, chunk, h = xb.shape
-    num_pages, page_size, hkv, hd = k_pages.shape
+    num_pages, hkv, page_size, hd = k_pages.shape
     nh = hkv   # pool head axis is authoritative (head-sharded under mp)
     kv_quant = k_scales is not None
     dtype = xb.dtype
@@ -621,13 +628,11 @@ def mega_attn_layer_reference(xb, p, k_pages, v_pages, page_table,
     # gathered context (dequantized when the pools are int8)
     pt = jnp.clip(page_table, 0, num_pages - 1)
     pps = page_table.shape[1]
-    kc = k_pages[pt].reshape(b, pps * page_size, hkv, hd)
-    vc = v_pages[pt].reshape(b, pps * page_size, hkv, hd)
+    kc = gather_pages(k_pages, pt)
+    vc = gather_pages(v_pages, pt)
     if kv_quant:
-        kc = (kc.astype(jnp.float32)
-              * k_scales[pt].reshape(b, pps * page_size, hkv)[..., None])
-        vc = (vc.astype(jnp.float32)
-              * v_scales[pt].reshape(b, pps * page_size, hkv)[..., None])
+        kc = kc.astype(jnp.float32) * gather_pages(k_scales, pt)[..., None]
+        vc = vc.astype(jnp.float32) * gather_pages(v_scales, pt)[..., None]
     kc, vc = kc.astype(jnp.float32), vc.astype(jnp.float32)
     scale = 1.0 / math.sqrt(hd)
     s_ctx = jnp.einsum("bcnd,bsnd->bncs", q, kc, precision=_MXU) * scale
@@ -832,7 +837,7 @@ def mega_mlp(y2, s_res, p, *, use_kernel=None, fuse_epilogue=True,
             kern, grid=(nf,), in_specs=in_specs,
             out_specs=pl.BlockSpec((t8, h), lambda i: (0, 0)),
             out_shape=jax.ShapeDtypeStruct((t8, h), jnp.float32),
-            compiler_params=pltpu.TPUCompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",)),
             interpret=_interpret(),
         )(*args)

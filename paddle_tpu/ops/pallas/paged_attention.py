@@ -2,10 +2,13 @@
 
 The serving-side sibling of ``flash_attention.py``: one query token per
 sequence attends over that sequence's K/V prefix, which lives in a POOL of
-fixed-size pages (``[num_pages, page_size, kv_heads, head_dim]``) indexed by
-a per-sequence page table — the vLLM/Ragged-Paged-Attention memory layout
-(arxiv 2604.15464) that lets a continuous-batching scheduler admit/evict
-sequences without copying or fragmenting the cache.
+fixed-size pages (``[num_pages, kv_heads, page_size, head_dim]`` — head-major
+inside a page, so one (page, head) block is a contiguous ``[page_size,
+head_dim]`` tile that satisfies Mosaic's (8, 128) rule on a block's last
+two dims) indexed by a per-sequence page table — the
+vLLM/Ragged-Paged-Attention memory layout (arxiv 2604.15464) that lets a
+continuous-batching scheduler admit/evict sequences without copying or
+fragmenting the cache.
 
 Kernel shape (TPU-idiomatic, following the flash kernel's conventions):
 
@@ -72,6 +75,11 @@ NEG_INF = -1e30
 _MXU = jax.lax.Precision.DEFAULT
 
 
+# stable pallas_call name (survives into the compiled HLO and the device
+# trace): how a check or a trace reduction finds the unified step's kernel
+RAGGED_KERNEL_NAME = "ragged_paged_attention"
+
+
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
@@ -128,7 +136,7 @@ def _kernel_impl(q4, k_pages, v_pages, page_table, lengths, scale):
     """q4: [b, kv_heads, G8, d] (group padded); returns [b, kv_heads, G8, d]
     fp32."""
     b, hkv, g8, d = q4.shape
-    num_pages, page_size = k_pages.shape[0], k_pages.shape[1]
+    num_pages, page_size = k_pages.shape[0], k_pages.shape[2]
     pps = page_table.shape[1]
     grid = (b, hkv, pps)
 
@@ -142,10 +150,10 @@ def _kernel_impl(q4, k_pages, v_pages, page_table, lengths, scale):
             jax.lax.div(lens_ref[bi] + ps - jnp.int32(1), ps) - jnp.int32(1),
             jnp.int32(0))
         page = pt_ref[bi, jnp.minimum(jnp.int32(j), last)]
-        return (jnp.clip(page, 0, num_pages - 1), 0, h, 0)
+        return (jnp.clip(page, 0, num_pages - 1), h, 0, 0)
 
     q_spec = pl.BlockSpec((None, None, g8, d), lambda bi, h, j, *_: (bi, h, 0, 0))
-    kv_spec = pl.BlockSpec((None, page_size, None, d), kv_imap)
+    kv_spec = pl.BlockSpec((None, None, page_size, d), kv_imap)
     o_spec = pl.BlockSpec((None, None, g8, d), lambda bi, h, j, *_: (bi, h, 0, 0))
     ml_spec = pl.BlockSpec((None, None, g8, 1), lambda bi, h, j, *_: (bi, h, 0, 0))
 
@@ -164,7 +172,7 @@ def _kernel_impl(q4, k_pages, v_pages, page_table, lengths, scale):
     with _atc.x64_off():
         out, _, _ = pl.pallas_call(
             kern, grid_spec=grid_spec, out_shape=out_shape,
-            compiler_params=pltpu.TPUCompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary", "arbitrary")),
             interpret=_interpret(),
         )(lengths.astype(jnp.int32), page_table.astype(jnp.int32),
@@ -177,6 +185,14 @@ def _kernel_impl(q4, k_pages, v_pages, page_table, lengths, scale):
 # ---------------------------------------------------------------------------
 
 
+def gather_pages(pool, pt):
+    """A page pool ``[num_pages, kv_heads, page_size, ...]`` gathered by the
+    (clipped) page table ``pt [b, pps]`` into each sequence's contiguous
+    token-major view ``[b, pps * page_size, kv_heads, ...]``."""
+    g = jnp.swapaxes(pool[pt], 2, 3)          # [b, pps, ps, hkv, ...]
+    return g.reshape(g.shape[0], -1, *g.shape[3:])
+
+
 def paged_attention_reference(q, k_pages, v_pages, page_table, lengths,
                               scale=None):
     """Gather the paged cache into a contiguous view and run masked decode
@@ -185,20 +201,19 @@ def paged_attention_reference(q, k_pages, v_pages, page_table, lengths,
     HBM). Numerically the oracle for the kernel; also the measured baseline
     ``bench_serve.py`` compares the kernel against.
 
-    q: [b, num_q_heads, d]; k/v_pages: [num_pages, page_size, kv_heads, d];
+    q: [b, num_q_heads, d]; k/v_pages: [num_pages, kv_heads, page_size, d];
     page_table: [b, pages_per_seq] int; lengths: [b] int (0 = empty slot).
     Returns [b, num_q_heads, d] in q's dtype.
     """
     b, hq, d = q.shape
-    num_pages, page_size, hkv, _ = k_pages.shape
+    num_pages, hkv, page_size, _ = k_pages.shape
     pps = page_table.shape[1]
     group = hq // hkv
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     pt = jnp.clip(page_table, 0, num_pages - 1)
-    # [b, pps, ps, hkv, d] -> [b, S, hkv, d]
-    k = k_pages[pt].reshape(b, pps * page_size, hkv, d)
-    v = v_pages[pt].reshape(b, pps * page_size, hkv, d)
+    k = gather_pages(k_pages, pt)
+    v = gather_pages(v_pages, pt)
     qg = q.reshape(b, hkv, group, d)
     s = jnp.einsum("bhgd,bshd->bhgs", qg.astype(jnp.float32),
                    k.astype(jnp.float32),
@@ -232,7 +247,7 @@ def paged_attention(q, k_pages, v_pages, page_table, lengths, scale=None,
     forces the reference. See :func:`paged_attention_reference` for shapes.
     """
     b, hq, d = q.shape
-    hkv = k_pages.shape[2]
+    hkv = k_pages.shape[1]
     assert hq % hkv == 0, f"GQA needs q heads {hq} divisible by kv {hkv}"
     assert k_pages.shape == v_pages.shape
     assert page_table.shape[0] == b and lengths.shape == (b,)
@@ -265,15 +280,18 @@ def _ragged_kernel(lens_ref, qlens_ref, pt_ref, q_ref, k_ref, v_ref,
                    *refs, page_size, group, scale, quant=False):
     """``quant=False``: refs = (o, m, l) and K/V tiles arrive in the
     compute dtype. ``quant=True`` (round-10 int8 KV): refs = (ks, vs, o,
-    m, l) — the page tiles arrive int8 with their per-(slot, head) scale
-    columns ([page_size, 1] blocks of the scale plane) and dequantize in
-    VMEM on the way into the two dots; the online-softmax recurrence is
-    IDENTICAL (one body, so the paths cannot drift)."""
+    m, l) — the page tiles arrive int8 with the page's scale rows
+    ([kv_heads, page_size] blocks of the scale plane). The int8 values are
+    exact in the compute dtype, so the per-token scales fold into the two
+    dots' fp32 sides as [1, page_size] lane vectors: ``(q . kq) * ks`` and
+    ``(p * vs) . vq``; the online-softmax recurrence is IDENTICAL (one
+    body, so the paths cannot drift)."""
     if quant:
         ks_ref, vs_ref, o_ref, m_ref, l_ref = refs
     else:
         o_ref, m_ref, l_ref = refs
     b = pl.program_id(0)
+    h = pl.program_id(1)
     j = pl.program_id(2)
     kv_len = lens_ref[b]     # context INCLUDING this chunk's tokens
     q_len = qlens_ref[b]     # valid query tokens this step (0 = idle lane)
@@ -290,9 +308,11 @@ def _ragged_kernel(lens_ref, qlens_ref, pt_ref, q_ref, k_ref, v_ref,
         k = k_ref[...]           # [page_size, d]
         v = v_ref[...]
         if quant:
-            k = (k.astype(jnp.float32) * ks_ref[...]).astype(q.dtype)
-            v = (v.astype(jnp.float32) * vs_ref[...]).astype(q.dtype)
+            k = k.astype(q.dtype)
+            v = v.astype(q.dtype)
         s = _dotf32(q, k, ((1,), (1,))) * scale          # [R, ps] f32
+        if quant:
+            s = s * ks_ref[pl.ds(h, 1), :]
         col = j * page_size + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 1)
         # row r serves query token r // group: it may attend every key up
@@ -307,7 +327,8 @@ def _ragged_kernel(lens_ref, qlens_ref, pt_ref, q_ref, k_ref, v_ref,
         p = jnp.exp(s - m_next)
         l_next = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
         l_safe = jnp.where(l_next == 0.0, 1.0, l_next)
-        pv = _dotf32(p.astype(v.dtype), v, ((1,), (0,)))  # [R, d]
+        pw = p * vs_ref[pl.ds(h, 1), :] if quant else p
+        pv = _dotf32(pw.astype(v.dtype), v, ((1,), (0,)))  # [R, d]
         o_ref[...] = ((o_ref[...] * (l_prev * alpha) + pv) / l_safe
                       ).astype(o_ref.dtype)
         m_ref[...] = m_next
@@ -318,9 +339,9 @@ def _ragged_kernel_impl(q4, k_pages, v_pages, page_table, kv_lens, q_lens,
                         group, scale, k_scales=None, v_scales=None):
     """q4: [b, kv_heads, R, d] with R = chunk*group padded to the sublane
     tile; returns [b, kv_heads, R, d] fp32. ``k_scales``/``v_scales``
-    ([num_pages, page_size, kv_heads] or None) flip the int8-KV kernel."""
+    ([num_pages, kv_heads, page_size] or None) flip the int8-KV kernel."""
     b, hkv, r8, d = q4.shape
-    num_pages, page_size = k_pages.shape[0], k_pages.shape[1]
+    num_pages, page_size = k_pages.shape[0], k_pages.shape[2]
     pps = page_table.shape[1]
     grid = (b, hkv, pps)
     quant = k_scales is not None
@@ -336,14 +357,17 @@ def _ragged_kernel_impl(q4, k_pages, v_pages, page_table, kv_lens, q_lens,
         return jnp.clip(page, 0, num_pages - 1)
 
     def kv_imap(bi, h, j, *refs):
-        return (kv_page(bi, h, j, *refs), 0, h, 0)
+        return (kv_page(bi, h, j, *refs), h, 0, 0)
 
     def scale_imap(bi, h, j, *refs):
-        return (kv_page(bi, h, j, *refs), 0, h)
+        return (kv_page(bi, h, j, *refs), 0, 0)
 
     q_spec = pl.BlockSpec((None, None, r8, d), lambda bi, h, j, *_: (bi, h, 0, 0))
-    kv_spec = pl.BlockSpec((None, page_size, None, d), kv_imap)
-    sc_spec = pl.BlockSpec((None, page_size, 1), scale_imap)
+    kv_spec = pl.BlockSpec((None, None, page_size, d), kv_imap)
+    # a page's scales for ALL local heads ([kv_heads, page_size] — the
+    # plane's full last two dims, which is what the (8, 128) block rule
+    # admits); the kernel slices its own head's row
+    sc_spec = pl.BlockSpec((None, hkv, page_size), scale_imap)
     o_spec = pl.BlockSpec((None, None, r8, d), lambda bi, h, j, *_: (bi, h, 0, 0))
     ml_spec = pl.BlockSpec((None, None, r8, 1), lambda bi, h, j, *_: (bi, h, 0, 0))
 
@@ -368,9 +392,9 @@ def _ragged_kernel_impl(q4, k_pages, v_pages, page_table, kv_lens, q_lens,
     with _atc.x64_off():
         out, _, _ = pl.pallas_call(
             kern, grid_spec=grid_spec, out_shape=out_shape,
-            compiler_params=pltpu.TPUCompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary", "arbitrary")),
-            interpret=_interpret(),
+            interpret=_interpret(), name=RAGGED_KERNEL_NAME,
         )(kv_lens.astype(jnp.int32), q_lens.astype(jnp.int32),
           page_table.astype(jnp.int32), *args)
     return out
@@ -386,23 +410,21 @@ def ragged_paged_attention_reference(q, k_pages, v_pages, page_table,
     rows (0 = idle lane — its output rows are zero). Query token t of slot
     b sits at absolute position ``kv_lens[b] - q_lens[b] + t`` and attends
     all keys at positions <= its own. With ``k_scales``/``v_scales``
-    ([num_pages, page_size, kv_heads]) the pages are int8 and dequantize
+    ([num_pages, kv_heads, page_size]) the pages are int8 and dequantize
     after the gather. Returns [b, chunk, num_q_heads, d].
     """
     b, c, hq, d = q.shape
-    num_pages, page_size, hkv, _ = k_pages.shape
+    num_pages, hkv, page_size, _ = k_pages.shape
     pps = page_table.shape[1]
     group = hq // hkv
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     pt = jnp.clip(page_table, 0, num_pages - 1)
-    k = k_pages[pt].reshape(b, pps * page_size, hkv, d)
-    v = v_pages[pt].reshape(b, pps * page_size, hkv, d)
+    k = gather_pages(k_pages, pt)
+    v = gather_pages(v_pages, pt)
     if k_scales is not None:
-        k = (k.astype(jnp.float32)
-             * k_scales[pt].reshape(b, pps * page_size, hkv)[..., None])
-        v = (v.astype(jnp.float32)
-             * v_scales[pt].reshape(b, pps * page_size, hkv)[..., None])
+        k = k.astype(jnp.float32) * gather_pages(k_scales, pt)[..., None]
+        v = v.astype(jnp.float32) * gather_pages(v_scales, pt)[..., None]
     qg = q.reshape(b, c, hkv, group, d)
     s = jnp.einsum("bchgd,bshd->bhgcs", qg.astype(jnp.float32),
                    k.astype(jnp.float32), precision=_MXU) * scale
@@ -432,13 +454,13 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, kv_lens, q_lens,
     chunk's K/V must already be written to the pages). ``use_kernel`` as in
     :func:`paged_attention`. Rows past ``q_lens`` are garbage the caller
     must ignore (their page writes drop; the reference zeroes them).
-    ``k_scales``/``v_scales`` ([num_pages, page_size, kv_heads]) mark the
+    ``k_scales``/``v_scales`` ([num_pages, kv_heads, page_size]) mark the
     pools int8 (round-10 quantized KV); dequantization fuses into the
     kernel's page loop (or the gathered reference) — pages stay int8
     end-to-end in HBM.
     """
     b, c, hq, d = q.shape
-    hkv = k_pages.shape[2]
+    hkv = k_pages.shape[1]
     assert hq % hkv == 0, f"GQA needs q heads {hq} divisible by kv {hkv}"
     assert k_pages.shape == v_pages.shape
     assert page_table.shape[0] == b
@@ -510,8 +532,8 @@ def autotune_page_size(batch, hq, hkv, d, max_len=2048, dtype=jnp.bfloat16,
     for ps in candidates:
         pps = (max_len + ps - 1) // ps
         num_pages = batch * pps + 1
-        kp = jax.random.normal(kk, (num_pages, ps, hkv, d), dtype)
-        vp = jax.random.normal(kv, (num_pages, ps, hkv, d), dtype)
+        kp = jax.random.normal(kk, (num_pages, hkv, ps, d), dtype)
+        vp = jax.random.normal(kv, (num_pages, hkv, ps, d), dtype)
         pt = jnp.arange(batch * pps, dtype=jnp.int32).reshape(batch, pps)
         lens = jnp.full((batch,), max_len, jnp.int32)
         try:
@@ -571,8 +593,8 @@ def autotune_chunk_size(batch, hq, hkv, d, max_len=2048, page_size=None,
     pps = (max_len + ps - 1) // ps
     num_pages = batch * pps + 1
     kq, kk, kv = jax.random.split(jax.random.PRNGKey(0), 3)
-    kp = jax.random.normal(kk, (num_pages, ps, hkv, d), dtype)
-    vp = jax.random.normal(kv, (num_pages, ps, hkv, d), dtype)
+    kp = jax.random.normal(kk, (num_pages, hkv, ps, d), dtype)
+    vp = jax.random.normal(kv, (num_pages, hkv, ps, d), dtype)
     pt = jnp.arange(batch * pps, dtype=jnp.int32).reshape(batch, pps)
     best, best_t = None, float("inf")
     for chunk in candidates:

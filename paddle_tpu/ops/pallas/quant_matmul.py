@@ -350,7 +350,7 @@ def _fwd_impl(x2, qweight, scales2d):
                     s_lo,
                 ],
                 out_specs=o_spec, out_shape=out_shape,
-                compiler_params=pltpu.TPUCompilerParams(
+                compiler_params=pltpu.CompilerParams(
                     dimension_semantics=("parallel", "parallel",
                                          "arbitrary")),
                 interpret=_interpret(),
@@ -372,7 +372,7 @@ def _fwd_impl(x2, qweight, scales2d):
                 s_lo, s_hi,
             ],
             out_specs=o_spec, out_shape=out_shape,
-            compiler_params=pltpu.TPUCompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=_interpret(),
         )(x2, x2, qweight, scales2d, scales2d)
@@ -401,7 +401,7 @@ def _bwd_impl(dy, qweight, scales2d, k, x_dtype):
                 ],
                 out_specs=pl.BlockSpec((bm, bk), lambda i, kk, j: (i, kk)),
                 out_shape=jax.ShapeDtypeStruct((m, k), jnp.float32),
-                compiler_params=pltpu.TPUCompilerParams(
+                compiler_params=pltpu.CompilerParams(
                     dimension_semantics=("parallel", "parallel",
                                          "arbitrary")),
                 interpret=_interpret(),
@@ -422,7 +422,7 @@ def _bwd_impl(dy, qweight, scales2d, k, x_dtype):
             ],
             out_specs=[half_spec, half_spec],
             out_shape=[jax.ShapeDtypeStruct((m, k2), jnp.float32)] * 2,
-            compiler_params=pltpu.TPUCompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=_interpret(),
         )(dyc, qweight, scales2d, scales2d)

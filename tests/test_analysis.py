@@ -1034,8 +1034,8 @@ class TestCostModel:
         L, h, t = self.L, self.H, self.T
         emb = jnp.ones((16, h), jnp.float32)
         stack = jnp.ones((L, h, h), jnp.float32)
-        k_pages = jnp.ones((L, 3, 4, 2, 4), jnp.float32)  # heads*hd == h
-        v_pages = jnp.ones((L, 3, 4, 2, 4), jnp.float32)
+        k_pages = jnp.ones((L, 3, 2, 4, 4), jnp.float32)  # heads*hd == h
+        v_pages = jnp.ones((L, 3, 2, 4, 4), jnp.float32)
 
         def step(emb, stack, k_pages, v_pages):
             def body(c, w):

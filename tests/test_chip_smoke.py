@@ -1,0 +1,71 @@
+"""The guards around a chip run: ``chip_smoke.py`` refuses anything but a
+TPU, the compile cache sits at one fixed place, and a peak is never made
+up for a device the table does not know."""
+import os
+import subprocess
+import sys
+
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _run(args, cwd=REPO, **env):
+    full_env = {k: v for k, v in os.environ.items()
+                if k != "JAX_COMPILATION_CACHE_DIR"}
+    full_env.update(env)
+    return subprocess.run([sys.executable, *args], cwd=cwd, env=full_env,
+                          stdout=subprocess.PIPE, stderr=subprocess.PIPE,
+                          text=True, timeout=300)
+
+
+def test_chip_smoke_refuses_the_cpu():
+    proc = _run(["chip_smoke.py"], JAX_PLATFORMS="cpu")
+    assert proc.returncode != 0
+    assert "'cpu'" in proc.stderr, proc.stderr[-2000:]
+    # no result line: nothing on stdout claims ok
+    assert '"ok"' not in proc.stdout
+
+
+_PROBE = ("import jax; "
+          "from paddle_tpu.framework.compile_cache import "
+          "configure_compile_cache as c; "
+          "print(c()); print(jax.config.jax_compilation_cache_dir)")
+
+
+def test_compile_cache_is_fixed_inside_the_checkout(tmp_path):
+    """Unset: the same in-checkout, git-ignored directory from two working
+    directories (two processes)."""
+    outs = []
+    for cwd in (REPO, str(tmp_path)):
+        proc = _run(["-c", _PROBE], cwd=cwd, PYTHONPATH=REPO,
+                    JAX_PLATFORMS="cpu")
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        outs.append(proc.stdout.split())
+    want = os.path.join(REPO, ".jax_cache")
+    assert outs == [[want, want], [want, want]]
+    with open(os.path.join(REPO, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split(), (
+            ".jax_cache/ is not git-ignored")
+
+
+def test_compile_cache_placed_from_outside_is_left_alone(tmp_path):
+    """``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it, code sets nothing."""
+    placed = str(tmp_path / "cache")
+    proc = _run(["-c", _PROBE], PYTHONPATH=REPO, JAX_PLATFORMS="cpu",
+                JAX_COMPILATION_CACHE_DIR=placed)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert proc.stdout.split() == [placed, placed]
+
+
+def test_chip_peak_raises_on_an_unknown_device():
+    sys.path.insert(0, REPO)
+    try:
+        import bench
+    finally:
+        sys.path.remove(REPO)
+    assert bench._chip_peak("TPU v5 lite") == ("TPU v5 lite", 197e12)
+    with pytest.raises(ValueError, match="no peak FLOP/s known"):
+        bench._chip_peak("TPU v9 imaginary")
+    with pytest.raises(ValueError, match="no peak FLOP/s known"):
+        bench._chip_peak("cpu")

@@ -49,16 +49,16 @@ def _layer(rng, h=H, f=F, quant=None, group=-1, head_major=False):
 def _pools(rng, num_pages, nh, kv_quant):
     if kv_quant:
         kq = jnp.asarray(rng.randint(-127, 128,
-                                     (num_pages, PAGE, nh, HD)), jnp.int8)
+                                     (num_pages, nh, PAGE, HD)), jnp.int8)
         vq = jnp.asarray(rng.randint(-127, 128,
-                                     (num_pages, PAGE, nh, HD)), jnp.int8)
-        ks = jnp.asarray(np.abs(rng.randn(num_pages, PAGE, nh)) * 0.01
+                                     (num_pages, nh, PAGE, HD)), jnp.int8)
+        ks = jnp.asarray(np.abs(rng.randn(num_pages, nh, PAGE)) * 0.01
                          + 1e-3, jnp.float32)
-        vs = jnp.asarray(np.abs(rng.randn(num_pages, PAGE, nh)) * 0.01
+        vs = jnp.asarray(np.abs(rng.randn(num_pages, nh, PAGE)) * 0.01
                          + 1e-3, jnp.float32)
         return kq, vq, ks, vs
-    kq = jnp.asarray(rng.randn(num_pages, PAGE, nh, HD), jnp.float32)
-    vq = jnp.asarray(rng.randn(num_pages, PAGE, nh, HD), jnp.float32)
+    kq = jnp.asarray(rng.randn(num_pages, nh, PAGE, HD), jnp.float32)
+    vq = jnp.asarray(rng.randn(num_pages, nh, PAGE, HD), jnp.float32)
     return kq, vq, None, None
 
 

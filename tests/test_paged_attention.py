@@ -19,8 +19,8 @@ def _case(rng, b, hq, hkv, d, page_size, pps, dtype=jnp.float32,
         return jnp.asarray(rng.randn(*shape) * 0.5, dtype)
 
     q = t(b, hq, d)
-    kp = t(num_pages, page_size, hkv, d)
-    vp = t(num_pages, page_size, hkv, d)
+    kp = t(num_pages, hkv, page_size, d)
+    vp = t(num_pages, hkv, page_size, d)
     # non-trivial page table: a random permutation of the pool, so a bug
     # that reads pages in pool order (ignoring the table) cannot pass
     pt = jnp.asarray(rng.permutation(num_pages)[:b * pps].reshape(b, pps),
@@ -101,8 +101,9 @@ def test_reference_matches_dense_attention(rng):
     for bi in range(b):
         L = int(lens_np[bi])
         pages = np.asarray(pt)[bi]
-        k_lin = np.asarray(kp)[pages].reshape(-1, hkv, d)[:L]
-        v_lin = np.asarray(vp)[pages].reshape(-1, hkv, d)[:L]
+        # pool pages are head-major [hkv, page_size, d]
+        k_lin = np.asarray(kp)[pages].swapaxes(1, 2).reshape(-1, hkv, d)[:L]
+        v_lin = np.asarray(vp)[pages].swapaxes(1, 2).reshape(-1, hkv, d)[:L]
         for h in range(hq):
             kv_h = h // group
             s = (k_lin[:, kv_h] @ np.asarray(q)[bi, h]) / math.sqrt(d)
@@ -143,8 +144,8 @@ def _ragged_case(rng, b, c, hq, hkv, d, page_size, pps, dtype=jnp.float32):
         return jnp.asarray(rng.randn(*shape) * 0.5, dtype)
 
     q = t(b, c, hq, d)
-    kp = t(num_pages, page_size, hkv, d)
-    vp = t(num_pages, page_size, hkv, d)
+    kp = t(num_pages, hkv, page_size, d)
+    vp = t(num_pages, hkv, page_size, d)
     pt = jnp.asarray(rng.permutation(num_pages)[:b * pps].reshape(b, pps),
                      jnp.int32)
     return q, kp, vp, pt
@@ -352,16 +353,16 @@ def test_paged_write_packed_quant_roundtrip(rng):
     from paddle_tpu.inference.kv_cache import paged_write_packed_quant
 
     num_pages, page_size, h, d = 4, 4, 2, 8
-    pages = jnp.zeros((num_pages, page_size, h, d), jnp.int8)
-    scales = jnp.zeros((num_pages, page_size, h), jnp.float32)
+    pages = jnp.zeros((num_pages, h, page_size, d), jnp.int8)
+    scales = jnp.zeros((num_pages, h, page_size), jnp.float32)
     pt = jnp.asarray([[0, 2], [3, -1]], jnp.int32)
     toks = jnp.asarray(rng.randn(3, h, d), jnp.float32)
     tok_slot = jnp.asarray([0, 0, -1], jnp.int32)   # last = padding
     tok_pos = jnp.asarray([1, 5, 0], jnp.int32)     # page 0 row 1, page 2 row 1
     pages, scales = paged_write_packed_quant(pages, scales, toks, pt,
                                              tok_slot, tok_pos, page_size)
-    got0 = np.asarray(pages)[0, 1] * np.asarray(scales)[0, 1][:, None]
-    got1 = np.asarray(pages)[2, 1] * np.asarray(scales)[2, 1][:, None]
+    got0 = np.asarray(pages)[0, :, 1] * np.asarray(scales)[0, :, 1][:, None]
+    got1 = np.asarray(pages)[2, :, 1] * np.asarray(scales)[2, :, 1][:, None]
     for got, want in ((got0, np.asarray(toks)[0]),
                       (got1, np.asarray(toks)[1])):
         bound = np.abs(want).max(-1, keepdims=True) / 127 + 1e-6

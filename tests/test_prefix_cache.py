@@ -315,15 +315,15 @@ def _fill(m, tokens, seed=0):
     for i in range(0, n, m.page_size):
         pg = int(m._page_table[slot, i // m.page_size])
         t = min(m.page_size, n - i)
-        m.k_pages = m.k_pages.at[:, pg, :t].set(
-            jnp.asarray(k[:, i:i + t], m.k_pages.dtype))
-        m.v_pages = m.v_pages.at[:, pg, :t].set(
-            jnp.asarray(v[:, i:i + t], m.v_pages.dtype))
+        m.k_pages = m.k_pages.at[:, pg, :, :t].set(
+            jnp.asarray(k[:, i:i + t], m.k_pages.dtype).swapaxes(1, 2))
+        m.v_pages = m.v_pages.at[:, pg, :, :t].set(
+            jnp.asarray(v[:, i:i + t], m.v_pages.dtype).swapaxes(1, 2))
         if m.quantize_kv:
-            m.k_scales = m.k_scales.at[:, pg, :t].set(
-                jnp.asarray(ks[:, i:i + t]))
-            m.v_scales = m.v_scales.at[:, pg, :t].set(
-                jnp.asarray(vs[:, i:i + t]))
+            m.k_scales = m.k_scales.at[:, pg, :, :t].set(
+                jnp.asarray(ks[:, i:i + t]).swapaxes(1, 2))
+            m.v_scales = m.v_scales.at[:, pg, :, :t].set(
+                jnp.asarray(vs[:, i:i + t]).swapaxes(1, 2))
     m._seq_lens[slot] = n
     m.register_prefix(slot, list(tokens))
     m.free(slot)

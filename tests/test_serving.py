@@ -971,7 +971,7 @@ def test_int8_kv_cache_pools_are_int8(rng):
             sp.step()
         assert sp.cache.k_pages.dtype == jnp.int8
         assert sp.cache.v_pages.dtype == jnp.int8
-        assert sp.cache.k_scales.shape == (2, sp.cache.num_pages, 8, 4)
+        assert sp.cache.k_scales.shape == (2, sp.cache.num_pages, 4, 8)
         assert len(r.output_ids) == 3
     finally:
         model.config.kv_cache_dtype = None

@@ -1,0 +1,193 @@
+"""Lower for the TPU, on the CPU.
+
+Interpret mode has no tiling rule, so a kernel that has only ever run here
+can be one Mosaic refuses (PR 21 found three). ``jax.jit(f).trace(*avals)
+.lower(lowering_platforms=("tpu",))`` reaches Pallas's Mosaic lowering
+checks without a chip: every ``pallas_call`` the two ``chip_smoke.py``
+phases reach, plus ``quant_matmul`` / ``grouped_matmul`` / ``mega_mlp`` /
+``fused_mlp``, is lowered at the smoke's real widths (GPT-3 760M: hidden
+1536, 12 heads of 128, ffn 6144; the serving defaults of 8 lanes, chunk 16,
+64-token pages) from abstract inputs — nothing is allocated. A lowering
+that passes is not a compile that passes (the fast-memory limit and
+Mosaic's layout inference are only met by the chip's compiler); this is the
+gate that stops an interpret-only kernel from reaching the chip again.
+"""
+import functools
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+H, HEADS, HD, FFN = 1536, 12, 128, 6144
+LANES, CHUNK, PAGE, PAGES_PER_SLOT = 8, 16, 64, 17
+POOL = LANES * PAGES_PER_SLOT
+BUDGET = LANES + CHUNK
+BF16 = jnp.bfloat16
+
+
+def sds(*shape, dtype=BF16):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+@pytest.fixture(autouse=True)
+def _as_on_tpu(monkeypatch):
+    """The kernels pick interpret mode and ``use_kernel=None`` from
+    ``jax.default_backend()``: answer as the chip would."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def mosaic_calls(fn, *avals) -> int:
+    """Lower ``fn`` for the TPU in the hot paths' 32-bit mode; the number
+    of Mosaic custom calls in the result."""
+    with jax.enable_x64(False):
+        lowered = jax.jit(fn).trace(*avals).lower(
+            lowering_platforms=("tpu",))
+    return lowered.as_text().count("tpu_custom_call")
+
+
+# -- the serving phase: ragged paged attention ------------------------------
+
+
+@pytest.mark.parametrize("local_heads", [HEADS, HEADS // 4],
+                         ids=["one-chip", "mesh4-shard"])
+@pytest.mark.parametrize("kv", ["fp", "int8"])
+def test_ragged_paged_attention_lowers(kv, local_heads):
+    from paddle_tpu.ops.pallas.paged_attention import ragged_paged_attention
+
+    q = sds(LANES, CHUNK, local_heads, HD)
+    table = sds(LANES, PAGES_PER_SLOT, dtype=jnp.int32)
+    lens = sds(LANES, dtype=jnp.int32)
+    if kv == "fp":
+        pool = sds(POOL, local_heads, PAGE, HD)
+        n = mosaic_calls(ragged_paged_attention, q, pool, pool, table,
+                         lens, lens)
+    else:
+        pool = sds(POOL, local_heads, PAGE, HD, dtype=jnp.int8)
+        scales = sds(POOL, local_heads, PAGE, dtype=jnp.float32)
+
+        def fn(q, k, v, table, kv_lens, q_lens, ks, vs):
+            return ragged_paged_attention(q, k, v, table, kv_lens, q_lens,
+                                          k_scales=ks, v_scales=vs)
+
+        n = mosaic_calls(fn, q, pool, pool, table, lens, lens, scales,
+                         scales)
+    assert n == 1
+
+
+def test_paged_decode_attention_lowers():
+    from paddle_tpu.ops.pallas.paged_attention import paged_attention
+
+    pool = sds(POOL, HEADS, PAGE, HD)
+    n = mosaic_calls(paged_attention, sds(LANES, HEADS, HD), pool, pool,
+                     sds(LANES, PAGES_PER_SLOT, dtype=jnp.int32),
+                     sds(LANES, dtype=jnp.int32))
+    assert n == 1
+
+
+# -- the training phase: flash attention forward + backward -----------------
+
+
+def test_flash_attention_fwd_bwd_lowers():
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True).astype(
+            jnp.float32).sum()
+
+    qkv = sds(8, 1024, HEADS, HD)
+    n = mosaic_calls(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                     qkv, qkv, qkv)
+    assert n == 2
+
+
+# -- kernels behind config flags the smoke leaves off -----------------------
+
+
+@pytest.mark.parametrize("k,n", [(H, 3 * H), (H, H), (H, FFN), (FFN, H)])
+def test_quant_matmul_int8_lowers(k, n):
+    from paddle_tpu.ops.pallas.quant_matmul import quant_matmul
+
+    calls = mosaic_calls(quant_matmul, sds(BUDGET, k),
+                         sds(k, n, dtype=jnp.int8),
+                         sds(n, dtype=jnp.float32))
+    assert calls == 1
+
+
+def test_grouped_matmul_lowers():
+    from paddle_tpu.ops.pallas.grouped_matmul import grouped_matmul
+
+    experts, rows = 4, 256
+    x = sds(rows, H)
+    offsets = sds(experts + 1, dtype=jnp.int32)
+
+    def loss(x, w, offsets):
+        return grouped_matmul(x, w, offsets).astype(jnp.float32).sum()
+
+    assert mosaic_calls(jax.value_and_grad(loss, argnums=(0, 1)), x,
+                        sds(experts, H, FFN), offsets) >= 2
+
+    def int8(x, w, offsets, scales):
+        return grouped_matmul(x, w, offsets, scales=scales)
+
+    assert mosaic_calls(int8, x, sds(experts, H, FFN, dtype=jnp.int8),
+                        offsets,
+                        sds(experts, FFN, dtype=jnp.float32)) == 1
+
+
+def _layer_weights():
+    return {"ln1_g": sds(H), "ln1_b": sds(H), "ln2_g": sds(H),
+            "ln2_b": sds(H), "wqkv": sds(H, 3 * H), "bqkv": sds(3 * H),
+            "wo": sds(H, H), "bo": sds(H), "w1": sds(H, FFN),
+            "b1": sds(FFN), "w2": sds(FFN, H), "b2": sds(H)}
+
+
+def test_mega_mlp_lowers():
+    from paddle_tpu.ops.pallas.mega_decode import mega_mlp
+
+    rows = sds(LANES * CHUNK, H)
+    assert mosaic_calls(functools.partial(mega_mlp, chunk=CHUNK), rows,
+                        rows, _layer_weights()) == 1
+
+
+@pytest.mark.xfail(
+    strict=True, raises=ValueError,
+    reason="mega_attn_layer does not lower: 'The Pallas TPU lowering "
+           "currently requires that the last two dimensions of your block "
+           "shape are divisible by 8 and 128 respectively, or be equal to "
+           "the respective dimensions of the overall array. Block spec for "
+           "args[8] in pallas_call _mega_attn_kernel ... has block shape "
+           "(Blocked(1536), Squeezed(), Squeezed(), Blocked(128)), array "
+           "shape (1536, 3, 12, 128)' — the per-head wqkv view squeezes "
+           "the second-minor dim (ROADMAP D3)")
+def test_mega_attn_layer_lowers():
+    from paddle_tpu.ops.pallas.mega_decode import mega_attn_layer
+
+    pool = sds(POOL, HEADS, PAGE, HD)
+    lens = sds(LANES, dtype=jnp.int32)
+    mosaic_calls(mega_attn_layer, sds(LANES, CHUNK, H), _layer_weights(),
+                 pool, pool, sds(LANES, PAGES_PER_SLOT, dtype=jnp.int32),
+                 lens, lens)
+
+
+def test_fused_mlp_fwd_bwd_lowers():
+    """``bench.py --fused-mlp``'s kernels at the train step's shapes (the
+    gelu backward's row block is sized by the fast-memory budget)."""
+    from paddle_tpu.ops.pallas import fused_mlp as fm
+
+    def ln(x, res, g, b):
+        y, s = fm.fused_ln_residual(x, res, g, b, eps=1e-5,
+                                    use_kernel=True)
+        return (y.astype(jnp.float32).sum()
+                + s.astype(jnp.float32).sum())
+
+    x = sds(8, 1024, H)
+    assert mosaic_calls(jax.grad(ln, argnums=(0, 1, 2, 3)), x, x, sds(H),
+                        sds(H)) == 2
+
+    def gelu(u, b):
+        return fm.fused_bias_gelu(u, b, use_kernel=True).astype(
+            jnp.float32).sum()
+
+    assert mosaic_calls(jax.value_and_grad(gelu, argnums=(0, 1)),
+                        sds(8, 1024, FFN), sds(FFN)) == 2
