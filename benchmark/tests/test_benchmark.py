@@ -1,0 +1,431 @@
+"""The benchmark's own checks: CPU, tiny widths, a few seconds in all.
+
+No test starts a chip run. Run them with
+
+    JAX_PLATFORMS=cpu python -m pytest benchmark/tests -q
+"""
+from __future__ import annotations
+
+import contextlib
+import json
+import os
+import re
+import subprocess
+import sys
+
+import pytest
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+BENCH = os.path.dirname(HERE)
+ROOT = os.path.dirname(BENCH)
+sys.path.insert(0, ROOT)
+
+from benchmark import reduce_trace, run, stats  # noqa: E402
+from benchmark.generators import closed_loop  # noqa: E402
+from benchmark.kernels import flash_attention, ragged_paged_attention  # noqa: E402
+from benchmark.kernels.roofline import least_seconds  # noqa: E402
+
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+
+TINY = {
+    "n_embd": 128, "n_layer": 2, "n_head": 2, "n_inner": 512,
+    "n_positions": 128, "vocab_size": 500, "layer_norm_epsilon": 1e-5,
+    "initializer_range": 0.02, "dtype": "float32",
+    "assumed": {"padded_vocab_size": 512},
+    "serve": {"max_batch": 4, "max_seq_len": 128, "page_size": 16,
+              "num_pages": 32, "token_budget": 64, "chunk": 16},
+    "train": {"batch_size": 2, "seq_len": 128, "num_micro": 1,
+              "recompute": True, "flash_attention": True,
+              "state_dtype": "float32"},
+}
+TINY_CHAT = {
+    "clients": 4, "schedule_seed": 7,
+    "prompt": {"dist": "lognormal", "median": 24, "sigma": 0.5,
+               "min": 8, "max": 60},
+    "answer": {"dist": "lognormal", "median": 10, "sigma": 0.4,
+               "min": 4, "max": 20},
+    "first_round_answer": {"dist": "uniform", "min": 2, "max": 8},
+    "fill_requests": 4, "trace_seconds": 1,
+}
+
+
+class FakeClock:
+    """Advances by a fixed tick per reading: time passes only as the loop
+    reads it, so a run is a function of its inputs alone."""
+
+    def __init__(self, tick=0.01):
+        self.now, self.tick = 0.0, tick
+
+    def __call__(self):
+        self.now += self.tick
+        return self.now
+
+
+class FakeContext:
+    def __init__(self, config, traffic, *, seed=3, seconds=0.5, clock=None):
+        import time
+
+        self.config, self.traffic = config, traffic
+        self.chips, self.seed, self.seconds = 1, seed, seconds
+        self.generator = run.load_module("generators", traffic["generator"])
+        self.clock = clock or time.perf_counter
+        self.capture = None
+        self.span = lambda name: contextlib.nullcontext()
+        self.phases = {}
+
+    def mark(self, phase):
+        self.phases[phase] = True
+
+    def window_opens(self):
+        return self.clock()
+
+    def window_closes(self, at=None):
+        return self.clock() if at is None else at
+
+
+# ---- the manifest and the files it names ---------------------------------
+
+def manifest():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        return json.load(f)
+
+
+def test_manifest_parses_and_every_file_is_found_by_name():
+    m = manifest()
+    assert set(m) == {"command", "paths", "run_seconds", "configs",
+                      "workloads", "end_to_end", "per_layer"}
+    for cell in m["workloads"]:
+        loaded = run.load_cell(cell["name"], m)
+        traffic = loaded["traffic"]
+        assert run.load_module("drivers", traffic["driver"]).run
+        gen = run.load_module("generators", traffic["generator"])
+        assert gen.KIND == traffic["driver"]
+        assert traffic["driver"] in loaded["config"], cell["name"]
+        names = {x["name"] for x in loaded["end_to_end"]}
+        assert "setup_s" in names and len(names) >= 2, cell["name"]
+        assert loaded["per_layer"], cell["name"]
+        for metric in loaded["per_layer"]:
+            assert metric["moves"] in names, (cell["name"], metric["name"])
+
+
+def test_manifest_keeps_the_contracts_limits():
+    m = manifest()
+    e2e = {x["name"] for x in m["end_to_end"]}
+    for entry in m["configs"] + m["workloads"] + m["end_to_end"] \
+            + m["per_layer"]:
+        assert NAME.match(entry["name"]), entry["name"]
+        assert len(entry.get("why", "x")) <= 200
+    for c in m["configs"]:
+        assert c["file"].startswith(tuple(p + "/" for p in m["paths"]))
+        assert set(c) == {"name", "source", "file", "reduced", "why"}
+    for w in m["workloads"]:
+        assert set(w) == {"name", "config", "traffic", "chips", "why"}
+    four = sum(w["chips"] == 4 for w in m["workloads"])
+    assert four <= max(1, len(m["workloads"]) // 4)
+    for x in m["end_to_end"]:
+        assert 0.01 <= x["bound"] <= 0.1
+        assert x["source"] in ("host_clock", "device_trace")
+    for x in m["per_layer"]:
+        assert x["moves"] in e2e and "bound" not in x
+    runs = 2 + 14 * 24
+    assert runs * (m["run_seconds"] + 60) + 24 * 2 * 90 + 1200 <= 43200
+
+
+def test_every_metric_has_a_reader_that_agrees_with_the_manifest():
+    m = manifest()
+    for kind, metrics in (("end_to_end", m["end_to_end"]),
+                          ("layer_metrics", m["per_layer"])):
+        for metric in metrics:
+            reader = run.reader_for(kind, metric["name"])
+            assert callable(reader.read)
+            assert (reader.UNIT, reader.BETTER, reader.SOURCE) == (
+                metric["unit"], metric["better"], metric["source"]), metric
+            if kind == "layer_metrics":
+                assert reader.LAYER == metric["layer"], metric["name"]
+
+
+def test_run_and_reduce_trace_name_no_cell_config_or_metric():
+    m = manifest()
+    names = {x["name"] for k in ("configs", "workloads", "end_to_end",
+                                 "per_layer") for x in m[k]}
+    names |= {x["name"].split(".")[0] for x in m["per_layer"]}
+    for path in ("run.py", "reduce_trace.py"):
+        with open(os.path.join(BENCH, path)) as f:
+            text = f.read()
+        assert not [n for n in names if n in text], path
+
+
+# ---- generators ----------------------------------------------------------
+
+def test_closed_loop_is_a_function_of_the_seed():
+    def offered(seed):
+        gen = closed_loop.build(TINY_CHAT, seed, vocab_size=500,
+                                max_seq_len=128)
+        first = gen.start()
+        nxt = gen.after_step(0.0, [(1, 0), (3, 0)])
+        return first + nxt
+
+    a, b, c = offered(5), offered(5), offered(2 ** 31 + 11)
+    assert [r["key"] for r in a] == [(0, 0), (1, 0), (2, 0), (3, 0),
+                                     (1, 1), (3, 1)]
+    for x, y, z in zip(a, b, c):
+        assert x["prompt"].tolist() == y["prompt"].tolist()
+        # another seed: the same sizes in the same places, other tokens
+        assert (len(x["prompt"]), x["answer"]) == (len(z["prompt"]),
+                                                   z["answer"])
+        assert x["prompt"].tolist() != z["prompt"].tolist()
+    assert all(r["answer"] <= 8 for r in a[:4])      # first round is cut
+    assert all(8 <= len(r["prompt"]) <= 60 and r["prompt"].max() < 500
+               for r in a)
+
+
+@pytest.fixture(scope="module")
+def tiny_predictor_factory():
+    import jax.numpy as jnp
+
+    import paddle_tpu as paddle
+    from paddle_tpu.inference import ServingPredictor
+    from paddle_tpu.models.gpt import GPTForCausalLM
+
+    from benchmark.drivers import serve
+
+    def make():
+        paddle.seed(1)
+        model = GPTForCausalLM(serve.model_config(TINY))
+        model.eval()
+        dep = TINY["serve"]
+        return ServingPredictor(
+            model, dtype=jnp.float32, max_batch=dep["max_batch"],
+            max_seq_len=dep["max_seq_len"], page_size=dep["page_size"],
+            num_pages=dep["num_pages"], token_budget=dep["token_budget"],
+            chunk=dep["chunk"])
+
+    return make
+
+
+def test_chat_loop_step_sequence_repeats_exactly(tiny_predictor_factory):
+    from benchmark.drivers import serve
+
+    def one_run(seed=11):
+        sp = tiny_predictor_factory()
+        gen = closed_loop.build(TINY_CHAT, seed, vocab_size=500,
+                                max_seq_len=128)
+        loop = serve.Loop(sp, gen, FakeClock(), lambda n:
+                          contextlib.nullcontext(), observe=True)
+        loop.submit(gen.start(), 0.0)
+        for _ in range(80):
+            loop.step()
+        sp.flush()
+        return loop
+
+    a, b = one_run(), one_run()
+    assert a.deliveries == b.deliveries and len(a.deliveries) > 100
+    assert a.finished == b.finished and len(a.finished) >= 8
+    assert [s[1:] for s in a.steps] == [s[1:] for s in b.steps]
+    assert serve.schedule_digest(a, 80) == serve.schedule_digest(b, 80)
+    # another seed changes the tokens, not the work
+    assert serve.schedule_digest(one_run(2 ** 31 + 11), 80) == \
+        serve.schedule_digest(a, 80)
+    # every finished request got exactly the answer it asked for
+    got = {}
+    for _, key, n in a.deliveries:
+        got[key] = got.get(key, 0) + n
+    assert all(got[key] == a.requests[key]["answer"]
+               for _, key in a.finished)
+
+
+# ---- the arithmetic ------------------------------------------------------
+
+EVENTS = [  # (time, request, tokens): r1 drains a lump of three at t=1.6
+    (1.0, "r1", 1), (1.1, "r2", 1), (1.2, "r2", 1), (1.3, "r2", 1),
+    (1.6, "r1", 3), (1.7, "r2", 1), (2.5, "r3", 1), (2.6, "r3", 1),
+]
+REQUESTS = {"r1": {"submit": 0.2, "prompt": 100, "answer": 4},
+            "r2": {"submit": 0.9, "prompt": 40, "answer": 4},
+            "r3": {"submit": 2.1, "prompt": 7, "answer": 2}}
+
+
+def test_served_tokens_on_a_hand_made_event_list():
+    # window (1.05, 2.55]: r2's and r3's first tokens fall inside (40 + 7
+    # prompt tokens), r1's does not; output tokens inside: 1+1+1+3+1+1 = 8
+    assert stats.served_tokens(EVENTS, REQUESTS, 1.05, 2.55) == 55
+    assert stats.served_tokens(EVENTS, REQUESTS, 0.0, 3.0) == 147 + 10
+
+
+def test_token_gaps_spread_a_lump_evenly():
+    gaps = sorted(stats.token_gaps(EVENTS, 0.0, 3.0))
+    # r2: 0.1, 0.1, 0.4; r1: three tokens share 0.6; r3: 0.1
+    assert gaps == pytest.approx(sorted([0.1, 0.1, 0.4, 0.2, 0.2, 0.2, 0.1]))
+    assert stats.percentile(gaps, 95) == pytest.approx(0.34)
+    assert sorted(stats.delivery_stalls(EVENTS, 0.0, 3.0)) == pytest.approx(
+        [0.1, 0.1, 0.1, 0.4, 0.6])
+    assert stats.times_to_first_token(EVENTS, REQUESTS, 0.5, 3.0) == \
+        pytest.approx([0.2, 0.4])
+    assert stats.percentile([], 95) is None
+    assert stats.percentile([3.0], 50) == 3.0
+
+
+def test_end_to_end_readers_on_the_hand_made_list():
+    serve_run = {"serve": {"deliveries": EVENTS, "requests": REQUESTS},
+                 "clock": {"t_open": 1.05, "t_close": 2.55, "window_s": 1.5,
+                           "paused_s": 0.0, "set_up": 12.5}}
+    assert run.reader_for("end_to_end", "served_tok_s").read(serve_run) == \
+        pytest.approx(55 / 1.5)
+    p95, note = run.reader_for("end_to_end", "gap_p95_ms").read(serve_run)
+    assert note == {"samples": 6} and p95 == pytest.approx(350.0)
+    assert run.reader_for("end_to_end", "setup_s").read(serve_run) == 12.5
+    train_run = {"train": {"steps": 10, "tokens_per_step": 16384},
+                 "clock": {"window_s": 5.5, "paused_s": 0.5}}
+    assert run.reader_for("end_to_end", "train_tok_s").read(train_run) == \
+        pytest.approx(32768.0)
+
+
+def test_kernel_needs_and_roofline():
+    ops, nbytes = ragged_paged_attention.needs(
+        [(1, 100), (4, 4), (0, 50)], num_heads=2, head_dim=8, kv_bytes=2,
+        q_bytes=2, out_bytes=4)
+    # lane 1: one row scores 100 keys; lane 2: rows score 1+2+3+4 keys
+    assert ops == 4 * 8 * 2 * (100 + 10)
+    assert nbytes == 2 * (100 + 4) * 16 * 2 + (1 + 4) * 16 * 6
+    fwd_ops, fwd_bytes = flash_attention.needs_fwd(
+        batch=1, seq=4, heads=1, head_dim=8, elem_bytes=2)
+    assert fwd_ops == 2 * (2 * 8 * 10) and fwd_bytes == 4 * 4 * 8 * 2
+    assert flash_attention.needs_bwd(batch=1, seq=4, heads=1, head_dim=8,
+                                     elem_bytes=2)[0] == 2 * fwd_ops
+    peak = {"bf16_flops_per_s": 100.0, "hbm_bytes_per_s": 10.0}
+    assert least_seconds(1000, 50, peak) == (10.0, "compute")
+    assert least_seconds(100, 50, peak) == (5.0, "memory")
+
+
+# ---- the trace reduction ---------------------------------------------------
+
+def hand_built_planes():
+    us = 1e-6
+    ops = [
+        ("%while.3 = (s32[], bf16[2,4]) while(...)", 10 * us, 50 * us),
+        ("%my_kernel.6 = (f32[4,2]{1,0}) custom-call(...)", 12 * us, 20 * us),
+        ("%copy_fusion.8 = bf16[32,2]{1,0} fusion(...)", 35 * us, 10 * us),
+        ("%copy_fusion.9 = bf16[32,2]{1,0} fusion(...)", 45 * us, 5 * us),
+        # 60..80 idle, under the host's "reconcile" span
+        ("%my_kernel.7 = (f32[4,2]{1,0}) custom-call(...)", 80 * us, 20 * us),
+        # 100..110 idle, under no span
+        ("%add.1 = f32[8]{0} add(...)", 110 * us, 10 * us),
+    ]
+    return {
+        "/device:TPU:0": {
+            "XLA Ops": ops,
+            "XLA Modules": [("jit_step(123)", 10 * us, 50 * us),
+                            ("jit_step(123)", 80 * us, 20 * us),
+                            ("jit_step(123)", 110 * us, 10 * us)],
+            "Async XLA Ops": [("%copy-start.1 = ...", 0.0, 500 * us)],
+        },
+        "/host:CPU": {
+            "other/1": [("Linearize", 0.0, 200 * us)],
+            "python3": [("bench.step", 5 * us, 90 * us),
+                        ("reconcile", 55 * us, 30 * us),
+                        ("bench.step", 112 * us, 5 * us)],
+        },
+    }
+
+
+def test_reduce_trace_on_a_hand_built_trace():
+    reduced = reduce_trace.reduce(hand_built_planes())
+    (dev,) = reduced["devices"]
+    assert dev["busy_s"] == pytest.approx(80e-6)        # the union
+    assert dev["window"] == pytest.approx((10e-6, 120e-6))
+    # the while holds 35 us of children: 15 us are its own
+    assert dev["ops"]["while"]["seconds"] == pytest.approx(15e-6)
+    assert dev["ops"]["my_kernel"] == {"seconds": pytest.approx(40e-6),
+                                       "calls": 2}
+    assert dev["op_seconds"]["copy_fusion_bf16_32_2_"] == \
+        pytest.approx(15e-6)
+    assert [round(s * 1e6) for s, _ in dev["launches"]["jit_step"]] == \
+        [10, 80, 110]
+    assert reduced["idle_gaps"] == {
+        "reconcile": pytest.approx(20e-6),
+        reduce_trace.NO_SPAN: pytest.approx(10e-6)}
+    busy, window = reduce_trace.busy_and_window(reduced)
+    assert (busy, window) == (pytest.approx(80e-6), pytest.approx(110e-6))
+    top = reduce_trace.breakdown(reduced, limit=2)
+    assert top["device_ops"][0] == ["my_kernel_f32_4_2_",
+                                    pytest.approx(40e-6)]
+    assert len(top["device_ops"]) == 2 and len(top["idle_gaps"]) == 2
+    assert reduce_trace.op_label("TraceMe name") == ("TraceMe name",
+                                                     "TraceMe name")
+
+
+def test_trace_readers_on_the_hand_built_trace():
+    traced = {"trace": reduce_trace.reduce(hand_built_planes())}
+    assert run.reader_for("layer_metrics", "device_idle_share.x").read(
+        traced) == pytest.approx(100 * 30 / 110)
+    assert run.reader_for("layer_metrics", "copy_busy_share.x").read(
+        traced) == pytest.approx(100 * 15 / 80)
+    period, note = run.reader_for("layer_metrics", "step_period_ms").read(
+        traced)
+    assert period == pytest.approx(0.05) and note["launches"] == 3
+    # a reader that finds nothing to read returns nothing
+    for name in ("ragged_attn_busy_share", "flash_attn_roofline",
+                 "collective_exposed_share", "ttft_p50_ms.x", "train_mfu"):
+        assert run.reader_for("layer_metrics", name).read(traced) is None
+
+
+# ---- the drivers at a tiny size --------------------------------------------
+
+def test_train_driver_agrees_with_the_reference_at_a_tiny_size():
+    from benchmark.drivers import train
+
+    traffic = {"driver": "train", "generator": "token_batches",
+               "params": {"batches": 2, "warmup_steps": 3,
+                          "trace_seconds": 1}}
+    out = train.run(FakeContext(TINY, traffic, seconds=0.3))
+    info = out["info"]
+    assert info["loss_matches_reference"] and info["loss_falls"], info
+    assert info["first_loss_rel_err"] < 1e-4
+    assert out["attempted"] >= 1 and out["failed"] == 0
+    assert out["train"]["tokens_per_step"] == 2 * 128
+    assert out["clock"]["window_s"] >= 0.3
+
+
+def test_serve_driver_agrees_with_the_reference_at_a_tiny_size():
+    from benchmark.drivers import serve
+
+    traffic = {"driver": "serve", "generator": "closed_loop",
+               "params": TINY_CHAT}
+    serve.CHECK_PROMPTS, serve.CHECK_PAD = (40, 50), 64
+    out = serve.run(FakeContext(TINY, traffic, seconds=0.5))
+    check = out["info"]["reference_check"]
+    # float32 on both sides: prefill, then decode through the paged cache,
+    # against the reference's full forward
+    assert check["ok"] and max(check["rms_share_of_std"]) < 1e-3, check
+    assert out["failed"] == 0 and out["info"]["step_traces"] == 1
+    assert out["info"]["deliveries_in_window"] > 0
+    run_record = dict(out, chips=1)
+    assert run.reader_for("end_to_end", "served_tok_s").read(run_record) > 0
+
+
+# ---- the entry points ------------------------------------------------------
+
+def test_run_fails_without_a_tpu_and_says_so():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    name = manifest()["workloads"][0]["name"]
+    proc = subprocess.run(
+        [sys.executable, os.path.join(BENCH, "run.py"), "--workload", name,
+         "--seed", "2147483659", "--seconds", "1", "--trace", "0"],
+        cwd=ROOT, env=env, capture_output=True, text=True, timeout=120)
+    assert proc.returncode != 0
+    assert "needs a TPU" in proc.stderr and "cpu" in proc.stderr
+    assert not any(line.startswith('{"correct"')
+                   for line in proc.stdout.splitlines())
+
+
+def test_prove_summarises_two_sets_the_way_the_driver_reads_them():
+    from benchmark import prove
+
+    a = [100.0, 101.0, 99.0, 100.5, 100.2, 130.0]
+    b = [100.1, 100.0, 99.9, 100.3, 100.2, 100.1]
+    s = prove.summarise({"m": {"A": a, "B": b}})["m"]
+    assert s["spread"] == pytest.approx(max(stats.spread(a),
+                                            stats.spread(b)))
+    # tightness: the run farthest from each set's median is left out
+    assert s["spread_trimmed_mean"] < 0.02
+    assert s["median_shift"] == pytest.approx(100.1 / 100.35 - 1)
