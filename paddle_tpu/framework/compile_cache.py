@@ -21,10 +21,15 @@ def configure_compile_cache() -> str:
     ``JAX_COMPILATION_CACHE_DIR`` set, JAX reads the variable itself and
     nothing is set in code; otherwise ``jax_compilation_cache_dir`` becomes
     :data:`CACHE_DIR`."""
+    import jax
+
+    # the step programs name their parts in HLO metadata
+    # (observability.step_scope) and a device trace reads the names out of
+    # the executable: by default the key leaves metadata out, and a program
+    # would then be served an executable compiled before it had its names
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if placed:
         return placed
-    import jax
-
     jax.config.update("jax_compilation_cache_dir", CACHE_DIR)
     return CACHE_DIR

@@ -145,7 +145,7 @@ import numpy as np
 
 import jax.numpy as jnp
 
-from ..observability import (MetricsRegistry, counter_event, monotonic,
+from ..observability import (MetricsRegistry, monotonic,
                              request_begin, request_end, request_event,
                              span, tracing_active)
 from ..profiler.record import recorder as _recorder
@@ -241,6 +241,10 @@ class Request:
         self.submit_time = (monotonic() if submit_time is None
                             else float(submit_time))
         self.first_token_time: float | None = None
+        # first admission (not replay), and the dispatch of the step that
+        # fed the prompt's last chunk: queue wait and prefill, on monotonic()
+        self.admit_time: float | None = None
+        self.prefill_end_time: float | None = None
         self.cached_prefix_len = 0   # tokens served from the prefix cache
         self._registered = False     # prompt pages in the prefix registry
 
@@ -332,7 +336,7 @@ class _Pending:
     the engine's ONE hard sync."""
 
     __slots__ = ("out", "ne", "completing", "spec", "spec_slots",
-                 "must_sync")
+                 "must_sync", "step_no")
 
     def __init__(self, out, ne, completing, spec, spec_slots, must_sync):
         self.out = out                 # device next_toks / out_ids
@@ -341,6 +345,7 @@ class _Pending:
         self.spec = spec
         self.spec_slots = spec_slots   # lanes advancing by n_emit + trim
         self.must_sync = must_sync     # some emission could finish a req
+        self.step_no = 0               # serving_steps at its dispatch
 
 
 class ServingPredictor:
@@ -687,6 +692,23 @@ class ServingPredictor:
         self._m_ttft = m.histogram(
             "serving_ttft_ms", "submit -> first generated token",
             buckets=(1, 2, 5, 10, 25, 50, 100, 250, 1000, 5000))
+        self._m_rows_prefill = m.counter(
+            "serving_rows_prefill",
+            "real rows fed by lanes still feeding known context")
+        self._m_rows_decode = m.counter(
+            "serving_rows_decode",
+            "real rows fed by decode lanes (drafts included)")
+        self._m_queue_wait = m.histogram(
+            "serving_queue_wait_ms", "submit -> first admission",
+            buckets=(1, 10, 100, 1000, 10000, 100000))
+        self._m_prefill = m.histogram(
+            "serving_prefill_ms",
+            "first admission -> dispatch of the prompt's last chunk",
+            buckets=(1, 10, 100, 1000, 10000, 100000))
+        self._m_reconcile_lag = m.histogram(
+            "serving_reconcile_lag_steps",
+            "steps dispatched between an entry's dispatch and its reconcile",
+            buckets=(0, 1, 2, 4, 8, 16, 64))
         self._m_inflight = m.gauge(
             "serving_inflight_depth", "dispatched-unreconciled steps")
         self._m_running = m.gauge(
@@ -1178,6 +1200,10 @@ class ServingPredictor:
         """Telemetry for one (re-)admission: counter + the request's
         async trace lane ('b' once per window; replays get an instant)."""
         self._m_admitted.inc()
+        if req.admit_time is None:
+            req.admit_time = monotonic()
+            self._m_queue_wait.observe(
+                (req.admit_time - req.submit_time) * 1e3)
         if not tracing_active():
             return
         already_open = self._lane_open(req.req_id)
@@ -1387,9 +1413,7 @@ class ServingPredictor:
         with span("reconcile"):
             e = self._inflight.popleft()
             self._m_inflight.set(len(self._inflight))
-            # sample the ring-depth track on the way DOWN too — a trace
-            # of a drain (flush) must show the ring emptying
-            counter_event("inflight_steps", len(self._inflight))
+            self._m_reconcile_lag.observe(self.steps - e.step_no)
             try:
                 return self._reconcile_one_impl(e)
             except Exception as exc:
@@ -1569,7 +1593,6 @@ class ServingPredictor:
         dropped = [e] + list(self._inflight)
         self._inflight.clear()
         self._m_inflight.set(0)
-        counter_event("inflight_steps", 0)
         reopen: dict[int, Request] = {}
         for entry in dropped:
             # round 19: spec entries charge one pending token per
@@ -1649,8 +1672,8 @@ class ServingPredictor:
         self._consec_failures = 0
         self._inflight.append(entry)
         self._m_inflight.set(len(self._inflight))
-        counter_event("inflight_steps", len(self._inflight))
         self._m_steps.inc()
+        entry.step_no = self.steps
         if not self.async_engine:
             # sync engine: pipeline depth zero, reconcile the step just
             # dispatched (the oracle the async engine is gated against)
@@ -2044,10 +2067,26 @@ class ServingPredictor:
         # like the sync engine's multi-token emission
         for _, req, _, _ in completing:
             req._pending_n += 1
-        # count-based cache accounting at pack time: plain lanes advance
-        # by what they fed; speculative lanes advance at reconcile (their
-        # watermark is n_emit, a device value)
+        # the scheduler's own count of what it packed: a lane feeds prefill
+        # rows while its context is known ahead (prompt chunks, replay), and
+        # decode rows once it feeds one generated token (plus its drafts)
+        now = None
         for slot, n in sched.items():
+            req = self.running[slot]
+            written = cache.seq_len(slot)
+            n_prompt = len(req.prompt_ids)
+            if slot in decode_set and written >= n_prompt:
+                self._m_rows_decode.inc(n)
+            else:
+                self._m_rows_prefill.inc(n)
+            if (req.prefill_end_time is None
+                    and written + n - int(spec_len[slot]) >= n_prompt):
+                now = monotonic() if now is None else now
+                req.prefill_end_time = now
+                self._m_prefill.observe((now - req.admit_time) * 1e3)
+            # count-based cache accounting at pack time: plain lanes
+            # advance by what they fed; speculative lanes advance at
+            # reconcile (their watermark is n_emit, a device value)
             if not spec_len[slot]:
                 cache.advance(slot, n)
         spec_slots = [s for s in sched if spec_len[s]]

@@ -1104,6 +1104,7 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
     from ..inference.kv_cache import (paged_copy_pages, paged_write_packed,
                                       paged_write_packed_prequant,
                                       paged_write_packed_quant)
+    from ..observability.tracing import step_scope
     from ..ops.pallas.paged_attention import ragged_paged_attention
 
     cfg = config
@@ -1178,21 +1179,24 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
         # copy-on-write BEFORE any write: diverging lanes get a private
         # copy of their shared tail page across every layer (scale planes
         # are page-keyed, so they ride the same copy lanes)
-        k_pages = paged_copy_pages(k_pages, cow_src, cow_dst)
-        v_pages = paged_copy_pages(v_pages, cow_src, cow_dst)
-        if kv_quant:
-            k_scales = paged_copy_pages(k_scales, cow_src, cow_dst)
-            v_scales = paged_copy_pages(v_scales, cow_src, cow_dst)
+        with step_scope("cow"):
+            k_pages = paged_copy_pages(k_pages, cow_src, cow_dst)
+            v_pages = paged_copy_pages(v_pages, cow_src, cow_dst)
+            if kv_quant:
+                k_scales = paged_copy_pages(k_scales, cow_src, cow_dst)
+                v_scales = paged_copy_pages(v_scales, cow_src, cow_dst)
         valid = tok_slot >= 0
         slot_c = jnp.clip(tok_slot, 0, b - 1)
-        # device-resident feedback: tokens the host scheduled before
-        # materializing their value read the previous step's carry —
-        # the async engine's device-side half of the pipeline
-        tok_ids = jnp.where((feedback > 0) & valid, prev_toks[slot_c],
-                            tok_ids)
-        x = (jnp.take(params["tok_emb"], jnp.maximum(tok_ids, 0), axis=0)
-             + params["pos_emb"][
-                 jnp.clip(tok_pos, 0, params["pos_emb"].shape[0] - 1)])
+        with step_scope("embed"):
+            # device-resident feedback: tokens the host scheduled before
+            # materializing their value read the previous step's carry —
+            # the async engine's device-side half of the pipeline
+            tok_ids = jnp.where((feedback > 0) & valid, prev_toks[slot_c],
+                                tok_ids)
+            x = (jnp.take(params["tok_emb"], jnp.maximum(tok_ids, 0),
+                          axis=0)
+                 + params["pos_emb"][
+                     jnp.clip(tok_pos, 0, params["pos_emb"].shape[0] - 1)])
         ctx = (kv_lens + q_lens).astype(jnp.int32)
         # packed <-> chunk-block index plumbing (shared by every layer):
         # each token's row in the attention kernel's [b, chunk] blocks
@@ -1206,31 +1210,39 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
             else:
                 p, kp, vp = layer
                 ks = vs = None
-            y = _srv_ln(x, p["ln1_g"], p["ln1_b"], eps)
-            qkv = _srv_mm(y, p["wqkv"], use_kernel) + p["bqkv"]
-            q, k_t, v_t = _split_qkv(qkv, nh_l, hd,
-                                     head_major=mesh is not None)
-            if kv_quant:
-                kp, ks = paged_write_packed_quant(
-                    kp, ks, k_t, page_table, tok_slot, tok_pos, page_size)
-                vp, vs = paged_write_packed_quant(
-                    vp, vs, v_t, page_table, tok_slot, tok_pos, page_size)
-            else:
-                kp = paged_write_packed(kp, k_t, page_table, tok_slot,
-                                        tok_pos, page_size)
-                vp = paged_write_packed(vp, v_t, page_table, tok_slot,
-                                        tok_pos, page_size)
-            qb = jnp.zeros((b, chunk, nh_l, hd), q.dtype
-                           ).at[scatter_b, off_c].set(q, mode="drop")
-            ab = ragged_paged_attention(qb, kp, vp, page_table, ctx, q_lens,
-                                        use_kernel=use_kernel,
-                                        k_scales=ks, v_scales=vs)
-            a = ab[slot_c, off_c]                    # back to packed [t]
-            x = x + _srv_psum(_srv_mm(a.reshape(t, nh_l * hd), p["wo"],
-                                      use_kernel), axis) + p["bo"]
-            x = x + _srv_ffn(cfg, p, _srv_ln(x, p["ln2_g"], p["ln2_b"],
-                                             eps),
-                             use_kernel, axis, valid=valid)
+            with step_scope("ln"):
+                y = _srv_ln(x, p["ln1_g"], p["ln1_b"], eps)
+            with step_scope("qkv"):
+                qkv = _srv_mm(y, p["wqkv"], use_kernel) + p["bqkv"]
+                q, k_t, v_t = _split_qkv(qkv, nh_l, hd,
+                                         head_major=mesh is not None)
+            with step_scope("kv_write"):
+                if kv_quant:
+                    kp, ks = paged_write_packed_quant(
+                        kp, ks, k_t, page_table, tok_slot, tok_pos,
+                        page_size)
+                    vp, vs = paged_write_packed_quant(
+                        vp, vs, v_t, page_table, tok_slot, tok_pos,
+                        page_size)
+                else:
+                    kp = paged_write_packed(kp, k_t, page_table, tok_slot,
+                                            tok_pos, page_size)
+                    vp = paged_write_packed(vp, v_t, page_table, tok_slot,
+                                            tok_pos, page_size)
+            with step_scope("attn"):
+                qb = jnp.zeros((b, chunk, nh_l, hd), q.dtype
+                               ).at[scatter_b, off_c].set(q, mode="drop")
+                ab = ragged_paged_attention(qb, kp, vp, page_table, ctx,
+                                            q_lens, use_kernel=use_kernel,
+                                            k_scales=ks, v_scales=vs)
+                a = ab[slot_c, off_c]                # back to packed [t]
+            with step_scope("attn_out"):
+                x = x + _srv_psum(_srv_mm(a.reshape(t, nh_l * hd), p["wo"],
+                                          use_kernel), axis) + p["bo"]
+            with step_scope("ln"):
+                y = _srv_ln(x, p["ln2_g"], p["ln2_b"], eps)
+            with step_scope("mlp"):
+                x = x + _srv_ffn(cfg, p, y, use_kernel, axis, valid=valid)
             return x, ((kp, vp, ks, vs) if kv_quant else (kp, vp))
 
         def mega_block(xb, layer):
@@ -1306,16 +1318,18 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
             body = mega_block
         else:
             carry0, body = x, block
-        if kv_quant:
-            x, (k_pages, v_pages, k_scales, v_scales) = jax.lax.scan(
-                body, carry0, (params["layers"], k_pages, v_pages,
-                               k_scales, v_scales))
-        else:
-            x, (k_pages, v_pages) = jax.lax.scan(
-                body, carry0, (params["layers"], k_pages, v_pages))
+        # the scan itself is scoped, so its own slicing of the stacked
+        # pools and stacking of its outputs fall under "layers" alone
+        with step_scope("layers"):
+            if kv_quant:
+                x, (k_pages, v_pages, k_scales, v_scales) = jax.lax.scan(
+                    body, carry0, (params["layers"], k_pages, v_pages,
+                                   k_scales, v_scales))
+            else:
+                x, (k_pages, v_pages) = jax.lax.scan(
+                    body, carry0, (params["layers"], k_pages, v_pages))
         if mega:
             x = x[slot_c, off_c]                     # back to packed [t]
-        x = _srv_ln(x, params["lnf_g"], params["lnf_b"], eps)
         if spec_k:
             # -- speculative verify + fused accept epilogue --------------
             # rows last_idx .. last_idx+spec_k are the lane's verify rows
@@ -1324,9 +1338,11 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
             k1 = spec_k + 1
             rows = last_idx[:, None] + jnp.arange(k1)[None]     # [b, k1]
             rows_c = jnp.clip(rows, 0, t - 1)
-            h_rows = x[rows_c]                                  # [b,k1,h]
-            logits_rows = _srv_logits(params, h_rows).astype(jnp.float32)
-            greedy = jnp.argmax(logits_rows, -1).astype(jnp.int32)
+            with step_scope("head"):
+                x = _srv_ln(x, params["lnf_g"], params["lnf_b"], eps)
+                h_rows = x[rows_c]                              # [b,k1,h]
+                logits_rows = _srv_logits(params,
+                                          h_rows).astype(jnp.float32)
             v = logits_rows.shape[-1]
 
             def _samp():
@@ -1345,30 +1361,36 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
                     keys.reshape(b * k1, 2), rep(temperature), rep(top_k),
                     rep(top_p)).reshape(b, k1)
 
-            sampled = jax.lax.cond(jnp.any(temperature > 0.0), _samp,
-                                   lambda: greedy)
-            out_ids = jnp.where((temperature > 0.0)[:, None], sampled,
-                                greedy)
-            # accept while draft i matches the token the model actually
-            # emits at its position: drafts ride the packed token stream
-            drafts = tok_ids[jnp.clip(rows[:, 1:], 0, t - 1)]   # [b, k]
-            ok = ((drafts == out_ids[:, :spec_k])
-                  & (jnp.arange(spec_k)[None] < spec_len[:, None]))
-            n_emit = (1 + jnp.cumprod(ok.astype(jnp.int32), axis=1).sum(1)
-                      ).astype(jnp.int32)
-            # per-lane carry: an emitting lane's LAST emitted token
-            last_emit = jnp.take_along_axis(
-                out_ids, jnp.maximum(n_emit - 1, 0)[:, None], axis=1)[:, 0]
-            next_toks = jnp.where(emit_mask > 0, last_emit, prev_toks)
+            with step_scope("sample"):
+                greedy = jnp.argmax(logits_rows, -1).astype(jnp.int32)
+                sampled = jax.lax.cond(jnp.any(temperature > 0.0), _samp,
+                                       lambda: greedy)
+                out_ids = jnp.where((temperature > 0.0)[:, None], sampled,
+                                    greedy)
+                # accept while draft i matches the token the model
+                # actually emits at its position: drafts ride the packed
+                # token stream
+                drafts = tok_ids[jnp.clip(rows[:, 1:], 0, t - 1)]  # [b, k]
+                ok = ((drafts == out_ids[:, :spec_k])
+                      & (jnp.arange(spec_k)[None] < spec_len[:, None]))
+                n_emit = (1 + jnp.cumprod(ok.astype(jnp.int32),
+                                          axis=1).sum(1)).astype(jnp.int32)
+                # per-lane carry: an emitting lane's LAST emitted token
+                last_emit = jnp.take_along_axis(
+                    out_ids, jnp.maximum(n_emit - 1, 0)[:, None],
+                    axis=1)[:, 0]
+                next_toks = jnp.where(emit_mask > 0, last_emit, prev_toks)
             if kv_quant:
                 return (out_ids, n_emit, next_toks, logits_rows[:, 0],
                         k_pages, v_pages, k_scales, v_scales)
             return (out_ids, n_emit, next_toks, logits_rows[:, 0],
                     k_pages, v_pages)
-        # each slot's LAST packed token yields its next-token decision
-        h_last = x[jnp.clip(last_idx, 0, t - 1)]                  # [b, h]
-        logits = _srv_logits(params, h_last).astype(jnp.float32)
-        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        with step_scope("head"):
+            x = _srv_ln(x, params["lnf_g"], params["lnf_b"], eps)
+            # each slot's LAST packed token yields its next-token decision
+            h_last = x[jnp.clip(last_idx, 0, t - 1)]              # [b, h]
+            logits = _srv_logits(params, h_last).astype(jnp.float32)
+
         # the epilogue's [b, vocab] sort/softmax/cumsum (and the key
         # folds) only EXECUTE on steps where some lane actually samples —
         # all-greedy steps (the flagship greedy serving loop) pay just
@@ -1378,13 +1400,15 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
             return _sample_epilogue(logits, keys, temperature, top_k,
                                     top_p)
 
-        sampled = jax.lax.cond(jnp.any(temperature > 0.0), _samp,
-                               lambda: greedy)
-        next_ids = jnp.where(temperature > 0.0, sampled, greedy)
-        # per-lane carry: emitting lanes refresh, everyone else passes
-        # the previous token through (a lane skipped by the budget still
-        # feeds its latest token through feedback next step)
-        next_toks = jnp.where(emit_mask > 0, next_ids, prev_toks)
+        with step_scope("sample"):
+            greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            sampled = jax.lax.cond(jnp.any(temperature > 0.0), _samp,
+                                   lambda: greedy)
+            next_ids = jnp.where(temperature > 0.0, sampled, greedy)
+            # per-lane carry: emitting lanes refresh, everyone else passes
+            # the previous token through (a lane skipped by the budget
+            # still feeds its latest token through feedback next step)
+            next_toks = jnp.where(emit_mask > 0, next_ids, prev_toks)
         if kv_quant:
             return (next_toks, logits, k_pages, v_pages, k_scales,
                     v_scales)

@@ -35,6 +35,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..framework.jit32 import jit32
+from ..observability.tracing import step_scope
 from .gpt import GPTConfig
 
 
@@ -262,22 +263,27 @@ def _block(p, x, config: GPTConfig, mesh: Mesh, dp_axis="dp"):
     x = cs(x, P(dp_axis, "mp", None))
     if "attn" in config.ablate:  # perf attribution: skip the whole branch
         return _block_mlp(p, x, config, cs, dp_axis, mesh)
-    if fused:
-        from ..ops.pallas import fused_mlp as _fm
+    with step_scope("ln"):
+        if fused:
+            from ..ops.pallas import fused_mlp as _fm
 
-        # single-pass LN kernel (fp32 stats, mean/rstd saved for backward;
-        # tags its outputs "ln_out" so remat_save_ln keeps working)
-        y = _fm.fused_layer_norm(x, p["ln1_g"], p["ln1_b"],
-                                 eps=config.layer_norm_eps, use_kernel=True)
-    else:
-        y = _layer_norm(x, p["ln1_g"], p["ln1_b"], config.layer_norm_eps)
-    if not fused and getattr(config, "remat_save_ln", False):
-        from jax.ad_checkpoint import checkpoint_name
+            # single-pass LN kernel (fp32 stats, mean/rstd saved for
+            # backward; tags its outputs "ln_out" so remat_save_ln keeps
+            # working)
+            y = _fm.fused_layer_norm(x, p["ln1_g"], p["ln1_b"],
+                                     eps=config.layer_norm_eps,
+                                     use_kernel=True)
+        else:
+            y = _layer_norm(x, p["ln1_g"], p["ln1_b"],
+                            config.layer_norm_eps)
+        if not fused and getattr(config, "remat_save_ln", False):
+            from jax.ad_checkpoint import checkpoint_name
 
-        y = checkpoint_name(y, "ln_out")
-    qkv = y @ p["wqkv"] + p["bqkv"]           # column-parallel -> [mb,s,3h]/mp
-    qkv = cs(qkv, P(dp_axis, None, "mp"))
-    q, k, v = jnp.split(qkv, 3, axis=-1)
+            y = checkpoint_name(y, "ln_out")
+    with step_scope("qkv"):
+        qkv = y @ p["wqkv"] + p["bqkv"]       # column-parallel -> [mb,s,3h]/mp
+        qkv = cs(qkv, P(dp_axis, None, "mp"))
+        q, k, v = jnp.split(qkv, 3, axis=-1)
 
     def heads(t):  # [mb, s, h] -> [mb, nh, s, hd], heads sharded over mp
         t = t.reshape(mb, s, nh, hd).transpose(0, 2, 1, 3)
@@ -287,48 +293,52 @@ def _block(p, x, config: GPTConfig, mesh: Mesh, dp_axis="dp"):
         use_flash = config.use_flash_attention and s % 128 == 0
     else:
         use_flash = config.force_flash  # interpret-mode kernel for CPU tests
-    if use_flash:
-        # fused Pallas kernel: no S x S residuals in fwd or bwd. On a mesh
-        # the kernel runs per-device via shard_map: heads (mp) and batch
-        # (dp) are embarrassingly parallel in flash attention, so no
-        # collectives are needed inside the region — reference never shards
-        # a head *across* devices either (mp_layers.py splits by whole
-        # heads).
-        from ..ops.pallas.flash_attention import flash_attention
+    with step_scope("attn"):
+        if use_flash:
+            # fused Pallas kernel: no S x S residuals in fwd or bwd. On a
+            # mesh the kernel runs per-device via shard_map: heads (mp) and
+            # batch (dp) are embarrassingly parallel in flash attention, so
+            # no collectives are needed inside the region — reference never
+            # shards a head *across* devices either (mp_layers.py splits by
+            # whole heads).
+            from ..ops.pallas.flash_attention import flash_attention
 
-        qh = q.reshape(mb, s, nh, hd)
-        kh = k.reshape(mb, s, nh, hd)
-        vh = v.reshape(mb, s, nh, hd)
-        if math.prod(mesh.shape.values()) > 1:
-            # manual over EVERY mesh axis (Mosaic calls cannot be
-            # partitioned automatically): under pp the stage dim arrives
-            # through ``_pipeline``'s vmap(spmd_axis_name="pp")
-            spec = P(dp_axis, None, "mp", None)
+            qh = q.reshape(mb, s, nh, hd)
+            kh = k.reshape(mb, s, nh, hd)
+            vh = v.reshape(mb, s, nh, hd)
+            if math.prod(mesh.shape.values()) > 1:
+                # manual over EVERY mesh axis (Mosaic calls cannot be
+                # partitioned automatically): under pp the stage dim
+                # arrives through ``_pipeline``'s vmap(spmd_axis_name="pp")
+                spec = P(dp_axis, None, "mp", None)
 
-            def local_flash(qs, ks, vs):
-                return flash_attention(qs, ks, vs, causal=True)
+                def local_flash(qs, ks, vs):
+                    return flash_attention(qs, ks, vs, causal=True)
 
-            o = jax.shard_map(
-                local_flash,
-                in_specs=(spec, spec, spec),
-                out_specs=spec,
-                check_vma=False,
-            )(qh, kh, vh)
+                o = jax.shard_map(
+                    local_flash,
+                    in_specs=(spec, spec, spec),
+                    out_specs=spec,
+                    check_vma=False,
+                )(qh, kh, vh)
+            else:
+                o = flash_attention(qh, kh, vh, causal=True)
+            o = o.reshape(mb, s, h)
         else:
-            o = flash_attention(qh, kh, vh, causal=True)
-        o = o.reshape(mb, s, h)
-    else:
-        q, k, v = heads(q), heads(k), heads(v)
-        scores = jnp.einsum("bnqd,bnkd->bnqk", q, k) / math.sqrt(hd)
-        causal = jnp.tril(jnp.ones((s, s), bool))
-        scores = jnp.where(causal, scores, -1e30)
-        attn = jax.nn.softmax(scores, axis=-1)
-        o = jnp.einsum("bnqk,bnkd->bnqd", attn, v)
-        o = o.transpose(0, 2, 1, 3).reshape(mb, s, h)
-    o = o @ p["wo"] + p["bo"]                  # row-parallel
+            q, k, v = heads(q), heads(k), heads(v)
+            scores = jnp.einsum("bnqd,bnkd->bnqk", q, k) / math.sqrt(hd)
+            causal = jnp.tril(jnp.ones((s, s), bool))
+            scores = jnp.where(causal, scores, -1e30)
+            attn = jax.nn.softmax(scores, axis=-1)
+            o = jnp.einsum("bnqk,bnkd->bnqd", attn, v)
+            o = o.transpose(0, 2, 1, 3).reshape(mb, s, h)
+    with step_scope("attn_out"):
+        o = o @ p["wo"] + p["bo"]              # row-parallel
+        if not fused:
+            # reduce-scatter onto SP layout
+            x = x + cs(o, P(dp_axis, "mp", None))
     if fused:
         return _block_mlp_fused(p, x, o, config), jnp.float32(0.0)
-    x = x + cs(o, P(dp_axis, "mp", None))      # reduce-scatter onto SP layout
     return _block_mlp(p, x, config, cs, dp_axis, mesh)
 
 
@@ -341,26 +351,31 @@ def _block_mlp_fused(p, x, branch, config: GPTConfig):
 
     if "mlp" in config.ablate:  # perf attribution: skip the whole branch
         return x + branch
-    y, s = _fm.fused_ln_residual(branch, x, p["ln2_g"], p["ln2_b"],
-                                 eps=config.layer_norm_eps, use_kernel=True)
-    y = _fm.fused_bias_gelu(y @ p["w1"], p["b1"], use_kernel=True)
-    return s + (y @ p["w2"] + p["b2"])
+    with step_scope("ln"):
+        y, s = _fm.fused_ln_residual(branch, x, p["ln2_g"], p["ln2_b"],
+                                     eps=config.layer_norm_eps,
+                                     use_kernel=True)
+    with step_scope("mlp"):
+        y = _fm.fused_bias_gelu(y @ p["w1"], p["b1"], use_kernel=True)
+        return s + (y @ p["w2"] + p["b2"])
 
 
 def _block_mlp(p, x, config: GPTConfig, cs, dp_axis="dp", mesh=None):
     if "mlp" in config.ablate:  # perf attribution: skip the whole branch
         return x, jnp.float32(0.0)
-    y = _layer_norm(x, p["ln2_g"], p["ln2_b"], config.layer_norm_eps)
-    if getattr(config, "remat_save_ln", False):
-        from jax.ad_checkpoint import checkpoint_name
+    with step_scope("ln"):
+        y = _layer_norm(x, p["ln2_g"], p["ln2_b"], config.layer_norm_eps)
+        if getattr(config, "remat_save_ln", False):
+            from jax.ad_checkpoint import checkpoint_name
 
-        y = checkpoint_name(y, "ln_out")
-    if getattr(config, "moe_experts", 0):
-        return _moe_mlp(p, x, y, config, cs, dp_axis, mesh)
-    y = jax.nn.gelu(y @ p["w1"] + p["b1"], approximate=True)
-    y = cs(y, P(dp_axis, None, "mp"))
-    y = y @ p["w2"] + p["b2"]
-    x = x + cs(y, P(dp_axis, "mp", None))
+            y = checkpoint_name(y, "ln_out")
+    with step_scope("mlp"):
+        if getattr(config, "moe_experts", 0):
+            return _moe_mlp(p, x, y, config, cs, dp_axis, mesh)
+        y = jax.nn.gelu(y @ p["w1"] + p["b1"], approximate=True)
+        y = cs(y, P(dp_axis, None, "mp"))
+        y = y @ p["w2"] + p["b2"]
+        x = x + cs(y, P(dp_axis, "mp", None))
     return x, jnp.float32(0.0)
 
 
@@ -449,7 +464,10 @@ def _stage_fn(p_stage, x, config: GPTConfig, mesh: Mesh, dp_axis="dp"):
                 policy,
                 jax.checkpoint_policies.save_only_these_names(*names))
         body = jax.checkpoint(body, policy=policy)
-    (x, aux), _ = lax.scan(body, (x, jnp.float32(0.0)), p_stage)
+    # the scan itself is scoped: its own slicing of the stacked weights and
+    # stacking of the saved residuals fall under "layers" and under no part
+    with step_scope("layers"):
+        (x, aux), _ = lax.scan(body, (x, jnp.float32(0.0)), p_stage)
     return x, aux
 
 
@@ -479,7 +497,8 @@ def _pipeline(stages, mbs, mesh: Mesh, config: GPTConfig, dp_axis="dp"):
         def one(mb):
             return _stage_fn(p_one, mb, config, mesh, dp_axis)
 
-        ys, auxs = jax.lax.map(one, mbs)
+        with step_scope("pipeline"):
+            ys, auxs = jax.lax.map(one, mbs)
         return ys, jnp.sum(auxs)
 
     total = num_micro + num_stages - 1
@@ -498,9 +517,12 @@ def _pipeline(stages, mbs, mesh: Mesh, config: GPTConfig, dp_axis="dp"):
         y, aux = stage_v(stages, acts)
         return jnp.roll(y, 1, axis=0), (y[last], aux)
 
-    init = jnp.zeros((num_stages,) + mbs.shape[1:], mbs.dtype)
-    _, (outs, auxs) = lax.scan(step, init,
-                               jnp.arange(total, dtype=jnp.int32))
+    # the schedule's own work (inject, ring shift, collecting the last
+    # stage's outputs) is what falls under "pipeline" and under no part
+    with step_scope("pipeline"):
+        init = jnp.zeros((num_stages,) + mbs.shape[1:], mbs.dtype)
+        _, (outs, auxs) = lax.scan(step, init,
+                                   jnp.arange(total, dtype=jnp.int32))
     # stage s at time t runs microbatch t - s; everything else in the
     # warm-up/drain window is recycled garbage — mask its aux out
     t_idx = jnp.arange(total)[:, None]
@@ -528,45 +550,48 @@ def _loss_fn_inner(params, ids, labels, config: GPTConfig, mesh: Mesh,
                    num_micro: int, dp_axis="dp"):
     cs = _mk_cs(mesh)
     b, s = ids.shape
-    x = jnp.take(params["tok_emb"], ids, axis=0) + params["pos_emb"][:s]
-    x = cs(x, P(dp_axis, None, None))
+    with step_scope("embed"):
+        x = jnp.take(params["tok_emb"], ids, axis=0) + params["pos_emb"][:s]
+        x = cs(x, P(dp_axis, None, None))
     mb = b // num_micro
     mbs = x.reshape(num_micro, mb, s, x.shape[-1])
     y, moe_aux = _pipeline(params["stages"], mbs, mesh, config, dp_axis)
     y = y.reshape(b, s, -1)
-    y = _layer_norm(y, params["lnf_g"], params["lnf_b"], config.layer_norm_eps)
+    # the final norm, the chunked tied head and the cross-entropy
+    with step_scope("head_loss"):
+        y = _layer_norm(y, params["lnf_g"], params["lnf_b"], config.layer_norm_eps)
 
-    # Shifted next-token CE, chunked over the sequence with remat: the full
-    # [b, s, vocab] fp32 logits (3.2 GB at bs16/seq1024/50k vocab) never
-    # materialize — each chunk's logits are recomputed in backward. Costs one
-    # extra head matmul pass (~2hv/token, ~8% of step FLOPs at 125M) and
-    # buys 2-4x batch on a 16 GB chip, a clear MFU win.
-    emb = params["tok_emb"]
-    # shift labels left; the last position has no target (masked below)
-    lb = jnp.concatenate([labels[:, 1:], labels[:, :1]], axis=1)
-    chunk = s
-    while chunk > 128 or s % chunk:
-        chunk //= 2
-    nchunks = s // chunk
-    yc = y.reshape(b, nchunks, chunk, -1).transpose(1, 0, 2, 3)
-    lbc = lb.reshape(b, nchunks, chunk).transpose(1, 0, 2)
+        # Shifted next-token CE, chunked over the sequence with remat: the full
+        # [b, s, vocab] fp32 logits (3.2 GB at bs16/seq1024/50k vocab) never
+        # materialize — each chunk's logits are recomputed in backward. Costs one
+        # extra head matmul pass (~2hv/token, ~8% of step FLOPs at 125M) and
+        # buys 2-4x batch on a 16 GB chip, a clear MFU win.
+        emb = params["tok_emb"]
+        # shift labels left; the last position has no target (masked below)
+        lb = jnp.concatenate([labels[:, 1:], labels[:, :1]], axis=1)
+        chunk = s
+        while chunk > 128 or s % chunk:
+            chunk //= 2
+        nchunks = s // chunk
+        yc = y.reshape(b, nchunks, chunk, -1).transpose(1, 0, 2, 3)
+        lbc = lb.reshape(b, nchunks, chunk).transpose(1, 0, 2)
 
-    def chunk_nll(args):
-        y_ch, lb_ch = args
-        lg = (y_ch @ emb.T).astype(jnp.float32)  # [b, chunk, v]
-        lg = cs(lg, P(dp_axis, None, "mp"))  # vocab-sharded over mp (tied head)
-        if "ce" in config.ablate:
-            # perf attribution: keep the head matmul (and the chunked remat
-            # structure), drop the softmax-CE math
-            return jnp.sum(lg, axis=-1) * 1e-9
-        lse = jax.scipy.special.logsumexp(lg, axis=-1)
-        tgt = jnp.take_along_axis(lg, lb_ch[..., None], axis=-1)[..., 0]
-        return lse - tgt  # [b, chunk]
+        def chunk_nll(args):
+            y_ch, lb_ch = args
+            lg = (y_ch @ emb.T).astype(jnp.float32)  # [b, chunk, v]
+            lg = cs(lg, P(dp_axis, None, "mp"))  # vocab-sharded over mp (tied head)
+            if "ce" in config.ablate:
+                # perf attribution: keep the head matmul (and the chunked remat
+                # structure), drop the softmax-CE math
+                return jnp.sum(lg, axis=-1) * 1e-9
+            lse = jax.scipy.special.logsumexp(lg, axis=-1)
+            tgt = jnp.take_along_axis(lg, lb_ch[..., None], axis=-1)[..., 0]
+            return lse - tgt  # [b, chunk]
 
-    nll = lax.map(jax.checkpoint(chunk_nll), (yc, lbc))  # [nchunks, b, chunk]
-    nll = nll.transpose(1, 0, 2).reshape(b, s)
-    valid = (jnp.arange(s) < s - 1).astype(jnp.float32)
-    loss = jnp.sum(nll * valid) / (b * (s - 1))
+        nll = lax.map(jax.checkpoint(chunk_nll), (yc, lbc))  # [nchunks, b, chunk]
+        nll = nll.transpose(1, 0, 2).reshape(b, s)
+        valid = (jnp.arange(s) < s - 1).astype(jnp.float32)
+        loss = jnp.sum(nll * valid) / (b * (s - 1))
     if getattr(config, "moe_experts", 0):
         # mean aux per (layer, microbatch), weighted into the objective
         loss = loss + (getattr(config, "moe_aux_weight", 0.01)
@@ -679,8 +704,9 @@ def build_spmd_train_step(
 
     def step(params, mom, ids, labels):
         loss, grads = sync_grads(params, ids, labels)
-        mom2 = jax.tree.map(lambda m, g: momentum * m + g, mom, grads)
-        params2 = jax.tree.map(lambda p, m: p - lr * m, params, mom2)
+        with step_scope("optimizer"):
+            mom2 = jax.tree.map(lambda m, g: momentum * m + g, mom, grads)
+            params2 = jax.tree.map(lambda p, m: p - lr * m, params, mom2)
         return params2, mom2, loss
 
     jitted_inner = jit32(

@@ -8,11 +8,13 @@ Two halves, one import surface:
   split, the watchdog monitor thread), and ``snapshot()`` /
   ``snapshot_flat()`` export — the schema-checked ``telemetry``
   sub-object riding the bench JSON lines.
-- :mod:`.tracing` — host spans + per-request async lanes + counter
-  tracks recorded into the profiler's event buffer and exported through
+- :mod:`.tracing` — host spans + per-request async lanes recorded into
+  the profiler's event buffer and exported through
   ``profiler.export_chrome_tracing``; ``monotonic()``/``monotonic_ns()``
   are THE timing clock for ``inference/`` and ``distributed/`` (tpulint
-  AL006 fences raw ``time.perf_counter()`` there to this layer).
+  AL006 fences raw ``time.perf_counter()`` there to this layer);
+  ``step_scope()`` names the parts of the two step programs on the device
+  (``STEP_SCOPES``, read by ``benchmark/scope_trace.py``).
 
 Cost contract: with observability disabled (no profiler window open,
 ``default_registry`` off) every instrument call is one flag check and an
@@ -23,15 +25,15 @@ from .fleet import FleetInstruments
 from .metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                       default_registry, disable_metrics, enable_metrics,
                       merge_snapshots, metrics_enabled)
-from .tracing import (REQUEST_SPAN, counter_event, device_annotation,
+from .tracing import (REQUEST_SPAN, STEP_SCOPES, device_annotation,
                       monotonic, monotonic_ns, request_begin, request_end,
-                      request_event, span, tracing_active)
+                      request_event, span, step_scope, tracing_active)
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "default_registry",
     "enable_metrics", "disable_metrics", "metrics_enabled",
     "merge_snapshots", "span", "request_begin", "request_event",
-    "request_end", "counter_event", "tracing_active", "monotonic",
-    "monotonic_ns", "device_annotation", "REQUEST_SPAN",
-    "FleetInstruments",
+    "request_end", "tracing_active", "monotonic",
+    "monotonic_ns", "device_annotation", "REQUEST_SPAN", "STEP_SCOPES",
+    "step_scope", "FleetInstruments",
 ]

@@ -15,8 +15,11 @@ buffer (``profiler/record.py``):
   by ``(category, id, name)``): one lane per request showing its whole
   lifecycle (admit → prefill chunks → decode/spec steps → preemption /
   replay → eos) across the scheduler steps that interleave it.
-- :func:`counter_event` — a Chrome counter track (``C`` phase): scalar
-  series over time (the async engine's in-flight ring depth).
+- :func:`step_scope` — ``jax.named_scope`` for one name of
+  :data:`STEP_SCOPES`, the closed list of parts of the two benchmarked
+  step programs. The name rides every HLO operation's ``op_name`` path
+  into the device trace, where ``benchmark/scope_trace.py`` charges each
+  operation's own time to the innermost such name.
 - :func:`monotonic` / :func:`monotonic_ns` — THE timing clock for
   ``paddle_tpu/inference`` and ``paddle_tpu/distributed`` (tpulint AL006
   flags raw ``time.perf_counter()`` there; timing belongs to this layer
@@ -36,8 +39,8 @@ from ..profiler.record import now_ns, recorder
 
 __all__ = [
     "span", "request_begin", "request_event", "request_end",
-    "counter_event", "tracing_active", "monotonic", "monotonic_ns",
-    "device_annotation", "set_device_tracing",
+    "tracing_active", "monotonic", "monotonic_ns",
+    "device_annotation", "set_device_tracing", "STEP_SCOPES", "step_scope",
 ]
 
 monotonic = time.perf_counter
@@ -148,9 +151,26 @@ def request_end(req_id, args=None) -> None:
                         args=args)
 
 
-def counter_event(name: str, value) -> None:
-    """One sample on a Chrome counter track (``C`` phase)."""
-    if not recorder.enabled:
-        return
-    recorder.record_raw(name, "C", category="counter",
-                        args={"value": float(value)})
+# -- device-side scopes of the step programs ----------------------------------
+
+#: the parts of the serving step (``models/gpt.py build_unified_step``) and
+#: of the train step (``models/gpt_spmd.py``). ``layers`` wraps the layer
+#: scan itself, so the scan's own slicing and stacking of what it carries
+#: fall under ``layers`` and under no part. The benchmark's scope readers
+#: depend on these names letter for letter.
+STEP_SCOPES = (
+    "cow", "embed", "layers", "ln", "qkv", "kv_write", "attn", "attn_out",
+    "mlp", "head", "sample", "head_loss", "optimizer", "pipeline",
+)
+
+
+def step_scope(name: str):
+    """``jax.named_scope(name)`` for a name of :data:`STEP_SCOPES`. Metadata
+    only: it names the operations traced under it and adds none. Any other
+    name is an error at trace time."""
+    if name not in STEP_SCOPES:
+        raise ValueError(f"step_scope: {name!r} is not one of STEP_SCOPES "
+                         f"{STEP_SCOPES}")
+    import jax
+
+    return jax.named_scope(name)

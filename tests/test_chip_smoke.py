@@ -58,6 +58,22 @@ def test_compile_cache_placed_from_outside_is_left_alone(tmp_path):
     assert proc.stdout.split() == [placed, placed]
 
 
+@pytest.mark.parametrize("placed", [None, "somewhere"])
+def test_compile_cache_keys_on_metadata(tmp_path, placed):
+    """The step programs' scope names are HLO metadata and a device trace
+    reads them out of the executable; JAX's default key strips metadata, so
+    the parent of a renamed program and the program itself would share one
+    entry (the tiny unified step's ``jit_step`` does, on the CPU)."""
+    env = dict(PYTHONPATH=REPO, JAX_PLATFORMS="cpu")
+    if placed:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / placed)
+    proc = _run(["-c", "import jax; from paddle_tpu.framework.compile_cache "
+                 "import configure_compile_cache as c; c(); print(jax.config."
+                 "jax_compilation_cache_include_metadata_in_key)"], **env)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert proc.stdout.split() == ["True"]
+
+
 def test_chip_peak_raises_on_an_unknown_device():
     sys.path.insert(0, REPO)
     try:

@@ -270,9 +270,6 @@ class TestServingTelemetry:
             assert "eos" in instants[rid]
             assert "decode" in instants[rid] or \
                 "prefill_chunk" in instants[rid]
-        # the in-flight ring depth counter track rode along (async engine)
-        assert any(e["ph"] == "C" and e["name"] == "inflight_steps"
-                   for e in events)
         # tracing OFF again after stop(): spans are the shared no-op
         assert not recorder.enabled
 
@@ -311,6 +308,74 @@ class TestServingTelemetry:
         assert budget_s < 0.02 * step_s, (
             f"disabled-path instrumentation budget {budget_s * 1e6:.1f}us "
             f"is not <2% of the {step_s * 1e6:.0f}us serving step")
+
+    @pytest.mark.parametrize("async_engine", [True, False])
+    def test_scheduler_counts_its_own_rows_and_waits(self, rng,
+                                                     async_engine):
+        """PR 26: the rows ``_pack_dispatch`` counts as it packs them are
+        the rows ``KVCacheManager.seq_len`` shows were written; queue wait
+        is observed once per request, at first admission; prefill once, at
+        the dispatch of the prompt's last chunk; the reconcile lag is a
+        count of steps and is 0 for the synchronous engine."""
+        from paddle_tpu.inference import ServingPredictor
+
+        sp = ServingPredictor(_tiny_model(), max_batch=2, page_size=8,
+                              max_seq_len=64, use_kernel=False, chunk=8,
+                              token_budget=16, async_engine=async_engine)
+        prompts = [rng.randint(0, TINY["vocab_size"], (n,))
+                   for n in (5, 19, 9, 26)]   # 4 requests on 2 lanes
+        reqs = [sp.add_request(p, max_new_tokens=6) for p in prompts]
+        written, before = 0, {}
+        while sp.has_work():
+            sp.step()
+            # a lane is retired at the start of the step after its last
+            # row, so every row a lane was fed shows here
+            after = {slot: (r.req_id, sp.cache.seq_len(slot))
+                     for slot, r in sp.running.items()}
+            for slot, (rid, kv) in after.items():
+                had = before.get(slot)
+                written += kv - (had[1] if had and had[0] == rid else 0)
+            before = after
+        sp.flush()
+        flat = sp.telemetry()
+        rows = flat["serving_rows_prefill"] + flat["serving_rows_decode"]
+        assert rows == written
+        # every context token but the last generated one is fed once
+        assert rows == sum(len(r.prompt_ids) + len(r.output_ids) - 1
+                           for r in reqs)
+        assert flat["serving_rows_prefill"] == sum(map(len, prompts))
+        assert flat["serving_rows_decode"] == 4 * (6 - 1)
+        assert flat["serving_queue_wait_ms_count"] == 4   # first admissions
+        assert flat["serving_requests_admitted"] >= 4
+        assert flat["serving_prefill_ms_count"] == 4
+        assert flat["serving_prefill_ms_sum"] >= 0
+        for r in reqs:
+            assert r.submit_time <= r.admit_time <= r.prefill_end_time \
+                <= r.first_token_time
+        assert flat["serving_reconcile_lag_steps_count"] == \
+            flat["serving_steps"]
+        if async_engine:
+            assert flat["serving_reconcile_lag_steps_sum"] >= 1
+        else:
+            assert flat["serving_reconcile_lag_steps_sum"] == 0
+
+    def test_queue_wait_is_not_observed_again_on_replay(self, rng):
+        """A preempted request is admitted twice and waited for once."""
+        from paddle_tpu.inference import ServingPredictor
+
+        sp = ServingPredictor(_tiny_model(), max_batch=2, page_size=4,
+                              num_pages=6, max_seq_len=32, use_kernel=False)
+        for n in (8, 8):
+            sp.add_request(rng.randint(0, TINY["vocab_size"], (n,)),
+                           max_new_tokens=10)
+        while sp.has_work():
+            sp.step()
+        sp.flush()
+        flat = sp.telemetry()
+        assert flat["serving_preemptions"] >= 1
+        assert flat["serving_requests_admitted"] > 2
+        assert flat["serving_queue_wait_ms_count"] == 2
+        assert flat["serving_prefill_ms_count"] == 2
 
     def test_disabled_registry_rejected_loudly(self):
         """The predictor's (and KV manager's) counters back the
@@ -360,9 +425,8 @@ class TestServingTelemetry:
         assert laned <= begins     # ...re-opened, with NO orphan phases
         ends = {e.id for e in recorder.aux if e.ph == "e"}
         assert ends == begins      # finished in-window: lanes complete
-        # the scheduler spans + counter track still recorded
+        # the scheduler spans still recorded
         assert any(e.name == "pack_dispatch" for e in recorder.events)
-        assert any(e.ph == "C" for e in recorder.aux)
         recorder.clear()
 
     def test_tracing_preserves_emissions(self, rng):
@@ -558,6 +622,119 @@ class TestServingTelemetry:
         hz = sp.healthz()
         self._check_healthz(hz)
         assert hz["status"] == "ok" and hz["shed_reason"] is None
+
+
+# ---------------------------------------------------------------------------
+# PR 26: named scopes inside the two step programs
+# ---------------------------------------------------------------------------
+
+SERVE_SCOPES = ("cow", "embed", "layers", "ln", "qkv", "kv_write", "attn",
+                "attn_out", "mlp", "head", "sample")
+TRAIN_SCOPES = ("embed", "layers", "ln", "qkv", "attn", "attn_out", "mlp",
+                "pipeline", "head_loss", "optimizer")
+
+
+def _scope_components(lowered_text):
+    """Every ``/``-separated component of every location path in a lowered
+    module's text, wrappers such as ``transpose(jvp(ln))`` peeled off."""
+    import re
+
+    found = set()
+    for path in re.findall(r'loc\("([^"]+)"', lowered_text):
+        for part in path.split("/"):
+            found.add(part)
+            found.update(re.findall(r"[\w.]+", part))
+    return found
+
+
+class TestStepScopes:
+    def test_step_scope_takes_only_the_closed_list(self):
+        from paddle_tpu.observability import STEP_SCOPES, step_scope
+
+        assert set(SERVE_SCOPES) | set(TRAIN_SCOPES) == set(STEP_SCOPES)
+        with step_scope("ln"):
+            pass
+        with pytest.raises(ValueError, match="STEP_SCOPES"):
+            step_scope("layer_norm")
+
+    def test_named_scope_is_used_through_the_helper_only(self):
+        import os
+        import re
+
+        from paddle_tpu.observability import STEP_SCOPES
+
+        root = os.path.dirname(paddle.__file__)
+        raw, used = [], {}
+        for folder, _, files in os.walk(root):
+            for name in files:
+                if not name.endswith(".py"):
+                    continue
+                path = os.path.join(folder, name)
+                with open(path) as f:
+                    text = f.read()
+                rel = os.path.relpath(path, root)
+                if "named_scope" in text:
+                    raw.append(rel)
+                names = re.findall(r'step_scope\("(\w+)"\)', text)
+                if names and rel != os.path.join("observability",
+                                                 "tracing.py"):
+                    used[rel] = set(names)
+        assert raw == [os.path.join("observability", "tracing.py")]
+        assert sorted(used) == [os.path.join("models", "gpt.py"),
+                                os.path.join("models", "gpt_spmd.py")]
+        assert used[os.path.join("models", "gpt.py")] == set(SERVE_SCOPES)
+        assert used[os.path.join("models", "gpt_spmd.py")] == \
+            set(TRAIN_SCOPES)
+        assert set().union(*used.values()) <= set(STEP_SCOPES)
+
+    def test_unified_step_lowers_with_every_serving_scope(self, rng):
+        import jax
+
+        from paddle_tpu.inference import ServingPredictor
+
+        sp = ServingPredictor(_tiny_model(), max_batch=2, page_size=8,
+                              max_seq_len=64, use_kernel=False)
+        step_fn, seen = sp._unified, []
+
+        def tapped(*args):
+            seen.append(jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), args))
+            return step_fn(*args)
+
+        tapped.trace_count = step_fn.trace_count
+        sp._unified = tapped
+        sp.generate([rng.randint(0, TINY["vocab_size"], (9,))],
+                    max_new_tokens=2)
+        found = _scope_components(
+            step_fn.lower(*seen[0]).as_text(debug_info=True))
+        assert set(SERVE_SCOPES) <= found, set(SERVE_SCOPES) - found
+        assert not {"head_loss", "optimizer", "pipeline"} & found
+
+    @pytest.mark.parametrize("pp", [1, 2])
+    def test_train_step_lowers_with_every_training_scope(self, pp):
+        import jax
+        from jax.sharding import Mesh
+
+        from paddle_tpu.models.gpt import GPTConfig
+        from paddle_tpu.models.gpt_spmd import build_spmd_train_step
+
+        if len(jax.devices()) < pp:
+            pytest.skip(f"needs {pp} host devices")
+        cfg = GPTConfig(vocab_size=64, hidden_size=32, num_layers=2,
+                        num_heads=4, max_seq_len=32, recompute=True)
+        mesh = Mesh(np.array(jax.devices()[:pp]).reshape(1, pp, 1),
+                    ("dp", "pp", "mp"))
+        step, params, mom, (ids, labels) = build_spmd_train_step(
+            cfg, mesh, batch_size=4, seq_len=32, num_micro=2)
+        with jax.set_mesh(mesh):
+            text = step.lower(params, mom, ids, labels).as_text(
+                debug_info=True)
+        found = _scope_components(text)
+        assert set(TRAIN_SCOPES) <= found, set(TRAIN_SCOPES) - found
+        # the scopes survive the backward pass and jax.checkpoint
+        assert "transpose(jvp(pipeline))" in text
+        assert "rematted_computation/ln" in text
+        assert not {"cow", "kv_write", "sample"} & found
 
 
 # ---------------------------------------------------------------------------

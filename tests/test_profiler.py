@@ -156,11 +156,11 @@ def test_make_scheduler_repeat_forever_and_edges():
 
 
 def test_chrome_export_round_trips_aux_events(tmp_path):
-    """Round 15: async request phases + counter tracks recorded through
-    the observability span API ride the chrome export and json.load back
-    with their phase/id/args intact."""
-    from paddle_tpu.observability import (counter_event, request_begin,
-                                          request_end, request_event, span)
+    """Round 15: async request phases recorded through the observability
+    span API, and a raw counter-track sample, ride the chrome export and
+    json.load back with their phase/id/args intact."""
+    from paddle_tpu.observability import (request_begin, request_end,
+                                          request_event, span)
 
     p = Profiler(on_trace_ready=export_chrome_tracing(str(tmp_path), "aux"))
     p.start()
@@ -168,7 +168,8 @@ def test_chrome_export_round_trips_aux_events(tmp_path):
         pass
     assert request_begin(7, args={"req_id": 7})
     request_event(7, "admit", args={"slot": 0})
-    counter_event("inflight_steps", 2)
+    recorder.record_raw("inflight_steps", "C", category="counter",
+                        args={"value": 2.0})
     request_end(7)
     p.stop()
     events = load_profiler_result(str(p._last_export))
