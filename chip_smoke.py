@@ -10,9 +10,11 @@ ffn 6144, vocab 50304, bf16) with seeded random weights:
 - *serve*: ``ServingPredictor`` with its defaults (unified ragged step, async
   engine, prefix cache, ``use_kernel=None``) answers more requests than it
   has lanes. Every request must finish; the compiled step must hold the
-  Mosaic custom call of the ragged paged-attention kernel; the step must have
-  been traced once; and, outside the timed window, one recorded step's logits
-  from the kernel path must agree with ``use_kernel=False`` on the same chip.
+  Mosaic custom call of the ragged paged-attention kernel and must not copy,
+  slice or restack the KV pools (they stay one donated buffer: its temp is
+  under one stacked pool's bytes); the step must have been traced once; and,
+  outside the timed window, one recorded step's logits from the kernel path
+  must agree with ``use_kernel=False`` on the same chip.
 - *train*: ``build_spmd_train_step`` (recompute + flash attention, bs8
   seq1024, bf16 state) takes a few steps on its fixed batch: finite,
   decreasing loss, flash forward and backward custom calls in the compiled
@@ -95,6 +97,48 @@ def _device():
 
 def _is_mosaic_call(line: str, kernel: str) -> bool:
     return 'custom_call_target="tpu_custom_call"' in line and kernel in line
+
+
+_POOL_MOVERS = ("copy", "dynamic-slice", "dynamic-update-slice")
+
+
+def pool_copies(hlo_text: str, stack_shape) -> list[str]:
+    """The instructions of a compiled serving step that move a whole KV
+    pool: a ``copy``, ``dynamic-slice`` or ``dynamic-update-slice`` (alone
+    or inside a fusion) whose result has the stacked pool's shape
+    ``[layers, pages, heads, page, hd]`` or one layer's. The copy-on-write
+    lanes are let through (scope ``cow``, read off the instruction or off
+    the nearest fusion or loop around it that has a name): they update the
+    stack in place, a page at a time, through a ``dynamic-update-slice`` of
+    its shape."""
+    import re
+
+    dims = "|".join(",".join(map(str, shape))
+                    for shape in (stack_shape, stack_shape[1:]))
+    moved = re.compile(r"= \w+\[(%s)\]\S* (%s)\("
+                       % (dims, "|".join(_POOL_MOVERS)))
+    scope = re.compile(r'op_name="([^"]*)"')
+    calls = re.compile(r"(?:calls|body|condition|to_apply)=%?([\w.\-]+)")
+    computation, found, called_from = None, [], {}
+    for line in hlo_text.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .*\{$", line)
+        if head:
+            computation = head.group(1)
+            continue
+        named = scope.search(line)
+        name = named.group(1) if named else None
+        for callee in calls.findall(line):
+            called_from[callee] = (computation, name)
+        if moved.search(line):
+            found.append((computation, name, line.strip()))
+
+    def owner(computation, name):
+        while name is None and computation in called_from:
+            computation, name = called_from[computation]
+        return name or ""
+
+    return [line for computation, name, line in found
+            if "/cow/" not in owner(computation, name)]
 
 
 def _abstract(args):
@@ -231,7 +275,21 @@ def phase_serve(chips: int) -> dict:
              "no Mosaic custom call of the ragged paged-attention kernel in "
              "the compiled serving step")
 
+    # the pools stay one donated buffer through the step (PR 27): nothing
+    # pool-shaped is copied, sliced out of the stack or stacked back, and
+    # the step's temporaries are far under one stacked pool (the scanned
+    # pools cost a second copy of both stacks)
+    stack = sp.cache.k_pages
+    local = (stack.shape[:2] + (stack.shape[2] // chips,) + stack.shape[3:])
+    moved = pool_copies(compiled.as_text(), local)
+    _require(not moved,
+             f"the compiled serving step moves a whole KV pool "
+             f"{len(moved)} times, first: {moved[:1]}")
     mem = _memory(chips, compiled)
+    pool_bytes = stack.nbytes // chips
+    _require(mem["program_temp_bytes"] < pool_bytes,
+             f"the serving step's temp is {mem['program_temp_bytes']} bytes, "
+             f"not under one stacked pool's {pool_bytes}")
     if chips > 1:
         _shards_everywhere("serving pools",
                            (sp.cache.k_pages, sp.cache.v_pages), chips)
@@ -275,6 +333,7 @@ def phase_serve(chips: int) -> dict:
             "prefix_hit_rate": round(hit_rate, 4),
             "step_traces": sp.decode_trace_count,
             "ragged_kernel_calls": kernel_calls,
+            "stacked_pool_bytes": pool_bytes,
             "logits_err_max_frac_std": round(err, 5),
             "logits_err_rms_frac_std": round(err_rms, 5),
             "logits_lanes": int(live.sum()), **mem}

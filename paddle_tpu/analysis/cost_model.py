@@ -202,9 +202,10 @@ def program_flow_bytes(jaxpr, mult: int = 1) -> int:
 
 
 def find_layer_scan(jaxpr):
-    """The layer scan of a serving step: the ``scan`` equation carrying the
-    most xs bytes (the stacked per-layer weights + the threaded KV pools
-    dominate every other loop in the program). Recurses sub-jaxprs."""
+    """The layer scan of a serving step: the ``scan`` equation with the most
+    xs bytes (the stacked per-layer weights dominate every other loop's
+    scanned inputs; the unified step's KV pools ride its carry, not its
+    xs). Recurses sub-jaxprs."""
     best, best_bytes = None, -1
     for eqn in _iter_eqns_all(jaxpr):
         if eqn.primitive.name != "scan":
@@ -244,14 +245,18 @@ def static_hbm_report(closed, n_param_leaves: int, pool_avals, *,
 
     # carry layout discriminates the activation regime: the megakernel path
     # scans a blocked [b, chunk, h] lane carry, the per-op chain a packed
-    # [t, h] stream. h is the carry's minor dim, act dtype its dtype.
+    # [t, h] stream. h is the carry's minor dim, act dtype its dtype. The
+    # unified step carries its stacked pools and scale planes too (rank 5
+    # and 4, each chip's head shard under a mesh): told apart by rank.
     n_consts = int(scan.params.get("num_consts", 0))
     n_carry = int(scan.params.get("num_carry", 0))
+    pool_ranks = {len(a.shape) for a in pool_avals if a is not None}
     carries = [getattr(v, "aval", None)
                for v in scan.invars[n_consts:n_consts + n_carry]]
-    carries = [a for a in carries if a is not None and len(a.shape)]
+    carries = [a for a in carries if a is not None and len(a.shape)
+               and len(a.shape) not in pool_ranks]
     if not carries:
-        raise ValueError("layer scan has no array carry")
+        raise ValueError("layer scan has no activation carry")
     carry = max(carries, key=_aval_bytes)
     mega = len(carry.shape) == 3
     hidden = int(carry.shape[-1])

@@ -63,6 +63,7 @@ from __future__ import annotations
 import math
 import zlib
 from collections import OrderedDict
+from typing import NamedTuple
 
 import numpy as np
 
@@ -177,8 +178,76 @@ def _packed_dest(page_table, tok_slot, tok_pos, page_size, num_pages):
     return jnp.where(valid, pg, num_pages), pos % page_size
 
 
+class PackedWritePlan(NamedTuple):
+    """One step's packed write, page by page (see
+    :func:`packed_write_plan`): ``order/page/lo/hi [n]`` in the write
+    kernel's grid order, ``src [n, page_size]`` the packed token whose row
+    lands at each row of each touched page."""
+    order: jax.Array
+    page: jax.Array
+    lo: jax.Array
+    hi: jax.Array
+    src: jax.Array
+
+
+def packed_write_plan(page_table, tok_slot, tok_pos, page_size, num_pages):
+    """The packed write's destinations regrouped by TOUCHED PAGE, for the
+    in-place kernel (``ops/pallas/paged_write``): made once per step from
+    the arguments every packed write of the step shares, used by each
+    layer's writes (``plan=``).
+
+    A row is narrower than a tile of the pool, so the kernel rewrites whole
+    pages: item ``i`` is one touched page, ``lo[i] .. hi[i]`` its new rows.
+    The bound on touched pages, ``n = 2 * batch + budget // page_size``,
+    holds because a slot's rows of one step are consecutive positions (the
+    same contract ragged attention's ``kv_lens``/``q_lens`` state), and for
+    the same reason a page's new rows are one run. Grid steps past the last
+    touched page repeat it (the kernel's no-stale-read rule); with nothing
+    to write every step rewrites page 0 with itself.
+    """
+    b, t = page_table.shape[0], tok_slot.shape[0]
+    n = 2 * b + t // page_size
+    pg, row = _packed_dest(page_table, tok_slot, tok_pos, page_size,
+                           num_pages)
+    # ascending, so the dropped tokens' sentinel (num_pages) sorts last
+    touched = jnp.unique(pg, size=n, fill_value=num_pages)
+    item = jnp.where(pg < num_pages, jnp.searchsorted(touched, pg), n)
+    src = jnp.zeros((n, page_size), jnp.int32).at[item, row].set(
+        jnp.arange(t, dtype=jnp.int32), mode="drop")
+    lo = jnp.full((n,), page_size, jnp.int32).at[item].min(row, mode="drop")
+    hi = jnp.zeros((n,), jnp.int32).at[item].max(row + 1, mode="drop")
+    last = jnp.maximum(jnp.sum(touched < num_pages) - 1, 0)
+    order = jnp.minimum(jnp.arange(n), last).astype(jnp.int32)
+    return PackedWritePlan(
+        order=order, page=jnp.clip(touched[order], 0, num_pages - 1),
+        lo=lo[order], hi=hi[order], src=src)
+
+
+def _write_rows(pages, vals, page_table, tok_slot, tok_pos, page_size,
+                layer, plan):
+    """The one row write behind the three packed writes. ``vals [budget,
+    kv_heads(, head_dim)]`` land in ``pages`` (a pool or a scale plane) at
+    ``[pg, :, row]``; with ``layer``, at ``[layer, pg, :, row]`` of the
+    STACKED ``[num_layers, num_pages, ...]`` pool, the other layers
+    untouched; with ``plan`` too, through the in-place Pallas kernel."""
+    vals = vals.astype(pages.dtype)
+    if plan is not None:
+        from ..ops.pallas.paged_write import paged_write_pages
+
+        # page-aligned new rows, [n, kv_heads, page_size(, head_dim)]
+        new = jnp.swapaxes(vals[plan.src], 1, 2)
+        return paged_write_pages(pages, new, plan.order, plan.page,
+                                 plan.lo, plan.hi, layer)
+    pg, row = _packed_dest(page_table, tok_slot, tok_pos, page_size,
+                           pages.shape[0 if layer is None else 1])
+    dest = (pg, slice(None), row)
+    if layer is not None:
+        dest = (layer,) + dest
+    return pages.at[dest].set(vals, mode="drop")
+
+
 def paged_write_packed(pages, toks, page_table, tok_slot, tok_pos,
-                       page_size):
+                       page_size, layer=None, plan=None):
     """Write a PACKED token stream into the page pool in one scatter (the
     unified-step write: the step's dense dims run over the flat token
     budget, each token carrying its owning slot + absolute position).
@@ -187,14 +256,22 @@ def paged_write_packed(pages, toks, page_table, tok_slot, tok_pos,
     kv_heads, head_dim]; page_table: [batch, pages_per_slot] int32;
     tok_slot: [budget] int32 owning slot (< 0 = padding, dropped);
     tok_pos: [budget] int32 absolute write position. Returns the pool.
+
+    ``layer`` (a traced scalar; all three packed writes take it): ``pages``
+    is the STACKED pool ``[num_layers, num_pages, ...]`` and the rows land
+    in that layer of it — how the unified step's layer scan writes into the
+    one buffer it carries, without slicing a layer's pool out of the stack.
+    ``plan`` (:func:`packed_write_plan` of the same ``page_table``,
+    ``tok_slot``, ``tok_pos``; needs ``layer``) writes through the Pallas
+    kernel that updates the stack in place: on the chip XLA's scatter
+    copies its whole operand into a layout of its own and back.
     """
-    pg, row = _packed_dest(page_table, tok_slot, tok_pos, page_size,
-                           pages.shape[0])
-    return pages.at[pg, :, row].set(toks, mode="drop")
+    return _write_rows(pages, toks, page_table, tok_slot, tok_pos,
+                       page_size, layer, plan)
 
 
 def paged_write_packed_quant(pages, scales, toks, page_table, tok_slot,
-                             tok_pos, page_size):
+                             tok_pos, page_size, layer=None, plan=None):
     """Quantize-on-write for the int8 KV cache: the packed write
     (:func:`paged_write_packed`) with a per-token-per-head symmetric int8
     quantization fused in front of the scatter.
@@ -207,19 +284,18 @@ def paged_write_packed_quant(pages, scales, toks, page_table, tok_slot,
     (``scale = absmax / 127``), so pages never need rescaling as later
     tokens land. Returns ``(pages, scales)``.
     """
-    pg, row = _packed_dest(page_table, tok_slot, tok_pos, page_size,
-                           pages.shape[0])
     tf = toks.astype(jnp.float32)
     absmax = jnp.max(jnp.abs(tf), axis=-1)           # [budget, kv_heads]
     s = jnp.maximum(absmax, 1e-8) / 127.0
     q = jnp.clip(jnp.round(tf / s[..., None]), -127, 127).astype(jnp.int8)
-    pages = pages.at[pg, :, row].set(q, mode="drop")
-    scales = scales.at[pg, :, row].set(s.astype(scales.dtype), mode="drop")
-    return pages, scales
+    return paged_write_packed_prequant(pages, scales, q, s, page_table,
+                                       tok_slot, tok_pos, page_size,
+                                       layer, plan)
 
 
 def paged_write_packed_prequant(pages, scales, q_toks, s_toks, page_table,
-                                tok_slot, tok_pos, page_size):
+                                tok_slot, tok_pos, page_size, layer=None,
+                                plan=None):
     """Scatter ALREADY-QUANTIZED packed K/V rows + their scale rows into
     the int8 pool — the round-16 megakernel write path: the fused layer
     kernel quantizes the new token's K/V inline in VMEM (the exact
@@ -233,13 +309,9 @@ def paged_write_packed_prequant(pages, scales, q_toks, s_toks, page_table,
     scatter is position-addressed and never cared how many rows a lane
     contributed. Returns ``(pages, scales)``.
     """
-    pg, row = _packed_dest(page_table, tok_slot, tok_pos, page_size,
-                           pages.shape[0])
-    pages = pages.at[pg, :, row].set(q_toks.astype(pages.dtype),
-                                     mode="drop")
-    scales = scales.at[pg, :, row].set(s_toks.astype(scales.dtype),
-                                       mode="drop")
-    return pages, scales
+    dest = (page_table, tok_slot, tok_pos, page_size, layer, plan)
+    return (_write_rows(pages, q_toks, *dest),
+            _write_rows(scales, s_toks, *dest))
 
 
 def paged_copy_pages(pages, src, dst):

@@ -1002,6 +1002,19 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
     Every array argument keeps its shape step over step: one trace, one
     executable (``fn.trace_count[0]`` is the gate).
 
+    WHERE THE POOLS LIVE (PR 27): ``k_pages`` / ``v_pages`` (and, int8, the
+    scale planes) are stacked ``[num_layers, num_pages, kv_heads, page_size,
+    head_dim]`` and stay ONE donated buffer from argument to result. The
+    layer scan carries them (its scanned inputs are the stacked weights and
+    the layer index, its scanned outputs nothing); layer ``i`` writes its
+    new rows into the stack at ``[i, page, :, row]``
+    (``paged_write_packed*(layer=i)``; where the kernels run, through the
+    Pallas kernel that aliases the stack) and ``ragged_paged_attention(
+    layer=i)`` reads layer ``i`` of it through its block index maps. No
+    layer's pool is sliced out of the stack or stacked back: as scanned
+    inputs and outputs the pools cost five pool-sized copies per layer and
+    step and a second copy of both stacks in the program's temp.
+
     DEVICE-RESIDENT FEEDBACK (round 13, the async engine's enabler):
     ``feedback[t]`` marks packed tokens whose id the HOST DOES NOT KNOW
     YET — the step reads them from ``prev_toks[tok_slot]`` instead of
@@ -1101,11 +1114,13 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
     import jax
     import jax.numpy as jnp
 
-    from ..inference.kv_cache import (paged_copy_pages, paged_write_packed,
+    from ..inference.kv_cache import (packed_write_plan, paged_copy_pages,
+                                      paged_write_packed,
                                       paged_write_packed_prequant,
                                       paged_write_packed_quant)
     from ..observability.tracing import step_scope
-    from ..ops.pallas.paged_attention import ragged_paged_attention
+    from ..ops.pallas.paged_attention import (ragged_paged_attention,
+                                              use_kernel_default)
 
     cfg = config
     eps = cfg.layer_norm_eps
@@ -1204,12 +1219,23 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
         off_c = jnp.clip(off, 0, chunk - 1)
         scatter_b = jnp.where(valid, tok_slot, b)    # b = dropped row
 
-        def block(x, layer):
-            if kv_quant:
-                p, kp, vp, ks, vs = layer
-            else:
-                p, kp, vp = layer
-                ks = vs = None
+        # The layer scan CARRIES the stacked pools (and scale planes): one
+        # donated buffer from argument to result. A layer writes its rows
+        # into it at [layer, page, :, row] and the ragged kernel reads
+        # layer i of it by index, so nothing pool-shaped is ever sliced
+        # out of the stack or stacked back (as scanned inputs/outputs the
+        # pools cost five pool-sized copies per layer and step on the
+        # chip). Where the kernels run, the write is one too: XLA's scatter
+        # would move the whole stack to a layout of its own and back.
+        dest = (page_table, tok_slot, tok_pos, page_size)
+        kernels = use_kernel_default() if use_kernel is None else use_kernel
+        with step_scope("kv_write"):
+            plan = (packed_write_plan(*dest, k_pages.shape[1]) if kernels
+                    else None)
+
+        def block(carry, layer):
+            x, kp, vp, ks, vs = carry
+            p, li = layer
             with step_scope("ln"):
                 y = _srv_ln(x, p["ln1_g"], p["ln1_b"], eps)
             with step_scope("qkv"):
@@ -1218,23 +1244,22 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
                                          head_major=mesh is not None)
             with step_scope("kv_write"):
                 if kv_quant:
-                    kp, ks = paged_write_packed_quant(
-                        kp, ks, k_t, page_table, tok_slot, tok_pos,
-                        page_size)
-                    vp, vs = paged_write_packed_quant(
-                        vp, vs, v_t, page_table, tok_slot, tok_pos,
-                        page_size)
+                    kp, ks = paged_write_packed_quant(kp, ks, k_t, *dest,
+                                                      layer=li, plan=plan)
+                    vp, vs = paged_write_packed_quant(vp, vs, v_t, *dest,
+                                                      layer=li, plan=plan)
                 else:
-                    kp = paged_write_packed(kp, k_t, page_table, tok_slot,
-                                            tok_pos, page_size)
-                    vp = paged_write_packed(vp, v_t, page_table, tok_slot,
-                                            tok_pos, page_size)
+                    kp = paged_write_packed(kp, k_t, *dest, layer=li,
+                                            plan=plan)
+                    vp = paged_write_packed(vp, v_t, *dest, layer=li,
+                                            plan=plan)
             with step_scope("attn"):
                 qb = jnp.zeros((b, chunk, nh_l, hd), q.dtype
                                ).at[scatter_b, off_c].set(q, mode="drop")
                 ab = ragged_paged_attention(qb, kp, vp, page_table, ctx,
                                             q_lens, use_kernel=use_kernel,
-                                            k_scales=ks, v_scales=vs)
+                                            k_scales=ks, v_scales=vs,
+                                            layer=li)
                 a = ab[slot_c, off_c]                # back to packed [t]
             with step_scope("attn_out"):
                 x = x + _srv_psum(_srv_mm(a.reshape(t, nh_l * hd), p["wo"],
@@ -1243,24 +1268,25 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
                 y = _srv_ln(x, p["ln2_g"], p["ln2_b"], eps)
             with step_scope("mlp"):
                 x = x + _srv_ffn(cfg, p, y, use_kernel, axis, valid=valid)
-            return x, ((kp, vp, ks, vs) if kv_quant else (kp, vp))
+            return (x, kp, vp, ks, vs), None
 
-        def mega_block(xb, layer):
+        def mega_block(carry, layer):
             # the round-16 fused layer (round 22: ragged chunks, any
             # 1..chunk rows per lane): the whole attention side is ONE
             # kernel over the [b, chunk] lane blocks (attention reads the
             # pool at kv_lens and handles this step's rows in-register —
             # same math as write-then-attend at ctx), the MLP side one
             # more; only the emitted new K/V rows touch HBM between them
-            if kv_quant:
-                p, kp, vp, ks, vs = layer
-            else:
-                p, kp, vp = layer
-                ks = vs = None
+            xb, kp, vp, ks, vs = carry
+            p, li = layer
             h = xb.shape[-1]
-            res = mega_attn_layer(xb, p, kp, vp, page_table, kv_lens,
-                                  q_lens, eps=eps, k_scales=ks,
-                                  v_scales=vs,
+            # the fused kernels still take ONE layer's pool: the slice
+            # stays until mega_attn_layer lowers (ROADMAP.md D3) and can
+            # be given the layer index like the ragged kernel
+            res = mega_attn_layer(xb, p, kp[li], vp[li], page_table,
+                                  kv_lens, q_lens, eps=eps,
+                                  k_scales=None if ks is None else ks[li],
+                                  v_scales=None if vs is None else vs[li],
                                   head_major=mesh is not None,
                                   use_kernel=use_kernel,
                                   fuse_epilogue=fuse_mega)
@@ -1285,17 +1311,15 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
                 # token's row out of its lane block)
                 kp, ks = paged_write_packed_prequant(
                     kp, ks, k_new[slot_c, off_c], k_sc[slot_c, off_c],
-                    page_table, tok_slot, tok_pos, page_size)
+                    *dest, layer=li, plan=plan)
                 vp, vs = paged_write_packed_prequant(
                     vp, vs, v_new[slot_c, off_c], v_sc[slot_c, off_c],
-                    page_table, tok_slot, tok_pos, page_size)
+                    *dest, layer=li, plan=plan)
             else:
-                kp = paged_write_packed(kp, k_new[slot_c, off_c],
-                                        page_table, tok_slot, tok_pos,
-                                        page_size)
-                vp = paged_write_packed(vp, v_new[slot_c, off_c],
-                                        page_table, tok_slot, tok_pos,
-                                        page_size)
+                kp = paged_write_packed(kp, k_new[slot_c, off_c], *dest,
+                                        layer=li, plan=plan)
+                vp = paged_write_packed(vp, v_new[slot_c, off_c], *dest,
+                                        layer=li, plan=plan)
             if fuse_mega:
                 out = mega_mlp(y2.reshape(b * chunk, h),
                                s.reshape(b * chunk, h), p,
@@ -1306,28 +1330,25 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
                                 fuse_epilogue=False, chunk=chunk)
                 out = (s.reshape(b * chunk, h)
                        + (_srv_psum(part, axis) + p["b2"]))
-            return (out.reshape(b, chunk, h),
-                    ((kp, vp, ks, vs) if kv_quant else (kp, vp)))
+            return (out.reshape(b, chunk, h), kp, vp, ks, vs), None
 
         if mega:
             # lane-block layout for the fused layers: packed tokens
             # scatter into their [b, chunk] rows once, stay blocked
             # through every layer, and gather back for the epilogue
-            carry0 = jnp.zeros((b, chunk, x.shape[-1]), x.dtype
-                               ).at[scatter_b, off_c].set(x, mode="drop")
+            x0 = jnp.zeros((b, chunk, x.shape[-1]), x.dtype
+                           ).at[scatter_b, off_c].set(x, mode="drop")
             body = mega_block
         else:
-            carry0, body = x, block
+            x0, body = x, block
+        layers = (params["layers"],
+                  jnp.arange(k_pages.shape[0], dtype=jnp.int32))
         # the scan itself is scoped, so its own slicing of the stacked
-        # pools and stacking of its outputs fall under "layers" alone
+        # weights falls under "layers" alone (fp pools: the scale planes
+        # are None, an empty part of the carry)
         with step_scope("layers"):
-            if kv_quant:
-                x, (k_pages, v_pages, k_scales, v_scales) = jax.lax.scan(
-                    body, carry0, (params["layers"], k_pages, v_pages,
-                                   k_scales, v_scales))
-            else:
-                x, (k_pages, v_pages) = jax.lax.scan(
-                    body, carry0, (params["layers"], k_pages, v_pages))
+            (x, k_pages, v_pages, k_scales, v_scales), _ = jax.lax.scan(
+                body, (x0, k_pages, v_pages, k_scales, v_scales), layers)
         if mega:
             x = x[slot_c, off_c]                     # back to packed [t]
         if spec_k:

@@ -185,11 +185,14 @@ def _kernel_impl(q4, k_pages, v_pages, page_table, lengths, scale):
 # ---------------------------------------------------------------------------
 
 
-def gather_pages(pool, pt):
+def gather_pages(pool, pt, layer=None):
     """A page pool ``[num_pages, kv_heads, page_size, ...]`` gathered by the
     (clipped) page table ``pt [b, pps]`` into each sequence's contiguous
-    token-major view ``[b, pps * page_size, kv_heads, ...]``."""
-    g = jnp.swapaxes(pool[pt], 2, 3)          # [b, pps, ps, hkv, ...]
+    token-major view ``[b, pps * page_size, kv_heads, ...]``. With ``layer``
+    the pool is stacked ``[num_layers, num_pages, ...]`` and the one gather
+    reads that layer's pages."""
+    g = pool[pt] if layer is None else pool[layer, pt]
+    g = jnp.swapaxes(g, 2, 3)                 # [b, pps, ps, hkv, ...]
     return g.reshape(g.shape[0], -1, *g.shape[3:])
 
 
@@ -335,18 +338,31 @@ def _ragged_kernel(lens_ref, qlens_ref, pt_ref, q_ref, k_ref, v_ref,
         l_ref[...] = l_next
 
 
+def _ragged_kernel_stacked(body, lens_ref, qlens_ref, pt_ref, layer_ref,
+                           *refs):
+    """The stacked pools' form of the kernel: the same body; the fourth
+    scalar-prefetch operand, the layer index, is the index maps' business
+    alone."""
+    return body(lens_ref, qlens_ref, pt_ref, *refs)
+
+
 def _ragged_kernel_impl(q4, k_pages, v_pages, page_table, kv_lens, q_lens,
-                        group, scale, k_scales=None, v_scales=None):
+                        group, scale, k_scales=None, v_scales=None,
+                        layer=None):
     """q4: [b, kv_heads, R, d] with R = chunk*group padded to the sublane
     tile; returns [b, kv_heads, R, d] fp32. ``k_scales``/``v_scales``
-    ([num_pages, kv_heads, page_size] or None) flip the int8-KV kernel."""
+    ([num_pages, kv_heads, page_size] or None) flip the int8-KV kernel.
+    ``layer`` (an int32 scalar, or None): the pools and scale planes are
+    stacked ``[num_layers, ...]`` and the block index maps lead with it —
+    a fourth scalar-prefetch operand; the body never sees the stack."""
     b, hkv, r8, d = q4.shape
-    num_pages, page_size = k_pages.shape[0], k_pages.shape[2]
+    stacked = layer is not None
+    num_pages, page_size = k_pages.shape[-4], k_pages.shape[-2]
     pps = page_table.shape[1]
     grid = (b, hkv, pps)
     quant = k_scales is not None
 
-    def kv_page(bi, h, j, lens_ref, qlens_ref, pt_ref):
+    def kv_page(bi, h, j, lens_ref, qlens_ref, pt_ref, *layer_ref):
         # identical clamping to the decode kernel: pages past the last
         # valid one re-fetch it (their compute is skipped)
         ps = jnp.int32(page_size)
@@ -354,20 +370,23 @@ def _ragged_kernel_impl(q4, k_pages, v_pages, page_table, kv_lens, q_lens,
             jax.lax.div(lens_ref[bi] + ps - jnp.int32(1), ps) - jnp.int32(1),
             jnp.int32(0))
         page = pt_ref[bi, jnp.minimum(jnp.int32(j), last)]
-        return jnp.clip(page, 0, num_pages - 1)
+        # the stacked forms' leading block index: this call's layer
+        return (tuple(ref[0] for ref in layer_ref)
+                + (jnp.clip(page, 0, num_pages - 1),))
 
     def kv_imap(bi, h, j, *refs):
-        return (kv_page(bi, h, j, *refs), h, 0, 0)
+        return kv_page(bi, h, j, *refs) + (h, 0, 0)
 
     def scale_imap(bi, h, j, *refs):
-        return (kv_page(bi, h, j, *refs), 0, 0)
+        return kv_page(bi, h, j, *refs) + (0, 0)
 
+    lead = (None,) if stacked else ()
     q_spec = pl.BlockSpec((None, None, r8, d), lambda bi, h, j, *_: (bi, h, 0, 0))
-    kv_spec = pl.BlockSpec((None, None, page_size, d), kv_imap)
+    kv_spec = pl.BlockSpec(lead + (None, None, page_size, d), kv_imap)
     # a page's scales for ALL local heads ([kv_heads, page_size] — the
     # plane's full last two dims, which is what the (8, 128) block rule
     # admits); the kernel slices its own head's row
-    sc_spec = pl.BlockSpec((None, hkv, page_size), scale_imap)
+    sc_spec = pl.BlockSpec(lead + (None, hkv, page_size), scale_imap)
     o_spec = pl.BlockSpec((None, None, r8, d), lambda bi, h, j, *_: (bi, h, 0, 0))
     ml_spec = pl.BlockSpec((None, None, r8, 1), lambda bi, h, j, *_: (bi, h, 0, 0))
 
@@ -376,8 +395,15 @@ def _ragged_kernel_impl(q4, k_pages, v_pages, page_table, kv_lens, q_lens,
     if quant:
         in_specs += [sc_spec, sc_spec]
         args += [k_scales.astype(jnp.float32), v_scales.astype(jnp.float32)]
+    prefetch = [kv_lens.astype(jnp.int32), q_lens.astype(jnp.int32),
+                page_table.astype(jnp.int32)]
+    kern = functools.partial(_ragged_kernel, page_size=page_size,
+                             group=group, scale=scale, quant=quant)
+    if stacked:
+        prefetch.append(jnp.asarray(layer, jnp.int32).reshape(1))
+        kern = functools.partial(_ragged_kernel_stacked, kern)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=len(prefetch),
         grid=grid,
         in_specs=in_specs,
         out_specs=[o_spec, ml_spec, ml_spec],
@@ -387,22 +413,20 @@ def _ragged_kernel_impl(q4, k_pages, v_pages, page_table, kv_lens, q_lens,
         jax.ShapeDtypeStruct((b, hkv, r8, 1), jnp.float32),
         jax.ShapeDtypeStruct((b, hkv, r8, 1), jnp.float32),
     ]
-    kern = functools.partial(_ragged_kernel, page_size=page_size,
-                             group=group, scale=scale, quant=quant)
     with _atc.x64_off():
         out, _, _ = pl.pallas_call(
             kern, grid_spec=grid_spec, out_shape=out_shape,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary", "arbitrary")),
             interpret=_interpret(), name=RAGGED_KERNEL_NAME,
-        )(kv_lens.astype(jnp.int32), q_lens.astype(jnp.int32),
-          page_table.astype(jnp.int32), *args)
+        )(*prefetch, *args)
     return out
 
 
 def ragged_paged_attention_reference(q, k_pages, v_pages, page_table,
                                      kv_lens, q_lens, scale=None,
-                                     k_scales=None, v_scales=None):
+                                     k_scales=None, v_scales=None,
+                                     layer=None):
     """Gather-based oracle for the ragged kernel (and the non-TPU path).
 
     q: [b, chunk, num_q_heads, d] right-padded query chunks; kv_lens: [b]
@@ -411,20 +435,24 @@ def ragged_paged_attention_reference(q, k_pages, v_pages, page_table,
     b sits at absolute position ``kv_lens[b] - q_lens[b] + t`` and attends
     all keys at positions <= its own. With ``k_scales``/``v_scales``
     ([num_pages, kv_heads, page_size]) the pages are int8 and dequantize
-    after the gather. Returns [b, chunk, num_q_heads, d].
+    after the gather. ``layer``: the pools and scale planes are stacked
+    ``[num_layers, ...]`` and the gather reads that layer of them. Returns
+    [b, chunk, num_q_heads, d].
     """
     b, c, hq, d = q.shape
-    num_pages, hkv, page_size, _ = k_pages.shape
+    num_pages, hkv, page_size, _ = k_pages.shape[-4:]
     pps = page_table.shape[1]
     group = hq // hkv
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     pt = jnp.clip(page_table, 0, num_pages - 1)
-    k = gather_pages(k_pages, pt)
-    v = gather_pages(v_pages, pt)
+    k = gather_pages(k_pages, pt, layer)
+    v = gather_pages(v_pages, pt, layer)
     if k_scales is not None:
-        k = k.astype(jnp.float32) * gather_pages(k_scales, pt)[..., None]
-        v = v.astype(jnp.float32) * gather_pages(v_scales, pt)[..., None]
+        k = k.astype(jnp.float32) * gather_pages(k_scales, pt,
+                                                 layer)[..., None]
+        v = v.astype(jnp.float32) * gather_pages(v_scales, pt,
+                                                 layer)[..., None]
     qg = q.reshape(b, c, hkv, group, d)
     s = jnp.einsum("bchgd,bshd->bhgcs", qg.astype(jnp.float32),
                    k.astype(jnp.float32), precision=_MXU) * scale
@@ -445,7 +473,7 @@ def ragged_paged_attention_reference(q, k_pages, v_pages, page_table,
 
 def ragged_paged_attention(q, k_pages, v_pages, page_table, kv_lens, q_lens,
                            scale=None, use_kernel: bool | None = None,
-                           k_scales=None, v_scales=None):
+                           k_scales=None, v_scales=None, layer=None):
     """Ragged prefill+decode attention over the paged KV cache.
 
     The unified-step entry: each slot contributes ``q_lens[b]`` (0..chunk)
@@ -458,11 +486,24 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, kv_lens, q_lens,
     pools int8 (round-10 quantized KV); dequantization fuses into the
     kernel's page loop (or the gathered reference) — pages stay int8
     end-to-end in HBM.
+
+    Where the pools live: the unified step keeps every layer's pool in ONE
+    stacked buffer ``[num_layers, num_pages, kv_heads, page_size, d]`` (scale
+    planes ``[num_layers, num_pages, kv_heads, page_size]``) for the whole
+    step and passes it here whole, with ``layer`` (a traced int32 scalar)
+    naming the layer to read: the kernel's block index maps lead with it, so
+    no layer's pool is ever sliced out of the stack (151 MB a layer and pool
+    at the 590M deployment). Without ``layer`` the pools are one layer's,
+    4-D, as the decode step, the draft chain and the autotuners pass them.
     """
     b, c, hq, d = q.shape
-    hkv = k_pages.shape[1]
+    hkv = k_pages.shape[-3]
     assert hq % hkv == 0, f"GQA needs q heads {hq} divisible by kv {hkv}"
     assert k_pages.shape == v_pages.shape
+    assert k_pages.ndim == (4 if layer is None else 5), (
+        f"pools of rank {k_pages.ndim} with layer={layer!r}: a stacked "
+        "[num_layers, ...] pool needs layer=, one layer's pool must not "
+        "have it")
     assert page_table.shape[0] == b
     assert kv_lens.shape == (b,) and q_lens.shape == (b,)
     assert (k_scales is None) == (v_scales is None)
@@ -473,7 +514,7 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, kv_lens, q_lens,
     if not use_kernel:
         return ragged_paged_attention_reference(
             q, k_pages, v_pages, page_table, kv_lens, q_lens, scale=scale,
-            k_scales=k_scales, v_scales=v_scales)
+            k_scales=k_scales, v_scales=v_scales, layer=layer)
     group = hq // hkv
     # rows = chunk-major, group-minor: [b, c, hkv, g, d] -> [b, hkv, c*g, d]
     q4 = q.reshape(b, c, hkv, group, d).transpose(0, 2, 1, 3, 4)
@@ -483,7 +524,8 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, kv_lens, q_lens,
         q4 = jnp.pad(q4, ((0, 0), (0, 0), (0, r8 - c * group), (0, 0)))
     out = _ragged_kernel_impl(q4, k_pages, v_pages, page_table, kv_lens,
                               q_lens, group, float(scale),
-                              k_scales=k_scales, v_scales=v_scales)
+                              k_scales=k_scales, v_scales=v_scales,
+                              layer=layer)
     out = out[:, :, :c * group, :].reshape(b, hkv, c, group, d)
     out = out.transpose(0, 2, 1, 3, 4).reshape(b, c, hq, d)
     return out.astype(q.dtype)

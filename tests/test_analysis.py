@@ -1025,7 +1025,7 @@ class TestCostModel:
 
     L, H, T = 2, 8, 4
 
-    def _toy(self):
+    def _toy(self, carried_pools=False):
         import jax.numpy as jnp
         from jax import lax
 
@@ -1041,7 +1041,17 @@ class TestCostModel:
             def body(c, w):
                 return c @ w, ()
 
-            c, _ = lax.scan(body, emb[:t], stack)
+            def carrying(c, w):
+                # the unified step's form: the stacked pools ride the
+                # carry beside the activation and dwarf it
+                x, kp, vp = c
+                return (x @ w, kp.at[0, 0, 0, 0].add(x[0, :4]), vp), ()
+
+            if carried_pools:
+                (c, k_pages, v_pages), _ = lax.scan(
+                    carrying, (emb[:t], k_pages, v_pages), stack)
+            else:
+                c, _ = lax.scan(body, emb[:t], stack)
             return c.sum() + k_pages.sum() + v_pages.sum()
 
         closed = trace_callable(step, emb, stack, k_pages, v_pages)
@@ -1058,10 +1068,11 @@ class TestCostModel:
         base.update(kw)
         return ServingGeometry(**base)
 
-    def test_static_report_matches_hand_count(self):
+    @pytest.mark.parametrize("carried_pools", [False, True])
+    def test_static_report_matches_hand_count(self, carried_pools):
         from paddle_tpu.analysis import cost_model
 
-        closed, pools = self._toy()
+        closed, pools = self._toy(carried_pools)
         rep = cost_model.static_hbm_report(closed, 2, pools,
                                            batch=2, avg_ctx=8.0)
         assert rep["num_layers"] == self.L and rep["hidden"] == self.H

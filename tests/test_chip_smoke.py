@@ -85,3 +85,43 @@ def test_chip_peak_raises_on_an_unknown_device():
         bench._chip_peak("TPU v9 imaginary")
     with pytest.raises(ValueError, match="no peak FLOP/s known"):
         bench._chip_peak("cpu")
+
+
+# the forms a pool-sized move takes in the compiled serving step (TPU HLO of
+# the 590M deployment, cut down): a fused in-place update inside the
+# copy-on-write loop, whose name sits two callers up, and the parent's three
+# kinds of whole-pool copy
+_HLO = """
+%fused_computation.25 (param_0.1: bf16[18,768,12,64,128], param_1.2: s32[]) -> bf16[18,768,12,64,128] {
+  ROOT %dynamic-update-slice.20 = bf16[18,768,12,64,128]{4,3,2,1,0:T(8,128)(2,1)} dynamic-update-slice(%param_0.1, %select.4, %param_1.2)
+}
+%wide.while_body.2 (wide.param.1: (s32[], bf16[18,768,12,64,128])) -> (s32[], bf16[18,768,12,64,128]) {
+  %fusion.219 = bf16[18,768,12,64,128]{4,3,2,1,0:T(8,128)(2,1)} fusion(%get-tuple-element.850), kind=kLoop, calls=%fused_computation.25
+}
+%fused_computation.9 (param_0.7: bf16[18,768,12,64,128], param_1.8: s32[]) -> bf16[18,768,12,64,128] {
+  ROOT %dynamic_update_slice.13 = bf16[18,768,12,64,128]{4,3,2,1,0:T(8,128)(2,1)} dynamic-update-slice(%param_0.7, %copy.78, %param_1.8)
+}
+%region_3.22 (arg_tuple.0: (s32[], bf16[512,1536])) -> (s32[], bf16[512,1536]) {
+  %copy.83 = bf16[768,12,64,128]{3,2,1,0:T(8,128)(2,1)} copy(%fusion.200), metadata={op_name="jit(step)/layers/while/body/closed_call/kv_write/scatter" stack_frame_id=69}
+  %stacked.4 = bf16[18,768,12,64,128]{4,3,2,1,0:T(8,128)(2,1)} fusion(%get-tuple-element.9), kind=kLoop, calls=%fused_computation.9, metadata={op_name="jit(step)/layers/while/body/dynamic_update_slice"}
+  %paged_kv_write.8 = bf16[18,768,12,64,128]{4,3,2,1,0:T(8,128)(2,1)} custom-call(%a, %b), custom_call_target="tpu_custom_call"
+}
+ENTRY %main.48 (args_11_.1: bf16[18,768,12,64,128]) -> bf16[18,768,12,64,128] {
+  %while.11 = (s32[], bf16[18,768,12,64,128]) while(%tuple.183), condition=%wide.while_cond.2, body=%wide.while_body.2, metadata={op_name="jit(step)/cow/gather" stack_frame_id=25}
+  %copy.137 = bf16[18,768,12,64,128]{4,3,2,1,0:T(8,128)(2,1)} copy(%get-tuple-element.1007), backend_config={"flag_configs":[]}
+  %copy.5 = bf16[512,12,128]{2,1,0:T(8,128)(2,1)} copy(%fusion.3)
+}
+"""
+
+
+def test_pool_copies_finds_whole_pool_moves_and_lets_cow_through():
+    sys.path.insert(0, REPO)
+    try:
+        from chip_smoke import pool_copies
+    finally:
+        sys.path.remove(REPO)
+    found = pool_copies(_HLO, (18, 768, 12, 64, 128))
+    assert [line.split(" = ")[0].split("%")[-1] for line in found] == [
+        "dynamic_update_slice.13", "copy.83", "copy.137"]
+    # another deployment's pool: nothing here has its shape
+    assert pool_copies(_HLO, (24, 136, 12, 64, 128)) == []

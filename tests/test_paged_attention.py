@@ -369,3 +369,133 @@ def test_paged_write_packed_quant_roundtrip(rng):
         assert (np.abs(got - want) <= bound).all()
     # padding token wrote nowhere: only the two target rows are nonzero
     assert int((np.asarray(scales) != 0).sum()) == 2 * h
+
+
+# -- PR 27: the stacked pool, addressed by layer index ----------------------
+
+LAYERS = 3
+
+
+def _stack(rng, pool):
+    """LAYERS different pools of ``pool``'s shape and dtype, stacked."""
+    noise = rng.randn(LAYERS, *pool.shape) * 0.5
+    if jnp.issubdtype(pool.dtype, jnp.integer):
+        return jnp.asarray(np.clip(np.round(noise * 60), -127, 127),
+                           pool.dtype)
+    if pool.ndim == 3:                      # a scale plane: positive
+        noise = np.abs(noise) + 0.01
+    return jnp.asarray(noise, pool.dtype)
+
+
+@pytest.mark.parametrize("layer", range(LAYERS))
+@pytest.mark.parametrize("kv", ["fp", "int8"])
+@pytest.mark.parametrize("use_kernel", [True, False],
+                         ids=["kernel", "reference"])
+def test_ragged_layer_form_equals_pool_of_that_layer(rng, use_kernel, kv,
+                                                     layer):
+    """``layer=i`` on the stacked pools reads exactly what the 4-D form
+    reads from ``pools[i]``: the same kernel body behind another index map
+    (bit-equal), the same oracle behind ``pools[layer]``."""
+    b, c, hq, hkv, d, page_size, pps = 3, 4, 8, 2, 16, 8, 3
+    q, kp, vp, pt = _ragged_case(rng, b, c, hq, hkv, d, page_size, pps)
+    kv_lens = jnp.asarray([17, 4, 0], jnp.int32)
+    q_lens = jnp.asarray([1, 4, 0], jnp.int32)
+    if kv == "int8":
+        kp, ks, vp, vs = _quant_pools(kp, vp)
+        stacks = [_stack(rng, p) for p in (kp, vp, ks, vs)]
+    else:
+        stacks = [_stack(rng, p) for p in (kp, vp)] + [None, None]
+    k5, v5, ks4, vs4 = stacks
+    one = [None if s is None else s[layer] for s in stacks]
+    want = pa.ragged_paged_attention(
+        q, one[0], one[1], pt, kv_lens, q_lens, use_kernel=use_kernel,
+        k_scales=one[2], v_scales=one[3])
+    # a traced index, as the layer scan hands it over
+    got = jax.jit(lambda li: pa.ragged_paged_attention(
+        q, k5, v5, pt, kv_lens, q_lens, use_kernel=use_kernel,
+        k_scales=ks4, v_scales=vs4, layer=li))(jnp.int32(layer))
+    ql = np.asarray(q_lens)
+    for bi in range(b):  # rows past q_lens are unspecified for the kernel
+        np.testing.assert_array_equal(np.asarray(got)[bi, :ql[bi]],
+                                      np.asarray(want)[bi, :ql[bi]])
+
+
+def test_ragged_layer_form_refuses_a_rank_mismatch(rng):
+    q, kp, vp, pt = _ragged_case(rng, 2, 4, 4, 4, 16, 8, 2)
+    lens = jnp.asarray([4, 4], jnp.int32)
+    with pytest.raises(AssertionError, match="rank"):
+        pa.ragged_paged_attention(q, kp, vp, pt, lens, lens, layer=0)
+    with pytest.raises(AssertionError, match="rank"):
+        pa.ragged_paged_attention(q, kp[None], vp[None], pt, lens, lens)
+
+
+def _write_case(rng):
+    """A packed step over 3 slots: slot 0 decodes one row in mid-page, slot
+    1 feeds a chunk that crosses a page boundary, slot 2 starts a fresh
+    page; one token is padding, one hits an unallocated (-1) page."""
+    num_pages, page_size, h, d = 9, 4, 2, 8
+    pt = jnp.asarray([[5, 2, -1], [0, 7, 3], [8, -1, -1]], jnp.int32)
+    #               slot0  slot1 (pos 2..6: pages 0 and 7)  slot2  pad  -1 page
+    tok_slot = jnp.asarray([0, 1, 1, 1, 1, 1, 2, 2, -1, 0], jnp.int32)
+    tok_pos = jnp.asarray([6, 2, 3, 4, 5, 6, 0, 1, 0, 9], jnp.int32)
+    toks = jnp.asarray(rng.randn(tok_slot.shape[0], h, d), jnp.float32)
+    return num_pages, page_size, h, d, pt, tok_slot, tok_pos, toks
+
+
+@pytest.mark.parametrize("layer", range(LAYERS))
+@pytest.mark.parametrize("write", ["fp", "quant", "prequant"])
+@pytest.mark.parametrize("form", ["scatter", "kernel"])
+def test_packed_write_layer_form_equals_write_on_that_layer(rng, form,
+                                                            write, layer):
+    """The ``layer=`` form of the three packed writes (the jnp scatter, and
+    through ``plan=`` the in-place Pallas kernel) against the old write on
+    ``pools[layer]``: that layer equal, the other layers untouched, padding
+    and -1 pages dropped."""
+    from paddle_tpu.inference import kv_cache as kvc
+
+    num_pages, page_size, h, d, pt, tok_slot, tok_pos, toks = \
+        _write_case(rng)
+    dest = (pt, tok_slot, tok_pos, page_size)
+    plan = (kvc.packed_write_plan(*dest, num_pages) if form == "kernel"
+            else None)
+    if write == "fp":
+        stacks = (_stack(rng, jnp.zeros((num_pages, h, page_size, d),
+                                        jnp.float32)),)
+        fn, vals = kvc.paged_write_packed, (toks,)
+    else:
+        stacks = (_stack(rng, jnp.zeros((num_pages, h, page_size, d),
+                                        jnp.int8)),
+                  _stack(rng, jnp.zeros((num_pages, h, page_size),
+                                        jnp.float32)))
+        fn, vals = kvc.paged_write_packed_quant, (toks,)
+        if write == "prequant":
+            q = jnp.asarray(rng.randint(-127, 128, toks.shape), jnp.int8)
+            s = jnp.asarray(np.abs(rng.randn(*toks.shape[:2])) + 0.01,
+                            jnp.float32)
+            fn, vals = kvc.paged_write_packed_prequant, (q, s)
+    want = fn(*(s[layer] for s in stacks), *vals, *dest)
+    got = jax.jit(lambda li: fn(*stacks, *vals, *dest, layer=li,
+                                plan=plan))(jnp.int32(layer))
+    if write == "fp":
+        want, got = (want,), (got,)
+    for before, w, g in zip(stacks, want, got):
+        expect = np.array(before)
+        expect[layer] = np.asarray(w)
+        assert (expect[layer] != np.asarray(before)[layer]).any()
+        np.testing.assert_array_equal(np.asarray(g), expect)
+
+
+def test_packed_write_plan_with_nothing_to_write(rng):
+    """A step of padding only: every grid step of the kernel rewrites page
+    0 with itself, and the stack comes back as it went in."""
+    from paddle_tpu.inference import kv_cache as kvc
+
+    num_pages, page_size, h, d, pt, tok_slot, tok_pos, toks = \
+        _write_case(rng)
+    dest = (pt, jnp.full_like(tok_slot, -1), tok_pos, page_size)
+    plan = kvc.packed_write_plan(*dest, num_pages)
+    assert int(jnp.max(plan.hi - plan.lo)) <= 0
+    stack = _stack(rng, jnp.zeros((num_pages, h, page_size, d),
+                                  jnp.float32))
+    got = kvc.paged_write_packed(stack, toks, *dest, layer=1, plan=plan)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(stack))

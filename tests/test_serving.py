@@ -2959,3 +2959,71 @@ def test_bench_serve_moe_leg_gates():
     # by top_k/E and agree within the serving-moe-step contract
     assert rec["hbm_bytes_per_token_static"] > 0
     assert abs(rec["hbm_model_drift_frac"]) <= 0.02
+
+
+# -- PR 27: the pools stay one buffer through the step ----------------------
+
+
+@pytest.mark.parametrize("spec_k", [0, 2], ids=["plain", "spec2"])
+@pytest.mark.parametrize("kv_quant", [False, True], ids=["fp", "int8"])
+@pytest.mark.parametrize("use_kernel", [None, True],
+                         ids=["scatter", "kernels"])
+def test_layer_scan_carries_the_pools(rng, use_kernel, kv_quant, spec_k):
+    """The traced unified step: the stacked pools (and scale planes) ride
+    the layer scan's CARRY. Nothing pool-shaped is a scanned input or
+    output, and no equation of the body slices a layer out of a stack or
+    stacks one back (``pools[i]`` and the scan's own handling of xs / ys
+    are ``dynamic_slice`` / ``dynamic_update_slice`` of the whole stack:
+    five pool-sized copies per layer on the chip)."""
+    import jax
+
+    from paddle_tpu.analysis.cost_model import _iter_eqns_all, find_layer_scan
+
+    model = _tiny_model()
+    if kv_quant:
+        model.config.kv_cache_dtype = "int8"
+    try:
+        sp = ServingPredictor(model, max_batch=2, max_seq_len=48,
+                              page_size=8, use_kernel=use_kernel,
+                              spec_decode_k=spec_k)
+    finally:
+        model.config.kv_cache_dtype = None
+    step, seen = sp._unified, []
+
+    def tapped(*args):
+        seen.append(args)
+        return step(*args)
+
+    tapped.trace_count = step.trace_count
+    sp._unified = tapped
+    sp.generate([rng.randint(0, TINY["vocab_size"], (9,)).tolist()],
+                max_new_tokens=2)
+    # shapes only: the pools of the recorded call were donated to it
+    closed = jax.make_jaxpr(step)(*jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), seen[0]))
+
+    cache = sp.cache
+    pools = [cache.k_pages, cache.v_pages]
+    if kv_quant:
+        pools += [cache.k_scales, cache.v_scales]
+    stacked = {(p.shape, p.dtype) for p in pools}
+    one_layer = {(p.shape[1:], p.dtype) for p in pools}
+
+    def pooled(var, shapes=stacked | one_layer):
+        aval = getattr(var, "aval", None)
+        return aval is not None and (tuple(aval.shape), aval.dtype) in shapes
+
+    scan = find_layer_scan(closed.jaxpr)
+    assert scan.params["length"] == TINY["num_layers"]
+    n_lead = scan.params["num_consts"] + scan.params["num_carry"]
+    carried = scan.invars[scan.params["num_consts"]:n_lead]
+    assert sum(pooled(v, stacked) for v in carried) == len(pools)
+    assert not any(pooled(v) for v in scan.invars[n_lead:])           # xs
+    assert not any(pooled(v)
+                   for v in scan.outvars[scan.params["num_carry"]:])  # ys
+    moves = [eqn.primitive.name
+             for eqn in _iter_eqns_all(scan.params["jaxpr"].jaxpr)
+             if eqn.primitive.name in ("dynamic_slice",
+                                       "dynamic_update_slice")
+             and pooled(eqn.invars[0])]
+    assert moves == []

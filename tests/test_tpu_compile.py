@@ -1,0 +1,110 @@
+"""Compile for the TPU, on the CPU: the unified serving step.
+
+``tests/test_tpu_lowering.py`` stops at Pallas's lowering; this file hands
+the whole step to the chip's own compiler, which is installed here and
+compiles for a chip that is described, not attached (the
+``on-chip-measurement`` guide, section 2). What only the compiled program
+shows is whether the KV pools stay ONE buffer through the step (PR 27): XLA's
+layout assignment and copy insertion decide that, not the jaxpr. At the
+cells' widths (12 heads of 128, 64-token pages) and two layers, so a compile
+takes seconds.
+
+The topology is described inside a fixture, never at import: one process at a
+time may load the TPU's library, and every xdist worker imports this file.
+Keep such tests in this one file.
+"""
+import os
+import sys
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+LAYERS, H, HEADS, HD, FFN, VOCAB, SEQ = 2, 1536, 12, 128, 6144, 50304, 2048
+LANES, PAGE, PAGES, BUDGET, CHUNK = 8, 64, 136, 128, 64
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # such a compile is written to the persistent cache and cannot be read
+    # back without a chip: keep it out
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", cached)
+    compilation_cache.reset_cache()
+
+
+def _step_avals(dev, kv_quant):
+    """The unified step's arguments as shapes on ``dev`` (signature in
+    ``build_unified_step``'s docstring)."""
+    def sds(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=dev)
+
+    i32 = jnp.int32
+    layers = {k: sds(LAYERS, *shape) for k, shape in dict(
+        ln1_g=(H,), ln1_b=(H,), wqkv=(H, 3 * H), bqkv=(3 * H,), wo=(H, H),
+        bo=(H,), ln2_g=(H,), ln2_b=(H,), w1=(H, FFN), b1=(FFN,),
+        w2=(FFN, H), b2=(H,)).items()}
+    params = dict(tok_emb=sds(VOCAB, H), pos_emb=sds(SEQ, H), lnf_g=sds(H),
+                  lnf_b=sds(H), layers=layers)
+    pool = sds(LAYERS, PAGES, HEADS, PAGE, HD,
+               dtype=jnp.int8 if kv_quant else jnp.bfloat16)
+    pools = [pool, pool]
+    if kv_quant:
+        pools += [sds(LAYERS, PAGES, HEADS, PAGE, dtype=jnp.float32)] * 2
+    tok, lane = sds(BUDGET, dtype=i32), sds(LANES, dtype=i32)
+    return ([params, tok, tok, tok, lane, lane, lane, tok, lane, lane, lane]
+            + pools
+            + [sds(LANES, SEQ // PAGE, dtype=i32), lane, lane,
+               sds(LANES, 2, dtype=jnp.uint32), sds(LANES, dtype=jnp.float32),
+               lane, sds(LANES, dtype=jnp.float32)]), pool
+
+
+@pytest.mark.parametrize("kv_quant", [False, True], ids=["fp", "int8"])
+def test_compiled_step_keeps_the_pools_in_one_buffer(one_chip, monkeypatch,
+                                                     kv_quant):
+    """No ``copy``, ``dynamic-slice`` or ``dynamic-update-slice`` of a
+    pool's or the stack's shape outside the copy-on-write lanes, both
+    kernels in the program, and a temp far under one stacked pool (the
+    scanned pools cost a second copy of both stacks)."""
+    import paddle_tpu  # noqa: F401  framework config (matmul precision)
+    from paddle_tpu.models.gpt import GPTConfig, build_unified_step
+    from paddle_tpu.ops.pallas.paged_attention import RAGGED_KERNEL_NAME
+    from paddle_tpu.ops.pallas.paged_write import KV_WRITE_KERNEL_NAME
+
+    sys.path.insert(0, REPO)
+    try:
+        from chip_smoke import _is_mosaic_call, pool_copies
+    finally:
+        sys.path.remove(REPO)
+
+    # the kernels pick interpret mode and ``use_kernel=None`` from
+    # ``jax.default_backend()``: answer as the chip would
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = GPTConfig(vocab_size=VOCAB, hidden_size=H, num_layers=LAYERS,
+                    num_heads=HEADS, max_seq_len=SEQ)
+    step = build_unified_step(cfg, PAGE, CHUNK, kv_quant=kv_quant)
+    avals, pool = _step_avals(one_chip, kv_quant)
+    compiled = step.lower(*avals).compile()
+    hlo = compiled.as_text()
+    for kernel in (RAGGED_KERNEL_NAME, KV_WRITE_KERNEL_NAME):
+        assert any(_is_mosaic_call(line, kernel)
+                   for line in hlo.splitlines()), kernel
+    assert pool_copies(hlo, pool.shape) == []
+    pool_bytes = pool.size * pool.dtype.itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes

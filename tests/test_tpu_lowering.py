@@ -75,6 +75,56 @@ def test_ragged_paged_attention_lowers(kv, local_heads):
     assert n == 1
 
 
+LAYERS = 24
+
+
+@pytest.mark.parametrize("kv", ["fp", "int8"])
+def test_ragged_paged_attention_lowers_on_the_stacked_pools(kv):
+    """The unified step's form: the whole ``[layers, ...]`` stack as the
+    operand, the layer a traced scalar in the block index maps."""
+    from paddle_tpu.ops.pallas.paged_attention import ragged_paged_attention
+
+    q = sds(LANES, CHUNK, HEADS, HD)
+    table = sds(LANES, PAGES_PER_SLOT, dtype=jnp.int32)
+    lens = sds(LANES, dtype=jnp.int32)
+    layer = sds(dtype=jnp.int32)
+    pool = sds(LAYERS, POOL, HEADS, PAGE, HD,
+               dtype=BF16 if kv == "fp" else jnp.int8)
+    scales = (sds(LAYERS, POOL, HEADS, PAGE, dtype=jnp.float32)
+              if kv == "int8" else None)
+
+    def fn(q, k, v, table, kv_lens, q_lens, layer, ks=None, vs=None):
+        return ragged_paged_attention(q, k, v, table, kv_lens, q_lens,
+                                      k_scales=ks, v_scales=vs, layer=layer)
+
+    planes = () if scales is None else (scales, scales)
+    assert mosaic_calls(fn, q, pool, pool, table, lens, lens, layer,
+                        *planes) == 1
+
+
+@pytest.mark.parametrize("what", ["bf16-pool", "int8-pool", "scale-plane"])
+def test_paged_kv_write_lowers(what):
+    """The in-place row write of the unified step, through the packed
+    writes' ``plan=`` form: one Mosaic call per pool or plane."""
+    from paddle_tpu.inference import kv_cache as kvc
+
+    dtype = {"bf16-pool": BF16, "int8-pool": jnp.int8,
+             "scale-plane": jnp.float32}[what]
+    tail = () if what == "scale-plane" else (HD,)
+    stack = sds(LAYERS, POOL, HEADS, PAGE, *tail, dtype=dtype)
+    rows = sds(BUDGET, HEADS, *tail, dtype=dtype)
+    table = sds(LANES, PAGES_PER_SLOT, dtype=jnp.int32)
+    tok = sds(BUDGET, dtype=jnp.int32)
+
+    def fn(stack, rows, table, tok_slot, tok_pos, layer):
+        dest = (table, tok_slot, tok_pos, PAGE)
+        plan = kvc.packed_write_plan(*dest, POOL)
+        return kvc._write_rows(stack, rows, *dest, layer, plan)
+
+    assert mosaic_calls(fn, stack, rows, table, tok, tok,
+                        sds(dtype=jnp.int32)) == 1
+
+
 def test_paged_decode_attention_lowers():
     from paddle_tpu.ops.pallas.paged_attention import paged_attention
 
