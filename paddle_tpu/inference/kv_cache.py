@@ -314,17 +314,34 @@ def paged_write_packed_prequant(pages, scales, q_toks, s_toks, page_table,
             _write_rows(scales, s_toks, *dest))
 
 
-def paged_copy_pages(pages, src, dst):
+def paged_copy_pages(pages, src, dst, lane_by_lane=False):
     """Copy-on-write page copies, traced into the unified step.
 
     pages: [num_layers, num_pages, kv_heads, page_size, head_dim] (the
     stacked pool as the jits see it); src/dst: [batch] int32 pool indices,
     ``dst == num_pages`` (the host's no-op sentinel) drops the copy. Each
     active lane duplicates one page across every layer.
+
+    ``lane_by_lane``: one page at a time, each a dynamic slice written back
+    in place, instead of one gather and scatter over all lanes. What a pool
+    whose rows are wider than 512 values needs (a latent cache's): compiled
+    for the chip, the gather of such rows is split by slicing the WHOLE pool
+    into narrower ones first, 4.7 GB of temporaries and a pass over the pool
+    every step at the DeepSeek-V2-Lite deployment.
     """
     num_pages = pages.shape[1]
     src_c = jnp.clip(src, 0, num_pages - 1)
-    return pages.at[:, dst].set(pages[:, src_c], mode="drop")
+    if not lane_by_lane:
+        return pages.at[:, dst].set(pages[:, src_c], mode="drop")
+
+    def one(i, pages):
+        to = jnp.clip(dst[i], 0, num_pages - 1)
+        page = jax.lax.dynamic_slice_in_dim(pages, src_c[i], 1, axis=1)
+        kept = jax.lax.dynamic_slice_in_dim(pages, to, 1, axis=1)
+        return jax.lax.dynamic_update_slice_in_dim(
+            pages, jnp.where(dst[i] < num_pages, page, kept), to, axis=1)
+
+    return jax.lax.fori_loop(0, src.shape[0], one, pages)
 
 
 def batched_import_rows(pages, vals, pg, row):
@@ -389,8 +406,23 @@ class KVCacheManager:
                  max_batch, max_seq_len, page_size=None, num_q_heads=None,
                  dtype=jnp.float32, enable_prefix_cache=False,
                  quantize_kv=False, mesh=None, metrics=None,
-                 host_tier_bytes=0):
+                 host_tier_bytes=0, latent=False):
         from ..ops.pallas.paged_attention import preferred_page_size
+
+        # a LATENT cache (multi-head latent attention): ONE pool, one row
+        # per token shared by every head — ``num_kv_heads`` 1, ``head_dim``
+        # the row's width. Page table, prefix cache, CoW and the packed
+        # write serve it as they serve a K pool; there is no V pool.
+        self.latent = bool(latent)
+        if self.latent:
+            unsupported = [name for name, on in (
+                ("quantize_kv", quantize_kv), ("mesh", mesh is not None),
+                ("host_tier_bytes", host_tier_bytes)) if on]
+            if unsupported or num_kv_heads != 1:
+                raise NotImplementedError(
+                    f"a latent cache is one pool of one shared row per "
+                    f"token (num_kv_heads 1, got {num_kv_heads}) and does "
+                    f"not take {', '.join(unsupported) or 'more heads'} yet")
 
         if page_size is None:
             page_size = preferred_page_size(
@@ -416,7 +448,7 @@ class KVCacheManager:
         self.quantize_kv = bool(quantize_kv)
         pool_dtype = jnp.int8 if self.quantize_kv else dtype
         self.k_pages = jnp.zeros(shape, pool_dtype)
-        self.v_pages = jnp.zeros(shape, pool_dtype)
+        self.v_pages = None if self.latent else jnp.zeros(shape, pool_dtype)
         if self.quantize_kv:
             sshape = shape[:4]
             self.k_scales = jnp.zeros(sshape, jnp.float32)
@@ -1195,6 +1227,11 @@ class KVCacheManager:
 
     def _planes(self) -> dict:
         """Payload plane name -> this manager's pool array."""
+        if self.latent:
+            raise NotImplementedError(
+                "page payloads (kv_transfer frames, host-tier entries, "
+                "imported prefix pages) are not defined for a latent cache "
+                "yet")
         return {name: getattr(self, attr)
                 for name, attr in _PLANE_ATTRS.items()
                 if self.quantize_kv or name in ("k", "v")}
@@ -1444,12 +1481,23 @@ class KVCacheManager:
     def slot_pages(self, slot: int) -> jnp.ndarray:
         return jnp.asarray(self._page_table[slot])
 
-    def update_pages(self, k_pages, v_pages, k_scales=None,
+    def pools(self) -> tuple:
+        """The donated pools in the order the unified step takes and
+        returns them: ``(k, v[, k_scales, v_scales])``, or the one latent
+        pool."""
+        if self.latent:
+            return (self.k_pages,)
+        if self.quantize_kv:
+            return (self.k_pages, self.v_pages, self.k_scales, self.v_scales)
+        return (self.k_pages, self.v_pages)
+
+    def update_pages(self, k_pages, v_pages=None, k_scales=None,
                      v_scales=None) -> None:
         """Adopt the pools returned by a jitted prefill/decode step (scale
-        planes too on the int8-KV path)."""
+        planes too on the int8-KV path; a latent cache's one pool)."""
         self.k_pages = k_pages
-        self.v_pages = v_pages
+        if v_pages is not None:
+            self.v_pages = v_pages
         if k_scales is not None:
             self.k_scales = k_scales
         if v_scales is not None:

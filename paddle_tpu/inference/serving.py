@@ -397,11 +397,51 @@ class ServingPredictor:
                 "ServingPredictor requires an enabled metrics registry; "
                 "the one passed is disabled")
         self._init_instruments()
+        # a model with dropless routed experts: rows routed, and rows per
+        # expert (the fullest expert's share of the mean is max over mean of
+        # these). A model without them reports neither.
+        self._m_moe_rows = self._m_moe_expert_rows = self._m_moe_fed = None
+        self._moe_unread: list = []
+        if getattr(cfg, "n_routed_experts", 0):
+            self._m_moe_fed = self.metrics.counter(
+                "serving_moe_experts_fed",
+                "(layer, expert) pairs that received a row in a step: whose "
+                "weights the step had to read")
+            self._m_moe_rows = self.metrics.counter(
+                "serving_moe_rows_routed",
+                "token-expert pairs computed by the routed layers")
+            self._m_moe_expert_rows = self.metrics.counter(
+                "serving_moe_expert_rows", "the same, by expert",
+                labels=("expert",))
         # round 11: mesh (None | int mp degree | Mesh(("mp",))) serves the
         # steps tensor-parallel — params + KV pools sharded by head, the
         # scheduler and page/slot/prefix bookkeeping below stay host-global
         self.mesh = as_serving_mesh(mesh)
-        if dtype is None:
+        # a model with a LATENT cache (multi-head latent attention,
+        # models/deepseek_v2.py) brings its weight tree as the serving step
+        # scans it, in its serving dtype, on the device: nothing is extracted
+        # or copied. What this path does not extend to it yet fails here.
+        self.latent = bool(getattr(cfg, "kv_lora_rank", 0))
+        if self.latent:
+            unsupported = [name for name, on in (
+                ("kv_cache_dtype", kv_cache_dtype), ("mesh", mesh),
+                ("spec_decode_k", spec_decode_k),
+                ("mega_decode", mega_decode),
+                ("host_tier_bytes", host_tier_bytes),
+                ("draft_source='model'", draft_source == "model"),
+                ("draft_layers", draft_layers),
+                ("unified=False (build_prefill / build_decode_step)",
+                 not unified)) if on]
+            if unsupported:
+                raise NotImplementedError(
+                    f"not supported for a latent (MLA) cache yet: "
+                    f"{', '.join(unsupported)}")
+            import jax
+
+            self.params = (model.params if dtype is None else jax.tree.map(
+                lambda a: a if a.dtype == dtype else a.astype(dtype),
+                model.params))
+        elif dtype is None:
             # share the weak-keyed extraction with generate() — a second
             # predictor (or generate call) on one model reuses the stacks
             # (quantized per cfg.weight_dtype, sharded per mesh signature,
@@ -433,8 +473,8 @@ class ServingPredictor:
         self.max_batch = int(max_batch)
         self.prefill_bucket = int(prefill_bucket)
         self.unified = bool(unified)
-        self.kv_quant = kv_cache_quantized(kv_cache_dtype
-                                           or cfg.kv_cache_dtype)
+        self.kv_quant = kv_cache_quantized(
+            kv_cache_dtype or getattr(cfg, "kv_cache_dtype", None))
         if self.kv_quant and not self.unified:
             raise ValueError(
                 "int8 KV cache rides the unified step's quantize-on-write "
@@ -450,8 +490,14 @@ class ServingPredictor:
             num_pages = self.max_batch * pages_needed(self.max_seq_len, ps)
         if prefix_cache is None:
             prefix_cache = self.unified
+        # a latent cache: one pool of one row per token, the row padded to
+        # whole 128-lane tiles (compiled for the chip, an unpadded 576-wide
+        # row costs a copy of the whole pool at every kernel call)
+        kv_heads, kv_width = ((1, -(-cfg.latent_dim // 128) * 128)
+                              if self.latent
+                              else (cfg.num_heads, cfg.head_dim))
         self.cache = KVCacheManager(
-            cfg.num_layers, cfg.num_heads, cfg.head_dim,
+            cfg.num_layers, kv_heads, kv_width, latent=self.latent,
             num_pages=num_pages, max_batch=self.max_batch,
             max_seq_len=self.max_seq_len, page_size=page_size,
             num_q_heads=cfg.num_heads, dtype=kv_dtype,
@@ -1424,6 +1470,23 @@ class ServingPredictor:
                 self._recover_reconcile_failure(e, exc)
                 return {}
 
+    def _note_expert_rows(self) -> None:
+        """Feed the routed-expert counters from the row counts of the steps
+        dispatched so far. Called right after a reconcile materialized a
+        step's tokens: every count but those of the steps still in flight
+        behind it is complete by then, and those wait for their turn."""
+        ready = len(self._moe_unread) - len(self._inflight)
+        if ready <= 0:
+            return
+        rows, fed = np.sum([np.asarray(a) for a in self._moe_unread[:ready]],
+                           axis=0)
+        del self._moe_unread[:ready]
+        self._m_moe_rows.inc(int(rows.sum()))
+        self._m_moe_fed.inc(int(fed.sum()))
+        for expert in np.flatnonzero(rows):
+            self._m_moe_expert_rows.labels(expert=str(expert)).inc(
+                int(rows[expert]))
+
     def _note_first_token(self, req: Request) -> None:
         req.first_token_time = monotonic()
         self._m_ttft.observe((req.first_token_time - req.submit_time) * 1e3)
@@ -1449,6 +1512,8 @@ class ServingPredictor:
                 ne = np.asarray(e.ne)
             self._m_sync_s.inc(monotonic() - t0)
             self._did_sync = True
+            if self._moe_unread:
+                self._note_expert_rows()
         if not self._inflight:
             self._mark_drained()
         for slot in e.spec_slots:
@@ -1592,6 +1657,7 @@ class ServingPredictor:
         self._note_step_failure(exc)
         dropped = [e] + list(self._inflight)
         self._inflight.clear()
+        self._moe_unread.clear()   # the dropped steps' expert rows with them
         self._m_inflight.set(0)
         reopen: dict[int, Request] = {}
         for entry in dropped:
@@ -2033,9 +2099,7 @@ class ServingPredictor:
                 self._put_cached("temp", temp),
                 self._put_cached("top_k", top_k),
                 self._put_cached("top_p", top_p))
-        pools = ((cache.k_pages, cache.v_pages, cache.k_scales,
-                  cache.v_scales) if self.kv_quant
-                 else (cache.k_pages, cache.v_pages))
+        pools = cache.pools()
         # per-lane trace instants on the request lanes (tracing only):
         # what kind of work each scheduled request got this step
         if tracing_active():
@@ -2056,7 +2120,12 @@ class ServingPredictor:
             cache.update_pages(*res[4:])
         else:
             out_dev, ne_dev, carry = res[0], None, res[0]
-            cache.update_pages(*res[2:])
+            cache.update_pages(*res[2:2 + len(pools)])
+        if self._m_moe_rows is not None:
+            # the step's last result: rows per expert, summed over layers.
+            # It stays on the device until a reconcile materializes tokens
+            # anyway (no sync of its own)
+            self._moe_unread.append(res[2 + len(pools)])
         self._carry = carry
         # charge the dispatched-unmaterialized token per completing lane
         # only once the launch SUCCEEDED (round 17: a failed launch must

@@ -14,6 +14,7 @@ mp_layers.py:47,:333,:540) where GSPMD emits the collectives.
 """
 from __future__ import annotations
 
+import functools
 import math
 from dataclasses import dataclass
 
@@ -528,6 +529,17 @@ def _srv_ln(x, g, b, eps):
     return (((xf - mu) * jax.lax.rsqrt(var + eps)) * g + b).astype(x.dtype)
 
 
+def _srv_norm(config, x, p, name):
+    """The block's norm ``name`` (``ln1`` / ``ln2`` / ``lnf``) over the
+    weights ``p``, as the configuration has it: RMSNorm with a weight alone
+    where it states ``rms_norm_eps``, else the biased LayerNorm."""
+    if hasattr(config, "rms_norm_eps"):
+        from .deepseek_v2 import rms_norm
+
+        return rms_norm(x, p[name + "_g"], config.rms_norm_eps)
+    return _srv_ln(x, p[name + "_g"], p[name + "_b"], config.layer_norm_eps)
+
+
 def _srv_logits(params, h):
     """h [..., hidden] -> logits [..., vocab] (tied head unless lm_head)."""
     import jax.numpy as jnp
@@ -590,12 +602,29 @@ def _srv_moe(config, p, y, use_kernel=None, valid=None):
     return out.reshape(*lead, out.shape[-1])
 
 
-def _srv_ffn(config, p, y, use_kernel=None, axis=None, valid=None):
-    """Block FFN dispatch: dense ``_srv_mlp`` vs routed ``_srv_moe`` —
-    the ONE switch every serving builder goes through."""
+def _srv_ffn(config, p, y, use_kernel=None, axis=None, valid=None,
+             layer=None):
+    """Block FFN dispatch — the ONE switch every serving builder goes
+    through. Returns ``(out, rows)``: ``rows [2, E]`` int32, how many rows
+    each expert of a dropless routed layer received and whether it received
+    any, else ``None``. By what the
+    layer's weights ``p`` hold: routed gated experts beside shared ones
+    (``moe_w_gu``; ``models/deepseek_v2.py``), a dense gated SiLU MLP
+    (``w_gu``), the GShard-style ``_srv_moe``, or the biased GELU
+    ``_srv_mlp``. ``layer``: the expert stacks in ``p`` are whole, and this
+    is layer ``layer`` of them (``deepseek_v2.STACKED_BY_INDEX``)."""
+    if "moe_w_gu" in p:
+        from .deepseek_v2 import routed_ffn
+
+        return routed_ffn(config, p, y, use_kernel, valid=valid,
+                          with_counts=True, layer=layer)
+    if "w_gu" in p:
+        from .deepseek_v2 import gated_mlp
+
+        return gated_mlp(y, p["w_gu"], p["w_d"]), None
     if getattr(config, "moe_experts", 0):
-        return _srv_moe(config, p, y, use_kernel, valid=valid)
-    return _srv_mlp(p, y, use_kernel, axis)
+        return _srv_moe(config, p, y, use_kernel, valid=valid), None
+    return _srv_mlp(p, y, use_kernel, axis), None
 
 
 def _split_qkv(qkv, nh, hd, head_major):
@@ -1088,6 +1117,30 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
     per (budget, batch, spec_k), composing with ``kv_quant`` and ``mesh``
     (the epilogue replicates; donation covers the same pools).
 
+    WHAT THE CONFIGURATION CHOOSES (PR 28). The step's plumbing — packed
+    stream, feedback, CoW lanes, the pool-carrying scan, head, sampling — is
+    one; the block inside it is built from what ``config`` and the weight
+    tree state: the norm (``_srv_norm``), positions (a learned table added
+    at the embedding, or rotary angles from ``tok_pos`` inside attention),
+    the attention part (``mha`` over per-head K and V pools, or ``mla`` over
+    ONE latent pool), the FFN (``_srv_ffn``) and the head (``_srv_logits``).
+    A model whose stack is not uniform brings its stacks in order
+    (``dense_layers`` ahead of ``layers``): one scan each, one layer index
+    through both. A LATENT cache (``config.kv_lora_rank``; DeepSeek-V2's
+    MLA, ``models/deepseek_v2.py``) has one donated pool ``[num_layers,
+    num_pages, 1, page_size, row]``, the row ``[c | k_pe]`` padded to whole
+    128-lane tiles, written by the same in-place kernel and read by
+    ``ops/pallas/mla_paged_attention`` addressed by layer index; a layer of
+    routed experts adds a last result, ``expert_rows [2, E]`` int32: the
+    rows every expert received, and the layers in which it received any,
+    summed over the layers::
+
+        fn(params, ...the same 11 arrays..., latent_pages, page_table, ...)
+        -> (next_toks, logits, latent_pages, expert_rows)
+
+    ``kv_quant``, ``mesh``, ``spec_k`` and ``mega`` are not extended to the
+    latent cache and raise ``NotImplementedError`` here.
+
     ``mega=True`` (round 16) builds the MEGAKERNELIZED step: the per-op
     layer chain (qkv quant-GEMM -> ragged paged attention -> output GEMM
     -> fused MLP, each a separate kernel with activations round-tripping
@@ -1121,16 +1174,33 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
     from ..observability.tracing import step_scope
     from ..ops.pallas.paged_attention import (ragged_paged_attention,
                                               use_kernel_default)
+    from .deepseek_v2 import STACKED_BY_INDEX
 
     cfg = config
-    eps = cfg.layer_norm_eps
     trace_count = [0]
     mp, axis = _mesh_mp(mesh)
     nh_l, hd = cfg.num_heads // mp, cfg.head_dim
+    # a LATENT cache (multi-head latent attention, models/deepseek_v2.py):
+    # one pool whose row is [c | k_pe], shared by every head
+    latent = bool(getattr(cfg, "kv_lora_rank", 0))
+    if latent:
+        unsupported = [name for name, on in (
+            ("kv_cache_dtype='int8'", kv_quant), ("mesh", mesh is not None),
+            ("spec_decode_k", spec_k), ("mega_decode", mega)) if on]
+        if unsupported:
+            raise NotImplementedError(
+                f"the latent (MLA) cache does not serve "
+                f"{', '.join(unsupported)} yet: its one pool has no scale "
+                "planes, no head axis to shard and no fused layer kernel")
+        from ..ops.pallas.mla_paged_attention import (
+            TILE_DEFAULT, mla_ragged_paged_attention, tile_plan)
+        from .deepseek_v2 import (absorb_query, latent_qkv, softmax_scale,
+                                  unabsorb_output)
     if mega:
         from ..ops.pallas.mega_decode import (mega_attn_layer, mega_mlp,
                                               validate_mega_config)
 
+        eps = cfg.layer_norm_eps
         validate_mega_config(getattr(cfg, "weight_dtype", None),
                              getattr(cfg, "weight_quant_group_size", -1),
                              hd, mp,
@@ -1147,21 +1217,18 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
     # produced), then the donated pools [+ scale planes], then the
     # 7-array tail
     n_lead = 12 if spec_k else 11
-    n_pool = 4 if kv_quant else 2
+    n_pool = 1 if latent else 4 if kv_quant else 2
     n_out_lead = 4 if spec_k else 2
 
     def _body(*args):
         lead = args[:n_lead]
-        pools = args[n_lead:n_lead + n_pool]
+        pools = tuple(args[n_lead:n_lead + n_pool])
         (page_table, cow_src, cow_dst, base_keys, temperature, top_k,
          top_p) = args[n_lead + n_pool:]
         spec_len = lead[7] if spec_k else None
         feedback, prev_toks, emit_mask, produced = lead[n_lead - 4:]
-        k_scales, v_scales = (pools[2], pools[3]) if kv_quant else (None,
-                                                                    None)
         return _step_inner(*lead[:7], spec_len, feedback, prev_toks,
-                           emit_mask, produced, pools[0], pools[1],
-                           k_scales, v_scales, page_table, cow_src,
+                           emit_mask, produced, pools, page_table, cow_src,
                            cow_dst, base_keys, temperature, top_k, top_p)
 
     def step(*args):
@@ -1186,20 +1253,17 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
 
     def _step_inner(params, tok_ids, tok_slot, tok_pos, q_lens, kv_lens,
                     last_idx, spec_len, feedback, prev_toks, emit_mask,
-                    produced, k_pages, v_pages, k_scales, v_scales,
-                    page_table, cow_src, cow_dst, base_keys, temperature,
-                    top_k, top_p):
+                    produced, pools, page_table, cow_src, cow_dst, base_keys,
+                    temperature, top_k, top_p):
         t = tok_ids.shape[0]
         b = q_lens.shape[0]
         # copy-on-write BEFORE any write: diverging lanes get a private
         # copy of their shared tail page across every layer (scale planes
         # are page-keyed, so they ride the same copy lanes)
         with step_scope("cow"):
-            k_pages = paged_copy_pages(k_pages, cow_src, cow_dst)
-            v_pages = paged_copy_pages(v_pages, cow_src, cow_dst)
-            if kv_quant:
-                k_scales = paged_copy_pages(k_scales, cow_src, cow_dst)
-                v_scales = paged_copy_pages(v_scales, cow_src, cow_dst)
+            pools = tuple(paged_copy_pages(pool, cow_src, cow_dst,
+                                           lane_by_lane=latent)
+                          for pool in pools)
         valid = tok_slot >= 0
         slot_c = jnp.clip(tok_slot, 0, b - 1)
         with step_scope("embed"):
@@ -1208,10 +1272,12 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
             # the async engine's device-side half of the pipeline
             tok_ids = jnp.where((feedback > 0) & valid, prev_toks[slot_c],
                                 tok_ids)
-            x = (jnp.take(params["tok_emb"], jnp.maximum(tok_ids, 0),
-                          axis=0)
-                 + params["pos_emb"][
-                     jnp.clip(tok_pos, 0, params["pos_emb"].shape[0] - 1)])
+            x = jnp.take(params["tok_emb"], jnp.maximum(tok_ids, 0), axis=0)
+            if "pos_emb" in params:
+                # a learned position table; rotary models turn tok_pos into
+                # angles inside their attention part instead
+                x = x + params["pos_emb"][
+                    jnp.clip(tok_pos, 0, params["pos_emb"].shape[0] - 1)]
         ctx = (kv_lens + q_lens).astype(jnp.int32)
         # packed <-> chunk-block index plumbing (shared by every layer):
         # each token's row in the attention kernel's [b, chunk] blocks
@@ -1230,14 +1296,19 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
         dest = (page_table, tok_slot, tok_pos, page_size)
         kernels = use_kernel_default() if use_kernel is None else use_kernel
         with step_scope("kv_write"):
-            plan = (packed_write_plan(*dest, k_pages.shape[1]) if kernels
+            plan = (packed_write_plan(*dest, pools[0].shape[1]) if kernels
                     else None)
+        if latent:
+            with step_scope("attn"):
+                # the latent kernel's tiled layout of this step's rows
+                tiles = (tile_plan(tok_slot, off, q_lens, TILE_DEFAULT)
+                         if kernels else None)
 
-        def block(carry, layer):
-            x, kp, vp, ks, vs = carry
-            p, li = layer
-            with step_scope("ln"):
-                y = _srv_ln(x, p["ln1_g"], p["ln1_b"], eps)
+        def mha(p, y, pools, li):
+            """Multi-head attention over per-head K and V pools: packed
+            rows ``y [t, h]`` to ``[t, heads * head_dim]``."""
+            kp, vp, *scales = pools
+            ks, vs = scales or (None, None)
             with step_scope("qkv"):
                 qkv = _srv_mm(y, p["wqkv"], use_kernel) + p["bqkv"]
                 q, k_t, v_t = _split_qkv(qkv, nh_l, hd,
@@ -1261,14 +1332,57 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
                                             k_scales=ks, v_scales=vs,
                                             layer=li)
                 a = ab[slot_c, off_c]                # back to packed [t]
-            with step_scope("attn_out"):
-                x = x + _srv_psum(_srv_mm(a.reshape(t, nh_l * hd), p["wo"],
-                                          use_kernel), axis) + p["bo"]
+            return (a.reshape(t, nh_l * hd),
+                    (kp, vp, ks, vs) if kv_quant else (kp, vp))
+
+        def mla(p, y, pools, li):
+            """Latent attention: ONE row ``[c | k_pe]`` written per token,
+            read back in the absorbed form by every row of the step (a
+            decode row and a prefill chunk's rows alike: all of them read
+            the paged context, see ``models/deepseek_v2.py``)."""
+            (pool,) = pools
+            pad = pool.shape[-1] - cfg.latent_dim    # lanes to a whole tile
+            with step_scope("qkv"):
+                q_nope, q_pe, row = latent_qkv(cfg, p, y, tok_pos)
+                row = jnp.pad(row, ((0, 0), (0, pad)))
+            with step_scope("kv_write"):
+                pool = paged_write_packed(pool, row[:, None, :], *dest,
+                                          layer=li, plan=plan)
+            with step_scope("attn"):
+                with step_scope("attn_absorb"):
+                    q_abs = jnp.pad(absorb_query(cfg, p, q_nope, q_pe),
+                                    ((0, 0), (0, 0), (0, pad)))
+                o_lat = mla_ragged_paged_attention(
+                    q_abs, pool, page_table, ctx, q_lens, tok_slot, off,
+                    v_dim=cfg.kv_lora_rank, scale=softmax_scale(cfg),
+                    layer=li, use_kernel=use_kernel, plan=tiles,
+                    tile=TILE_DEFAULT)
+                with step_scope("attn_absorb"):
+                    a = unabsorb_output(cfg, p, o_lat)
+            return a, (pool,)
+
+        attention = mla if latent else mha
+
+        def block(carry, layer, whole=None):
+            x, pools = carry
+            p, li, lj = layer
+            if whole:
+                # stacks read by index inside their kernel: layer lj of them
+                p = dict(p, **whole)
             with step_scope("ln"):
-                y = _srv_ln(x, p["ln2_g"], p["ln2_b"], eps)
+                y = _srv_norm(cfg, x, p, "ln1")
+            a, pools = attention(p, y, pools, li)
+            with step_scope("attn_out"):
+                x = x + _srv_psum(_srv_mm(a, p["wo"], use_kernel), axis)
+                if "bo" in p:
+                    x = x + p["bo"]
+            with step_scope("ln"):
+                y = _srv_norm(cfg, x, p, "ln2")
             with step_scope("mlp"):
-                x = x + _srv_ffn(cfg, p, y, use_kernel, axis, valid=valid)
-            return (x, kp, vp, ks, vs), None
+                f, rows = _srv_ffn(cfg, p, y, use_kernel, axis, valid=valid,
+                                   layer=lj if whole else None)
+                x = x + f
+            return (x, pools), rows
 
         def mega_block(carry, layer):
             # the round-16 fused layer (round 22: ragged chunks, any
@@ -1277,8 +1391,9 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
             # pool at kv_lens and handles this step's rows in-register —
             # same math as write-then-attend at ctx), the MLP side one
             # more; only the emitted new K/V rows touch HBM between them
-            xb, kp, vp, ks, vs = carry
-            p, li = layer
+            xb, (kp, vp, *scales) = carry
+            ks, vs = scales or (None, None)
+            p, li, _ = layer
             h = xb.shape[-1]
             # the fused kernels still take ONE layer's pool: the slice
             # stays until mega_attn_layer lowers (ROADMAP.md D3) and can
@@ -1330,7 +1445,8 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
                                 fuse_epilogue=False, chunk=chunk)
                 out = (s.reshape(b * chunk, h)
                        + (_srv_psum(part, axis) + p["b2"]))
-            return (out.reshape(b, chunk, h), kp, vp, ks, vs), None
+            return (out.reshape(b, chunk, h),
+                    (kp, vp, ks, vs) if kv_quant else (kp, vp)), None
 
         if mega:
             # lane-block layout for the fused layers: packed tokens
@@ -1341,14 +1457,34 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
             body = mega_block
         else:
             x0, body = x, block
-        layers = (params["layers"],
-                  jnp.arange(k_pages.shape[0], dtype=jnp.int32))
-        # the scan itself is scoped, so its own slicing of the stacked
-        # weights falls under "layers" alone (fp pools: the scale planes
-        # are None, an empty part of the carry)
+        # the stacks the model's layers come in, in order: one for a uniform
+        # model, the leading dense layers' and then the routed layers' for
+        # one whose stack is not uniform (models/deepseek_v2.py). Each is
+        # one scan; the layer index counts through them all, since the pool
+        # stack is [all layers, pages, ...]
+        groups = [params[g] for g in ("dense_layers", "layers")
+                  if g in params]
+        # the scans are scoped, so their own slicing of the stacked weights
+        # falls under "layers" alone
+        carry, first, expert_rows = (x0, pools), 0, None
         with step_scope("layers"):
-            (x, k_pages, v_pages, k_scales, v_scales), _ = jax.lax.scan(
-                body, (x0, k_pages, v_pages, k_scales, v_scales), layers)
+            for stack in groups:
+                n = jax.tree.leaves(stack)[0].shape[0]
+                # what a kernel reads by layer index stays out of the scanned
+                # slices (none for a GPT block)
+                whole = {k: stack[k] for k in STACKED_BY_INDEX if k in stack}
+                within = jnp.arange(n, dtype=jnp.int32)
+                carry, rows = jax.lax.scan(
+                    functools.partial(body, whole=whole) if whole else body,
+                    carry,
+                    ({k: v for k, v in stack.items() if k not in whole},
+                     first + within, within))
+                first += n
+                if rows is not None:
+                    # [2, E] int32: the rows every expert received, and
+                    # the layers in which it received any
+                    expert_rows = rows.sum(axis=0)
+        x, pools = carry
         if mega:
             x = x[slot_c, off_c]                     # back to packed [t]
         if spec_k:
@@ -1360,7 +1496,7 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
             rows = last_idx[:, None] + jnp.arange(k1)[None]     # [b, k1]
             rows_c = jnp.clip(rows, 0, t - 1)
             with step_scope("head"):
-                x = _srv_ln(x, params["lnf_g"], params["lnf_b"], eps)
+                x = _srv_norm(cfg, x, params, "lnf")
                 h_rows = x[rows_c]                              # [b,k1,h]
                 logits_rows = _srv_logits(params,
                                           h_rows).astype(jnp.float32)
@@ -1401,13 +1537,9 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
                     out_ids, jnp.maximum(n_emit - 1, 0)[:, None],
                     axis=1)[:, 0]
                 next_toks = jnp.where(emit_mask > 0, last_emit, prev_toks)
-            if kv_quant:
-                return (out_ids, n_emit, next_toks, logits_rows[:, 0],
-                        k_pages, v_pages, k_scales, v_scales)
-            return (out_ids, n_emit, next_toks, logits_rows[:, 0],
-                    k_pages, v_pages)
+            return (out_ids, n_emit, next_toks, logits_rows[:, 0], *pools)
         with step_scope("head"):
-            x = _srv_ln(x, params["lnf_g"], params["lnf_b"], eps)
+            x = _srv_norm(cfg, x, params, "lnf")
             # each slot's LAST packed token yields its next-token decision
             h_last = x[jnp.clip(last_idx, 0, t - 1)]              # [b, h]
             logits = _srv_logits(params, h_last).astype(jnp.float32)
@@ -1430,10 +1562,9 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
             # the previous token through (a lane skipped by the budget
             # still feeds its latest token through feedback next step)
             next_toks = jnp.where(emit_mask > 0, next_ids, prev_toks)
-        if kv_quant:
-            return (next_toks, logits, k_pages, v_pages, k_scales,
-                    v_scales)
-        return next_toks, logits, k_pages, v_pages
+        if expert_rows is not None:
+            return (next_toks, logits, *pools, expert_rows)
+        return (next_toks, logits, *pools)
 
     jitted = jit32(step,
                    donate_argnums=tuple(range(n_lead, n_lead + n_pool)))
@@ -1747,7 +1878,7 @@ def build_draft_chain(config: GPTConfig, draft_layers: int, page_size: int,
                                   axis) + p["bo"]
                 x = x + _srv_ffn(cfg, p, _srv_ln(x, p["ln2_g"],
                                                  p["ln2_b"], eps),
-                                 use_kernel, axis, valid=valid)
+                                 use_kernel, axis, valid=valid)[0]
                 return x, ((kp, vp, ks, vs) if kv_quant else (kp, vp))
 
             def mega_block(xb, layer):

@@ -47,14 +47,15 @@ def moe_capacity(n_tokens: int, num_experts: int, top_k: int,
                    / int(num_experts)) * int(top_k), 4)
 
 
-def route_topk(logits, top_k: int):
+def route_topk(logits, top_k: int, renormalize: bool = True):
     """Deterministic top-k routing over router ``logits [N, E]``.
 
     Returns ``(gates [N, k] fp32, idx [N, k] int32, probs [N, E] fp32,
-    masks)`` — gates renormalized over the k selections (GShard denom),
-    ``masks`` the per-choice one-hot ``[N, E]`` list. ``jnp.argmax``
-    breaks ties to the lowest index, and the iterative masking keeps the
-    k experts distinct."""
+    masks)`` — gates renormalized over the k selections (GShard denom)
+    unless ``renormalize`` is off, when they are the k softmax scores as
+    they are (``norm_topk_prob: false``); ``masks`` the per-choice one-hot
+    ``[N, E]`` list. ``jnp.argmax`` breaks ties to the lowest index, and
+    the iterative masking keeps the k experts distinct."""
     n, e = logits.shape
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     p = probs
@@ -67,7 +68,8 @@ def route_topk(logits, top_k: int):
         masks.append(m)
         p = p * (1.0 - m)
     gates = jnp.stack(raw, axis=1)                       # [N, k]
-    gates = gates / jnp.maximum(gates.sum(axis=1, keepdims=True), 1e-9)
+    if renormalize:
+        gates = gates / jnp.maximum(gates.sum(axis=1, keepdims=True), 1e-9)
     return gates, jnp.stack(idxs, axis=1), probs, masks
 
 
@@ -102,15 +104,36 @@ def capacity_positions(masks, capacity: int, valid=None):
     return jnp.stack(poss, axis=1)                       # [N, k] float
 
 
-def _grouped_mm(xs, w, offsets, use_kernel):
+def _earlier_same_choice(chose, block: int = 128):
+    """For one-hot rows ``chose [M, E]`` (bool): how many EARLIER rows made
+    each row's choice, ``[M]`` int32. In blocks of 128 rows the count is a
+    product with a triangle (exact: the sums stay far under 2**24) plus a
+    running count over the few blocks; a running sum down all M rows is a
+    slow reduction on the chip."""
+    m, e = chose.shape
+    nb = -(-m // block)
+    rows = jnp.pad(chose, ((0, nb * block - m), (0, 0))).astype(
+        jnp.float32).reshape(nb, block, e)
+    before = jnp.arange(block)
+    triangle = (before[:, None] > before[None, :]).astype(jnp.float32)
+    within = jnp.einsum("ij,bje->bie", triangle, rows,
+                        preferred_element_type=jnp.float32)
+    per_block = rows.sum(axis=1)                                  # [nb, E]
+    blocks_before = jnp.cumsum(per_block, axis=0) - per_block
+    earlier = ((within + blocks_before[:, None, :]) * rows).sum(axis=-1)
+    return earlier.reshape(-1)[:m].astype(jnp.int32)
+
+
+def _grouped_mm(xs, w, offsets, use_kernel, layer=None):
     """fp stack or quantized ``{"q", "s"}`` dict through the ragged
-    grouped GEMM (the ``_srv_mm`` convention per expert stack)."""
+    grouped GEMM (the ``_srv_mm`` convention per expert stack); with
+    ``layer``, layer ``layer`` of an ``[L, E, ...]`` float stack."""
     from ..ops.pallas.grouped_matmul import grouped_matmul
 
     if isinstance(w, dict):
         return grouped_matmul(xs, w["q"], offsets, scales=w["s"],
                               use_kernel=use_kernel)
-    return grouped_matmul(xs, w, offsets, use_kernel=use_kernel)
+    return grouped_matmul(xs, w, offsets, use_kernel=use_kernel, layer=layer)
 
 
 def _expert_bias(b, eids):
@@ -119,66 +142,103 @@ def _expert_bias(b, eids):
 
 
 def moe_ffn(x, gate_w, w1, b1, w2, b2, *, top_k: int,
-            capacity_factor: float, use_kernel=None, valid=None,
-            with_stats: bool = False):
+            capacity_factor: float | None, use_kernel=None, valid=None,
+            with_stats: bool = False, renormalize: bool = True,
+            gated: bool = False, gate_scale: float = 1.0, layer=None):
     """The MoE FFN over 2D tokens ``x [N, d]``.
 
     gate_w ``[d, E]``; w1 ``[E, d, f]`` / w2 ``[E, f, d]`` (fp stacks or
     quantized ``{"q", "s"}`` dicts — inference/quantize.py layout); b1
-    ``[E, f]``; b2 ``[E, d]``. ``valid [N]`` masks padding rows (serving's
-    packed stream): invalid rows route nowhere — zero gates, no capacity
-    slot, zero output. Dropped token-choice pairs (capacity overflow)
-    keep their expert assignment in the grouped layout but combine with
-    gate 0 — the token rides the residual.
+    ``[E, f]``; b2 ``[E, d]`` (``None``: no bias). ``valid [N]`` masks
+    padding rows (serving's packed stream): invalid rows route nowhere —
+    zero gates, no capacity slot, zero output. Dropped token-choice pairs
+    (capacity overflow) keep their expert assignment in the grouped layout
+    but combine with gate 0 — the token rides the residual.
+
+    What a model's configuration chooses: ``capacity_factor=None`` routes
+    DROPLESS (no clamp: every choice of every valid token is computed);
+    ``renormalize`` as in :func:`route_topk`; ``gate_scale`` multiplies
+    the gates (``routed_scaling_factor``); ``gated``: ``w1`` is ``[E, d,
+    2f]``, the gate and up projections of a gated SiLU expert in ONE
+    grouped GEMM, ``(silu(x W_g) * x W_u) W_d``, instead of the biased
+    GELU pair. ``layer`` (a traced int32 scalar): ``w1`` / ``w2`` are the
+    whole model's stacks ``[L, E, ...]`` and this call is layer ``layer``
+    of them (``grouped_matmul(layer=)``).
 
     Returns ``(out [N, d], aux_loss)`` — plus a stats dict (``load [E]``
-    kept-pair fraction per expert, ``drop_rate``) when ``with_stats``.
+    kept-pair fraction per expert, ``drop_rate``, ``rows [E]`` int32 kept
+    pairs per expert) when ``with_stats``.
     """
+    from ..observability.tracing import step_scope
+
     n, d = x.shape
     e = gate_w.shape[-1]
     k = int(top_k)
-    logits = x.astype(jnp.float32) @ gate_w.astype(jnp.float32)
-    gates, idx, probs, masks = route_topk(logits, k)
-    aux = load_balance_aux(probs, masks[0], valid=valid)
-    cap = moe_capacity(n, e, k, capacity_factor)
-    pos = capacity_positions(masks, cap, valid=valid)
-    keep = (pos >= 0.0) & (pos < cap)                     # [N, k]
-    if valid is not None:
-        keep = keep & valid[:, None]
-    gates = gates * keep.astype(gates.dtype)
+    with step_scope("moe_route"):
+        logits = x.astype(jnp.float32) @ gate_w.astype(jnp.float32)
+        gates, idx, probs, masks = route_topk(logits, k, renormalize)
+        aux = load_balance_aux(probs, masks[0], valid=valid)
+        if capacity_factor is None:
+            cap = n
+            keep = jnp.ones((n, k), bool)
+        else:
+            cap = moe_capacity(n, e, k, capacity_factor)
+            pos = capacity_positions(masks, cap, valid=valid)
+            keep = (pos >= 0.0) & (pos < cap)                 # [N, k]
+        if valid is not None:
+            keep = keep & valid[:, None]
+        gates = gates * keep.astype(gates.dtype) * gate_scale
 
-    # token-choice pairs sorted by expert (stable: deterministic intra-
-    # expert order = token-major arrival) — the ragged grouped layout
-    pair_tok = jnp.repeat(jnp.arange(n, dtype=jnp.int32), k)  # [N*k]
-    eid = idx.reshape(-1)                                     # [N*k]
-    order = jnp.argsort(eid, stable=True).astype(jnp.int32)
-    tok_sorted = pair_tok[order]
-    eid_sorted = eid[order]
-    counts = jnp.bincount(eid, length=e)
-    offsets = jnp.concatenate(
-        [jnp.zeros((1,), jnp.int32),
-         jnp.cumsum(counts).astype(jnp.int32)])
+        # token-choice pairs grouped by expert (stable: deterministic intra-
+        # expert order = token-major arrival) — the ragged grouped layout.
+        # A counting sort: a pair's place is its expert's offset plus how
+        # many earlier pairs chose that expert, and the rows are scattered
+        # there. (On the v5e an argsort of the 6,144 pairs, the scalar
+        # scatter that inverts the permutation and a running sum down the
+        # pairs each cost about 3 ms a step of seven layers: PERF.md, PR 28.)
+        eid = idx.reshape(-1)                                     # [N*k]
+        chose = eid[:, None] == jnp.arange(e, dtype=eid.dtype)[None]
+        offsets = jnp.concatenate(
+            [jnp.zeros((1,), jnp.int32),
+             jnp.cumsum(chose.sum(axis=0, dtype=jnp.int32))])
+        place = offsets[eid] + _earlier_same_choice(chose)  # pair -> row
+        xs = jnp.zeros((n * k, d), x.dtype).at[place].set(
+            jnp.repeat(x, k, axis=0))                             # [N*k, d]
+        if b1 is not None or b2 is not None:
+            from ..ops.pallas.grouped_matmul import token_group_ids
 
-    xs = jnp.take(x, tok_sorted, axis=0)                      # [N*k, d]
-    h = _grouped_mm(xs, w1, offsets, use_kernel)
-    h = jax.nn.gelu(h + _expert_bias(b1, eid_sorted).astype(h.dtype),
-                    approximate=True)
-    y = (_grouped_mm(h.astype(x.dtype), w2, offsets, use_kernel)
-         + _expert_bias(b2, eid_sorted).astype(x.dtype))
-    g_sorted = gates.reshape(-1)[order].astype(jnp.float32)
-    out = jnp.zeros((n, d), jnp.float32).at[tok_sorted].add(
-        y.astype(jnp.float32) * g_sorted[:, None])
-    out = out.astype(x.dtype)
+            eid_sorted = token_group_ids(offsets, n * k)
+
+    with step_scope("moe_experts"):
+        h = _grouped_mm(xs, w1, offsets, use_kernel, layer)
+        if b1 is not None:
+            h = h + _expert_bias(b1, eid_sorted).astype(h.dtype)
+        if gated:
+            f = h.shape[-1] // 2
+            h = jax.nn.silu(h[:, :f]) * h[:, f:]
+        else:
+            h = jax.nn.gelu(h, approximate=True)
+        y = _grouped_mm(h.astype(x.dtype), w2, offsets, use_kernel, layer)
+        if b2 is not None:
+            y = y + _expert_bias(b2, eid_sorted).astype(x.dtype)
+
+    with step_scope("moe_route"):
+        # each token gathers its k experts' rows and sums them by its gates
+        # (a gather and a small product, not a scatter-add over N*k rows)
+        mine = jnp.take(y, place, axis=0).reshape(n, k, d)
+        out = jnp.einsum("nkd,nk->nd", mine.astype(jnp.float32),
+                         gates.astype(jnp.float32)).astype(x.dtype)
     if not with_stats:
         return out, aux
     kept = keep.astype(jnp.float32)
     n_pairs = (jnp.maximum(valid.astype(jnp.float32).sum(), 1.0) * k
                if valid is not None else jnp.float32(n * k))
-    load = jnp.zeros((e,), jnp.float32).at[eid].add(kept.reshape(-1))
+    load = jnp.sum(chose * kept.reshape(-1, 1), axis=0)   # kept pairs [E]
     stats = {
         "load": load / jnp.maximum(load.sum(), 1.0),
         "drop_rate": 1.0 - jnp.minimum(kept.sum() / n_pairs, 1.0),
         "capacity": jnp.float32(cap),
+        "rows": load.astype(jnp.int32),
     }
     return out, aux, stats
 

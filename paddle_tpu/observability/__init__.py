@@ -25,7 +25,8 @@ from .fleet import FleetInstruments
 from .metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                       default_registry, disable_metrics, enable_metrics,
                       merge_snapshots, metrics_enabled)
-from .tracing import (REQUEST_SPAN, STEP_SCOPES, device_annotation,
+from .tracing import (REQUEST_SPAN, STEP_SCOPES, STEP_SUBSCOPES,
+                      device_annotation,
                       monotonic, monotonic_ns, request_begin, request_end,
                       request_event, span, step_scope, tracing_active)
 
@@ -35,5 +36,6 @@ __all__ = [
     "merge_snapshots", "span", "request_begin", "request_event",
     "request_end", "tracing_active", "monotonic",
     "monotonic_ns", "device_annotation", "REQUEST_SPAN", "STEP_SCOPES",
+    "STEP_SUBSCOPES",
     "step_scope", "FleetInstruments",
 ]

@@ -17,7 +17,7 @@ buffer (``profiler/record.py``):
   replay → eos) across the scheduler steps that interleave it.
 - :func:`step_scope` — ``jax.named_scope`` for one name of
   :data:`STEP_SCOPES`, the closed list of parts of the two benchmarked
-  step programs. The name rides every HLO operation's ``op_name`` path
+  step programs, or of :data:`STEP_SUBSCOPES`, parts of those parts. The name rides every HLO operation's ``op_name`` path
   into the device trace, where ``benchmark/scope_trace.py`` charges each
   operation's own time to the innermost such name.
 - :func:`monotonic` / :func:`monotonic_ns` — THE timing clock for
@@ -40,7 +40,8 @@ from ..profiler.record import now_ns, recorder
 __all__ = [
     "span", "request_begin", "request_event", "request_end",
     "tracing_active", "monotonic", "monotonic_ns",
-    "device_annotation", "set_device_tracing", "STEP_SCOPES", "step_scope",
+    "device_annotation", "set_device_tracing", "STEP_SCOPES",
+    "STEP_SUBSCOPES", "step_scope",
 ]
 
 monotonic = time.perf_counter
@@ -157,20 +158,35 @@ def request_end(req_id, args=None) -> None:
 #: of the train step (``models/gpt_spmd.py``). ``layers`` wraps the layer
 #: scan itself, so the scan's own slicing and stacking of what it carries
 #: fall under ``layers`` and under no part. The benchmark's scope readers
-#: depend on these names letter for letter.
+#: depend on these names letter for letter (``benchmark/scope_trace.py``
+#: holds the same tuple, and a test of its own holds the two equal).
 STEP_SCOPES = (
     "cow", "embed", "layers", "ln", "qkv", "kv_write", "attn", "attn_out",
     "mlp", "head", "sample", "head_loss", "optimizer", "pipeline",
 )
+
+#: parts OF a part (PR 28), each used only inside the scope named with it: a
+#: reader that knows :data:`STEP_SCOPES` alone charges them to that scope,
+#: one that knows these too sees the split. Inside ``mlp``, a routed layer's
+#: ``moe_route`` (router product, top-k, the sort and gather into expert
+#: order, the weighted sum back), ``moe_experts`` (the grouped GEMMs and the
+#: activation between them) and ``moe_shared`` (the shared experts); inside
+#: ``attn``, a latent cache's ``attn_absorb`` (``W_kv_b``'s key half on the
+#: query, its value half behind the softmax).
+STEP_SUBSCOPES = {
+    "moe_route": "mlp", "moe_experts": "mlp", "moe_shared": "mlp",
+    "attn_absorb": "attn",
+}
 
 
 def step_scope(name: str):
     """``jax.named_scope(name)`` for a name of :data:`STEP_SCOPES`. Metadata
     only: it names the operations traced under it and adds none. Any other
     name is an error at trace time."""
-    if name not in STEP_SCOPES:
+    if name not in STEP_SCOPES and name not in STEP_SUBSCOPES:
         raise ValueError(f"step_scope: {name!r} is not one of STEP_SCOPES "
-                         f"{STEP_SCOPES}")
+                         f"{STEP_SCOPES} or STEP_SUBSCOPES "
+                         f"{tuple(STEP_SUBSCOPES)}")
     import jax
 
     return jax.named_scope(name)

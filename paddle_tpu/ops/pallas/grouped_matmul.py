@@ -53,6 +53,10 @@ from .quant_matmul import (
 
 _MXU = jax.lax.Precision.DEFAULT
 
+# stable pallas_call name of the forward kernels (survives into the compiled
+# HLO and the device trace): how a check or a trace reduction finds them
+GROUPED_KERNEL_NAME = "grouped_matmul"
+
 
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
@@ -76,19 +80,21 @@ def token_group_ids(group_offsets, m: int):
     (rows in ``[offsets[e], offsets[e+1])`` belong to group ``e``)."""
     e = group_offsets.shape[0] - 1
     offs = group_offsets.astype(jnp.int32)
+    # compare_all: one dense comparison against the E + 1 offsets; the
+    # default binary search is a chain of gathers, slow on the chip
     gid = jnp.searchsorted(offs, jnp.arange(m, dtype=jnp.int32),
-                           side="right") - 1
+                           side="right", method="compare_all") - 1
     return jnp.clip(gid, 0, e - 1).astype(jnp.int32)
 
 
 def _pack_layout(group_offsets, m: int, e: int, bm: int):
     """Padded-aligned repack plan: each group's rows are shifted so its
     range starts on a ``bm`` boundary (groups padded up to a multiple of
-    ``bm``). Returns ``(dest [M], tile_gid [MP/bm], mp)`` — ``dest`` is
-    where row ``i`` lands in the padded buffer, ``tile_gid[t]`` the ONE
-    group owning row tile ``t`` (dead tiles past the ragged end alias
-    group 0's id range harmlessly: their rows are zero and never
-    gathered back)."""
+    ``bm``). Returns ``(dest [M], tile_gid [MP/bm], mp, n_live)`` —
+    ``dest`` is where row ``i`` lands in the padded buffer, ``tile_gid[t]``
+    the ONE group owning row tile ``t``, ``n_live`` (int32 scalar) how many
+    leading tiles hold rows: the dead tiles past the ragged end carry the
+    last live tile's group (their rows are zero and never gathered back)."""
     offs = group_offsets.astype(jnp.int32)
     counts = offs[1:] - offs[:-1]                                  # [E]
     padded = -(-counts // bm) * bm
@@ -99,11 +105,13 @@ def _pack_layout(group_offsets, m: int, e: int, bm: int):
     rows = jnp.arange(m, dtype=jnp.int32)
     gid = token_group_ids(group_offsets, m)
     dest = poffs[gid] + (rows - offs[gid])
-    starts = jnp.arange(mp // bm, dtype=jnp.int32) * bm
+    n_live = poffs[-1] // bm
+    tiles = jnp.arange(mp // bm, dtype=jnp.int32)
+    starts = jnp.minimum(tiles, jnp.maximum(n_live - 1, 0)) * bm
     tile_gid = jnp.clip(
         jnp.searchsorted(poffs, starts, side="right") - 1, 0, e - 1
     ).astype(jnp.int32)
-    return dest, tile_gid, mp
+    return dest, tile_gid, mp, n_live
 
 
 def _norm_scales_grouped(scales, e: int, k: int, n: int):
@@ -189,22 +197,26 @@ def grouped_matmul_reference(x, weights, group_offsets, scales=None):
 # ---------------------------------------------------------------------------
 
 
-def _gmm_kernel(gid_ref, x_ref, w_ref, o_ref):
+def _gmm_kernel(gid_ref, meta_ref, x_ref, w_ref, o_ref):
     """One [bm, bn] output tile of ONE group, accumulating over k tiles:
     the weight tile is this tile's group's ``[bk, bn]`` slab (index map
-    reads the prefetched group id)."""
+    reads the prefetched group id). ``meta_ref`` is ``[live tiles, layer]``:
+    a dead tile (past the ``meta_ref[0]`` tiles that hold rows) computes nothing, and its index maps name the
+    blocks the step before it named, so it streams nothing either."""
     del gid_ref  # consumed by the index maps
     kstep = pl.program_id(2)
 
-    @pl.when(kstep == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
+    @pl.when(pl.program_id(0) < meta_ref[0])
+    def _live():
+        @pl.when(kstep == 0)
+        def _init():
+            o_ref[...] = jnp.zeros_like(o_ref)
 
-    x = x_ref[...]
-    w = w_ref[0].astype(x.dtype)
-    o_ref[...] += jax.lax.dot_general(
-        x, w, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32, precision=_MXU)
+        x = x_ref[...]
+        w = w_ref[0].astype(x.dtype)
+        o_ref[...] += jax.lax.dot_general(
+            x, w, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=_MXU)
 
 
 def _gmm_q_kernel(gid_ref, x_ref, w_ref, s_ref, o_ref):
@@ -304,14 +316,25 @@ def _blocks_for(e, m, k, n, bits, group_size, dtype):
     """(bm, bn, bk): bn/bk honor divisibility + scale-group alignment
     exactly like ``quant_matmul``; bm is free because the pack pads every
     group to a bm multiple (it only trades padding waste against MXU
-    row occupancy)."""
+    row occupancy). Without a tuned entry, float weights get tiles from
+    the shape: a row tile that holds a mean group (so an expert's weights
+    stream once, not once per 32 rows) and the whole contraction in one k
+    step where a ``[k, bn]`` weight tile stays near a megabyte."""
     hit = _atc.lookup(_sig(e, k, n, bits, group_size, dtype))
-    pm, pn, pk = (hit if hit and len(hit) == 3
-                  else (BM_DEFAULT, BN_DEFAULT, BK_DEFAULT))
+    if hit and len(hit) == 3:
+        pm, pn, pk = hit
+    else:
+        pm, pn, pk = BM_DEFAULT, BN_DEFAULT, BK_DEFAULT
+        if bits == 0:
+            while pm < 128 and pm * e < m:
+                pm *= 2
+            if k <= 2048 and k % 128 == 0:
+                pk = k
     bm = max(8, _div_pick(pm, 1024))          # pow2 row tile >= sublane min
     bn = _div_pick(pn, n)
     k_ext = k // 2 if bits == 4 else k
-    bk = _div_pick(pk, math.gcd(k_ext, group_size))
+    bk = (pk if pk == k_ext == group_size
+          else _div_pick(pk, math.gcd(k_ext, group_size)))
     return bm, bn, bk
 
 
@@ -383,11 +406,12 @@ def autotune_grouped_matmul(e, m, k, n, bits=8, group_size=-1,
 # ---------------------------------------------------------------------------
 
 
-def _fwd_impl(x2, weights, scales3d, group_offsets, k, bits, group_size):
+def _fwd_impl(x2, weights, scales3d, group_offsets, k, bits, group_size,
+              layer=None):
     m = x2.shape[0]
-    e, _, n = weights.shape
+    e, _, n = weights.shape[-3:]
     bm, bn, bk = _blocks_for(e, m, k, n, bits, group_size, x2.dtype)
-    dest, tile_gid, mp = _pack_layout(group_offsets, m, e, bm)
+    dest, tile_gid, mp, n_live = _pack_layout(group_offsets, m, e, bm)
     x_pad = jnp.zeros((mp, k), x2.dtype).at[dest].set(x2)
     out_shape = jax.ShapeDtypeStruct((mp, n), jnp.float32)
     o_spec = pl.BlockSpec((bm, bn), lambda i, j, kk, g: (i, j))
@@ -395,19 +419,41 @@ def _fwd_impl(x2, weights, scales3d, group_offsets, k, bits, group_size):
     semantics = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))
     if bits == 0:
+        nj, nk = n // bn, k // bk
+
+        # scalar prefetch: the tiles' groups, and meta = [live tiles, layer]
+        def dead(i, meta):
+            return i >= meta[0]
+
+        # a dead tile names the LAST blocks the last live tile named: the
+        # pipeline fetches nothing for a block index that did not change
+        def x_imap(i, j, kk, g, meta):
+            d = dead(i, meta)
+            return (jnp.where(d, jnp.maximum(meta[0] - 1, 0), i),
+                    jnp.where(d, nk - 1, kk))
+
+        def w_imap(i, j, kk, g, meta):
+            d = dead(i, meta)
+            # stacked weights [L, E, K, N]: the leading block index is this
+            # call's layer
+            return (() if layer is None else (meta[1],)) + (
+                g[i], jnp.where(d, nk - 1, kk), jnp.where(d, nj - 1, j))
+
+        w_block = (1, bk, bn) if layer is None else (None, 1, bk, bn)
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(mp // bm, n // bn, k // bk),
-            in_specs=[
-                x_spec,
-                pl.BlockSpec((1, bk, bn),
-                             lambda i, j, kk, g: (g[i], kk, j)),
-            ],
-            out_specs=o_spec)
+            num_scalar_prefetch=2, grid=(mp // bm, nj, nk),
+            in_specs=[pl.BlockSpec((bm, bk), x_imap),
+                      pl.BlockSpec(w_block, w_imap)],
+            out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk, g, meta: (i, j)))
+        meta = jnp.stack([n_live.astype(jnp.int32),
+                          jnp.asarray(0 if layer is None else layer,
+                                      jnp.int32)])
         with _atc.x64_off():
             out = pl.pallas_call(
                 _gmm_kernel, grid_spec=grid_spec, out_shape=out_shape,
                 compiler_params=semantics, interpret=_interpret(),
-            )(tile_gid, x_pad, weights)
+                name=GROUPED_KERNEL_NAME,
+            )(tile_gid, meta, x_pad, weights)
         return out[dest]
     if bits == 8:
         grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -474,7 +520,7 @@ def _bwd_dx_impl(dy, weights, scales3d, group_offsets, k, bits, group_size,
             dxs, gid[None, :, None].astype(jnp.int32), axis=0)[0].astype(
                 x_dtype)
     bm, bn, bk = _blocks_for(e, m, k, n, bits, group_size, x_dtype)
-    dest, tile_gid, mp = _pack_layout(group_offsets, m, e, bm)
+    dest, tile_gid, mp, _ = _pack_layout(group_offsets, m, e, bm)
     dy_pad = jnp.zeros((mp, n), x_dtype).at[dest].set(dy.astype(x_dtype))
     out_shape = jax.ShapeDtypeStruct((mp, k), jnp.float32)
     dx_spec = pl.BlockSpec((bm, bk), lambda i, kk, j, g: (i, kk))
@@ -564,8 +610,14 @@ _gmm.defvjp(_gmm_fwd, _gmm_bwd)
 
 
 def grouped_matmul(x, weights, group_offsets, scales=None,
-                   use_kernel: bool | None = None):
+                   use_kernel: bool | None = None, layer=None):
     """Ragged grouped GEMM: ``out[i] = x[i] @ dequant(weights)[g(i)]``.
+
+    ``layer`` (a traced int32 scalar; float weights, forward only):
+    ``weights`` is a STACK ``[L, E, K, N]`` and the call reads layer
+    ``layer`` of it through the kernel's block index maps — how a layer
+    scan uses its experts without slicing a gigabyte of them out of the
+    stack for every layer (the custom call would be handed a copy).
 
     x: ``[M, K]`` float rows PRE-SORTED by group (ascending group id);
     weights: ``[E, K, N]`` float/int8 or ``[E, K/2, N]`` nibble-packed
@@ -580,6 +632,23 @@ def grouped_matmul(x, weights, group_offsets, scales=None,
     if x.ndim != 2:
         raise ValueError(f"grouped_matmul wants 2D tokens [M, K], got "
                          f"{x.shape}")
+    if layer is not None:
+        if weights.ndim != 4 or weights.dtype == jnp.int8 \
+                or scales is not None:
+            raise ValueError(
+                f"grouped_matmul(layer=) wants a float stack [L, E, K, N], "
+                f"got {weights.dtype}{weights.shape}")
+        k = x.shape[1]
+        if use_kernel is None:
+            use_kernel = use_kernel_default() and _shape_ok(
+                k, weights.shape[-1], 0)
+        if not use_kernel:
+            return grouped_matmul_reference(
+                x, jax.lax.dynamic_index_in_dim(weights, layer,
+                                                keepdims=False),
+                group_offsets)
+        return _fwd_impl(x, weights, None, group_offsets.astype(jnp.int32),
+                         k, 0, k, layer=layer).astype(x.dtype)
     if weights.ndim != 3:
         raise ValueError(f"grouped_matmul wants stacked weights [E, K, N], "
                          f"got {weights.shape}")

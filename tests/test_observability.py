@@ -649,19 +649,27 @@ def _scope_components(lowered_text):
 
 class TestStepScopes:
     def test_step_scope_takes_only_the_closed_list(self):
-        from paddle_tpu.observability import STEP_SCOPES, step_scope
+        from paddle_tpu.observability import (STEP_SCOPES, STEP_SUBSCOPES,
+                                              step_scope)
 
         assert set(SERVE_SCOPES) | set(TRAIN_SCOPES) == set(STEP_SCOPES)
         with step_scope("ln"):
             pass
         with pytest.raises(ValueError, match="STEP_SCOPES"):
             step_scope("layer_norm")
+        # PR 28: parts of a part, each inside a scope of the closed list
+        assert set(STEP_SUBSCOPES) == {"moe_route", "moe_experts",
+                                       "moe_shared", "attn_absorb"}
+        assert set(STEP_SUBSCOPES.values()) <= set(STEP_SCOPES)
+        assert not set(STEP_SUBSCOPES) & set(STEP_SCOPES)
+        with step_scope("moe_route"):
+            pass
 
     def test_named_scope_is_used_through_the_helper_only(self):
         import os
         import re
 
-        from paddle_tpu.observability import STEP_SCOPES
+        from paddle_tpu.observability import STEP_SCOPES, STEP_SUBSCOPES
 
         root = os.path.dirname(paddle.__file__)
         raw, used = [], {}
@@ -680,12 +688,21 @@ class TestStepScopes:
                                                  "tracing.py"):
                     used[rel] = set(names)
         assert raw == [os.path.join("observability", "tracing.py")]
-        assert sorted(used) == [os.path.join("models", "gpt.py"),
-                                os.path.join("models", "gpt_spmd.py")]
-        assert used[os.path.join("models", "gpt.py")] == set(SERVE_SCOPES)
+        assert sorted(used) == [os.path.join("models", "deepseek_v2.py"),
+                                os.path.join("models", "gpt.py"),
+                                os.path.join("models", "gpt_spmd.py"),
+                                os.path.join("models", "moe.py")]
+        assert used[os.path.join("models", "gpt.py")] == \
+            set(SERVE_SCOPES) | {"attn_absorb"}
         assert used[os.path.join("models", "gpt_spmd.py")] == \
             set(TRAIN_SCOPES)
-        assert set().union(*used.values()) <= set(STEP_SCOPES)
+        # a routed layer's parts, inside the serving step's "mlp"
+        assert used[os.path.join("models", "moe.py")] == {"moe_route",
+                                                          "moe_experts"}
+        assert used[os.path.join("models", "deepseek_v2.py")] == \
+            {"moe_shared"}
+        assert set().union(*used.values()) <= \
+            set(STEP_SCOPES) | set(STEP_SUBSCOPES)
 
     def test_unified_step_lowers_with_every_serving_scope(self, rng):
         import jax

@@ -108,3 +108,43 @@ def test_compiled_step_keeps_the_pools_in_one_buffer(one_chip, monkeypatch,
     assert pool_copies(hlo, pool.shape) == []
     pool_bytes = pool.size * pool.dtype.itemsize
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
+
+
+def test_compiled_latent_step_keeps_its_one_pool_in_one_buffer(one_chip,
+                                                               monkeypatch):
+    """PR 28: the DeepSeek-V2-Lite block through the same step, at the
+    cell's widths (16 heads over a 576-value latent row stored 640 wide, 64
+    experts of width 1408, a 102,400-word head), one dense and one routed
+    layer. The latent kernel, the in-place row write and the grouped GEMM
+    are Mosaic calls of the program; nothing of the pool's shape is copied,
+    the copy-on-write lanes included (their gather of 640-wide rows would
+    slice the whole pool: they go lane by lane); and the expert stacks are
+    read by layer index, so the program's temporaries stay far under one
+    layer's experts (1.1 GB)."""
+    import paddle_tpu  # noqa: F401  framework config (matmul precision)
+    from paddle_tpu.models.deepseek_v2 import DeepseekV2Config
+    from paddle_tpu.models.gpt import build_unified_step
+
+    sys.path.insert(0, REPO)
+    try:
+        from chip_smoke import _is_mosaic_call, pool_copies
+        from benchmark.tools.compile_serve_latent_moe_for_v5e import (
+            step_avals)
+    finally:
+        sys.path.remove(REPO)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = DeepseekV2Config(num_layers=2, max_seq_len=4096)
+    dep = dict(token_budget=256, max_batch=8, max_seq_len=4096, page_size=64)
+    step = build_unified_step(cfg, 64, 64)
+    avals = step_avals(cfg, dep, 512, one_chip, jnp.bfloat16)
+    compiled = step.lower(*avals).compile()
+    hlo = compiled.as_text()
+    for kernel in ("mla_ragged_paged_attention", "paged_kv_write",
+                   "grouped_matmul"):
+        assert any(_is_mosaic_call(line, kernel)
+                   for line in hlo.splitlines()), kernel
+    pool = avals[11]
+    assert pool.shape == (2, 512, 1, 64, 640)
+    assert pool_copies(hlo, pool.shape) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.3e9

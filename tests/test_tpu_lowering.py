@@ -185,6 +185,50 @@ def test_grouped_matmul_lowers():
                         sds(experts, FFN, dtype=jnp.float32)) == 1
 
 
+@pytest.mark.parametrize("what", ["latent attention", "latent row write",
+                                  "stacked experts"])
+def test_latent_moe_kernels_lower_at_deepseek_v2_lite_widths(what):
+    """PR 28's kernels at the DeepSeek-V2-Lite cell's widths: 16 heads over
+    one 576-value row stored 640 wide, 64-token pages, 256 page slots a lane;
+    64 experts of 2048 x 2816 read by layer index out of their stack."""
+    from paddle_tpu.inference.kv_cache import (packed_write_plan,
+                                               paged_write_packed)
+    from paddle_tpu.ops.pallas.grouped_matmul import grouped_matmul
+    from paddle_tpu.ops.pallas.mla_paged_attention import (
+        mla_ragged_paged_attention)
+
+    layers, pages, row, lanes, budget, slots = 3, 512, 640, 32, 1024, 256
+    i32 = jnp.int32
+    pool = sds(layers, pages, 1, PAGE, row)
+    table = sds(lanes, slots, dtype=i32)
+    tok, lane = sds(budget, dtype=i32), sds(lanes, dtype=i32)
+
+    if what == "latent attention":
+        def fn(q, pool, table, ctx, q_lens, slot, off, layer):
+            return mla_ragged_paged_attention(
+                q, pool, table, ctx, q_lens, slot, off, v_dim=512,
+                scale=0.1, layer=layer)
+
+        calls = mosaic_calls(fn, sds(budget, 16, row), pool, table, lane,
+                             lane, tok, tok, sds(dtype=i32))
+    elif what == "latent row write":
+        def fn(pool, rows, table, slot, pos, layer):
+            plan = packed_write_plan(table, slot, pos, PAGE, pages)
+            return paged_write_packed(pool, rows, table, slot, pos, PAGE,
+                                      layer=layer, plan=plan)
+
+        calls = mosaic_calls(fn, pool, sds(budget, 1, row), table, tok, tok,
+                             sds(dtype=i32))
+    else:
+        def fn(x, w, offsets, layer):
+            return grouped_matmul(x, w, offsets, layer=layer)
+
+        calls = mosaic_calls(fn, sds(6 * budget, 2048),
+                             sds(2, 64, 2048, 2816),
+                             sds(65, dtype=i32), sds(dtype=i32))
+    assert calls == 1
+
+
 def _layer_weights():
     return {"ln1_g": sds(H), "ln1_b": sds(H), "ln2_g": sds(H),
             "ln2_b": sds(H), "wqkv": sds(H, 3 * H), "bqkv": sds(3 * H),
