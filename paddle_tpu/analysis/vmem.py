@@ -65,7 +65,10 @@ def pallas_footprints(closed) -> list[dict]:
         out.append({
             "kernel": (eqn.params["name"]
                        or eqn.params["jaxpr"].debug_info.func_name),
-            "grid": tuple(int(g) for g in gm.grid),
+            # a dynamic grid bound (``ragged_paged_attention``'s work
+            # items) is known only at run time: None in the report
+            "grid": tuple(int(g) if isinstance(g, int) else None
+                          for g in gm.grid),
             "block_bytes": blocks,
             "scratch_bytes": scratch,
             "vmem_bytes": LIVE_BUFFERS * blocks + scratch,

@@ -401,6 +401,9 @@ class ServingPredictor:
         # expert (the fullest expert's share of the mean is max over mean of
         # these). A model without them reports neither.
         self._m_moe_rows = self._m_moe_expert_rows = self._m_moe_fed = None
+        # only a predictor whose step runs ``ragged_paged_attention`` counts
+        # that kernel's grid steps (set where the step is built)
+        self._attn_grid = self._m_attn_live = self._m_attn_grid = None
         self._moe_unread: list = []
         if getattr(cfg, "n_routed_experts", 0):
             self._m_moe_fed = self.metrics.counter(
@@ -552,6 +555,26 @@ class ServingPredictor:
                 mesh=self.mesh, spec_k=self.spec_k,
                 mega=self.mega_decode)
             self._prefill = self._decode = None
+            if not (self.latent or self.mega_decode):
+                # the step's attention is ``ragged_paged_attention``: what a
+                # scheduled lane's context costs it in grid steps, by the
+                # kernel module's own function (per chip under a mesh)
+                from ..ops.pallas.paged_attention import ragged_grid
+
+                heads = cfg.num_heads // (self.mesh.shape["mp"]
+                                          if self.mesh is not None else 1)
+                self._attn_grid = ragged_grid(
+                    self.max_batch, self.cache.pages_per_slot, self.chunk,
+                    heads, heads, self.cache.page_size, cfg.head_dim,
+                    "int8" if self.kv_quant else kv_dtype, kv_dtype)
+                self._m_attn_live = self.metrics.counter(
+                    "serving_attn_blocks_live",
+                    "grid steps of a ragged_paged_attention call that hold "
+                    "a scheduled lane's keys, summed over dispatched steps")
+                self._m_attn_grid = self.metrics.counter(
+                    "serving_attn_blocks_grid",
+                    "grid steps a ragged_paged_attention call launches, "
+                    "summed over dispatched steps")
         else:
             self._unified = None
             self._decode = build_decode_step(cfg, self.cache.page_size,
@@ -2140,10 +2163,12 @@ class ServingPredictor:
         # rows while its context is known ahead (prompt chunks, replay), and
         # decode rows once it feeds one generated token (plus its drafts)
         now = None
+        contexts = []
         for slot, n in sched.items():
             req = self.running[slot]
             written = cache.seq_len(slot)
             n_prompt = len(req.prompt_ids)
+            contexts.append(written + n)
             if slot in decode_set and written >= n_prompt:
                 self._m_rows_decode.inc(n)
             else:
@@ -2158,6 +2183,10 @@ class ServingPredictor:
             # reconcile (their watermark is n_emit, a device value)
             if not spec_len[slot]:
                 cache.advance(slot, n)
+        if self._attn_grid is not None:
+            self._m_attn_live.inc(
+                sum(map(self._attn_grid.live_steps, contexts)))
+            self._m_attn_grid.inc(self._attn_grid.steps(contexts))
         spec_slots = [s for s in sched if spec_len[s]]
         # a speculating lane always completes, so a prefill-only round
         # (completing empty) carries nothing to materialize — the entry

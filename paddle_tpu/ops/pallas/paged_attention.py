@@ -41,11 +41,40 @@ unified-step kernel (Ragged Paged Attention, arxiv 2604.15464): each
 sequence contributes 1..chunk query tokens per step (decode lanes feed 1,
 prefill chunks feed up to ``chunk``), causal within the chunk, online
 softmax across that sequence's pages. Query rows for one (sequence,
-kv-head) program are laid out ``[chunk * group, head_dim]`` (chunk-major,
+kv-head) pair are laid out ``[chunk * group, head_dim]`` (chunk-major,
 GQA group minor) so one MXU dot serves the whole chunk; the per-row causal
 limit is ``kv_start + row // group + 1``. The per-step chunk size is a
 trace-time constant autotuned on the shared cache
 (:func:`preferred_chunk_size` / :func:`autotune_chunk_size`).
+
+The ragged kernel's grid (PR 29) pays for keys, not for page slots. The
+decode kernel above still visits every (sequence, head, page slot); the
+ragged one does not:
+
+- one grid step serves ALL local KV heads of a lane (a page's block for
+  every head is one contiguous ``[kv_heads, page_size, head_dim]`` tile;
+  where that does not fit the fast-memory budget, the largest divisor of the
+  head count that does, and the head groups lead the grid) over SEVERAL
+  pages: the pool is passed once per page a step reads, each operand with
+  its own block index map, so the pipeline fetches them together. Two
+  64-key pages side by side make a 128-key score tile; the accumulator is
+  rescaled once a grid step and divided by ``l`` once a lane; ``m``, ``l``
+  and the float32 accumulator live in VMEM scratch;
+- the grid's second axis runs over WORK ITEMS, not over lanes x page slots:
+  each lane's key blocks that hold keys, lane after lane, listed by the
+  wrapper from ``kv_lens`` / ``q_lens`` (``_work_items``) and handed over as
+  scalar-prefetch operands, the grid's bound being their count (a dynamic
+  grid dimension). A page slot past a lane's context is never visited; an
+  idle lane keeps one item that writes its zero rows; inside a live item a
+  page slot past the context names the page its operand named before
+  (nothing is fetched) and its key block computes nothing;
+- a lane that feeds at most a few rows (a decode lane's one token, a verify
+  lane's drafts) takes the few-rows form of the same body, chosen per grid
+  step from ``q_lens``: 8 query rows a head, not ``chunk * group``.
+
+:func:`ragged_grid` is that layout as a function of the operands' shapes and
+dtypes, written once: the kernel is built from it and the scheduler counts
+the grid steps of its lanes' contexts with it.
 
 SPMD contract (round 11): under the multi-chip serving mesh these kernels
 run PER CHIP inside a fully-manual ``shard_map`` over ``Mesh(("mp",))`` —
@@ -60,6 +89,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -279,148 +309,315 @@ def paged_attention(q, k_pages, v_pages, page_table, lengths, scale=None,
 # ---------------------------------------------------------------------------
 
 
-def _ragged_kernel(lens_ref, qlens_ref, pt_ref, q_ref, k_ref, v_ref,
-                   *refs, page_size, group, scale, quant=False):
-    """``quant=False``: refs = (o, m, l) and K/V tiles arrive in the
-    compute dtype. ``quant=True`` (round-10 int8 KV): refs = (ks, vs, o,
-    m, l) — the page tiles arrive int8 with the page's scale rows
-    ([kv_heads, page_size] blocks of the scale plane). The int8 values are
-    exact in the compute dtype, so the per-token scales fold into the two
-    dots' fp32 sides as [1, page_size] lane vectors: ``(q . kq) * ks`` and
-    ``(p * vs) . vq``; the online-softmax recurrence is IDENTICAL (one
-    body, so the paths cannot drift)."""
+# What one grid step of the ragged kernel covers (measured on the v5e at the
+# 590M serving cells, PERF.md, PR 29: with no dead grid steps 2, 4, 6 and 8
+# pages a step are within 4% of each other; 4 needs half the operands of 8):
+# the pages one step reads, the fast memory its blocks may take of Mosaic's
+# 16 MiB scoped default (double buffers counted; the rest is the compiler's
+# own), and the query rows of the few-rows form (one f32 sublane tile).
+PAGES_PER_STEP = 4
+VMEM_BUDGET = 12 << 20
+FEW_ROWS = 8
+
+
+class RaggedGrid(NamedTuple):
+    """The grid of one ``ragged_paged_attention`` call (:func:`ragged_grid`):
+    ``heads`` KV heads and ``pages`` pages a grid step, ``pair`` pages side by
+    side in one score tile, the padded query ``rows`` of a lane and head, how
+    many of them the few-rows form computes (``few_rows``: 0 where the block
+    has no more), and what the grid runs over: ``groups`` of heads x the key
+    blocks that hold keys, lane after lane (an idle lane has one grid step,
+    which writes its zero rows), at most ``lanes * blocks`` a group."""
+    heads: int
+    pages: int
+    pair: int
+    rows: int
+    few_rows: int
+    page_size: int
+    groups: int
+    lanes: int
+    blocks: int
+
+    @property
+    def keys(self) -> int:
+        """Keys one grid step covers."""
+        return self.pages * self.page_size
+
+    def live_steps(self, kv_len: int) -> int:
+        """Grid steps that hold keys of a scheduled lane's context."""
+        return self.groups * -(-int(kv_len) // self.keys)
+
+    def steps(self, contexts) -> int:
+        """Grid steps one call launches when the scheduled lanes' contexts
+        are ``contexts`` (the lanes not named are idle)."""
+        live = [max(self.groups, self.live_steps(n)) for n in contexts]
+        return self.groups * (self.lanes - len(live)) + sum(live)
+
+
+def ragged_grid(lanes, pps, chunk, hq, hkv, page_size, head_dim, kv_dtype,
+                q_dtype) -> RaggedGrid:
+    """How :func:`ragged_paged_attention` lays its grid over operands of
+    these shapes: written once, so the scheduler's count of live and launched
+    grid steps (``inference/serving.py``) cannot drift from the kernel.
+
+    A grid step reads ``min(PAGES_PER_STEP, pps)`` pages of as many local KV
+    heads as fit :data:`VMEM_BUDGET` (the largest divisor of ``hkv``): K and
+    V blocks double-buffered, int8 pools with their scale planes (whose block
+    is all heads of a page whatever the step takes), the query block, the
+    float32 output block and the softmax state in scratch."""
+    group = hq // hkv
+    rows = max(8, -(-chunk * group // 8) * 8)
+    kv_bytes, q_bytes = jnp.dtype(kv_dtype).itemsize, jnp.dtype(q_dtype).itemsize
+    quant = jnp.dtype(kv_dtype) == jnp.int8
+    pages = max(1, min(PAGES_PER_STEP, pps))
+    # pages side by side in one score tile, up to 128 keys (whole vector
+    # lanes, a full-width MXU pass); int8 pages stay apart: their scale rows
+    # would have to be joined along the lanes
+    wide = 1 if quant else max(1, 128 // page_size)
+    pair = max(p for p in range(1, pages + 1) if pages % p == 0 and p <= wide)
+    score_lanes = -(-pair * page_size // 128) * 128   # a tile's last dim
+    per_head = (2 * 2 * pages * page_size * head_dim * kv_bytes     # K, V
+                + rows * head_dim * (2 * q_bytes + 2 * 4 + 4)       # q, o, acc
+                + 3 * rows * 128 * 4                                # m, l, top
+                + pages // pair * rows * score_lanes * 4)           # scores
+    fixed = 2 * 2 * pages * hkv * page_size * 4 if quant else 0
+    heads = max([h for h in range(1, hkv + 1) if hkv % h == 0
+                 and fixed + h * per_head <= VMEM_BUDGET] or [1])
+    few = max(FEW_ROWS, -(-group // 8) * 8)
+    return RaggedGrid(heads=heads, pages=pages, pair=pair, rows=rows,
+                      few_rows=few if few < rows else 0, page_size=page_size,
+                      groups=hkv // heads, lanes=lanes,
+                      blocks=-(-pps // pages))
+
+
+def _ragged_kernel(lens_ref, qlens_ref, lane_ref, blk_ref, last_ref, tbl_ref,
+                   *refs, plan, group, scale, quant, stacked):
+    """One grid step: ``plan.heads`` KV heads of one lane over ``plan.pages``
+    pages. refs: [layer (stacked pools only: the index maps' business alone)]
+    q, K pages x P, V pages x P, [K scale rows x P, V scale rows x P], o,
+    then scratch: the softmax state m, l, acc, and within a step the key
+    blocks' scores and their running row maxima.
+
+    ``quant`` (round-10 int8 KV): the page tiles arrive int8 with the page's
+    scale rows ([kv_heads, page_size] blocks of the scale plane). The int8
+    values are exact in the compute dtype, so the per-token scales fold into
+    the two dots' fp32 sides as [1, page_size] lane vectors: ``(q . kq) *
+    ks`` and ``(p * vs) . vq``; the online-softmax recurrence is IDENTICAL
+    (one body, so the paths cannot drift)."""
+    pages, pair, page_size = plan.pages, plan.pair, plan.page_size
+    refs = refs[1:] if stacked else refs
+    q_ref, refs = refs[0], refs[1:]
+    k_refs, v_refs, refs = refs[:pages], refs[pages:2 * pages], refs[2 * pages:]
     if quant:
-        ks_ref, vs_ref, o_ref, m_ref, l_ref = refs
-    else:
-        o_ref, m_ref, l_ref = refs
-    b = pl.program_id(0)
-    h = pl.program_id(1)
-    j = pl.program_id(2)
+        ks_refs, vs_refs, refs = (refs[:pages], refs[pages:2 * pages],
+                                  refs[2 * pages:])
+    o_ref, m_ref, l_ref, acc_ref, top_ref, s_ref = refs
+    g = pl.program_id(0)
+    i = pl.program_id(1)
+    b = lane_ref[i]
+    j = blk_ref[i]
     kv_len = lens_ref[b]     # context INCLUDING this chunk's tokens
     q_len = qlens_ref[b]     # valid query tokens this step (0 = idle lane)
+    keys = pair * page_size
+    first_key = j * plan.keys
 
     @pl.when(j == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
-        o_ref[...] = jnp.zeros_like(o_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when((j * page_size < kv_len) & (q_len > 0))
-    def _accumulate():
-        q = q_ref[...]           # [R, d] rows = chunk-major * group-minor
-        k = k_ref[...]           # [page_size, d]
-        v = v_ref[...]
-        if quant:
-            k = k.astype(q.dtype)
-            v = v.astype(q.dtype)
-        s = _dotf32(q, k, ((1,), (1,))) * scale          # [R, ps] f32
-        if quant:
-            s = s * ks_ref[pl.ds(h, 1), :]
-        col = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
+    def tile(page_refs, c, h):
+        """Key block ``c`` of this step for head ``h``: ``pair`` pages side
+        by side, ``[keys, d]``."""
+        parts = [page_refs[c * pair + t][h] for t in range(pair)]
+        return parts[0] if pair == 1 else jnp.concatenate(parts, axis=0)
+
+    def accumulate(rows):
+        """One online-softmax update of every head's first ``rows`` query
+        rows over this step's pages: the scores of all its key blocks first
+        (kept in ``s_ref``, their running row maxima in ``top_ref``), ONE
+        rescale of the accumulator, then the weighted values. A key block
+        past the context computes nothing; inside a block's region the heads
+        are independent straight-line code, so their products overlap."""
         # row r serves query token r // group: it may attend every key up
         # to and including its own position kv_start + r // group
-        qi = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // group
-        limit = (kv_len - q_len) + qi + jnp.int32(1)
-        s = jnp.where(col < jnp.minimum(limit, kv_len), s, NEG_INF)
-        m_prev = m_ref[...]                               # [R, 1]
-        l_prev = l_ref[...]
-        m_next = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_next)
-        p = jnp.exp(s - m_next)
-        l_next = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        l_safe = jnp.where(l_next == 0.0, 1.0, l_next)
-        pw = p * vs_ref[pl.ds(h, 1), :] if quant else p
-        pv = _dotf32(pw.astype(v.dtype), v, ((1,), (0,)))  # [R, d]
-        o_ref[...] = ((o_ref[...] * (l_prev * alpha) + pv) / l_safe
+        qi = jax.lax.broadcasted_iota(jnp.int32, (rows, keys), 0) // group
+        limit = jnp.minimum((kv_len - q_len) + qi + jnp.int32(1), kv_len)
+        col = first_key + jax.lax.broadcasted_iota(jnp.int32, (rows, keys), 1)
+
+        def scores(c):
+            seen = col + jnp.int32(c * keys) < limit
+            for h in range(plan.heads):
+                q = q_ref[h, :rows, :]                       # [rows, d]
+                k = tile(k_refs, c, h)
+                if quant:
+                    k = k.astype(q.dtype)
+                s = _dotf32(q, k, ((1,), (1,))) * scale      # [rows, keys]
+                if quant:
+                    s = s * ks_refs[c][pl.ds(g * plan.heads + h, 1), :]
+                s = jnp.where(seen, s, NEG_INF)
+                s_ref[c, h, :rows, :] = s
+                top = (m_ref if c == 0 else top_ref)[h, :rows, :]
+                top_ref[h, :rows, :] = jnp.maximum(
+                    top, jnp.max(s, axis=-1, keepdims=True))
+
+        def values(c):
+            for h in range(plan.heads):
+                m_next = top_ref[h, :rows, :]                # [rows, 1]
+                p = jnp.exp(s_ref[c, h, :rows, :] - m_next)
+                v = tile(v_refs, c, h)
+                if quant:
+                    v = v.astype(q_ref.dtype)
+                    pw = p * vs_refs[c][pl.ds(g * plan.heads + h, 1), :]
+                else:
+                    pw = p
+                l_add = jnp.sum(p, axis=-1, keepdims=True)
+                pv = _dotf32(pw.astype(v.dtype), v, ((1,), (0,)))
+                if c == 0:
+                    # the step's one rescale of what the blocks before gave
+                    alpha = jnp.exp(m_ref[h, :rows, :] - m_next)
+                    l_ref[h, :rows, :] = l_ref[h, :rows, :] * alpha + l_add
+                    acc_ref[h, :rows, :] = acc_ref[h, :rows, :] * alpha + pv
+                    m_ref[h, :rows, :] = m_next
+                else:
+                    l_ref[h, :rows, :] += l_add
+                    acc_ref[h, :rows, :] += pv
+
+        # the step's first key block holds keys wherever the step is live
+        for part in (scores, values):
+            part(0)
+            for c in range(1, pages // pair):
+                pl.when(first_key + jnp.int32(c * keys) < kv_len)(
+                    functools.partial(part, c))
+
+    # a decode lane feeds ONE token (a verify lane a few): the few-rows form
+    # spares it the chunk's other rows' products and exponentials; a prefill
+    # chunk runs whole. Chosen per grid step from q_lens.
+    live = (q_len > 0) & (first_key < kv_len)
+    if plan.few_rows:
+        few = q_len * group <= plan.few_rows
+        pl.when(live & few)(lambda: accumulate(plan.few_rows))
+        pl.when(live & jnp.logical_not(few))(lambda: accumulate(plan.rows))
+    else:
+        pl.when(live)(lambda: accumulate(plan.rows))
+
+    @pl.when(last_ref[i] == 1)
+    def _finish():
+        l = l_ref[...]
+        o_ref[...] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)
                       ).astype(o_ref.dtype)
-        m_ref[...] = m_next
-        l_ref[...] = l_next
 
 
-def _ragged_kernel_stacked(body, lens_ref, qlens_ref, pt_ref, layer_ref,
-                           *refs):
-    """The stacked pools' form of the kernel: the same body; the fourth
-    scalar-prefetch operand, the layer index, is the index maps' business
-    alone."""
-    return body(lens_ref, qlens_ref, pt_ref, *refs)
+def _work_items(page_table, kv_lens, q_lens, plan, num_pages):
+    """The grid's second axis, item by item: every lane's live key blocks in
+    order, lane after lane (an idle or empty lane keeps ONE item, so its zero
+    rows are written). Returns ``total`` (the items this call runs: the
+    grid's dynamic bound), each item's ``lane`` and key ``block``, whether it
+    is its lane's ``last`` (``[n]``, n the static bound), and the page every
+    (item, operand) names (``[n * pages]``): a slot past the lane's context
+    names the page its operand named on the item BEFORE, across lanes too,
+    so the pipeline fetches nothing for it."""
+    b, pps = page_table.shape
+    pages, i32 = plan.pages, jnp.int32
+    n = b * plan.blocks
+    live_blocks = jnp.where(q_lens > 0, -(-kv_lens // i32(plan.keys)), 0)
+    per_lane = jnp.maximum(live_blocks, 1)
+    ends = jnp.cumsum(per_lane)
+    item = jnp.arange(n, dtype=i32)
+    lane = jnp.minimum(jnp.sum(item[:, None] >= ends[None, :], axis=1),
+                       b - 1).astype(i32)
+    block = item - (ends - per_lane)[lane]
+    last = (block == per_lane[lane] - 1).astype(i32)
+    slot = block[:, None] * pages + jnp.arange(pages, dtype=i32)[None, :]
+    live = ((slot * plan.page_size < kv_lens[lane][:, None])
+            & (q_lens[lane][:, None] > 0) & (slot < pps)
+            & (item[:, None] < ends[-1]))
+    page = jnp.clip(page_table, 0, num_pages - 1)[
+        lane[:, None], jnp.minimum(slot, pps - 1)]
+    last_live = jax.lax.cummax(jnp.where(live, item[:, None], 0), axis=0)
+    named = jnp.take_along_axis(page, last_live, axis=0)
+    return ends[-1].astype(i32), lane, block.astype(i32), last, named.reshape(-1)
 
 
 def _ragged_kernel_impl(q4, k_pages, v_pages, page_table, kv_lens, q_lens,
-                        group, scale, k_scales=None, v_scales=None,
+                        plan, group, scale, k_scales=None, v_scales=None,
                         layer=None):
-    """q4: [b, kv_heads, R, d] with R = chunk*group padded to the sublane
-    tile; returns [b, kv_heads, R, d] fp32. ``k_scales``/``v_scales``
-    ([num_pages, kv_heads, page_size] or None) flip the int8-KV kernel.
-    ``layer`` (an int32 scalar, or None): the pools and scale planes are
-    stacked ``[num_layers, ...]`` and the block index maps lead with it —
-    a fourth scalar-prefetch operand; the body never sees the stack."""
-    b, hkv, r8, d = q4.shape
+    """q4: [b, kv_heads, R, d] with R = ``plan.rows``; returns [b, kv_heads,
+    R, d] fp32. ``k_scales``/``v_scales`` ([num_pages, kv_heads, page_size]
+    or None) flip the int8-KV kernel. ``layer`` (an int32 scalar, or None):
+    the pools and scale planes are stacked ``[num_layers, ...]`` and the
+    block index maps lead with it, one more scalar-prefetch operand; the
+    body never sees the stack. The pools are passed ``plan.pages`` times,
+    each operand with its own block index map, so one grid step's pages
+    are fetched together."""
+    b, hkv, rows, d = q4.shape
     stacked = layer is not None
     num_pages, page_size = k_pages.shape[-4], k_pages.shape[-2]
-    pps = page_table.shape[1]
-    grid = (b, hkv, pps)
     quant = k_scales is not None
+    hs, pages = plan.heads, plan.pages
+    i32 = jnp.int32
 
-    def kv_page(bi, h, j, lens_ref, qlens_ref, pt_ref, *layer_ref):
-        # identical clamping to the decode kernel: pages past the last
-        # valid one re-fetch it (their compute is skipped)
-        ps = jnp.int32(page_size)
-        last = jnp.maximum(
-            jax.lax.div(lens_ref[bi] + ps - jnp.int32(1), ps) - jnp.int32(1),
-            jnp.int32(0))
-        page = pt_ref[bi, jnp.minimum(jnp.int32(j), last)]
+    def kv_page(k, g, i, lens_ref, qlens_ref, lane_ref, blk_ref, last_ref,
+                tbl_ref, *layer_ref):
         # the stacked forms' leading block index: this call's layer
         return (tuple(ref[0] for ref in layer_ref)
-                + (jnp.clip(page, 0, num_pages - 1),))
+                + (tbl_ref[i * i32(pages) + i32(k)],))
 
-    def kv_imap(bi, h, j, *refs):
-        return kv_page(bi, h, j, *refs) + (h, 0, 0)
+    def kv_imap(k, g, i, *refs):
+        return kv_page(k, g, i, *refs) + (g, 0, 0)
 
-    def scale_imap(bi, h, j, *refs):
-        return kv_page(bi, h, j, *refs) + (0, 0)
+    def scale_imap(k, g, i, *refs):
+        return kv_page(k, g, i, *refs) + (0, 0)
+
+    def q_imap(g, i, lens_ref, qlens_ref, lane_ref, *_):
+        return (lane_ref[i], g, 0, 0)
 
     lead = (None,) if stacked else ()
-    q_spec = pl.BlockSpec((None, None, r8, d), lambda bi, h, j, *_: (bi, h, 0, 0))
-    kv_spec = pl.BlockSpec(lead + (None, None, page_size, d), kv_imap)
-    # a page's scales for ALL local heads ([kv_heads, page_size] — the
-    # plane's full last two dims, which is what the (8, 128) block rule
-    # admits); the kernel slices its own head's row
-    sc_spec = pl.BlockSpec(lead + (None, hkv, page_size), scale_imap)
-    o_spec = pl.BlockSpec((None, None, r8, d), lambda bi, h, j, *_: (bi, h, 0, 0))
-    ml_spec = pl.BlockSpec((None, None, r8, 1), lambda bi, h, j, *_: (bi, h, 0, 0))
-
-    in_specs = [q_spec, kv_spec, kv_spec]
-    args = [q4, k_pages, v_pages]
+    q_spec = pl.BlockSpec((None, hs, rows, d), q_imap)
+    kv_specs = [pl.BlockSpec(lead + (None, hs, page_size, d),
+                             functools.partial(kv_imap, k))
+                for k in range(pages)]
+    in_specs = [q_spec] + kv_specs + kv_specs
+    args = [q4] + [k_pages] * pages + [v_pages] * pages
     if quant:
-        in_specs += [sc_spec, sc_spec]
-        args += [k_scales.astype(jnp.float32), v_scales.astype(jnp.float32)]
-    prefetch = [kv_lens.astype(jnp.int32), q_lens.astype(jnp.int32),
-                page_table.astype(jnp.int32)]
-    kern = functools.partial(_ragged_kernel, page_size=page_size,
-                             group=group, scale=scale, quant=quant)
+        # a page's scales for ALL local heads ([kv_heads, page_size]: the
+        # plane's full last two dims, which is what the (8, 128) block rule
+        # admits); the kernel slices each head's row
+        sc_specs = [pl.BlockSpec(lead + (None, hkv, page_size),
+                                 functools.partial(scale_imap, k))
+                    for k in range(pages)]
+        in_specs += sc_specs + sc_specs
+        args += ([k_scales.astype(jnp.float32)] * pages
+                 + [v_scales.astype(jnp.float32)] * pages)
+    kv_lens, q_lens = kv_lens.astype(i32), q_lens.astype(i32)
+    total, *items = _work_items(page_table.astype(i32), kv_lens, q_lens,
+                                plan, num_pages)
+    prefetch = [kv_lens, q_lens, *items]
     if stacked:
-        prefetch.append(jnp.asarray(layer, jnp.int32).reshape(1))
-        kern = functools.partial(_ragged_kernel_stacked, kern)
+        prefetch.append(jnp.asarray(layer, i32).reshape(1))
+    kern = functools.partial(_ragged_kernel, plan=plan, group=group,
+                             scale=scale, quant=quant, stacked=stacked)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
-        grid=grid,
+        grid=(plan.groups, total),
         in_specs=in_specs,
-        out_specs=[o_spec, ml_spec, ml_spec],
+        out_specs=pl.BlockSpec((None, hs, rows, d), q_imap),
+        scratch_shapes=[pltpu.VMEM((hs, rows, 1), jnp.float32),
+                        pltpu.VMEM((hs, rows, 1), jnp.float32),
+                        pltpu.VMEM((hs, rows, d), jnp.float32),
+                        pltpu.VMEM((hs, rows, 1), jnp.float32),
+                        pltpu.VMEM((pages // plan.pair, hs, rows,
+                                    plan.pair * page_size), jnp.float32)],
     )
-    out_shape = [
-        jax.ShapeDtypeStruct((b, hkv, r8, d), jnp.float32),
-        jax.ShapeDtypeStruct((b, hkv, r8, 1), jnp.float32),
-        jax.ShapeDtypeStruct((b, hkv, r8, 1), jnp.float32),
-    ]
     with _atc.x64_off():
-        out, _, _ = pl.pallas_call(
-            kern, grid_spec=grid_spec, out_shape=out_shape,
+        return pl.pallas_call(
+            kern, grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((b, hkv, rows, d), jnp.float32),
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+                dimension_semantics=("parallel", "arbitrary")),
             interpret=_interpret(), name=RAGGED_KERNEL_NAME,
         )(*prefetch, *args)
-    return out
 
 
 def ragged_paged_attention_reference(q, k_pages, v_pages, page_table,
@@ -516,14 +713,16 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, kv_lens, q_lens,
             q, k_pages, v_pages, page_table, kv_lens, q_lens, scale=scale,
             k_scales=k_scales, v_scales=v_scales, layer=layer)
     group = hq // hkv
+    plan = ragged_grid(b, page_table.shape[1], c, hq, hkv,
+                       k_pages.shape[-2], d, k_pages.dtype, q.dtype)
     # rows = chunk-major, group-minor: [b, c, hkv, g, d] -> [b, hkv, c*g, d]
     q4 = q.reshape(b, c, hkv, group, d).transpose(0, 2, 1, 3, 4)
     q4 = q4.reshape(b, hkv, c * group, d)
-    r8 = max(8, ((c * group + 7) // 8) * 8)
-    if r8 != c * group:
-        q4 = jnp.pad(q4, ((0, 0), (0, 0), (0, r8 - c * group), (0, 0)))
+    if plan.rows != c * group:
+        q4 = jnp.pad(q4, ((0, 0), (0, 0), (0, plan.rows - c * group),
+                          (0, 0)))
     out = _ragged_kernel_impl(q4, k_pages, v_pages, page_table, kv_lens,
-                              q_lens, group, float(scale),
+                              q_lens, plan, group, float(scale),
                               k_scales=k_scales, v_scales=v_scales,
                               layer=layer)
     out = out[:, :, :c * group, :].reshape(b, hkv, c, group, d)

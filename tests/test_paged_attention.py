@@ -499,3 +499,216 @@ def test_packed_write_plan_with_nothing_to_write(rng):
                                   jnp.float32))
     got = kvc.paged_write_packed(stack, toks, *dest, layer=1, plan=plan)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(stack))
+
+
+# -- PR 29: the ragged kernel's grid (all heads and several pages a step) ----
+
+
+def _assert_valid_rows_match(out, ref, q_lens, tol=2e-5):
+    """Rows past ``q_lens`` are unspecified for the kernel."""
+    for i, n in enumerate(np.asarray(q_lens)):
+        np.testing.assert_allclose(np.asarray(out, np.float32)[i, :n],
+                                   np.asarray(ref, np.float32)[i, :n],
+                                   rtol=tol, atol=tol)
+
+
+def _plan_of(q, kp, pt):
+    b, c, hq, d = q.shape
+    return pa.ragged_grid(b, pt.shape[1], c, hq, kp.shape[-3], kp.shape[-2],
+                          d, kp.dtype, q.dtype)
+
+
+# 8-key pages, 20 page slots: a grid step covers 4 pages = 32 keys
+@pytest.mark.parametrize("q_len", [1, 8], ids=["decode", "chunk"])
+@pytest.mark.parametrize("kv_len", [21, 31, 32, 33, 64, 65, 160],
+                         ids=lambda n: f"ctx{n}")
+def test_ragged_context_against_the_key_blocks_edge(rng, kv_len, q_len):
+    """A context that ends inside a grid step's block of pages, exactly on
+    its edge, one key past it, and at the table's end."""
+    b, c, hq, hkv, d, page_size, pps = 2, 8, 4, 4, 16, 8, 20
+    q, kp, vp, pt = _ragged_case(rng, b, c, hq, hkv, d, page_size, pps)
+    plan = _plan_of(q, kp, pt)
+    assert (plan.pages, plan.keys, plan.blocks) == (4, 32, 5)
+    kv_lens = jnp.asarray([kv_len, 21], jnp.int32)
+    q_lens = jnp.asarray([q_len, 3], jnp.int32)
+    ref = pa.ragged_paged_attention_reference(q, kp, vp, pt, kv_lens, q_lens)
+    out = pa.ragged_paged_attention(q, kp, vp, pt, kv_lens, q_lens,
+                                    use_kernel=True)
+    _assert_valid_rows_match(out, ref, q_lens)
+
+
+@pytest.mark.parametrize("pps", [9, 11, 17])
+def test_ragged_page_slots_not_a_multiple_of_the_pages_a_step(rng, pps):
+    """The table's last grid step names fewer real slots than operands: a
+    context that fills the table to its last key, and one that ends inside
+    the table's last page."""
+    b, c, hq, hkv, d, page_size = 2, 4, 4, 2, 16, 8
+    q, kp, vp, pt = _ragged_case(rng, b, c, hq, hkv, d, page_size, pps)
+    assert pps % _plan_of(q, kp, pt).pages
+    kv_lens = jnp.asarray([pps * page_size, pps * page_size - 5], jnp.int32)
+    q_lens = jnp.asarray([4, 1], jnp.int32)
+    ref = pa.ragged_paged_attention_reference(q, kp, vp, pt, kv_lens, q_lens)
+    out = pa.ragged_paged_attention(q, kp, vp, pt, kv_lens, q_lens,
+                                    use_kernel=True)
+    _assert_valid_rows_match(out, ref, q_lens)
+
+
+def test_ragged_dead_lanes_between_live_ones(rng):
+    """An idle lane (``q_len`` 0, a stale context) between two live ones and
+    an empty lane (``kv_len`` 0) behind them: their page slots name what the
+    grid step before named, the live lanes read their own pages, and the
+    dead lanes' rows come back zero."""
+    b, c, hq, hkv, d, page_size, pps = 4, 4, 4, 4, 16, 8, 12
+    q, kp, vp, pt = _ragged_case(rng, b, c, hq, hkv, d, page_size, pps)
+    kv_lens = jnp.asarray([70, 30, 91, 0], jnp.int32)
+    q_lens = jnp.asarray([4, 0, 1, 0], jnp.int32)
+    ref = pa.ragged_paged_attention_reference(q, kp, vp, pt, kv_lens, q_lens)
+    out = pa.ragged_paged_attention(q, kp, vp, pt, kv_lens, q_lens,
+                                    use_kernel=True)
+    _assert_valid_rows_match(out, ref, q_lens)
+    assert not np.asarray(out)[[1, 3]].any()
+
+
+def test_work_items_visit_live_key_blocks_and_name_the_page_before():
+    """Three lanes over 4-key pages, 2 pages a grid step, 4 page slots: lane
+    0 holds 3 pages (two key blocks), lane 1 is idle (one item, so that its
+    zero rows are written), lane 2 holds one page. Four items run of the six
+    the table allows; a dead slot names its operand's page of the item
+    before."""
+    pt = jnp.asarray([[5, 6, 7, 9], [1, 2, 3, 4], [8, 0, 0, 0]], jnp.int32)
+    kv_lens = jnp.asarray([9, 30, 3], jnp.int32)
+    q_lens = jnp.asarray([1, 0, 1], jnp.int32)
+    plan = pa.ragged_grid(3, 4, 1, 1, 1, 4, 16, jnp.float32, jnp.float32
+                          )._replace(pages=2, blocks=2)
+    total, lane, block, last, named = pa._work_items(pt, kv_lens, q_lens,
+                                                     plan, num_pages=10)
+    assert int(total) == 4 == plan.steps([9, 3])
+    np.testing.assert_array_equal(np.asarray(lane)[:4], [0, 0, 1, 2])
+    np.testing.assert_array_equal(np.asarray(block)[:4], [0, 1, 0, 0])
+    np.testing.assert_array_equal(np.asarray(last)[:4], [0, 1, 1, 1])
+    np.testing.assert_array_equal(np.asarray(named).reshape(-1, 2)[:4],
+                                  [[5, 6], [7, 6], [7, 6], [8, 6]])
+
+
+@pytest.mark.parametrize("budget,heads", [(12 << 20, 4), (70_000, 2),
+                                          (30_000, 1)])
+def test_ragged_fewer_heads_a_step_than_kv_heads(rng, monkeypatch, budget,
+                                                 heads):
+    """Where a page of every head does not fit the fast-memory budget the
+    grid takes the largest divisor of the KV heads that does: the head
+    groups become the grid's first axis."""
+    b, c, hq, hkv, d, page_size, pps = 2, 4, 8, 4, 16, 8, 10
+    q, kp, vp, pt = _ragged_case(rng, b, c, hq, hkv, d, page_size, pps)
+    monkeypatch.setattr(pa, "VMEM_BUDGET", budget)
+    plan = _plan_of(q, kp, pt)
+    assert (plan.heads, plan.groups, plan.blocks) == (
+        heads, hkv // heads, 3)
+    kv_lens = jnp.asarray([77, 9], jnp.int32)
+    q_lens = jnp.asarray([4, 1], jnp.int32)
+    ref = pa.ragged_paged_attention_reference(q, kp, vp, pt, kv_lens, q_lens)
+    out = pa.ragged_paged_attention(q, kp, vp, pt, kv_lens, q_lens,
+                                    use_kernel=True)
+    _assert_valid_rows_match(out, ref, q_lens)
+
+
+def test_ragged_grid_at_the_serving_cells_widths():
+    """The 590M cells: all 12 heads and 4 pages a grid step, at most 192
+    grid steps a call where the lane x head x page-slot grid had 9,216, and
+    only the key blocks that hold keys among them; float32 pages of the same
+    shape fit too; 32 heads take 16 a step."""
+    plan = pa.ragged_grid(24, 32, 64, 12, 12, 64, 128, jnp.bfloat16,
+                          jnp.bfloat16)
+    assert (plan.heads, plan.pages, plan.pair, plan.rows, plan.few_rows) == (
+        12, 4, 2, 64, 8)
+    assert (plan.groups, plan.lanes, plan.blocks, plan.keys) == (
+        1, 24, 8, 256)
+    assert [plan.live_steps(n) for n in (0, 1, 256, 257, 2048)] == [
+        0, 1, 1, 2, 8]
+    # 24 idle lanes: one grid step each; every lane at the table's end
+    assert plan.steps([]) == 24 and plan.steps([2048] * 24) == 192
+    assert plan.steps([300, 64, 1400]) == 21 + 2 + 1 + 6
+    int8 = pa.ragged_grid(24, 32, 64, 12, 12, 64, 128, jnp.int8,
+                          jnp.bfloat16)
+    assert (int8.heads, int8.pair, int8.blocks) == (12, 1, 8)
+    # 32 heads do not fit the fast-memory budget whole: two groups of 16
+    wide = pa.ragged_grid(24, 32, 64, 32, 32, 64, 128, jnp.bfloat16,
+                          jnp.bfloat16)
+    assert (wide.heads, wide.groups) == (16, 2)
+    assert wide.steps([2048] * 24) == 2 * 192 and wide.live_steps(257) == 4
+    # the draft chain's chunk of one: no few-rows form beside the 8-row block
+    assert pa.ragged_grid(24, 32, 1, 12, 12, 64, 128, jnp.bfloat16,
+                          jnp.bfloat16).few_rows == 0
+
+
+@pytest.mark.parametrize("kv", ["fp", "int8"])
+@pytest.mark.parametrize("group", [1, 2, 4])
+def test_ragged_groups_and_int8_pages_over_several_key_blocks(rng, group,
+                                                              kv):
+    """Grouped-query heads (rows are chunk x group) and int8 pages with
+    their scale planes, over contexts of one, two and three grid steps,
+    decode lanes beside chunks."""
+    b, c, hkv, d, page_size, pps = 4, 8, 2, 16, 8, 20
+    q, kp, vp, pt = _ragged_case(rng, b, c, hkv * group, hkv, d, page_size,
+                                 pps)
+    scales = {}
+    if kv == "int8":
+        kp, ks, vp, vs = _quant_pools(kp, vp)
+        scales = dict(k_scales=ks, v_scales=vs)
+    kv_lens = jnp.asarray([150, 64, 9, 131], jnp.int32)
+    q_lens = jnp.asarray([8, 1, 5, 1], jnp.int32)
+    ref = pa.ragged_paged_attention_reference(q, kp, vp, pt, kv_lens, q_lens,
+                                              **scales)
+    out = pa.ragged_paged_attention(q, kp, vp, pt, kv_lens, q_lens,
+                                    use_kernel=True, **scales)
+    _assert_valid_rows_match(out, ref, q_lens)
+
+
+@pytest.mark.parametrize("kv", ["fp", "int8"])
+def test_ragged_layer_form_over_several_key_blocks(rng, kv):
+    """``layer=`` on the stacked pools against the 4-D call on that layer's
+    pool, with contexts that span grid steps (bit-equal: one body)."""
+    b, c, hq, hkv, d, page_size, pps = 3, 4, 4, 2, 16, 8, 11
+    q, kp, vp, pt = _ragged_case(rng, b, c, hq, hkv, d, page_size, pps)
+    kv_lens = jnp.asarray([88, 65, 0], jnp.int32)
+    q_lens = jnp.asarray([1, 4, 0], jnp.int32)
+    pools = (kp, vp)
+    if kv == "int8":
+        kq, ks, vq, vs = _quant_pools(kp, vp)
+        pools = (kq, vq, ks, vs)
+    stacks = [_stack(rng, p) for p in pools] + [None] * (4 - len(pools))
+    layer = 1
+    one = [None if s is None else s[layer] for s in stacks]
+    want = pa.ragged_paged_attention(
+        q, one[0], one[1], pt, kv_lens, q_lens, use_kernel=True,
+        k_scales=one[2], v_scales=one[3])
+    got = jax.jit(lambda li: pa.ragged_paged_attention(
+        q, stacks[0], stacks[1], pt, kv_lens, q_lens, use_kernel=True,
+        k_scales=stacks[2], v_scales=stacks[3], layer=li))(jnp.int32(layer))
+    for i, n in enumerate(np.asarray(q_lens)):
+        np.testing.assert_array_equal(np.asarray(got)[i, :n],
+                                      np.asarray(want)[i, :n])
+
+
+@pytest.mark.parametrize("group", [1, 2, 4])
+def test_ragged_few_rows_form_equals_the_chunk_form(rng, monkeypatch, group):
+    """Lanes of one row, of exactly as many rows as the few-rows form
+    computes, and of one more, against the reference; and the lanes the
+    few-rows form took equal to the same lanes through the chunk form (the
+    form switched off by asking for more rows than the block has)."""
+    c, hkv, d, page_size, pps = 16, 2, 16, 8, 12
+    q, kp, vp, pt = _ragged_case(rng, 4, c, hkv * group, hkv, d, page_size,
+                                 pps)
+    plan = _plan_of(q, kp, pt)
+    limit = plan.few_rows // group          # the few-rows form's last q_len
+    assert plan.few_rows == 8 and plan.rows == c * group
+    q_lens = jnp.asarray([1, limit, limit + 1, c], jnp.int32)
+    kv_lens = jnp.asarray([70, 33, 90, 64], jnp.int32)
+    ref = pa.ragged_paged_attention_reference(q, kp, vp, pt, kv_lens, q_lens)
+    few = pa.ragged_paged_attention(q, kp, vp, pt, kv_lens, q_lens,
+                                    use_kernel=True)
+    _assert_valid_rows_match(few, ref, q_lens)
+    monkeypatch.setattr(pa, "FEW_ROWS", plan.rows)
+    assert _plan_of(q, kp, pt).few_rows == 0
+    whole = pa.ragged_paged_attention(q, kp, vp, pt, kv_lens, q_lens,
+                                      use_kernel=True)
+    _assert_valid_rows_match(few, whole, q_lens, tol=1e-6)

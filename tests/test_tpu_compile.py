@@ -49,7 +49,7 @@ def one_chip():
     compilation_cache.reset_cache()
 
 
-def _step_avals(dev, kv_quant):
+def _step_avals(dev, kv_quant, LANES=LANES, PAGES=PAGES, BUDGET=BUDGET):
     """The unified step's arguments as shapes on ``dev`` (signature in
     ``build_unified_step``'s docstring)."""
     def sds(*shape, dtype=jnp.bfloat16):
@@ -148,3 +148,46 @@ def test_compiled_latent_step_keeps_its_one_pool_in_one_buffer(one_chip,
     assert pool.shape == (2, 512, 1, 64, 640)
     assert pool_copies(hlo, pool.shape) == []
     assert compiled.memory_analysis().temp_size_in_bytes < 0.3e9
+
+
+@pytest.mark.parametrize("kv_quant", [False, True], ids=["fp", "int8"])
+def test_compiled_step_at_the_590m_cells_serving_widths(one_chip, monkeypatch,
+                                                        kv_quant):
+    """PR 29: the step as the 590M serving cells run it (24 lanes, 32 page
+    slots of 64 keys, chunk 64, budget 512, 768 pages; two layers). The
+    ragged kernel, with all heads and several pages a grid step, is a Mosaic
+    call named ``ragged_paged_attention``, exactly one in the layer scan's
+    body; passing the stack once per page a step reads provokes no
+    pool-shaped copy; and a call launches at most lanes x ceil(page slots /
+    pages a step) grid steps (every lane at the table's end; one a lane when
+    all are idle), by the function the kernel module exports."""
+    import paddle_tpu  # noqa: F401  framework config (matmul precision)
+    from paddle_tpu.models.gpt import GPTConfig, build_unified_step
+    from paddle_tpu.ops.pallas.paged_attention import (RAGGED_KERNEL_NAME,
+                                                       ragged_grid)
+
+    sys.path.insert(0, REPO)
+    try:
+        from chip_smoke import _is_mosaic_call, pool_copies
+    finally:
+        sys.path.remove(REPO)
+
+    lanes, slots = 24, SEQ // PAGE
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = GPTConfig(vocab_size=VOCAB, hidden_size=H, num_layers=LAYERS,
+                    num_heads=HEADS, max_seq_len=SEQ)
+    step = build_unified_step(cfg, PAGE, CHUNK, kv_quant=kv_quant)
+    avals, pool = _step_avals(one_chip, kv_quant, LANES=lanes, PAGES=768,
+                              BUDGET=512)
+    compiled = step.lower(*avals).compile()
+    hlo = compiled.as_text()
+    assert sum(_is_mosaic_call(line, RAGGED_KERNEL_NAME)
+               for line in hlo.splitlines()) == 1
+    assert pool_copies(hlo, pool.shape) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        pool.size * pool.dtype.itemsize)
+    grid = ragged_grid(lanes, slots, CHUNK, HEADS, HEADS, PAGE, HD,
+                       pool.dtype, jnp.bfloat16)
+    assert grid.heads == HEADS and grid.pages > 1
+    assert grid.steps([SEQ] * lanes) == lanes * -(-slots // grid.pages) == 192
+    assert grid.steps([]) == lanes
