@@ -285,4 +285,7 @@ def run(ctx):
         "counters": counters,
         "program_bytes": hbm,
         "info": info,
+        "compared": {"logits_rms_share_of_std_max": {
+            "value": max(check["rms_share_of_std"], default=None),
+            "limit": LOGITS_TOL_RMS}},
     }

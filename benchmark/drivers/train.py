@@ -163,4 +163,6 @@ def run(ctx):
         },
         "program_bytes": hbm,
         "info": checks,
+        "compared": {"first_loss_rel_err": {"value": rel,
+                                            "limit": LOSS_TOL_REL}},
     }

@@ -1,11 +1,11 @@
 """Prove several cells in one call to the chip tool.
 
-    python benchmark/prove.py --cells a,b --runs 6 [--seconds S] [--traced 1]
+    python benchmark/prove.py --cells a,b --runs 6 [--seconds S] [--traced N]
                               [--out chiprun_out/prove]
 
-For each cell: optionally one traced run, then two sets of ``--runs`` runs
-with the same seeds in both sets, each run a child process of its own
-(``run.py``), one after another. This parent never imports JAX, so the chip
+For each cell: ``--traced`` traced runs (on seeds of their own), then two sets
+of ``--runs`` runs with the same seeds in both sets, each run a child process
+of its own (``run.py``), one after another. This parent never imports JAX, so the chip
 is the child's alone; all children share the checkout's compile cache. Every
 run's output goes to ``<out>/<cell>/<set><i>.out`` and its last line into
 ``<out>/<cell>/summary.json`` with, per metric, what the driver's check
@@ -99,22 +99,23 @@ def main(argv=None):
     for cell in args.cells.split(","):
         out_dir = os.path.join(ROOT, args.out, cell)
         os.makedirs(out_dir, exist_ok=True)
-        plan = [("T", 0, 1)] if args.traced else []
-        plan += [(s, i, 0) for s in args.sets.split(",")
-                 for i in range(args.runs)]
+        # traced runs take the seeds the sets leave unused
+        plan = [("T", i, 1, SEEDS[(args.runs + i) % len(SEEDS)])
+                for i in range(args.traced)]
+        plan += [(s, i, 0, SEEDS[i % len(SEEDS)])
+                 for s in args.sets.split(",") for i in range(args.runs)]
         by_metric, lines = {}, {}
-        for set_name, i, trace in plan:
+        for set_name, i, trace, seed in plan:
             tag = f"{set_name}{i}"
-            rc, last, took = one_run(cell, SEEDS[i % len(SEEDS)], seconds,
-                                     trace, os.path.join(out_dir,
-                                                         tag + ".out"))
+            rc, last, took = one_run(cell, seed, seconds, trace,
+                                     os.path.join(out_dir, tag + ".out"))
             lines[tag] = last
             ok = rc == 0 and last is not None and last["correct"] \
                 and last["failed"] == 0
             failed += not ok
             shown = {k: v["value"] for k, v in
                      (last or {}).get("metrics", {}).items()}
-            print(f"{cell} {tag} seed={SEEDS[i % len(SEEDS)]} rc={rc} "
+            print(f"{cell} {tag} seed={seed} rc={rc} "
                   f"ok={ok} took={took:.1f}s {json.dumps(shown)}",
                   flush=True)
             if ok and not trace:
@@ -129,8 +130,9 @@ def main(argv=None):
             print(f"{cell} {name}: " + json.dumps(
                 {k: v for k, v in row.items() if k not in ("A", "B")}),
                 flush=True)
-        if lines.get("T0"):
-            print(f"{cell} traced: {json.dumps(lines['T0'])}", flush=True)
+        for tag, line in lines.items():
+            if tag.startswith("T") and line:
+                print(f"{cell} traced {tag}: {json.dumps(line)}", flush=True)
     return 1 if failed else 0
 
 

@@ -5,7 +5,9 @@
 Loads the cell named in ``BENCHMARK.json``, sets the system up (timed as set-up), measures for ``--seconds`` and prints one JSON object as the
 last line of its output: ``correct``, ``attempted``, ``failed``, ``metrics``
 (the cell's end-to-end metrics, or with ``--trace 1`` its per-layer metrics),
-``device`` and, traced, ``breakdown``. Every line it prints names the device.
+``device``, traced ``breakdown``, and last ``compared`` (each number held
+against the reference, with its limit; also the last lines on standard
+error). Every line it prints names the device.
 It needs a TPU and fails without one.
 
 This file knows no cell, configuration or metric by name. A cell's
@@ -241,6 +243,12 @@ def main(argv=None):
                              "operation; no result")
         out_device.update(busy_s=busy_s, window_s=window_s)
         result["breakdown"] = reduce_trace.breakdown(run["trace"])
+    # each number the driver held against the reference, beside its limit:
+    # last in the line and last on standard error
+    result["compared"] = run.get("compared", {})
+    for name, c in result["compared"].items():
+        print(f"compared {name} {c['value']} limit {c['limit']}",
+              file=sys.stderr, flush=True)
     print(json.dumps(result), flush=True)
     return 0
 
