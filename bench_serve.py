@@ -1,29 +1,24 @@
-"""Serving benchmark: unified ragged serving step vs the legacy two-jit path,
-plus the round-10 quantized A/B legs (fp vs int8-weights vs
-int8-weights + int8-KV) and the round-13 sync-vs-async engine A/B.
+"""Serving benchmark: the unified ragged serving step, with the round-10
+quantized A/B legs (fp vs int8-weights vs int8-weights + int8-KV) and the
+round-13 sync-vs-async engine A/B.
 
-The round-9 serving A/B, joining the bench trajectory next to bench.py's
-training lines. Drives the continuous-batching ServingPredictor through a
-two-wave workload (admit half the lanes, then admit the SAME prompts into
+Joins the bench trajectory next to bench.py's training lines. Drives the
+continuous-batching ServingPredictor through a two-wave workload (admit half the lanes, then admit the SAME prompts into
 the remaining lanes while the first wave decodes — the prefix-cache +
 chunked-prefill steady state) and emits ONE JSON line per leg (same
 schema/contract as bench.py — the flagship quantized line LAST):
 
 - ``value``/``unit``: decode tokens/sec/chip over the timed steady phase
-- ``vs_baseline``: unified-step speedup over the legacy round-7 two-jit
-  path (bucketed batch-1 prefill jit + fixed-shape decode jit)
+- ``vs_baseline``: the leg over its baseline leg (each quantized leg and
+  the mesh leg over the fp unified step; a pair over its partner)
 - ``p50_ms``/``p99_ms``: per-step latency percentiles (timed phase)
 - ``ttft_p50_ms``/``ttft_p99_ms``: time-to-first-token percentiles over
-  the SECOND wave (warm executables — steady-state serving TTFT; wave-2
-  admissions on the legacy path pay a full head-of-line prompt forward,
-  on the unified path chunked prefill interleaves with decode)
+  the SECOND wave (warm executables — steady-state serving TTFT; chunked
+  prefill interleaves with decode)
 - ``prefix_hit_rate``: fraction of admitted context tokens served from
-  the prefix cache (0.0 on the legacy leg — it has no prefix cache)
-- ``decode_retraces``: decode/unified-step traces during the timed phase
+  the prefix cache
+- ``decode_retraces``: unified-step traces during the timed phase
   + 1 — MUST stay 1 (compile once, replay fixed-shape)
-- ``prefill_retraces``: prefill executables compiled over the WHOLE leg —
-  the bucketed-prefill compile count the two-jit split hides (one per
-  prompt-length bucket); the unified step has no prefill jit: always 0
 - ``hbm_bytes_per_token``: analytic HBM bytes a steady-state decode token
   reads (weights amortized over the batch + that token's KV context,
   scale planes included) — the quantity the round-10 weight-only int8 /
@@ -86,16 +81,6 @@ schema/contract as bench.py — the flagship quantized line LAST):
   (``fault_free_fallback_count`` exactly 0; ``prefill_fallback_count``
   > 0 after the pass) — degradation, never an outage.
 
-- ``mega_off_draft_overhead_frac``/``mega_off_accepted_tokens_per_step``:
-  round 22 — the ``unified-mega-mixed`` pair runs the SAME int8w+int8kv
-  continuous-arrival MIXED prefill+decode churn (not the decode-only
-  shape of ``unified-mega``) speculating k=4 through the model draft
-  source, per-op vs fully megakernelized: the ragged mega step serves
-  every round and the k-step draft chain is ONE fused dispatch. The
-  gates: ``hbm_bytes_per_token`` + ``device_ms_per_step`` strictly below
-  the paired off-leg figures, ``draft_overhead_frac`` shrinks at equal
-  acceptance, ``mega_emissions_match`` holds 1.0.
-
 ``--smoke``: tiny CPU config — always runnable (CI leg, rc 0; gather
 reference attention keeps it fast, kernel parity is the test suite's
 job); its lines name the CPU. Without ``--smoke`` the script needs a TPU
@@ -135,20 +120,15 @@ def _hbm_bytes_per_token(sp, batch, avg_ctx):
     embeddings/LM head/LN leaves are replicated and read whole: exactly
     the per-chip bandwidth the round-11 tensor-parallel leg buys down.
 
-    Activation accounting (the quantity the megakernel buys down): the
-    per-op layer chain writes-then-reads every intermediate between its
-    kernels — LN1 out (h) -> qkv (3h) -> attention out (h) -> output-GEMM
+    Activation accounting: the layer chain writes-then-reads every
+    intermediate between its kernels — LN1 out (h) -> qkv (3h) -> attention out (h) -> output-GEMM
     out (h) -> residual (h) -> LN2 out (h) -> MLP hidden and gelu out
     (4h each) -> MLP out (h): 17h elements per token per layer crossing
     HBM twice. Under mp only the head/column-sharded intermediates (qkv
     3h, attention out h, MLP hidden + gelu out 8h = 12h) shrink per chip;
     the LN outs, the residual, and the post-psum wo/MLP outputs (5h) are
-    full-width on every chip. The megakernelized path (chip-local by
-    contract) pins all of that in VMEM; the only activations crossing HBM
-    between its two kernels are the attention side's (y2, s) pair — 2h
-    elements (the emitted new K/V rows exist in both paths and ride the
-    KV term). Kernel-internal scratch blocks are written once and never
-    re-read — not counted for either path.
+    full-width on every chip. Kernel-internal scratch blocks are written
+    once and never re-read — not counted.
 
     Round 23: the formula (and the per-layer activation constants the
     paragraphs above derive) moved to ``paddle_tpu.analysis.cost_model``
@@ -163,8 +143,7 @@ def _hbm_bytes_per_token(sp, batch, avg_ctx):
     mp = 1 if sp.mesh is None else int(sp.mesh.shape["mp"])
     cfg = sp.config
     return analytic_hbm_bytes_per_token(geometry(
-        sp.params, sp.cache, batch=batch, avg_ctx=avg_ctx,
-        mega=getattr(sp, "mega_decode", False), mp=mp,
+        sp.params, sp.cache, batch=batch, avg_ctx=avg_ctx, mp=mp,
         moe_experts=getattr(cfg, "moe_experts", 0),
         moe_top_k=getattr(cfg, "moe_top_k", 0)))
 
@@ -181,16 +160,16 @@ class _ChurnLeg:
     """
 
     def __init__(self, *, hidden, layers, heads, vocab, batch, prompt,
-                 gen_len, page_size, chunk, unified, use_kernel, on_tpu,
+                 gen_len, page_size, chunk, use_kernel, on_tpu,
                  dtype=None, weight_dtype=None, kv_cache_dtype=None,
                  mesh_chips=1, spec_decode_k=0, spec_workload=False,
                  async_engine=False, observability=False,
-                 mega_decode=False, slo=None, draft_source=None,
+                 slo=None, draft_source=None,
                  draft_layers=None, spec_report=False,
                  moe_experts=0, moe_top_k=2, moe_capacity_factor=1.25):
         # async_engine stays EXPLICIT here (default False = the sync
         # baseline leg) even though round 14 flipped the predictor's own
-        # default to async: the legacy/quant/spec/spmd legs are the
+        # default to async: the quant/spec/spmd legs are the
         # like-for-like round-over-round baselines, and the round-13
         # interleaved sync-vs-async pair is the one engine A/B
         import jax.numpy as jnp
@@ -229,11 +208,10 @@ class _ChurnLeg:
             mesh = make_serving_mesh(mesh_chips)
         self.sp = ServingPredictor(
             model, max_batch=batch, page_size=page_size,
-            max_seq_len=max_len, use_kernel=use_kernel, unified=unified,
-            chunk=chunk,
+            max_seq_len=max_len, use_kernel=use_kernel, chunk=chunk,
             dtype=jnp.bfloat16 if (on_tpu and dtype is None) else dtype,
             mesh=mesh, spec_decode_k=spec_decode_k,
-            async_engine=async_engine, mega_decode=mega_decode, slo=slo,
+            async_engine=async_engine, slo=slo,
             draft_source=draft_source, draft_layers=draft_layers)
         rng = np.random.RandomState(0)
         if spec_workload:
@@ -277,8 +255,8 @@ class _ChurnLeg:
 
     def warm(self):
         """Fill the lanes and run until every first-wave request has
-        produced (compiles every shape: admission buckets, the unified /
-        decode executables), then drain any async deferrals."""
+        produced (compiles the unified step), then drain any async
+        deferrals."""
         self.top_up()
         self.first_wave = list(self.reqs)
         while any(not r.output_ids for r in self.first_wave):
@@ -322,10 +300,9 @@ class _ChurnLeg:
         self.win_host.append(sp.host_ms_per_step)
         self.win_draft.append(sp.draft_overhead_frac)
         # wall ms per dispatched step with work IN FLIGHT — the
-        # host-observable per-step device-time proxy the round-16
-        # megakernel leg shrinks (the gap fraction subtracts the
-        # host-only bubbles, so this never credits scheduler stalls
-        # to the device)
+        # host-observable per-step device-time proxy (the gap fraction
+        # subtracts the host-only bubbles, so this never credits
+        # scheduler stalls to the device)
         self.win_dev.append(dw * (1.0 - sp.step_gap_frac) * 1e3
                             / max(1, sp.steps - w_steps))
 
@@ -355,7 +332,6 @@ class _ChurnLeg:
             ttft_p99_ms=round(_percentile(ttfts, 99), 2),
             prefix_hit_rate=round(sp.prefix_hit_rate, 3),
             decode_retraces=sp.decode_trace_count - self.decode_before + 1,
-            prefill_retraces=sp.prefill_trace_count,
             hbm_bytes_per_token=_hbm_bytes_per_token(
                 sp, self.batch, self.prompt + self.gen_len // 2),
             mesh_chips=self.mesh_chips,
@@ -364,8 +340,7 @@ class _ChurnLeg:
             # round 13: the host-bubble metrics the async engine buys down
             step_gap_frac=round(float(np.median(self.win_gaps)), 4),
             host_ms_per_step=round(float(np.median(self.win_host)), 3),
-            # round 16: per-step wall time with work in flight — the
-            # megakernel A/B's device-time metric
+            # round 16: per-step wall time with work in flight
             device_ms_per_step=round(float(np.median(self.win_dev)), 3),
             # round 15: the schema-checked telemetry snapshot — the
             # serving-stack registry (predictor + KV cache) flat export,
@@ -375,10 +350,9 @@ class _ChurnLeg:
         )
         # round 23: the jaxpr-derived static HBM model next to the
         # analytic one, plus their relative drift — the same pair the
-        # tpulint JX007 contracts gate. Unified steps only (the legacy
-        # per-op leg has no single traced step to derive from); the keys
-        # are simply absent there, and the smoke tests assert presence on
-        # the unified legs so a silent derivation failure still fails CI
+        # tpulint JX007 contracts gate. On a derivation failure the keys
+        # are simply absent, and the smoke tests assert their presence so
+        # a silent failure still fails CI
         try:
             from paddle_tpu.analysis.cost_model import \
                 static_hbm_for_predictor
@@ -1035,55 +1009,6 @@ def bench_serving_obs_ab(*, steps, windows, **leg_kw):
     return off_leg.report(), on_leg.report(), ratio
 
 
-def bench_serving_mega_ab(*, steps, windows, **leg_kw):
-    """The round-16 megakernel pair: the SAME int8w+int8kv churn with the
-    decode hot loop per-op (mega off — the round-15 baseline) vs routed
-    through the fused per-layer megakernels (mega on), windows
-    interleaved like the engine A/B so machine drift hits both legs
-    alike. Both legs run the production async engine. Returns
-    ``(off_out, on_out)``; the emitted mega-on line carries the paired
-    off-leg stats (tokens/s, hbm bytes, device ms) and the greedy
-    emission bit-identity gate — the megakernel must only move WHERE the
-    math runs, never what it emits."""
-    off_leg = _ChurnLeg(mega_decode=False, async_engine=True, **leg_kw)
-    on_leg = _ChurnLeg(mega_decode=True, async_engine=True, **leg_kw)
-    off_leg.warm()
-    on_leg.warm()
-    with _gc_frozen():
-        for _ in range(windows):
-            off_leg.window(steps)
-            on_leg.window(steps)
-    return off_leg.report(), on_leg.report()
-
-
-def bench_serving_mega_mixed_ab(*, steps, windows, draft_layers, **leg_kw):
-    """The round-22 mixed-churn megakernel pair: the SAME int8w+int8kv
-    CONTINUOUS-ARRIVAL churn — every finished request immediately
-    replaced, so the timed windows mix chunked prefill and decode the
-    way a serving fleet does (NOT the decode-only shape round 16
-    measured) — speculating k=4 through the truncated-layer model draft
-    source, per-op (mega off) vs fully megakernelized (mega on: the
-    ragged mega step AND the single-dispatch fused draft chain), windows
-    interleaved so machine drift hits both legs alike. Both legs run the
-    production async engine. Returns ``(off_out, on_out)``; the emitted
-    mega-on line carries the paired off-leg stats (tokens/s, hbm bytes,
-    device ms, draft overhead, acceptance) and the greedy emission
-    bit-identity gate — the megakernel must only move WHERE the math
-    runs, never what it emits."""
-    kw = dict(spec_decode_k=4, draft_source="model",
-              draft_layers=draft_layers, async_engine=True,
-              spec_report=True, **leg_kw)
-    off_leg = _ChurnLeg(mega_decode=False, **kw)
-    on_leg = _ChurnLeg(mega_decode=True, **kw)
-    off_leg.warm()
-    on_leg.warm()
-    with _gc_frozen():
-        for _ in range(windows):
-            off_leg.window(steps)
-            on_leg.window(steps)
-    return off_leg.report(), on_leg.report()
-
-
 class _MoEChurnLeg(_ChurnLeg):
     """The round-25 MoE churn: the standard continuous-arrival churn over
     a top-k routed predictor, plus the router-health metrics on the
@@ -1123,9 +1048,8 @@ def bench_serving_moe_ab(*, steps, windows, **leg_kw):
     dense unified predictor vs a 4-expert top-2 routed one (capacity
     factor 1.25 — the production setting, drops allowed and REPORTED),
     windows interleaved so machine drift hits both legs alike. Both
-    legs run the production async engine. Unlike the mega A/Bs there is
-    no emission-identity gate — the two legs run different math by
-    construction; the contract is the schema one: the MoE line must
+    legs run the production async engine. There is no emission-identity
+    gate — the two legs run different math by construction; the contract is the schema one: the MoE line must
     carry the router-health keys (imbalance, drop rate, active-param
     fraction), its static-vs-analytic HBM drift must stay inside the
     JX007 tolerance (the top_k/E expert-stack scaling on BOTH model
@@ -1234,15 +1158,14 @@ def main():
     # and the PAIRED sync stats ride the async line (sync_tokens_per_s /
     # sync_step_gap_frac) so its strict gates never compare across
     # workloads. The emitted unified-step leg keeps the SHARED shape and
-    # stays the like-for-like baseline for the legacy/spmd/quant ratios.
+    # stays the like-for-like baseline for the spmd/quant ratios.
     ab_kw = dict(steps=max(12, shape["steps"]), windows=7)
     ab_shape = dict({k: v for k, v in shape.items() if k != "steps"},
                     gen_len=max(16, shape["gen_len"]),
                     batch=max(4, shape["batch"]),
                     prompt=max(16, shape["prompt"]))
     legs = [
-        ("legacy-two-jit", dict(unified=False)),
-        ("unified-step", dict(unified=True)),
+        ("unified-step", {}),
         # round-13 A/B: the SAME churn through the sync engine and the
         # async double-buffered engine — dispatch-ahead + deferred
         # reconcile vs one blocking sync per step; measured as one
@@ -1251,20 +1174,19 @@ def main():
         # round-15 A/B: the SAME churn with host tracing off vs on —
         # the observability overhead contract, measured interleaved
         ("unified-obs", None),
-        ("unified-spmd", dict(unified=True, mesh_chips=n_mp)),
+        ("unified-spmd", dict(mesh_chips=n_mp)),
         # round-12 speculation A/B: the SAME repetitive-prompt churn with
         # drafting off (the 1.0-tokens/lane-step anchor) vs k=4
-        ("unified-spec-base", dict(unified=True, spec_workload=True)),
-        ("unified-spec-k4", dict(unified=True, spec_workload=True,
-                                 spec_decode_k=4)),
+        ("unified-spec-base", dict(spec_workload=True)),
+        ("unified-spec-k4", dict(spec_workload=True, spec_decode_k=4)),
         # round-19 A/B: the SAME seeded-random (NON-repetitive) churn
         # speculating k=4 through the n-gram proposer vs the truncated-
         # layer model draft source, both on the async engine (spec steps
         # dispatch behind-by-one) — measured interleaved, cross-proposer
         # greedy emissions bit-identical
         ("unified-spec-model", None),
-        ("unified-int8w", dict(unified=True, weight_dtype="int8")),
-        ("unified-int8w-int8kv", dict(unified=True, weight_dtype="int8",
+        ("unified-int8w", dict(weight_dtype="int8")),
+        ("unified-int8w-int8kv", dict(weight_dtype="int8",
                                       kv_cache_dtype="int8")),
         # round-17 resilience A/B: the SAME churn shape flooded past
         # capacity (bounded queue + expired-deadline stragglers, SLO
@@ -1297,18 +1219,6 @@ def main():
         # rate, active-param fraction) on the line, the paired dense
         # tokens/s riding it as the efficiency anchor
         ("moe-churn", None),
-        # round-16 A/B: the SAME int8w+int8kv churn with the decode hot
-        # loop per-op vs megakernelized (fused per-layer Pallas kernels,
-        # activations pinned in VMEM) — measured interleaved, greedy
-        # emissions bit-identical; the new flagship line
-        ("unified-mega", None),
-        # round-22 A/B: the SAME int8w+int8kv MIXED prefill+decode churn
-        # (continuous arrivals — the realistic traffic shape) speculating
-        # k=4 through the model draft source, per-op vs fully
-        # megakernelized: the ragged mega step serves EVERY round (no
-        # prefill fallback) and the k-step draft chain is ONE fused
-        # dispatch — measured interleaved, greedy emissions bit-identical
-        ("unified-mega-mixed", None),
     ]
     if selected is not None:
         keep = set(selected)
@@ -1318,7 +1228,7 @@ def main():
     def _streams_match(a, b):
         # per-arrival greedy emission bit-identity across an interleaved
         # pair: FULL equality for requests finished in both legs, prefix
-        # equality for in-progress tails (shared by the async + mega A/Bs)
+        # equality for in-progress tails (shared by the interleaved A/Bs)
         def _same(i):
             (af, at), (bf, bt) = a[i], b[i]
             if af and bf:
@@ -1350,7 +1260,7 @@ def main():
         try:
             if name == "unified-async":
                 sync_out, async_out = bench_serving_ab(
-                    unified=True, on_tpu=on_tpu, use_kernel=use_kernel,
+                    on_tpu=on_tpu, use_kernel=use_kernel,
                     **ab_shape, **ab_kw)
                 out = dict(metric=ab_metric_for(name), **async_out)
                 # the paired sync stats ride the async line — its strict
@@ -1364,57 +1274,11 @@ def main():
                 out["async_emissions_match"] = _streams_match(
                     async_out["_streams"], sync_out["_streams"])
                 results[name] = out
-            elif name == "unified-mega":
-                off_out, on_out = bench_serving_mega_ab(
-                    unified=True, on_tpu=on_tpu, use_kernel=use_kernel,
-                    weight_dtype="int8", kv_cache_dtype="int8",
-                    **ab_shape, **ab_kw)
-                out = dict(metric=ab_metric_for(name), **on_out)
-                # the paired mega-off stats ride the mega-on line: its
-                # strict gates (hbm bytes strictly lower, emissions
-                # bit-identical) compare within the interleaved pair
-                out["mega_off_tokens_per_s"] = off_out["value"]
-                out["mega_off_hbm_bytes_per_token"] = (
-                    off_out["hbm_bytes_per_token"])
-                out["mega_off_device_ms_per_step"] = (
-                    off_out["device_ms_per_step"])
-                out["vs_baseline"] = (
-                    round(out["value"] / off_out["value"], 3)
-                    if off_out["value"] else 0.0)
-                out["mega_emissions_match"] = _streams_match(
-                    on_out["_streams"], off_out["_streams"])
-                results[name] = out
-            elif name == "unified-mega-mixed":
-                off_out, on_out = bench_serving_mega_mixed_ab(
-                    unified=True, on_tpu=on_tpu, use_kernel=use_kernel,
-                    weight_dtype="int8", kv_cache_dtype="int8",
-                    draft_layers=max(1, ab_shape["layers"] // 4),
-                    **ab_shape, **ab_kw)
-                out = dict(metric=ab_metric_for(name), **on_out)
-                # the paired per-op stats ride the mega-on line: the
-                # strict gates (hbm bytes + device ms strictly lower,
-                # draft overhead shrinks at equal acceptance, emissions
-                # bit-identical) compare within the interleaved pair
-                out["mega_off_tokens_per_s"] = off_out["value"]
-                out["mega_off_hbm_bytes_per_token"] = (
-                    off_out["hbm_bytes_per_token"])
-                out["mega_off_device_ms_per_step"] = (
-                    off_out["device_ms_per_step"])
-                out["mega_off_draft_overhead_frac"] = (
-                    off_out["draft_overhead_frac"])
-                out["mega_off_accepted_tokens_per_step"] = (
-                    off_out["accepted_tokens_per_step"])
-                out["vs_baseline"] = (
-                    round(out["value"] / off_out["value"], 3)
-                    if off_out["value"] else 0.0)
-                out["mega_emissions_match"] = _streams_match(
-                    on_out["_streams"], off_out["_streams"])
-                results[name] = out
             elif name == "unified-spec-model":
                 # the truncated self-draft keeps the first quarter of the
                 # stack (>= 1): 12 layers -> 3, the 2-layer smoke -> 1
                 ngram_out, model_out = bench_serving_spec_model_ab(
-                    unified=True, on_tpu=on_tpu, use_kernel=use_kernel,
+                    on_tpu=on_tpu, use_kernel=use_kernel,
                     draft_layers=max(1, ab_shape["layers"] // 4),
                     **ab_shape, **ab_kw)
                 out = dict(metric=ab_metric_for(name), **model_out)
@@ -1433,7 +1297,7 @@ def main():
                 results[name] = out
             elif name == "unified-overload":
                 over_out, nom_out = bench_serving_overload(
-                    unified=True, on_tpu=on_tpu, use_kernel=use_kernel,
+                    on_tpu=on_tpu, use_kernel=use_kernel,
                     **ab_shape, **ab_kw)
                 out = dict(metric=ab_metric_for(name), **over_out)
                 # the nominal partner's rates ride the overload line: the
@@ -1472,7 +1336,7 @@ def main():
                 results[name] = dict(metric=metric_for(name), **out)
             elif name == "moe-churn":
                 dense_out, moe_out = bench_serving_moe_ab(
-                    unified=True, on_tpu=on_tpu, use_kernel=use_kernel,
+                    on_tpu=on_tpu, use_kernel=use_kernel,
                     **ab_shape, **ab_kw)
                 out = dict(metric=ab_metric_for(name), **moe_out)
                 # the paired dense stats ride the MoE line: vs_baseline
@@ -1487,7 +1351,7 @@ def main():
                 results[name] = out
             elif name == "unified-obs":
                 off_out, on_out, ratio = bench_serving_obs_ab(
-                    unified=True, on_tpu=on_tpu, use_kernel=use_kernel,
+                    on_tpu=on_tpu, use_kernel=use_kernel,
                     **ab_shape, **ab_kw)
                 out = dict(metric=ab_metric_for(name), **on_out)
                 # the untraced partner rides the traced line; vs_baseline
@@ -1513,8 +1377,7 @@ def main():
             failed.append(name)
 
     # line order = leg order, flagship (quantized unified) LAST.
-    # vs_baseline: unified-step over the legacy two-jit path (the round-9
-    # contract), each quantized leg over the FP UNIFIED step (> 1 = the
+    # vs_baseline: each quantized leg over the FP UNIFIED step (> 1 = the
     # HBM bytes bought back turned into tokens/s)
     from paddle_tpu.analysis.bench_schema import checked_line
 
@@ -1545,8 +1408,7 @@ def main():
     # baselines the spec-off run of its OWN (repetitive) workload, so its
     # vs_baseline is the effective speculation speedup; the async leg
     # baselines the sync engine on the SAME interleaved churn
-    _emit("legacy-two-jit", None)
-    _emit("unified-step", "legacy-two-jit")
+    _emit("unified-step", None)
     _emit("unified-async", None)
     _emit("unified-obs", None)
     _emit("unified-spmd", "unified-step")
@@ -1580,13 +1442,6 @@ def main():
     # router-health keys are the headline — drop rate and imbalance at
     # capacity 1.25, throughput tracking active not total params)
     _emit("moe-churn", None)
-    # round-16 megakernelized int8w+int8kv decode A/B (self-baselined on
-    # its interleaved mega-off partner)
-    _emit("unified-mega", None)
-    # round-22 flagship LAST: the MIXED-churn megakernel A/B — ragged
-    # mega step + single-dispatch draft chain vs the per-op partner on
-    # continuous-arrival prefill+decode traffic (self-baselined)
-    _emit("unified-mega-mixed", None)
     if failed:
         raise SystemExit(f"bench_serve.py: legs failed: {', '.join(failed)}")
 

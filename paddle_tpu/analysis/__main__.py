@@ -9,7 +9,7 @@ Usage::
     python -m paddle_tpu.analysis --json           # machine-readable report
     python -m paddle_tpu.analysis --write-baseline # accept current findings
     python -m paddle_tpu.analysis --list-targets   # flagship target names
-    python -m paddle_tpu.analysis --target serving-mega-mixed
+    python -m paddle_tpu.analysis --target serving-unified
 """
 from __future__ import annotations
 

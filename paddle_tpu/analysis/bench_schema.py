@@ -35,7 +35,7 @@ _REQUIRED = {
 _OPTIONAL_NUMERIC = ("vs_baseline", "p50_ms", "p99_ms", "anchor_tflops",
                      "anchor_frac_peak", "ttft_p50_ms", "ttft_p99_ms",
                      "prefix_hit_rate", "decode_retraces",
-                     "prefill_retraces", "hbm_bytes_per_token",
+                     "hbm_bytes_per_token",
                      # round 23: the jaxpr-derived static HBM model and
                      # its relative drift against the analytic one — the
                      # pair the tpulint JX007 cost contracts gate
@@ -67,15 +67,9 @@ _OPTIONAL_NUMERIC = ("vs_baseline", "p50_ms", "p99_ms", "anchor_tflops",
                      # partner riding the traced leg's line, and the
                      # host trace events the traced windows recorded
                      "obs_off_tokens_per_s", "trace_events",
-                     # round 16: the megakernel A/B — wall ms per
-                     # dispatched step with work in flight (the host-
-                     # observable device-time proxy), the mega-off
-                     # interleaved partner's stats riding the mega-on
-                     # line, and the greedy emission bit-identity gate
-                     # of the pair
-                     "device_ms_per_step", "mega_off_tokens_per_s",
-                     "mega_off_hbm_bytes_per_token",
-                     "mega_off_device_ms_per_step", "mega_emissions_match",
+                     # round 16: wall ms per dispatched step with work
+                     # in flight (the host-observable device-time proxy)
+                     "device_ms_per_step",
                      # round 17: the overload/resilience leg — admissions
                      # shed by the SLO policy and deadline misses as
                      # fractions of attempted arrivals, terminal FAILED
@@ -130,14 +124,6 @@ _OPTIONAL_NUMERIC = ("vs_baseline", "p50_ms", "p99_ms", "anchor_tflops",
                      "tier_spill_drops", "tier_corrupt_detected",
                      "fault_free_corrupt_detected", "notier_tokens_per_s",
                      "notier_prefix_hit_rate", "notier_ttft_p99_ms",
-                     # round 22: the mixed-churn megakernel A/B (ragged
-                     # mega + the single-dispatch draft chain) — the
-                     # per-op partner's draft-overhead and acceptance
-                     # stats riding the mega-on line, so the
-                     # draft-overhead-shrinks-at-equal-acceptance gate
-                     # compares within the interleaved pair
-                     "mega_off_draft_overhead_frac",
-                     "mega_off_accepted_tokens_per_step",
                      # round 25: the dense-vs-MoE interleaved A/B — the
                      # router's per-window load dispersion (max expert
                      # load / mean, 1.0 = perfectly balanced), the
@@ -154,10 +140,10 @@ _OPTIONAL_STRING = ("mesh_shape", "comm_quant")
 #: drop out of round-over-round deltas exactly like the malformed lines
 #: this module exists to stop.
 KNOWN_LEGS = frozenset((
-    "legacy-two-jit", "unified-step", "unified-async", "unified-obs",
+    "unified-step", "unified-async", "unified-obs",
     "unified-spmd", "unified-spec-base", "unified-spec-k4",
     "unified-spec-model", "unified-int8w", "unified-int8w-int8kv",
-    "unified-mega", "unified-mega-mixed", "unified-overload",
+    "unified-overload",
     "fleet-churn", "fleet-disagg", "fleet-tiered", "moe-churn",
 ))
 

@@ -4,12 +4,11 @@ One frozen :class:`CostContract` per certified flagship sub-target (keys
 match the ``Finding.target`` strings the analyze functions emit). The
 contract is DATA: the declared serving geometry the static models evaluate
 at (``avg_ctx``/``batch``/``mp``), the JX007 drift tolerance against the
-bench analytic model, the JX008 per-geometry VMEM budget and
-mega-residency flag, the JX009 collective inventory, and the dpquant HLO
-wire expectations. The checking logic lives in :mod:`.cost_model`,
-:mod:`.vmem` and :mod:`.collectives_audit`; changing a claim means editing
-THIS table in the same commit that changes the program — anything else
-exits 2.
+bench analytic model, the JX008 per-geometry VMEM budget, the JX009
+collective inventory, and the dpquant HLO wire expectations. The checking
+logic lives in :mod:`.cost_model`, :mod:`.vmem` and
+:mod:`.collectives_audit`; changing a claim means editing THIS table in the
+same commit that changes the program — anything else exits 2.
 
 The VMEM budgets are per the ANALYSIS geometry (the tiny 2-layer h=32
 configs the targets trace): snug numbers a structural regression (a block
@@ -35,10 +34,8 @@ class CostContract:
     avg_ctx: float = 8.0          # declared steady-state context tokens
     batch: int = 2                # lanes amortizing the weight sweep
     mp: int = 1                   # model-parallel ways
-    mega: bool = False            # megakernel activation regime
     hbm_tolerance: float | None = None      # JX007 relative drift gate
     vmem_budget_bytes: int | None = None    # JX008 per-kernel budget
-    mega_vmem_resident: bool = False        # JX008 4h-never-in-HBM check
     collectives: dict | None = None         # JX009 exact jaxpr inventory
     hlo_require_s8: bool = False            # JX009 HLO: s8 on the wire
     hlo_fp_allreduce_max_elems: int = 1024  # JX009 HLO: fp allowance
@@ -46,18 +43,15 @@ class CostContract:
     moe_top_k: int = 0                      # JX007 experts read per token
 
 
-def _serving(mega: bool = False, mp: int = 1, *, vmem: bool = True,
+def _serving(mp: int = 1, *, vmem: bool = True,
              collectives: dict | None = None) -> CostContract:
     return CostContract(
-        mega=mega, mp=mp, hbm_tolerance=0.02,
+        mp=mp, hbm_tolerance=0.02,
         vmem_budget_bytes=_SERVING_VMEM_BUDGET if vmem else None,
-        mega_vmem_resident=mega,
         collectives={} if collectives is None else collectives)
 
 
 CONTRACTS: dict[str, CostContract] = {
-    # the round-7 per-op decode jit: the oldest hbm claim in the bench
-    "serving-decode": CostContract(hbm_tolerance=0.02, collectives={}),
     # round-9/10 unified steps (fp and int8w+int8kv)
     "serving-unified-step": _serving(),
     "serving-quant-unified-step": _serving(),
@@ -69,20 +63,6 @@ CONTRACTS: dict[str, CostContract] = {
     "serving-spec-step": _serving(vmem=False),
     "serving-spec-quant-step": _serving(vmem=False),
     "serving-async-step": _serving(vmem=False),
-    # round-16/22 megakernel steps: fused activation accounting + the
-    # 4h-never-in-HBM residency contract + kernel VMEM budgets
-    "serving-mega-step": _serving(mega=True),
-    "serving-mega-quant-step": _serving(mega=True),
-    "serving-mega-mixed-step": _serving(mega=True),
-    "serving-mega-mixed-quant-step": _serving(mega=True),
-    # the single-dispatch draft chains: VMEM + residency + zero
-    # collectives (no hbm model — the bench has no draft-chain leg)
-    "serving-mega-draft-chain": CostContract(
-        mega=True, vmem_budget_bytes=_SERVING_VMEM_BUDGET,
-        mega_vmem_resident=True, collectives={}),
-    "serving-mega-draft-chain-quant": CostContract(
-        mega=True, vmem_budget_bytes=_SERVING_VMEM_BUDGET,
-        mega_vmem_resident=True, collectives={}),
     # round-21 tiered restore landings: pure scatter, collective-free
     "serving-tiered-restore-fp": CostContract(collectives={}),
     "serving-tiered-restore-int8": CostContract(collectives={}),
@@ -92,9 +72,9 @@ CONTRACTS: dict[str, CostContract] = {
     "train-dpquant-step": CostContract(
         collectives=None, hlo_require_s8=True,
         hlo_fp_allreduce_max_elems=1024),
-    # round-25 MoE unified step (per-op path; mega rejects MoE): the hbm
-    # model charges a token only its top-k experts' weights — matching
-    # the analysis config (moe_experts=4, moe_top_k=2)
+    # round-25 MoE unified step: the hbm model charges a token only its
+    # top-k experts' weights — matching the analysis config
+    # (moe_experts=4, moe_top_k=2)
     "serving-moe-step": CostContract(
         hbm_tolerance=0.02, collectives={},
         moe_experts=4, moe_top_k=2),
@@ -132,18 +112,17 @@ def cost_certify(target: str, closed, *, params=None,
 
         geom = cost_model.geometry(
             params, cache, batch=contract.batch, avg_ctx=contract.avg_ctx,
-            mega=contract.mega, mp=contract.mp,
+            mp=contract.mp,
             moe_experts=contract.moe_experts,
             moe_top_k=contract.moe_top_k)
         findings += cost_model.check_hbm_model(
             closed, len(jax.tree.leaves(params)), _pools(cache), geom,
             contract.hbm_tolerance, target)
-    if (contract.vmem_budget_bytes is not None
-            or contract.mega_vmem_resident):
+    if contract.vmem_budget_bytes is not None:
         from . import vmem
 
         findings += vmem.check_vmem(closed, contract.vmem_budget_bytes,
-                                    contract.mega_vmem_resident, target)
+                                    target)
     if contract.collectives is not None:
         from . import collectives_audit
 

@@ -11,9 +11,7 @@ that claim into two independently-derived sides and gates their agreement:
 - the **static side** (:func:`static_hbm_report`): the same quantity derived
   from the TRACED JAXPR of the serving step — weight bytes measured off the
   program's parameter invars, layer count and hidden width read from the
-  layer scan, the mega-vs-per-op activation regime discriminated by the
-  scan's carry layout (a blocked ``[b, chunk, h]`` carry IS the megakernel
-  path), and the KV term from the pool invar geometry.
+  layer scan, and the KV term from the pool invar geometry.
 
 **JX007** fires when the two sides drift beyond the per-target tolerance
 declared in :mod:`.contracts` — i.e. when someone changes the traced program
@@ -47,23 +45,13 @@ PER_OP_SHARDED_ACT_H = 12
 #: per-op layer chain, full-width on every chip: LN1/LN2 outs, the
 #: residual, and the post-psum wo/MLP outputs
 PER_OP_FULL_ACT_H = 5
-#: megakernel path at mp=1 (epilogues fused): only the (y2, s) pair
-#: crosses HBM between the attention-side and MLP-side kernels
-MEGA_FUSED_ACT_H = 2
-#: megakernel path under mp (fuse_epilogue=False): the pre-psum partials,
-#: the completed s, y2, and the MLP-side partial + completed out — the
-#: psums replicate them full-width
-MEGA_UNFUSED_ACT_H = 5
 #: every inter-kernel intermediate crosses HBM twice (write + read)
 HBM_ROUNDTRIPS = 2
 
 
-def activation_elems_per_layer(h: int, mp: int = 1,
-                               mega: bool = False) -> float:
+def activation_elems_per_layer(h: int, mp: int = 1) -> float:
     """Per-layer per-token activation ELEMENTS crossing HBM between the
     step's kernels (one direction; multiply by :data:`HBM_ROUNDTRIPS`)."""
-    if mega:
-        return (MEGA_FUSED_ACT_H if mp == 1 else MEGA_UNFUSED_ACT_H) * h
     return PER_OP_SHARDED_ACT_H * h / mp + PER_OP_FULL_ACT_H * h
 
 
@@ -92,7 +80,6 @@ class ServingGeometry:
     mp: int
     batch: int
     avg_ctx: float
-    mega: bool
     # round-25 MoE: the expert stacks' bytes ride separately — a decode
     # token streams only its top-k experts' weights, not all E
     moe_experts: int = 0
@@ -119,7 +106,7 @@ def analytic_hbm_bytes_per_token(g: ServingGeometry) -> int:
         kv += 2 * g.num_layers * g.avg_ctx * g.kv_heads * 4 / g.mp
     h = g.kv_heads * g.head_dim
     act = (HBM_ROUNDTRIPS * g.num_layers
-           * activation_elems_per_layer(h, g.mp, g.mega) * g.act_itemsize)
+           * activation_elems_per_layer(h, g.mp) * g.act_itemsize)
     return int(wb + kv + act)
 
 
@@ -128,9 +115,8 @@ def analytic_hbm_bytes_per_token(g: ServingGeometry) -> int:
 MOE_EXPERT_STACK_KEYS = ("moe_w1", "moe_b1", "moe_w2", "moe_b2")
 
 
-def geometry(params, cache, *, batch: int, avg_ctx: float, mega: bool,
-             mp: int = 1, moe_experts: int = 0,
-             moe_top_k: int = 0) -> ServingGeometry:
+def geometry(params, cache, *, batch: int, avg_ctx: float, mp: int = 1,
+             moe_experts: int = 0, moe_top_k: int = 0) -> ServingGeometry:
     """Build the analytic geometry from a live (params, KVCacheManager)
     pair — the adapter both ``bench_serve.py`` and the cert targets use.
     ``moe_experts``/``moe_top_k`` (round 25) split the expert stacks out
@@ -157,7 +143,7 @@ def geometry(params, cache, *, batch: int, avg_ctx: float, mega: bool,
         kv_itemsize=jnp.dtype(cache.k_pages.dtype).itemsize,
         kv_quantized=bool(cache.quantize_kv),
         act_itemsize=jnp.dtype(params["tok_emb"].dtype).itemsize,
-        mp=mp, batch=batch, avg_ctx=avg_ctx, mega=mega,
+        mp=mp, batch=batch, avg_ctx=avg_ctx,
         moe_experts=moe_experts, moe_top_k=moe_top_k,
         expert_weight_bytes=expert_b)
 
@@ -243,11 +229,10 @@ def static_hbm_report(closed, n_param_leaves: int, pool_avals, *,
         raise ValueError("no layer scan found in the traced program")
     num_layers = int(scan.params["length"])
 
-    # carry layout discriminates the activation regime: the megakernel path
-    # scans a blocked [b, chunk, h] lane carry, the per-op chain a packed
-    # [t, h] stream. h is the carry's minor dim, act dtype its dtype. The
-    # unified step carries its stacked pools and scale planes too (rank 5
-    # and 4, each chip's head shard under a mesh): told apart by rank.
+    # the scan carries the packed [t, h] stream: h is the carry's minor dim,
+    # act dtype its dtype. The unified step carries its stacked pools and
+    # scale planes too (rank 5 and 4, each chip's head shard under a mesh):
+    # told apart by rank.
     n_consts = int(scan.params.get("num_consts", 0))
     n_carry = int(scan.params.get("num_carry", 0))
     pool_ranks = {len(a.shape) for a in pool_avals if a is not None}
@@ -258,7 +243,6 @@ def static_hbm_report(closed, n_param_leaves: int, pool_avals, *,
     if not carries:
         raise ValueError("layer scan has no activation carry")
     carry = max(carries, key=_aval_bytes)
-    mega = len(carry.shape) == 3
     hidden = int(carry.shape[-1])
     act_itemsize = carry.dtype.itemsize
 
@@ -299,7 +283,7 @@ def static_hbm_report(closed, n_param_leaves: int, pool_avals, *,
             kv += num_layers * avg_ctx * heads * a.dtype.itemsize / mp
 
     act = (HBM_ROUNDTRIPS * num_layers
-           * activation_elems_per_layer(hidden, mp, mega) * act_itemsize)
+           * activation_elems_per_layer(hidden, mp) * act_itemsize)
 
     return {
         "hbm_bytes_per_token": int(wb + kv + act),
@@ -308,7 +292,6 @@ def static_hbm_report(closed, n_param_leaves: int, pool_avals, *,
         "act_bytes_per_token": int(act),
         "num_layers": num_layers,
         "hidden": hidden,
-        "mega": mega,
         "flow_bytes_upper_bound": program_flow_bytes(jaxpr),
     }
 
@@ -332,12 +315,6 @@ def check_hbm_model(closed, n_param_leaves: int, pool_avals, geom,
             rule=JX007, target=target, detail="layer-scan-length",
             message=f"layer scan runs {static['num_layers']} trips but the "
                     f"geometry declares {geom.num_layers} layers"))
-    if static["mega"] != geom.mega:
-        findings.append(Finding(
-            rule=JX007, target=target, detail="activation-regime",
-            message=f"carry layout says mega={static['mega']} but the "
-                    f"geometry declares mega={geom.mega} — the activation "
-                    "accounting would use the wrong per-layer constant"))
     analytic = analytic_hbm_bytes_per_token(geom)
     drift = abs(static["hbm_bytes_per_token"] - analytic) / max(analytic, 1)
     if not math.isfinite(drift) or drift > tolerance:
@@ -355,23 +332,19 @@ def check_hbm_model(closed, n_param_leaves: int, pool_avals, geom,
 def static_hbm_for_predictor(sp, batch: int, avg_ctx: float):
     """The bench-side static entry: trace the predictor's OWN unified step
     (same builder, the predictor's live params/pools) and derive the static
-    number at the bench geometry. Returns None for non-unified predictors
-    (the legacy two-jit path has no single step program to certify)."""
+    number at the bench geometry."""
     import jax.numpy as jnp
 
     from ..models.gpt import build_unified_step
     from .jaxpr_checks import trace_callable
 
-    if not getattr(sp, "unified", False):
-        return None
     cfg, cache, chunk = sp.config, sp.cache, sp.chunk
     spec_k = int(getattr(sp, "spec_k", 0) or 0)
-    mega = bool(getattr(sp, "mega_decode", False))
     kv_quant = bool(cache.quantize_kv)
     mesh = sp.mesh
     step = build_unified_step(cfg, cache.page_size, chunk,
                               kv_quant=kv_quant, spec_k=spec_k,
-                              mesh=mesh, mega=mega)
+                              mesh=mesh)
     b = cache.max_batch
     budget = int(getattr(sp, "token_budget", 0)
                  or b * (1 + spec_k) + chunk)

@@ -7,20 +7,17 @@ builds of exactly the programs that carry the repo's numbers:
                   (op-dtype trace -> TR001 AMP cross-check);
 - ``bert-eager``  BertModel forward, same trace;
 - ``gpt-spmd``    the hybrid-parallel train step (jaxpr walk + donation);
-- ``serving``     build_prefill / build_decode_step jits (jaxpr walk +
-                  donation of the KV page pools);
-- ``serving-unified``  the round-9 unified ragged prefill+decode step jit
+- ``serving-unified``  the unified ragged prefill+decode step jit
                   (jaxpr walk + donation audit of the page pools —
-                  the ONE program the flagship serving path replays);
-- ``serving-quant``  the round-10 quantized serving jits: int8-weight
-                  prefill/decode + the int8-weight/int8-KV unified step
-                  (jaxpr walk incl. the JX001 scale-promotion audit,
-                  donation of pools AND scale planes);
-- ``serving-spmd``  the round-11 mesh-sharded serving jits over
-                  ``Mesh(("mp",))``: tensor-parallel prefill/decode + the
-                  sharded quantized unified step (jaxpr walk through the
-                  shard_map body, JX005 donation audit over the
-                  head-sharded pools and scale planes);
+                  the ONE program the serving path replays);
+- ``serving-quant``  the round-10 quantized serving step: the
+                  int8-weight/int8-KV unified step (jaxpr walk incl. the
+                  JX001 scale-promotion audit, donation of pools AND
+                  scale planes);
+- ``serving-spmd``  the round-11 mesh-sharded serving step over
+                  ``Mesh(("mp",))``: the sharded quantized unified step
+                  (jaxpr walk through the shard_map body, JX005 donation
+                  audit over the head-sharded pools and scale planes);
 - ``serving-spec``  the round-12 speculative unified step
                   (``spec_k > 0``: verify rows + fused accept epilogue),
                   fp and int8-weight/int8-KV variants — jaxpr walk of the
@@ -35,12 +32,6 @@ builds of exactly the programs that carry the repo's numbers:
                   dequant path (block scales multiplying into the decode
                   must never widen it to f64) + the JX005 donation audit
                   of (params, momentum);
-- ``serving-mega``  the round-16 megakernelized decode step
-                  (``build_unified_step(mega=True)`` at chunk-1 decode
-                  geometry): the fused per-layer Pallas kernels with
-                  inline dequant and in-kernel KV quantize-on-write, fp
-                  and int8-weight/int8-KV variants — JX001 audits the
-                  scale math, JX005 the pool/scale-plane donation;
 - ``serving-spec-model``  the round-19 model-draft speculative serving
                   pair: the truncated-layer SELF-DRAFT jit
                   (``build_draft_step`` — the first ``draft_layers``
@@ -59,16 +50,6 @@ builds of exactly the programs that carry the repo's numbers:
                   dispatch-ahead step that silently stopped aliasing its
                   pools would double cache memory exactly when two steps
                   are in flight;
-- ``serving-mega-mixed``  the round-22 ragged megakernel serving pair:
-                  the unified step built with ``mega=True`` at the MIXED
-                  packed geometry (chunk > 1, ragged q_lens — a decode
-                  lane and a prefill-chunk lane in ONE dispatch, the
-                  rounds round 16 still routed per-op) and the single-
-                  dispatch draft chain (``build_draft_chain`` — the whole
-                  k-step proposal scan as one jit running the mega layer
-                  blocks), fp and int8-weight/int8-KV variants — JX001
-                  audits the scale math at the ragged rows, JX005 the
-                  pool donation at each program's own shifted positions;
 - ``serving-tiered``  the round-21 tiered KV cache's batched restore
                   scatter (``batched_import_rows`` — the ONE donated
                   ``pages.at[:, pg, row].set(..., mode="drop")`` jit a
@@ -89,9 +70,9 @@ the traced jaxpr.
 Round 23 adds COST CERTIFICATION on top of the hazard walk: targets with
 an entry in :mod:`.contracts` re-trace their step with ``use_kernel=True``
 (the pallas path the TPU runs) and gate the static JX007 hbm model, the
-JX008 VMEM footprints / mega-residency contract and the JX009 collective
-inventory against the committed table; ``train-dpquant`` additionally
-compiles and audits the HLO wire (fp all-reduce ban + s8 payloads).
+JX008 VMEM footprints and the JX009 collective inventory against the
+committed table; ``train-dpquant`` additionally compiles and audits the HLO
+wire (fp all-reduce ban + s8 payloads).
 """
 from __future__ import annotations
 
@@ -200,53 +181,6 @@ def analyze_train_dpquant() -> list[Finding]:
     return findings
 
 
-def analyze_serving() -> list[Finding]:
-    import numpy as np
-
-    import jax.numpy as jnp
-
-    import paddle_tpu as paddle
-    from ..inference.kv_cache import KVCacheManager
-    from ..models.gpt import (GPTConfig, GPTForCausalLM, build_decode_step,
-                              build_prefill, serving_params)
-
-    cfg = GPTConfig(vocab_size=128, hidden_size=32, num_layers=2,
-                    num_heads=2, max_seq_len=32)
-    paddle.seed(0)
-    model = GPTForCausalLM(cfg)
-    params = serving_params(model)
-    page_size, b, s = 8, 2, 8
-    mgr = KVCacheManager(cfg.num_layers, cfg.num_heads, cfg.head_dim,
-                         num_pages=2 * b * (cfg.max_seq_len // page_size),
-                         max_batch=b, max_seq_len=cfg.max_seq_len,
-                         page_size=page_size, dtype=jnp.float32)
-    rng = np.random.RandomState(0)
-    ids2d = jnp.asarray(rng.randint(0, 128, (b, s)), jnp.int32)
-    lengths = jnp.full((b,), s, jnp.int32)
-    slots = [mgr.admit(s) for _ in range(b)]
-    pages = jnp.stack([mgr.slot_pages(sl) for sl in slots])
-
-    findings: list[Finding] = []
-    prefill = build_prefill(cfg, page_size)
-    pre_args = (params, ids2d, lengths, mgr.k_pages, mgr.v_pages, pages)
-    findings += analyze_jaxpr(trace_callable(prefill, *pre_args),
-                              "serving-prefill")
-    findings += check_donation(prefill, pre_args, (3, 4), "serving-prefill")
-
-    decode = build_decode_step(cfg, page_size)
-    dec_args = (params, jnp.zeros((b,), jnp.int32), lengths,
-                mgr.k_pages, mgr.v_pages,
-                jnp.stack([mgr.slot_pages(sl) for sl in slots]))
-    dec_closed = trace_callable(decode, *dec_args)
-    findings += analyze_jaxpr(dec_closed, "serving-decode")
-    findings += check_donation(decode, dec_args, (3, 4), "serving-decode")
-    # round 23: cost-certify the decode step against the bench analytic
-    # hbm model (the oldest per-token claim in bench_serve)
-    findings += cost_certify("serving-decode", dec_closed, params=params,
-                             cache=mgr)
-    return findings
-
-
 def analyze_serving_unified() -> list[Finding]:
     import numpy as np
 
@@ -310,8 +244,8 @@ def analyze_serving_unified() -> list[Finding]:
 
 
 def analyze_serving_quant() -> list[Finding]:
-    """Round-10 quantized serving: the int8-weight prefill/decode jits and
-    the int8-weight + int8-KV unified step. The jaxpr walk's JX001 leg is
+    """Round-10 quantized serving: the int8-weight + int8-KV unified step.
+    The jaxpr walk's JX001 leg is
     the scale-promotion audit — per-group scales multiplying into the
     compute must never widen it to f64 (and the donation audit covers the
     int8 pools AND their scale planes)."""
@@ -322,8 +256,7 @@ def analyze_serving_quant() -> list[Finding]:
     import paddle_tpu as paddle
     from ..inference.kv_cache import KVCacheManager
     from ..inference.quantize import quantize_serving_params
-    from ..models.gpt import (GPTConfig, GPTForCausalLM, build_decode_step,
-                              build_prefill, build_unified_step,
+    from ..models.gpt import (GPTConfig, GPTForCausalLM, build_unified_step,
                               serving_params)
 
     cfg = GPTConfig(vocab_size=128, hidden_size=32, num_layers=2,
@@ -332,33 +265,10 @@ def analyze_serving_quant() -> list[Finding]:
     model = GPTForCausalLM(cfg)
     params = quantize_serving_params(serving_params(model), "int8",
                                      group_size=16)
-    page_size, chunk, b, s = 8, 4, 2, 8
+    page_size, chunk, b = 8, 4, 2
     budget = b + chunk
     rng = np.random.RandomState(0)
     findings: list[Finding] = []
-
-    # weight-quantized prefill + decode (fp KV pools)
-    mgr = KVCacheManager(cfg.num_layers, cfg.num_heads, cfg.head_dim,
-                         num_pages=2 * b * (cfg.max_seq_len // page_size),
-                         max_batch=b, max_seq_len=cfg.max_seq_len,
-                         page_size=page_size, dtype=jnp.float32)
-    ids2d = jnp.asarray(rng.randint(0, 128, (b, s)), jnp.int32)
-    lengths = jnp.full((b,), s, jnp.int32)
-    slots = [mgr.admit(s) for _ in range(b)]
-    pages = jnp.stack([mgr.slot_pages(sl) for sl in slots])
-    prefill = build_prefill(cfg, page_size)
-    pre_args = (params, ids2d, lengths, mgr.k_pages, mgr.v_pages, pages)
-    findings += analyze_jaxpr(trace_callable(prefill, *pre_args),
-                              "serving-quant-prefill")
-    findings += check_donation(prefill, pre_args, (3, 4),
-                               "serving-quant-prefill")
-    decode = build_decode_step(cfg, page_size)
-    dec_args = (params, jnp.zeros((b,), jnp.int32), lengths,
-                mgr.k_pages, mgr.v_pages, pages)
-    findings += analyze_jaxpr(trace_callable(decode, *dec_args),
-                              "serving-quant-decode")
-    findings += check_donation(decode, dec_args, (3, 4),
-                               "serving-quant-decode")
 
     # int8-weight + int8-KV unified step (quantize-on-write + scale planes)
     qmgr = KVCacheManager(cfg.num_layers, cfg.num_heads, cfg.head_dim,
@@ -405,9 +315,8 @@ def analyze_serving_quant() -> list[Finding]:
 
 
 def analyze_serving_spmd() -> list[Finding]:
-    """Round-11 multi-chip SPMD serving: the mesh-sharded prefill/decode
-    jits (fp params head-sharded over ``Mesh(("mp",))``) and the sharded
-    int8-weight + int8-KV unified step. The jaxpr walk recurses the
+    """Round-11 multi-chip SPMD serving: the int8-weight + int8-KV unified
+    step sharded over ``Mesh(("mp",))``. The jaxpr walk recurses the
     shard_map body (collectives included); the JX005 donation audit
     covers the HEAD-SHARDED pools AND scale planes — a sharded donation
     that stops aliasing would double per-chip cache memory exactly where
@@ -421,8 +330,7 @@ def analyze_serving_spmd() -> list[Finding]:
     from ..distributed.mesh import make_serving_mesh
     from ..inference.kv_cache import KVCacheManager
     from ..inference.quantize import quantize_serving_params
-    from ..models.gpt import (GPTConfig, GPTForCausalLM, build_decode_step,
-                              build_prefill, build_unified_step,
+    from ..models.gpt import (GPTConfig, GPTForCausalLM, build_unified_step,
                               serving_params, shard_serving_params)
 
     cfg = GPTConfig(vocab_size=128, hidden_size=32, num_layers=2,
@@ -430,34 +338,10 @@ def analyze_serving_spmd() -> list[Finding]:
     mesh = make_serving_mesh(2 if len(jax.devices()) >= 2 else 1)
     paddle.seed(0)
     model = GPTForCausalLM(cfg)
-    fp_params = shard_serving_params(serving_params(model), mesh, cfg)
-    page_size, chunk, b, s = 8, 4, 2, 8
+    page_size, chunk, b = 8, 4, 2
     budget = b + chunk
     rng = np.random.RandomState(0)
     findings: list[Finding] = []
-
-    # mesh-sharded prefill + decode (fp params, fp pools)
-    mgr = KVCacheManager(cfg.num_layers, cfg.num_heads, cfg.head_dim,
-                         num_pages=2 * b * (cfg.max_seq_len // page_size),
-                         max_batch=b, max_seq_len=cfg.max_seq_len,
-                         page_size=page_size, dtype=jnp.float32, mesh=mesh)
-    ids2d = jnp.asarray(rng.randint(0, 128, (b, s)), jnp.int32)
-    lengths = jnp.full((b,), s, jnp.int32)
-    slots = [mgr.admit(s) for _ in range(b)]
-    pages = jnp.stack([mgr.slot_pages(sl) for sl in slots])
-    prefill = build_prefill(cfg, page_size, mesh=mesh)
-    pre_args = (fp_params, ids2d, lengths, mgr.k_pages, mgr.v_pages, pages)
-    findings += analyze_jaxpr(trace_callable(prefill, *pre_args),
-                              "serving-spmd-prefill")
-    findings += check_donation(prefill, pre_args, (3, 4),
-                               "serving-spmd-prefill")
-    decode = build_decode_step(cfg, page_size, mesh=mesh)
-    dec_args = (fp_params, jnp.zeros((b,), jnp.int32), lengths,
-                mgr.k_pages, mgr.v_pages, pages)
-    findings += analyze_jaxpr(trace_callable(decode, *dec_args),
-                              "serving-spmd-decode")
-    findings += check_donation(decode, dec_args, (3, 4),
-                               "serving-spmd-decode")
 
     # sharded int8-weight + int8-KV unified step: head-sharded pools AND
     # scale planes through the donation audit
@@ -775,262 +659,6 @@ def analyze_serving_spec_model() -> list[Finding]:
     return findings
 
 
-def analyze_serving_mega() -> list[Finding]:
-    """Round-16 megakernelized decode: the unified step built with
-    ``mega=True`` at its decode geometry (chunk = 1 row per lane, budget
-    = batch) — the per-layer chain replaced by the two fused Pallas
-    megakernels of ``ops/pallas/mega_decode``, with the kernel-quantized
-    new K/V rows scattering through ``paged_write_packed_prequant``. Both
-    the fp and the int8-weight + int8-KV variants walk through the jaxpr
-    checks — JX001 is the scale-promotion audit of the inline dequant
-    (weight scale rows multiplying into the MXU feed) and quantize-on-
-    write (absmax/127 scale math) paths, and JX005 the donation audit of
-    the pools (and scale planes): a megakernel step that silently stopped
-    aliasing its pools would double cache memory on every all-decode
-    round, exactly the rounds the kernel exists to accelerate."""
-    import numpy as np
-
-    import jax.numpy as jnp
-
-    import paddle_tpu as paddle
-    from ..inference.kv_cache import KVCacheManager
-    from ..inference.quantize import quantize_serving_params
-    from ..models.gpt import (GPTConfig, GPTForCausalLM, build_unified_step,
-                              serving_params)
-
-    cfg = GPTConfig(vocab_size=128, hidden_size=32, num_layers=2,
-                    num_heads=2, max_seq_len=32, mega_decode=True)
-    paddle.seed(0)
-    model = GPTForCausalLM(cfg)
-    fp_params = serving_params(model)
-    q_params = quantize_serving_params(serving_params(model), "int8",
-                                       group_size=16)
-    page_size, chunk, b = 8, 1, 2
-    budget = b * chunk
-    rng = np.random.RandomState(0)
-    findings: list[Finding] = []
-
-    def mega_args(params, mgr):
-        for _ in range(b):
-            mgr.admit_prefix([int(x) for x in rng.randint(0, 128, (8,))])
-        # the all-decode round the scheduler routes here: every lane
-        # feeds exactly one token at its context end
-        tok_ids = jnp.asarray(rng.randint(0, 128, (budget,)), jnp.int32)
-        tok_slot = jnp.arange(b, dtype=jnp.int32)
-        tok_pos = jnp.full((budget,), 8, jnp.int32)
-        q_lens = jnp.ones((b,), jnp.int32)
-        kv_lens = jnp.full((b,), 8, jnp.int32)
-        last_idx = jnp.arange(b, dtype=jnp.int32)
-        no_cow = jnp.full((b,), mgr.num_pages, jnp.int32)
-        feedback = jnp.zeros((budget,), jnp.int32)
-        prev_toks = jnp.zeros((b,), jnp.int32)
-        emit = jnp.ones((b,), jnp.int32)
-        produced = jnp.zeros((b,), jnp.int32)
-        keys = jnp.zeros((b, 2), jnp.uint32)
-        temp = jnp.asarray([0.0, 0.8], jnp.float32)
-        top_k = jnp.asarray([0, 40], jnp.int32)
-        top_p = jnp.asarray([1.0, 0.9], jnp.float32)
-        pools = ((mgr.k_pages, mgr.v_pages, mgr.k_scales, mgr.v_scales)
-                 if mgr.quantize_kv else (mgr.k_pages, mgr.v_pages))
-        return (params, tok_ids, tok_slot, tok_pos, q_lens, kv_lens,
-                last_idx, feedback, prev_toks, emit, produced) + pools + (
-                    mgr.page_table_device(), no_cow, no_cow, keys, temp,
-                    top_k, top_p)
-
-    # fp megakernel step: pools donate at (11, 12)
-    mgr = KVCacheManager(cfg.num_layers, cfg.num_heads, cfg.head_dim,
-                         num_pages=2 * b * (cfg.max_seq_len // page_size),
-                         max_batch=b, max_seq_len=cfg.max_seq_len,
-                         page_size=page_size, dtype=jnp.float32,
-                         enable_prefix_cache=True)
-    step = build_unified_step(cfg, page_size, chunk, mega=True)
-    args = mega_args(fp_params, mgr)
-    findings += analyze_jaxpr(trace_callable(step, *args),
-                              "serving-mega-step")
-    findings += check_donation(step, args, (11, 12), "serving-mega-step")
-    # round 23: cost-certify the kernel build — fused activation hbm
-    # accounting, per-kernel VMEM budgets, and the structural 4h-never-
-    # in-HBM residency contract
-    kstep = build_unified_step(cfg, page_size, chunk, mega=True,
-                               use_kernel=True)
-    findings += cost_certify("serving-mega-step",
-                             trace_callable(kstep, *args),
-                             params=fp_params, cache=mgr)
-
-    # int8-weight + int8-KV megakernel step (inline dequant + in-kernel
-    # quantize-on-write): pools AND scale planes donate at (11..14)
-    qmgr = KVCacheManager(cfg.num_layers, cfg.num_heads, cfg.head_dim,
-                          num_pages=2 * b * (cfg.max_seq_len // page_size),
-                          max_batch=b, max_seq_len=cfg.max_seq_len,
-                          page_size=page_size, dtype=jnp.float32,
-                          quantize_kv=True, enable_prefix_cache=True)
-    qcfg = GPTConfig(vocab_size=128, hidden_size=32, num_layers=2,
-                     num_heads=2, max_seq_len=32, mega_decode=True,
-                     weight_dtype="int8", weight_quant_group_size=16,
-                     kv_cache_dtype="int8")
-    qstep = build_unified_step(qcfg, page_size, chunk, kv_quant=True,
-                               mega=True)
-    qargs = mega_args(q_params, qmgr)
-    findings += analyze_jaxpr(trace_callable(qstep, *qargs),
-                              "serving-mega-quant-step")
-    findings += check_donation(qstep, qargs, (11, 12, 13, 14),
-                               "serving-mega-quant-step")
-    qkstep = build_unified_step(qcfg, page_size, chunk, kv_quant=True,
-                                mega=True, use_kernel=True)
-    findings += cost_certify("serving-mega-quant-step",
-                             trace_callable(qkstep, *qargs),
-                             params=q_params, cache=qmgr)
-    return findings
-
-
-def analyze_serving_mega_mixed() -> list[Finding]:
-    """Round-22 ragged megakernel serving: the unified step built with
-    ``mega=True`` at the MIXED packed geometry (chunk > 1, ragged
-    q_lens — one lane decoding a single token while another feeds a
-    prefill chunk; the round-16 target only walked the all-decode
-    chunk-1 shape) plus the single-dispatch draft chain
-    (``models/gpt.py build_draft_chain``): the whole k-step truncated-
-    layer proposal pass as one jit whose scan chains the mega layer
-    blocks device-side. Both the fp and the int8-weight + int8-KV
-    variants walk the jaxpr checks — JX001 audits the inline-dequant /
-    quantize-on-write scale math at the ragged rows, JX005 the pool
-    donation at the SHIFTED positions: the ragged mega step donates at
-    the unified layout (11, 12) / (11..14), the draft chain at its own
-    (4, 5) / (4..7) — a chain that silently stopped aliasing its draft
-    pool would double draft-cache memory every speculative round."""
-    import numpy as np
-
-    import jax.numpy as jnp
-
-    import paddle_tpu as paddle
-    from ..inference.kv_cache import KVCacheManager
-    from ..inference.quantize import quantize_serving_params
-    from ..models.gpt import (GPTConfig, GPTForCausalLM, build_draft_chain,
-                              build_unified_step, draft_serving_params,
-                              serving_params)
-
-    cfg = GPTConfig(vocab_size=128, hidden_size=32, num_layers=2,
-                    num_heads=2, max_seq_len=32, mega_decode=True)
-    paddle.seed(0)
-    model = GPTForCausalLM(cfg)
-    fp_params = serving_params(model)
-    q_params = quantize_serving_params(serving_params(model), "int8",
-                                       group_size=16)
-    page_size, chunk, b = 8, 2, 2
-    budget = b * chunk
-    rng = np.random.RandomState(0)
-    findings: list[Finding] = []
-
-    def mixed_args(params, mgr):
-        for _ in range(b):
-            mgr.admit_prefix([int(x) for x in rng.randint(0, 128, (8,))])
-        # the mixed round the round-22 kernels serve without a per-op
-        # fallback: lane 0 decodes one token, lane 1 feeds a 2-token
-        # prefill chunk — ragged q_lens, one packed pad row
-        tok_ids = jnp.asarray(rng.randint(0, 128, (budget,)), jnp.int32)
-        tok_slot = jnp.asarray([0, 1, 1, -1], jnp.int32)
-        tok_pos = jnp.asarray([8, 8, 9, 0], jnp.int32)
-        q_lens = jnp.asarray([1, 2], jnp.int32)
-        kv_lens = jnp.full((b,), 8, jnp.int32)
-        last_idx = jnp.asarray([0, 2], jnp.int32)
-        no_cow = jnp.full((b,), mgr.num_pages, jnp.int32)
-        feedback = jnp.zeros((budget,), jnp.int32)
-        prev_toks = jnp.zeros((b,), jnp.int32)
-        emit = jnp.ones((b,), jnp.int32)
-        produced = jnp.zeros((b,), jnp.int32)
-        keys = jnp.zeros((b, 2), jnp.uint32)
-        temp = jnp.asarray([0.0, 0.8], jnp.float32)
-        top_k = jnp.asarray([0, 40], jnp.int32)
-        top_p = jnp.asarray([1.0, 0.9], jnp.float32)
-        pools = ((mgr.k_pages, mgr.v_pages, mgr.k_scales, mgr.v_scales)
-                 if mgr.quantize_kv else (mgr.k_pages, mgr.v_pages))
-        return (params, tok_ids, tok_slot, tok_pos, q_lens, kv_lens,
-                last_idx, feedback, prev_toks, emit, produced) + pools + (
-                    mgr.page_table_device(), no_cow, no_cow, keys, temp,
-                    top_k, top_p)
-
-    def draft_args(params, mgr):
-        for _ in range(b):
-            mgr.admit_prefix([int(x) for x in rng.randint(0, 128, (8,))])
-        dparams = draft_serving_params(params, 1)
-        first = jnp.asarray(rng.randint(0, 128, (b,)), jnp.int32)
-        steps = jnp.asarray([2, 1], jnp.int32)   # ragged chain depths
-        kv_lens = jnp.full((b,), 8, jnp.int32)
-        pools = ((mgr.k_pages, mgr.v_pages, mgr.k_scales, mgr.v_scales)
-                 if mgr.quantize_kv else (mgr.k_pages, mgr.v_pages))
-        return (dparams, first, steps, kv_lens) + pools + (
-            mgr.page_table_device(),)
-
-    def pool(quantize_kv, layers=cfg.num_layers):
-        return KVCacheManager(
-            layers, cfg.num_heads, cfg.head_dim,
-            num_pages=2 * b * (cfg.max_seq_len // page_size), max_batch=b,
-            max_seq_len=cfg.max_seq_len, page_size=page_size,
-            dtype=jnp.float32, quantize_kv=quantize_kv,
-            enable_prefix_cache=True)
-
-    qcfg = GPTConfig(vocab_size=128, hidden_size=32, num_layers=2,
-                     num_heads=2, max_seq_len=32, mega_decode=True,
-                     weight_dtype="int8", weight_quant_group_size=16,
-                     kv_cache_dtype="int8")
-
-    # the ragged mega step, fp and int8w+int8kv: pools donate at the
-    # unified layout's (11, 12) / (11..14)
-    step = build_unified_step(cfg, page_size, chunk, mega=True)
-    mgr = pool(False)
-    args = mixed_args(fp_params, mgr)
-    findings += analyze_jaxpr(trace_callable(step, *args),
-                              "serving-mega-mixed-step")
-    findings += check_donation(step, args, (11, 12),
-                               "serving-mega-mixed-step")
-    # round 23: cost-certify the kernel builds at the ragged geometry —
-    # the acceptance target for the static hbm model
-    kstep = build_unified_step(cfg, page_size, chunk, mega=True,
-                               use_kernel=True)
-    findings += cost_certify("serving-mega-mixed-step",
-                             trace_callable(kstep, *args),
-                             params=fp_params, cache=mgr)
-    qstep = build_unified_step(qcfg, page_size, chunk, kv_quant=True,
-                               mega=True)
-    qmgr = pool(True)
-    qargs = mixed_args(q_params, qmgr)
-    findings += analyze_jaxpr(trace_callable(qstep, *qargs),
-                              "serving-mega-mixed-quant-step")
-    findings += check_donation(qstep, qargs, (11, 12, 13, 14),
-                               "serving-mega-mixed-quant-step")
-    qkstep = build_unified_step(qcfg, page_size, chunk, kv_quant=True,
-                                mega=True, use_kernel=True)
-    findings += cost_certify("serving-mega-mixed-quant-step",
-                             trace_callable(qkstep, *qargs),
-                             params=q_params, cache=qmgr)
-
-    # the single-dispatch draft chain (truncated 1-layer stack, k=2,
-    # mega blocks): draft pools donate at the chain layout's (4, 5) /
-    # (4..7)
-    chain = build_draft_chain(cfg, 1, page_size, 2, mega=True)
-    cargs = draft_args(fp_params, pool(False, layers=1))
-    findings += analyze_jaxpr(trace_callable(chain, *cargs),
-                              "serving-mega-draft-chain")
-    findings += check_donation(chain, cargs, (4, 5),
-                               "serving-mega-draft-chain")
-    kchain = build_draft_chain(cfg, 1, page_size, 2, mega=True,
-                               use_kernel=True)
-    findings += cost_certify("serving-mega-draft-chain",
-                             trace_callable(kchain, *cargs))
-    qchain = build_draft_chain(qcfg, 1, page_size, 2, kv_quant=True,
-                               mega=True)
-    qcargs = draft_args(q_params, pool(True, layers=1))
-    findings += analyze_jaxpr(trace_callable(qchain, *qcargs),
-                              "serving-mega-draft-chain-quant")
-    findings += check_donation(qchain, qcargs, (4, 5, 6, 7),
-                               "serving-mega-draft-chain-quant")
-    qkchain = build_draft_chain(qcfg, 1, page_size, 2, kv_quant=True,
-                                mega=True, use_kernel=True)
-    findings += cost_certify("serving-mega-draft-chain-quant",
-                             trace_callable(qkchain, *qcargs))
-    return findings
-
-
 def analyze_serving_tiered() -> list[Finding]:
     """Round 21: the tiered KV cache's batched restore landing —
     :func:`paddle_tpu.inference.kv_cache.batched_import_rows`, the one
@@ -1185,15 +813,12 @@ TARGETS = {
     "bert-eager": analyze_bert_eager,
     "gpt-spmd": analyze_gpt_spmd,
     "train-dpquant": analyze_train_dpquant,
-    "serving": analyze_serving,
     "serving-unified": analyze_serving_unified,
     "serving-quant": analyze_serving_quant,
     "serving-spmd": analyze_serving_spmd,
     "serving-spec": analyze_serving_spec,
     "serving-spec-model": analyze_serving_spec_model,
     "serving-async": analyze_serving_async,
-    "serving-mega": analyze_serving_mega,
-    "serving-mega-mixed": analyze_serving_mega_mixed,
     "serving-tiered": analyze_serving_tiered,
     "serving-moe": analyze_serving_moe,
     "train-moe-ep": analyze_train_moe_ep,

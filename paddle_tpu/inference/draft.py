@@ -215,9 +215,7 @@ class ModelDraftEngine:
     whose step 1 feeds each lane's live last context token and steps
     2..k feed the previous step's greedy argmax, so the intermediate
     draft tokens never touch the host and a speculative round costs ONE
-    draft dispatch (+ the target's verify step). With ``mega`` on, the
-    chain's layer blocks run the persistent mega kernels of
-    ``ops/pallas/mega_decode`` at chunk-1 geometry.
+    draft dispatch (+ the target's verify step).
 
     Crash consistency / preemption replay: per request the engine records
     the exact token ids it fed (``fed``). Every proposal starts by
@@ -234,7 +232,7 @@ class ModelDraftEngine:
     def __init__(self, config, params, draft_layers: int, *, page_size,
                  chunk, max_batch, max_seq_len, num_pages=None,
                  use_kernel=None, kv_quant=False, mesh=None, dtype=None,
-                 on_launch=None, max_k=None, mega=None):
+                 on_launch=None, max_k=None):
         from ..models.gpt import (build_draft_step, draft_config,
                                   draft_serving_params)
         from ..observability import MetricsRegistry
@@ -286,12 +284,7 @@ class ModelDraftEngine:
         # shorter scan, never masked steps it didn't ask for); ``max_k``
         # (the predictor passes its spec_k) pre-builds the steady-state
         # geometry so construction-time validation fires loudly.
-        # ``mega`` routes the chain's layer blocks through the
-        # persistent mega kernels (default: the config flag — the chain
-        # matches the parent build's kernel family).
         self.max_k = int(max_k) if max_k else 0
-        self.mega = bool(getattr(config, "mega_decode", False)
-                         if mega is None else mega)
         self._config = config
         self._use_kernel = use_kernel
         self._mesh = mesh
@@ -511,7 +504,7 @@ class ModelDraftEngine:
         return _draft_chain_fn(
             self._config, self.draft_layers, self.cache.page_size,
             int(k), self._use_kernel,
-            kv_quant=self.kv_quant, mesh=self._mesh, mega=self.mega)
+            kv_quant=self.kv_quant, mesh=self._mesh)
 
     def _ensure(self, st, new_len: int, keep: set) -> bool:
         """Grow a draft lane, evicting idle lanes under pressure — but

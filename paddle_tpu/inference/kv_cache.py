@@ -17,10 +17,10 @@ Split of responsibilities:
   (can the prompt + headroom fit?), per-step growth (allocate a page when a
   sequence crosses a page boundary), eviction. All O(pages) numpy/python —
   never inside a compiled program.
-- **device side (pure functions below)**: the scatters that write prefill
-  K/V and per-step decode K/V into the page pool. They are shape-stable
-  jnp functions traced INTO the prefill/decode jits (models/gpt.py), so the
-  cache arrays never round-trip through the host.
+- **device side (pure functions below)**: the scatters that write a step's
+  packed K/V rows into the page pool. They are shape-stable jnp functions
+  traced INTO the serving step's jit (models/gpt.py), so the cache arrays
+  never round-trip through the host.
 
 Page-table convention (shared with ops/pallas/paged_attention):
 ``page_table[slot, i]`` is the pool index of the slot's i-th page, ``-1``
@@ -127,41 +127,8 @@ def kv_cache_quantized(kv_cache_dtype) -> bool:
 
 
 # ---------------------------------------------------------------------------
-# device-side pure scatter helpers (traced into the prefill/decode jits)
+# device-side pure scatter helpers (traced into the serving step's jit)
 # ---------------------------------------------------------------------------
-
-
-def paged_write_tokens(pages, tok, page_table, positions, page_size):
-    """Write ONE token per slot into the page pool (the decode-step write).
-
-    pages: [num_pages, kv_heads, page_size, head_dim]; tok: [batch,
-    kv_heads, head_dim]; page_table: [batch, pages_per_slot] int32;
-    positions: [batch] int32 write position per slot (< 0 = inactive slot,
-    dropped). Returns the updated pool.
-    """
-    num_pages = pages.shape[0]
-    b = tok.shape[0]
-    pos = jnp.maximum(positions, 0)
-    pg = page_table[jnp.arange(b), pos // page_size]
-    # inactive slots and unallocated (-1) entries route out of bounds
-    pg = jnp.where((positions >= 0) & (pg >= 0), pg, num_pages)
-    return pages.at[pg, :, pos % page_size].set(tok, mode="drop")
-
-
-def paged_write_prefill(pages, seq, pages_for_slot, length, page_size):
-    """Scatter one slot's prompt K/V into its pages (copy-on-prefill).
-
-    pages: [num_pages, kv_heads, page_size, head_dim]; seq: [s_pad,
-    kv_heads, head_dim] (positions >= length are padding and dropped);
-    pages_for_slot: [pages_per_slot] int32 (-1 unallocated); length: scalar.
-    """
-    num_pages = pages.shape[0]
-    s_pad = seq.shape[0]
-    i = jnp.arange(s_pad)
-    pg = pages_for_slot[jnp.minimum(i // page_size,
-                                    pages_for_slot.shape[0] - 1)]
-    pg = jnp.where((i < length) & (pg >= 0), pg, num_pages)
-    return pages.at[pg, :, i % page_size].set(seq, mode="drop")
 
 
 def _packed_dest(page_table, tok_slot, tok_pos, page_size, num_pages):
@@ -296,18 +263,12 @@ def paged_write_packed_quant(pages, scales, toks, page_table, tok_slot,
 def paged_write_packed_prequant(pages, scales, q_toks, s_toks, page_table,
                                 tok_slot, tok_pos, page_size, layer=None,
                                 plan=None):
-    """Scatter ALREADY-QUANTIZED packed K/V rows + their scale rows into
-    the int8 pool — the round-16 megakernel write path: the fused layer
-    kernel quantizes the new token's K/V inline in VMEM (the exact
-    :func:`paged_write_packed_quant` formula) and emits int8 payloads
-    ``q_toks [budget, kv_heads, head_dim]`` with per-row-per-head scales
-    ``s_toks [budget, kv_heads]``; this is just the scatter half. Since
-    round 22 the MIXED ragged rounds drive it too: the budget packs a
-    VARIABLE 1..chunk rows per lane (a decode lane one row, a prefill-
-    chunk lane several, pad rows ``tok_slot == -1``), so consecutive
-    rows of one lane land at consecutive ``tok_pos`` — the drop-mode
-    scatter is position-addressed and never cared how many rows a lane
-    contributed. Returns ``(pages, scales)``.
+    """The scatter half of :func:`paged_write_packed_quant`: int8 payloads
+    ``q_toks [budget, kv_heads, head_dim]`` and their per-row-per-head
+    scales ``s_toks [budget, kv_heads]`` into the int8 pool and its scale
+    plane. The budget packs 1..chunk rows per lane (pad rows ``tok_slot ==
+    -1``); the drop-mode scatter is position-addressed. Returns ``(pages,
+    scales)``.
     """
     dest = (page_table, tok_slot, tok_pos, page_size, layer, plan)
     return (_write_rows(pages, q_toks, *dest),
@@ -1493,7 +1454,7 @@ class KVCacheManager:
 
     def update_pages(self, k_pages, v_pages=None, k_scales=None,
                      v_scales=None) -> None:
-        """Adopt the pools returned by a jitted prefill/decode step (scale
+        """Adopt the pools returned by a jitted serving step (scale
         planes too on the int8-KV path; a latent cache's one pool)."""
         self.k_pages = k_pages
         if v_pages is not None:

@@ -1,11 +1,9 @@
 """Continuous-batching autoregressive serving over the paged KV cache.
 
-Round 9: the serving front end schedules a UNIFIED ragged step — ONE
-fixed-shape jit (``models/gpt.py build_unified_step``) serves decode tokens
-and chunked-prefill tokens in the same program, driven by a per-step token
-budget. The round-7 two-jit path (bucketed batch-1 prefill + fixed-shape
-decode) is kept behind ``unified=False`` as the A/B baseline and the
-token-for-token equivalence oracle until a later PR deletes it.
+The serving front end schedules a UNIFIED ragged step — ONE fixed-shape jit
+(``models/gpt.py build_unified_step``) serves decode tokens and
+chunked-prefill tokens in the same program, driven by a per-step token
+budget. It is the only serving step program.
 
 Scheduling (the Ragged-Paged-Attention / chunked-prefill shape, PAPERS.md):
 
@@ -16,8 +14,8 @@ Scheduling (the Ragged-Paged-Attention / chunked-prefill shape, PAPERS.md):
   age, up to ``chunk`` tokens per slot per step) from admitting or
   preemption-replaying sequences;
 - a chunk that reaches the end of its context yields that slot's next
-  token (greedy argmax bit-identical to round 7, or the fused seeded
-  temperature/top-k/top-p epilogue).
+  token (greedy argmax, or the fused seeded temperature/top-k/top-p
+  epilogue).
 
 Prefix caching: admission matches the prompt against the page-granular
 content-hash registry (``KVCacheManager.admit_prefix``) and skips the
@@ -64,10 +62,10 @@ behind-by-one; steps that cannot complete anything defer up to
 ``max_inflight_steps`` and drain in one batched materialization
 (``flush()``). Greedy output is bit-identical and seeded sampling
 stream-identical to the synchronous engine (per-request streams are
-batch-order invariant); round 14 makes async the DEFAULT on the unified
-path (PR 8 soaked green) — ``async_engine=False`` keeps the synchronous
-engine as the oracle — both drive the SAME pack/capacity code, the sync
-engine simply reconciles at pipeline depth zero.
+batch-order invariant); async is the DEFAULT since round 14 —
+``async_engine=False`` keeps the synchronous engine as the oracle — both
+drive the SAME pack/capacity code, the sync engine simply reconciles at
+pipeline depth zero.
 
 Round 17 adds the RESILIENCE LAYER. Requests gain a terminal ``FAILED``
 state with a per-request ``error`` record ``{"code", "message"}`` —
@@ -125,16 +123,11 @@ accounting in lockstep at every drain.
 Knobs: ``max_batch`` (lanes), ``num_pages``/``page_size`` (pool geometry),
 ``max_seq_len`` (page-table width), ``chunk`` (per-slot prefill chunk,
 autotuned default), ``token_budget`` (tokens per step, default
-``max_batch * (1 + spec_k) + chunk``), ``prefix_cache`` (on by default
-when unified), ``spec_decode_k`` (speculation build geometry, default
+``max_batch * (1 + spec_k) + chunk``), ``prefix_cache`` (on by
+default), ``spec_decode_k`` (speculation build geometry, default
 ``config.spec_decode_k``), ``async_engine`` (the round-13 pipelined
 engine) + ``max_inflight_steps`` (deferral bound for steps that cannot
-complete any request), ``mega_decode`` (round 16, ragged since round 22;
-default ``config.mega_decode``: EVERY round — mixed prefill+decode
-included — runs the fused per-layer Pallas megakernels of
-``ops/pallas/mega_decode`` at the unified step's packed ragged geometry,
-activations pinned in VMEM, the draft chain collapsed to one dispatch;
-emissions are bit-identical either way).
+complete any request).
 """
 from __future__ import annotations
 
@@ -202,9 +195,9 @@ class Request:
         # the predictor's max_step_retries before the request FAILS
         self.retry_count = 0
         self._finish_counted = False
-        # sampling params (temperature == 0 -> greedy argmax, bit-identical
-        # to round 7); seed defaults to the request id so replays after
-        # preemption re-sample the SAME stream (keyed by tokens produced)
+        # sampling params (temperature == 0 -> greedy argmax); seed defaults
+        # to the request id so replays after preemption re-sample the SAME
+        # stream (keyed by tokens produced)
         self.temperature = float(temperature)
         self.top_k = int(top_k)
         self.top_p = float(top_p)
@@ -353,10 +346,8 @@ class ServingPredictor:
 
     ``add_request`` enqueues; ``step`` runs one scheduler round (admit /
     grow / preempt around ONE unified-step launch); ``generate`` drives
-    ``step`` until a set of prompts finishes. ``unified=False`` falls back
-    to the round-7 two-jit path (per-bucket prefill at admission + decode
-    step) — the A/B baseline. ``async_engine`` (round 13; the DEFAULT on
-    the unified path since round 14) overlaps host scheduling with device
+    ``step`` until a set of prompts finishes. ``async_engine`` (round 13;
+    the DEFAULT since round 14) overlaps host scheduling with device
     execution: ``step()`` dispatches round N and reconciles round N-1's
     deferred emissions (see the module docstring for the sync-boundary
     contract); ``flush()`` drains the in-flight ring; ``False`` selects
@@ -364,18 +355,16 @@ class ServingPredictor:
     """
 
     def __init__(self, model, *, max_batch=8, num_pages=None, page_size=None,
-                 max_seq_len=None, use_kernel=None, prefill_bucket=16,
-                 dtype=None, unified=True, chunk=None, token_budget=None,
-                 prefix_cache=None, kv_cache_dtype=None, mesh=None,
-                 spec_decode_k=None, async_engine=None,
-                 max_inflight_steps=4, metrics=None, mega_decode=None,
-                 slo=None, max_step_retries=3, retry_backoff_s=0.02,
+                 max_seq_len=None, use_kernel=None, dtype=None, chunk=None,
+                 token_budget=None, prefix_cache=None, kv_cache_dtype=None,
+                 mesh=None, spec_decode_k=None, async_engine=None,
+                 max_inflight_steps=4, metrics=None, slo=None,
+                 max_step_retries=3, retry_backoff_s=0.02,
                  replica_id=0, role="colocated", draft_source=None,
                  draft_layers=None, draft_num_pages=None,
                  host_tier_bytes=0):
         from ..distributed.mesh import as_serving_mesh
-        from ..models.gpt import (_serving_params_cached, build_decode_step,
-                                  build_prefill, build_unified_step,
+        from ..models.gpt import (_serving_params_cached, build_unified_step,
                                   serving_params, shard_serving_params)
 
         gpt = model.gpt if hasattr(model, "gpt") else model
@@ -429,12 +418,9 @@ class ServingPredictor:
             unsupported = [name for name, on in (
                 ("kv_cache_dtype", kv_cache_dtype), ("mesh", mesh),
                 ("spec_decode_k", spec_decode_k),
-                ("mega_decode", mega_decode),
                 ("host_tier_bytes", host_tier_bytes),
                 ("draft_source='model'", draft_source == "model"),
-                ("draft_layers", draft_layers),
-                ("unified=False (build_prefill / build_decode_step)",
-                 not unified)) if on]
+                ("draft_layers", draft_layers)) if on]
             if unsupported:
                 raise NotImplementedError(
                     f"not supported for a latent (MLA) cache yet: "
@@ -474,14 +460,8 @@ class ServingPredictor:
         self.max_seq_len = min(int(max_seq_len or cfg.max_seq_len),
                                cfg.max_seq_len)
         self.max_batch = int(max_batch)
-        self.prefill_bucket = int(prefill_bucket)
-        self.unified = bool(unified)
         self.kv_quant = kv_cache_quantized(
             kv_cache_dtype or getattr(cfg, "kv_cache_dtype", None))
-        if self.kv_quant and not self.unified:
-            raise ValueError(
-                "int8 KV cache rides the unified step's quantize-on-write "
-                "lanes; the legacy two-jit path serves fp only")
         kv_dtype = self.params["tok_emb"].dtype
         from ..ops.pallas.paged_attention import (preferred_chunk_size,
                                                   preferred_page_size)
@@ -492,7 +472,7 @@ class ServingPredictor:
                 cfg.num_heads, cfg.num_heads, cfg.head_dim, kv_dtype)
             num_pages = self.max_batch * pages_needed(self.max_seq_len, ps)
         if prefix_cache is None:
-            prefix_cache = self.unified
+            prefix_cache = True
         # a latent cache: one pool of one row per token, the row padded to
         # whole 128-lane tiles (compiled for the chip, an unpadded 576-wide
         # row costs a copy of the whole pool at every kernel call)
@@ -519,10 +499,6 @@ class ServingPredictor:
         if self.spec_k < 0:
             raise ValueError(f"spec_decode_k must be >= 0, got "
                              f"{self.spec_k}")
-        if self.spec_k and not self.unified:
-            raise ValueError(
-                "speculative decoding rides the unified step's verify "
-                "rows; the legacy two-jit path serves plain decode only")
         if self.spec_k and self.spec_k >= self.chunk:
             raise ValueError(
                 f"spec_decode_k {self.spec_k} needs 1 + k <= chunk "
@@ -531,59 +507,29 @@ class ServingPredictor:
         self.token_budget = int(
             token_budget
             or (self.max_batch * (1 + self.spec_k) + self.chunk))
-        # round 16 → 22: the megakernelized build. Round 16 kept a
-        # second all-decode-geometry program and routed by round content;
-        # round 22's ragged mega kernels accept the SAME packed
-        # (token_budget, chunk) geometry as the per-op step, so mega is
-        # now a build flavor of the ONE unified program — every round
-        # (mixed prefill+decode included) runs the fused per-layer Pallas
-        # kernels, and the round-content router is gone. Build-time
-        # validation (int4 weights) raises HERE — a predictor must fail
-        # loudly at construction, not on its first round.
-        # mega_decode=False stays bit-identical to round-15 behavior.
-        self.mega_decode = bool(
-            getattr(cfg, "mega_decode", False) if mega_decode is None
-            else mega_decode)
-        if self.mega_decode and not self.unified:
-            raise ValueError(
-                "mega_decode rides the unified step's packed layout; the "
-                "legacy two-jit path serves the per-op chain only")
-        if self.unified:
-            self._unified = build_unified_step(
-                cfg, self.cache.page_size, self.chunk,
-                use_kernel=use_kernel, kv_quant=self.kv_quant,
-                mesh=self.mesh, spec_k=self.spec_k,
-                mega=self.mega_decode)
-            self._prefill = self._decode = None
-            if not (self.latent or self.mega_decode):
-                # the step's attention is ``ragged_paged_attention``: what a
-                # scheduled lane's context costs it in grid steps, by the
-                # kernel module's own function (per chip under a mesh)
-                from ..ops.pallas.paged_attention import ragged_grid
+        self._unified = build_unified_step(
+            cfg, self.cache.page_size, self.chunk, use_kernel=use_kernel,
+            kv_quant=self.kv_quant, mesh=self.mesh, spec_k=self.spec_k)
+        if not self.latent:
+            # the step's attention is ``ragged_paged_attention``: what a
+            # scheduled lane's context costs it in grid steps, by the
+            # kernel module's own function (per chip under a mesh)
+            from ..ops.pallas.paged_attention import ragged_grid
 
-                heads = cfg.num_heads // (self.mesh.shape["mp"]
-                                          if self.mesh is not None else 1)
-                self._attn_grid = ragged_grid(
-                    self.max_batch, self.cache.pages_per_slot, self.chunk,
-                    heads, heads, self.cache.page_size, cfg.head_dim,
-                    "int8" if self.kv_quant else kv_dtype, kv_dtype)
-                self._m_attn_live = self.metrics.counter(
-                    "serving_attn_blocks_live",
-                    "grid steps of a ragged_paged_attention call that hold "
-                    "a scheduled lane's keys, summed over dispatched steps")
-                self._m_attn_grid = self.metrics.counter(
-                    "serving_attn_blocks_grid",
-                    "grid steps a ragged_paged_attention call launches, "
-                    "summed over dispatched steps")
-        else:
-            self._unified = None
-            self._decode = build_decode_step(cfg, self.cache.page_size,
-                                             use_kernel=use_kernel,
-                                             mesh=self.mesh)
-            # one jitted prefill; jax.jit caches one executable per prompt
-            # bucket shape (prompts are padded to _bucket multiples)
-            self._prefill = build_prefill(cfg, self.cache.page_size,
-                                          mesh=self.mesh)
+            heads = cfg.num_heads // (self.mesh.shape["mp"]
+                                      if self.mesh is not None else 1)
+            self._attn_grid = ragged_grid(
+                self.max_batch, self.cache.pages_per_slot, self.chunk,
+                heads, heads, self.cache.page_size, cfg.head_dim,
+                "int8" if self.kv_quant else kv_dtype, kv_dtype)
+            self._m_attn_live = self.metrics.counter(
+                "serving_attn_blocks_live",
+                "grid steps of a ragged_paged_attention call that hold "
+                "a scheduled lane's keys, summed over dispatched steps")
+            self._m_attn_grid = self.metrics.counter(
+                "serving_attn_blocks_grid",
+                "grid steps a ragged_paged_attention call launches, "
+                "summed over dispatched steps")
         # round 19: the draft SOURCE behind spec_decode_k — "ngram" (the
         # round-12 prompt-lookup table) or "model" (the truncated-layer
         # self-draft: ModelDraftEngine runs the first draft_layers layers
@@ -619,24 +565,17 @@ class ServingPredictor:
                 kv_quant=self.kv_quant, mesh=self.mesh,
                 on_launch=self._note_draft_launch,
                 # round 22: pin the fused chain's build geometry to the
-                # predictor's spec_k (one executable for every round) and
-                # match its kernel family to the parent build
-                max_k=self.spec_k, mega=self.mega_decode)
+                # predictor's spec_k (one executable for every round)
+                max_k=self.spec_k)
         # round 13: the async double-buffered engine — dispatch-ahead on
         # the unified step's device-resident token feedback; the sync
         # engine is the same pack/capacity code at pipeline depth zero.
-        # round 14: async is the DEFAULT on the unified path (PR 8 soaked:
-        # greedy bit-identical + seeded stream-identical to sync); pass
-        # async_engine=False for the explicit sync baseline, and the
-        # legacy two-jit path stays sync (it has no feedback carry)
-        if async_engine is None:
-            async_engine = self.unified
-        self.async_engine = bool(async_engine)
+        # round 14: async is the DEFAULT (PR 8 soaked: greedy bit-identical
+        # + seeded stream-identical to sync); pass async_engine=False for
+        # the explicit sync baseline
+        self.async_engine = True if async_engine is None else bool(
+            async_engine)
         self.max_inflight_steps = max(1, int(max_inflight_steps))
-        if self.async_engine and not self.unified:
-            raise ValueError(
-                "the async engine rides the unified step's device-resident "
-                "token feedback; the legacy two-jit path serves sync only")
         self._inflight: deque[_Pending] = deque()
         self._did_sync = False   # set by _reconcile_one, charged per call
         self.waiting: deque[Request] = deque()
@@ -984,18 +923,8 @@ class ServingPredictor:
     @property
     def decode_trace_count(self) -> int:
         """Times the serving step has been (re)traced — the no-retrace
-        gate asserts this stays constant after warmup. Unified mode counts
-        the ONE unified step; legacy counts the decode jit."""
-        fn = self._unified if self.unified else self._decode
-        return fn.trace_count[0]
-
-    @property
-    def prefill_trace_count(self) -> int:
-        """Times a prefill program was traced. The unified step has NO
-        separate prefill jit (always 0); the legacy path compiles one
-        executable per prompt-length bucket — this makes that count
-        visible (bench_serve reports + gates it)."""
-        return 0 if self.unified else self._prefill.trace_count[0]
+        gate asserts this stays constant after warmup."""
+        return self._unified.trace_count[0]
 
     @property
     def prefix_hit_rate(self) -> float:
@@ -1435,7 +1364,7 @@ class ServingPredictor:
     def flush(self) -> dict[int, list[int]]:
         """Materialize every in-flight step (the async engine's OUTPUT
         FLUSH — a hard sync boundary). Returns the landed tokens merged
-        in emission order; no-op for the sync engine / legacy path."""
+        in emission order; no-op for the sync engine."""
         t0 = monotonic()
         self._did_sync = False
         try:
@@ -1961,11 +1890,6 @@ class ServingPredictor:
         import jax
 
         b = self.max_batch
-        # round 22: the round-16 round-content route is GONE — the mega
-        # build accepts the unified step's ragged packed geometry, so
-        # EVERY round (mixed prefill+decode included) runs the one
-        # program that was built at construction. One fixed shape, one
-        # trace, one steady-pack cache.
         decode_set = set(decode_slots)
         t = self.token_budget
         step_fn = self._unified
@@ -2197,168 +2121,15 @@ class ServingPredictor:
                         completing, bool(self.spec_k), spec_slots,
                         must_sync)
 
-    # -- legacy (round-7 two-jit) path -------------------------------------
-
-    def _bucket(self, n: int) -> int:
-        b = self.prefill_bucket
-        return max(b, ((n + b - 1) // b) * b)
-
-    def _admit_one_legacy(self, req: Request) -> bool:
-        """Claim a slot + pages and prefill ``req``'s context into them."""
-        ctx = req._context_ids()
-        prefix, last = ctx[:-1], ctx[-1]
-        # all but the LAST context token prefill; the last token becomes
-        # the next decode step's input, and that step produces its
-        # successor. A 1-token context has no prefix to split: prefill the
-        # token itself and take the prefill's greedy argmax as the first
-        # output instead.
-        if not prefix:
-            prefix, last = ctx, None
-        need_len = len(prefix)
-        headroom = 1 if self.running else 0
-        if (not self.cache.can_admit(need_len)
-                or self.cache.available_page_count
-                < self.cache.pages_needed(need_len) + headroom):
-            return False
-        if len(ctx) > self.max_seq_len:
-            raise ValueError(
-                f"request {req.req_id}: context {len(ctx)} exceeds "
-                f"max_seq_len {self.max_seq_len}")
-        slot = self.cache.admit(need_len)
-        self._note_admit(req, slot, 0)
-        # bucket rounding must not push the prefill shape past the model's
-        # position table (max_seq_len need not be a bucket multiple)
-        padded = min(self._bucket(need_len), self.config.max_seq_len)
-        ids = np.zeros((1, padded), np.int32)
-        ids[0, :need_len] = prefix
-        next_ids, _, kp, vp = self._prefill(
-            self.params, jnp.asarray(ids),
-            jnp.asarray([need_len], jnp.int32),
-            self.cache.k_pages, self.cache.v_pages,
-            self.cache.slot_pages(slot)[None])
-        self.cache.update_pages(kp, vp)
-        if last is None:
-            # 1-token context: the prefill's greedy token IS the first
-            # generated token; decode continues from it
-            tok = int(np.asarray(next_ids)[0])
-            req.output_ids.append(tok)
-            self._m_tokens.inc()
-            if req.first_token_time is None:
-                self._note_first_token(req)
-            self._next_token[slot] = tok
-        else:
-            # multi-token context (fresh prompt or preemption replay):
-            # the last context token enters the next decode step, which
-            # produces its not-yet-recorded successor
-            self._next_token[slot] = last
-        req.state = RUNNING
-        self.running[slot] = req
-        return True
-
-    def _admit_waiting_legacy(self) -> None:
-        while self.waiting and self.cache.free_slot_count:
-            req = self.waiting[0]
-            if self._finish_waiting_unservable(req):
-                continue
-            if not self._admit_one_legacy(req):
-                if (not self.running and self.cache.available_page_count
-                        == self.cache.num_pages):
-                    self.waiting.popleft()
-                    self._fail_never_admittable(
-                        req, self.cache.pages_needed(
-                            len(req._context_ids()) - 1))
-                    continue
-                break
-            self.waiting.popleft()
-
-    def _step_legacy(self) -> dict[int, list[int]]:
-        if self._deadlines_armed:
-            self._shed_expired()
-        self._retire_finished()
-        # admit/retire to fixpoint: a fresh prompt whose prefill token
-        # already satisfies done (budget 1, or prefill token == eos) must
-        # retire BEFORE the decode step — it would otherwise collect a
-        # second token past its contract — and its freed lane can admit
-        # the next waiting request within this same round
-        while True:
-            self._admit_waiting_legacy()
-            if not any(r.done for r in self.running.values()):
-                break
-            self._retire_finished()
-        if not self.running:
-            return {}
-        # growth: every running sequence needs room for one more token.
-        # sorted() snapshots the slots — a preemption further down this
-        # loop removes entries, and a freed slot must not re-enter the
-        # capacity path (it would allocate pages into a parked page table)
-        for slot in sorted(self.running):
-            if slot not in self.running:
-                continue
-            if self.cache.seq_len(slot) + 1 > self.max_seq_len:
-                # hit the length ceiling: stop the sequence NOW
-                req = self.running.pop(slot)
-                req.truncated = True
-                self.cache.free(slot)
-                self._finish(req)
-                continue
-            while not self.cache.ensure_capacity(
-                    slot, self.cache.seq_len(slot) + 1):
-                victim_is_self = (max(self.running,
-                                      key=lambda s: self.running[s].req_id)
-                                  == slot)
-                if victim_is_self and len(self.running) == 1:
-                    # round 17: requeue through the bounded retry path
-                    # (FAILS after max_step_retries) instead of poisoning
-                    # the predictor — same policy as the unified path
-                    self._requeue_one(slot, RuntimeError(
-                        f"slot {slot}: cannot grow to "
-                        f"{self.cache.seq_len(slot) + 1} tokens — page "
-                        "pool too small for this sequence"),
-                        code="pool_exhausted")
-                    break
-                self._preempt_youngest()
-                if slot not in self.running:  # preempted itself
-                    break
-        if not self.running:
-            # the growth loop requeued/retired every lane (round 17:
-            # pool_exhausted no longer raises): nothing to decode
-            return {}
-        ids = jnp.asarray(self._next_token)
-        with span("dispatch"):
-            next_ids, _, kp, vp = self._decode(
-                self.params, ids, self.cache.seq_lens_device(),
-                self.cache.k_pages, self.cache.v_pages,
-                self.cache.page_table_device())
-        self._mark_dispatch()
-        self.cache.update_pages(kp, vp)
-        self._m_steps.inc()
-        t_sync = monotonic()
-        out = np.asarray(next_ids)
-        self._m_sync_s.inc(monotonic() - t_sync)
-        self._did_sync = True
-        self._mark_drained()
-        produced = {}
-        for slot, req in self.running.items():
-            tok = int(out[slot])
-            req.output_ids.append(tok)
-            self._m_tokens.inc()
-            if req.first_token_time is None:
-                self._note_first_token(req)
-            self._next_token[slot] = tok
-            self.cache.advance(slot)
-            produced[req.req_id] = [tok]
-        return produced
-
     # -- the step ----------------------------------------------------------
 
     def step(self) -> dict[int, list[int]]:
         """One scheduler round. Returns ``{req_id: [tokens]}`` for the
         tokens produced this step, in emission order — a speculative
         decode lane can emit several (accepted drafts + bonus) in one
-        round; a unified round that only advanced prefill chunks
-        produces none. The async engine returns the tokens RECONCILED by
-        this call (one step behind the dispatch; drain with
-        :meth:`flush`)."""
+        round; a round that only advanced prefill chunks produces none.
+        The async engine returns the tokens RECONCILED by this call (one
+        step behind the dispatch; drain with :meth:`flush`)."""
         t0 = monotonic()
         self._did_sync = False
         # the pool-squeeze seam ticks EVERY scheduler round (never
@@ -2367,9 +2138,7 @@ class ServingPredictor:
         # are exactly what blocks the next admission
         fault_point("pool", cache=self.cache)
         try:
-            if self.unified:
-                return self._step_unified()
-            return self._step_legacy()
+            return self._step_unified()
         finally:
             if self._did_sync:
                 # ONE hard sync per step()/flush() call no matter how

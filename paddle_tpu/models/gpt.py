@@ -97,24 +97,13 @@ class GPTConfig:
     # 0 keeps the round-12 n-gram proposer. Must be < num_layers (a full-
     # depth "draft" would just run the target twice — rejected loudly).
     spec_draft_layers: int = 0
-    # round-16 megakernel decode: route ALL-DECODE serving rounds through
-    # the fused per-layer Pallas megakernels (ops/pallas/mega_decode —
-    # LN1 -> QKV -> inline KV quantize -> ragged paged attention -> output
-    # GEMM -> residual+LN2 in ONE kernel, then the fused MLP kernel) with
-    # intermediate activations pinned in VMEM instead of the per-op chain
-    # XLA stitches through HBM. Mixed prefill+decode rounds keep the
-    # per-op unified step; greedy mega output matches the full-forward
-    # oracle token-for-token and mega=False is bit-identical to round 15.
-    # Serves mesh size 1/None, fp or int8 weights (int4 rejected loudly),
-    # fp or int8 KV.
-    mega_decode: bool = False
     # round-25 Mixture-of-Experts: moe_experts > 0 replaces every block's
     # dense MLP with a top-k routed expert FFN (models/moe.py — capacity
     # clamping drops overflow token-choices onto the residual, ragged
     # grouped Pallas GEMM streams only the routed experts' tiles).
-    # Serving runs through the per-op unified step (mega stays dense-only
-    # and rejects MoE loudly); training shards the expert stacks over the
-    # optional "ep" mesh axis (gpt_spmd + distributed/mesh.py).
+    # Serving runs through the unified step; training shards the expert
+    # stacks over the optional "ep" mesh axis (gpt_spmd +
+    # distributed/mesh.py).
     moe_experts: int = 0
     moe_top_k: int = 2
     moe_capacity_factor: float = 1.25
@@ -512,9 +501,9 @@ def serving_params(model):
     return params  # lm_head (when untied) rides _srv_nonlayer_weights
 
 
-# NOTE: _srv_ln/_srv_mlp/the prefill block are the serving-side pure
-# spellings of the decoder block — keep their math in lockstep with the
-# eager Layer classes above AND gpt_spmd's _layer_norm/_block_mlp (same
+# NOTE: _srv_ln/_srv_mlp are the serving-side pure spellings of the
+# decoder block — keep their math in lockstep with the eager Layer
+# classes above AND gpt_spmd's _layer_norm/_block_mlp (same
 # params-dict key schema); a drift in eps/gelu/LN-stat handling makes
 # generate() disagree with the trained model. The fp32 LN statistics here
 # are intentional (decode runs the weights' dtype, stats stay fp32).
@@ -756,204 +745,6 @@ def _kv_specs():
     return P(None, None, "mp", None, None), P(None, None, "mp", None)
 
 
-def build_prefill(config: GPTConfig, page_size: int,
-                  use_kernel: bool | None = None, mesh=None):
-    """One-jit prefill: forward the (right-padded) prompts, scatter each
-    slot's K/V into its pages, return the next-token ids + logits at each
-    prompt's last valid position.
-
-    Signature: ``fn(params, ids[b,s], lengths[b], k_pages, v_pages,
-    pages[b,pps]) -> (next_ids[b], logits[b,v], k_pages, v_pages)``.
-    Ragged prompts ride right-padding: causal masking keeps padded columns
-    out of every valid row's softmax, and the page scatter drops positions
-    past each length.
-
-    ``mesh`` (round 11): a ``Mesh(("mp",))`` shards the step — params per
-    :func:`serving_param_specs` (head-major qkv), pools on the head axis —
-    via ``shard_map``; attention/K-V writes run chip-local over each
-    chip's heads and only the row-parallel matmuls psum. The signature,
-    donation and trace-count contract are unchanged.
-    """
-    import jax
-    import jax.numpy as jnp
-
-    from ..inference.kv_cache import paged_write_prefill
-
-    cfg = config
-    if getattr(cfg, "moe_experts", 0):
-        raise ValueError(
-            "build_prefill predates the packed unified step and has no "
-            "MoE FFN path — serve moe_experts > 0 through "
-            "build_unified_step / ServingPredictor")
-    eps = cfg.layer_norm_eps
-    trace_count = [0]
-    mp, axis = _mesh_mp(mesh)
-    nh_l, hd = cfg.num_heads // mp, cfg.head_dim
-
-    def _prefill_inner(params, ids, lengths, k_pages, v_pages, pages):
-        b, s = ids.shape
-        x = (jnp.take(params["tok_emb"], ids, axis=0)
-             + params["pos_emb"][:s])
-
-        def block(x, p):
-            y = _srv_ln(x, p["ln1_g"], p["ln1_b"], eps)
-            qkv = _srv_mm(y, p["wqkv"], use_kernel) + p["bqkv"]
-            q, k, v = _split_qkv(qkv, nh_l, hd, head_major=mesh is not None)
-            s_ = jnp.einsum("bqnd,bknd->bnqk", q.astype(jnp.float32),
-                            k.astype(jnp.float32)) / math.sqrt(hd)
-            causal = jnp.tril(jnp.ones((s, s), bool))
-            s_ = jnp.where(causal[None, None], s_, -1e30)
-            a = jnp.einsum("bnqk,bknd->bqnd",
-                           jax.nn.softmax(s_, axis=-1),
-                           v.astype(jnp.float32)).astype(x.dtype)
-            x = x + _srv_psum(_srv_mm(a.reshape(b, s, nh_l * hd), p["wo"],
-                                      use_kernel), axis) + p["bo"]
-            x = x + _srv_mlp(p, _srv_ln(x, p["ln2_g"], p["ln2_b"], eps),
-                             use_kernel, axis)
-            return x, (k, v)
-
-        x, (ks, vs) = jax.lax.scan(block, x, params["layers"])
-        x = _srv_ln(x, params["lnf_g"], params["lnf_b"], eps)
-        h_last = x[jnp.arange(b), jnp.maximum(lengths - 1, 0)]
-        logits = _srv_logits(params, h_last).astype(jnp.float32)
-        next_ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-
-        # copy-on-prefill: scatter every slot's K/V into its pages.
-        # ks: [L, b, s, nh, hd] -> per (layer, slot) writes, vmapped over L
-        def write_all(pool, seqs):
-            for bi in range(b):  # b is static; unrolls into b scatters
-                pool = jax.vmap(
-                    paged_write_prefill, in_axes=(0, 0, None, None, None)
-                )(pool, seqs[:, bi], pages[bi], lengths[bi], page_size)
-            return pool
-
-        k_pages = write_all(k_pages, ks)
-        v_pages = write_all(v_pages, vs)
-        return next_ids, logits, k_pages, v_pages
-
-    def prefill(params, ids, lengths, k_pages, v_pages, pages):
-        trace_count[0] += 1
-        if mesh is not None:
-            from jax.sharding import PartitionSpec as P
-
-            kv_spec, _ = _kv_specs()
-            body = jax.shard_map(
-                _prefill_inner, mesh=mesh,
-                in_specs=(serving_param_specs(params), P(), P(), kv_spec,
-                          kv_spec, P()),
-                out_specs=(P(), P(), kv_spec, kv_spec),
-                check_vma=False)
-        else:
-            body = _prefill_inner
-        # MXU-native matmul precision (gpt_spmd.loss_fn convention): the
-        # framework-global "highest" would emulate bf16 serving matmuls
-        # multi-pass, 3-6x slower; attention scores stay explicit fp32
-        with jax.default_matmul_precision("default"):
-            return body(params, ids, lengths, k_pages, v_pages, pages)
-
-    # donate the pools like the decode step: every admission threads the
-    # full cache through this jit, and an un-donated scatter would copy it
-    jitted = jit32(prefill, donate_argnums=(3, 4))
-    # one executable per prompt-length bucket: the counter makes the
-    # bucketed-prefill compile count visible (bench_serve prefill_retraces)
-    jitted.trace_count = trace_count
-    return jitted
-
-
-def build_decode_step(config: GPTConfig, page_size: int,
-                      use_kernel: bool | None = None, mesh=None):
-    """The fixed-shape decode step, compiled once per (batch, cache
-    geometry): embed the incoming token, write its K/V into the pages,
-    paged-attend over every layer, emit the greedy next token.
-
-    Signature: ``fn(params, ids[b], lengths[b], k_pages, v_pages,
-    page_table[b,pps]) -> (next_ids[b], logits[b,v], k_pages, v_pages)``.
-    ``lengths`` counts tokens already cached per slot (0 = empty slot —
-    its lane computes masked garbage and writes nothing). Every array
-    argument keeps its shape step over step, so after the first call the
-    loop replays one compiled program — ``fn.trace_count[0]`` exposes the
-    trace count for the no-retrace gate.
-
-    ``mesh`` (round 11): shard over ``Mesh(("mp",))`` — the paged
-    attention kernel runs per chip over its own heads' pages (shard_map;
-    GSPMD never sees the pallas_call), psums only on the row-parallel
-    matmuls. Same signature/donation/trace contract.
-    """
-    import jax
-    import jax.numpy as jnp
-
-    from ..inference.kv_cache import paged_write_tokens
-    from ..ops.pallas.paged_attention import paged_attention
-
-    cfg = config
-    if getattr(cfg, "moe_experts", 0):
-        raise ValueError(
-            "build_decode_step predates the packed unified step and has "
-            "no MoE FFN path — serve moe_experts > 0 through "
-            "build_unified_step / ServingPredictor")
-    eps = cfg.layer_norm_eps
-    trace_count = [0]
-    mp, axis = _mesh_mp(mesh)
-    nh_l, hd = cfg.num_heads // mp, cfg.head_dim
-
-    def _step_inner(params, ids, lengths, k_pages, v_pages, page_table):
-        b = ids.shape[0]
-        active = lengths > 0
-        pos = jnp.where(active, lengths, -1)  # write position = current len
-        pos_emb_idx = jnp.clip(jnp.maximum(lengths, 0),
-                               0, params["pos_emb"].shape[0] - 1)
-        x = (jnp.take(params["tok_emb"], jnp.maximum(ids, 0), axis=0)
-             + params["pos_emb"][pos_emb_idx])          # [b, h]
-        ctx = jnp.where(active, lengths + 1, 0).astype(jnp.int32)
-
-        def block(x, layer):
-            p, kp, vp = layer
-            y = _srv_ln(x, p["ln1_g"], p["ln1_b"], eps)
-            qkv = _srv_mm(y, p["wqkv"], use_kernel) + p["bqkv"]
-            q, k_tok, v_tok = _split_qkv(qkv, nh_l, hd,
-                                         head_major=mesh is not None)
-            kp = paged_write_tokens(kp, k_tok, page_table, pos, page_size)
-            vp = paged_write_tokens(vp, v_tok, page_table, pos, page_size)
-            a = paged_attention(q, kp, vp, page_table, ctx,
-                                use_kernel=use_kernel)  # [b, nh_l, hd]
-            x = x + _srv_psum(_srv_mm(a.reshape(b, nh_l * hd), p["wo"],
-                                      use_kernel), axis) + p["bo"]
-            x = x + _srv_mlp(p, _srv_ln(x, p["ln2_g"], p["ln2_b"], eps),
-                             use_kernel, axis)
-            return x, (kp, vp)
-
-        x, (k_pages, v_pages) = jax.lax.scan(
-            block, x, (params["layers"], k_pages, v_pages))
-        x = _srv_ln(x, params["lnf_g"], params["lnf_b"], eps)
-        logits = _srv_logits(params, x).astype(jnp.float32)
-        next_ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return next_ids, logits, k_pages, v_pages
-
-    def step(params, ids, lengths, k_pages, v_pages, page_table):
-        trace_count[0] += 1
-        if mesh is not None:
-            from jax.sharding import PartitionSpec as P
-
-            kv_spec, _ = _kv_specs()
-            body = jax.shard_map(
-                _step_inner, mesh=mesh,
-                in_specs=(serving_param_specs(params), P(), P(), kv_spec,
-                          kv_spec, P()),
-                out_specs=(P(), P(), kv_spec, kv_spec),
-                check_vma=False)
-        else:
-            body = _step_inner
-        # MXU-native matmul precision — see build_prefill
-        with jax.default_matmul_precision("default"):
-            return body(params, ids, lengths, k_pages, v_pages, page_table)
-
-    # donate the page pools: the step rewrites them, and double-buffering
-    # the cache (the biggest serving allocation) would halve capacity
-    jitted = jit32(step, donate_argnums=(3, 4))
-    jitted.trace_count = trace_count
-    return jitted
-
-
 def _sample_epilogue(logits, keys, temperature, top_k, top_p):
     """Seeded temperature / top-k / top-p sampling, fused into the unified
     step (one [batch, vocab] sort + categorical — no host round-trip).
@@ -992,7 +783,7 @@ def _sample_epilogue(logits, keys, temperature, top_k, top_p):
 def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
                        use_kernel: bool | None = None,
                        kv_quant: bool = False, mesh=None,
-                       spec_k: int = 0, mega: bool = False):
+                       spec_k: int = 0):
     """ONE fixed-shape serving step for mixed ragged prefill + decode,
     driven by a per-step TOKEN BUDGET.
 
@@ -1026,8 +817,8 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
     slot's context (the scheduler knows). Copy-on-write lanes duplicate
     page ``cow_src -> cow_dst`` across every layer before any write
     (``cow_dst == num_pages`` is the no-op sentinel). Greedy lanes
-    (``temperature == 0``) take the same argmax as the round-7 decode
-    step, bit-identical; sampling lanes run the fused seeded epilogue.
+    (``temperature == 0``) take the argmax of the logits; sampling lanes
+    run the fused seeded epilogue.
     Every array argument keeps its shape step over step: one trace, one
     executable (``fn.trace_count[0]`` is the gate).
 
@@ -1138,38 +929,14 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
         fn(params, ...the same 11 arrays..., latent_pages, page_table, ...)
         -> (next_toks, logits, latent_pages, expert_rows)
 
-    ``kv_quant``, ``mesh``, ``spec_k`` and ``mega`` are not extended to the
-    latent cache and raise ``NotImplementedError`` here.
-
-    ``mega=True`` (round 16) builds the MEGAKERNELIZED step: the per-op
-    layer chain (qkv quant-GEMM -> ragged paged attention -> output GEMM
-    -> fused MLP, each a separate kernel with activations round-tripping
-    HBM between them) is replaced by the two persistent per-layer Pallas
-    kernels of ``ops/pallas/mega_decode`` — ``mega_attn_layer`` (LN1 +
-    QKV projection + inline int8 quantize of the new K/V rows + ragged
-    paged attention + output GEMM + residual + LN2, activations pinned in
-    VMEM) and ``mega_mlp`` (GEMM1 + gelu + GEMM2 + residual, the 4h
-    hidden state never materializing in HBM). The new K/V rows the
-    attention kernel emits (int8 payloads + scale rows on the quantized
-    path — quantized IN-KERNEL with the exact ``paged_write_packed_quant``
-    formula) scatter into the donated pools via
-    ``paged_write_packed(_prequant)``. Signature, donation, feedback,
-    spec verify rows and the one-trace-per-geometry contract are all
-    UNCHANGED. Round 22: the kernels serve the MIXED ragged-chunk
-    geometry (any 1..chunk rows per lane), so callers build mega at the
-    SAME ``(token_budget, chunk)`` geometry as the per-op step and route
-    EVERY round here — no prefill fallback, no second program. Under an
-    mp mesh the kernels run with ``fuse_epilogue=False`` (pre-psum
-    partials) and this builder completes ``psum -> bias -> residual ->
-    LN`` with the per-op spelling — the same two collectives per layer.
-    ``validate_mega_config`` rejects int4 weights at build time.
+    ``kv_quant``, ``mesh`` and ``spec_k`` are not extended to the latent
+    cache and raise ``NotImplementedError`` here.
     """
     import jax
     import jax.numpy as jnp
 
     from ..inference.kv_cache import (packed_write_plan, paged_copy_pages,
                                       paged_write_packed,
-                                      paged_write_packed_prequant,
                                       paged_write_packed_quant)
     from ..observability.tracing import step_scope
     from ..ops.pallas.paged_attention import (ragged_paged_attention,
@@ -1186,31 +953,16 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
     if latent:
         unsupported = [name for name, on in (
             ("kv_cache_dtype='int8'", kv_quant), ("mesh", mesh is not None),
-            ("spec_decode_k", spec_k), ("mega_decode", mega)) if on]
+            ("spec_decode_k", spec_k)) if on]
         if unsupported:
             raise NotImplementedError(
                 f"the latent (MLA) cache does not serve "
                 f"{', '.join(unsupported)} yet: its one pool has no scale "
-                "planes, no head axis to shard and no fused layer kernel")
+                "planes and no head axis to shard")
         from ..ops.pallas.mla_paged_attention import (
             TILE_DEFAULT, mla_ragged_paged_attention, tile_plan)
         from .deepseek_v2 import (absorb_query, latent_qkv, softmax_scale,
                                   unabsorb_output)
-    if mega:
-        from ..ops.pallas.mega_decode import (mega_attn_layer, mega_mlp,
-                                              validate_mega_config)
-
-        eps = cfg.layer_norm_eps
-        validate_mega_config(getattr(cfg, "weight_dtype", None),
-                             getattr(cfg, "weight_quant_group_size", -1),
-                             hd, mp,
-                             moe_experts=getattr(cfg, "moe_experts", 0))
-        # mp == 1: residual + LN2 / + b2 fuse INSIDE the kernels. mp > 1:
-        # the kernels emit pre-psum partials and the block completes the
-        # epilogue after the row-parallel psum — per-op spelling, same
-        # two collectives per layer
-        fuse_mega = mp == 1
-
     # argument layout (shared by the wrappers, shard_map specs and the
     # donation indices): params + 6 packed/lane arrays [+ spec_len] + the
     # 4 feedback arrays (feedback mask, prev_toks carry, emit_mask,
@@ -1247,7 +999,9 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
                 + (rep,) * (n_lead - 1) + pool_specs + (rep,) * 7,
                 out_specs=(rep,) * n_out_lead + pool_specs,
                 check_vma=False)
-        # MXU-native matmul precision — see build_prefill
+        # MXU-native matmul precision (gpt_spmd.loss_fn convention): the
+        # framework-global "highest" would emulate bf16 serving matmuls
+        # multi-pass, 3-6x slower; attention scores stay explicit fp32
         with jax.default_matmul_precision("default"):
             return body(*args)
 
@@ -1384,79 +1138,6 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
                 x = x + f
             return (x, pools), rows
 
-        def mega_block(carry, layer):
-            # the round-16 fused layer (round 22: ragged chunks, any
-            # 1..chunk rows per lane): the whole attention side is ONE
-            # kernel over the [b, chunk] lane blocks (attention reads the
-            # pool at kv_lens and handles this step's rows in-register —
-            # same math as write-then-attend at ctx), the MLP side one
-            # more; only the emitted new K/V rows touch HBM between them
-            xb, (kp, vp, *scales) = carry
-            ks, vs = scales or (None, None)
-            p, li, _ = layer
-            h = xb.shape[-1]
-            # the fused kernels still take ONE layer's pool: the slice
-            # stays until mega_attn_layer lowers (ROADMAP.md D3) and can
-            # be given the layer index like the ragged kernel
-            res = mega_attn_layer(xb, p, kp[li], vp[li], page_table,
-                                  kv_lens, q_lens, eps=eps,
-                                  k_scales=None if ks is None else ks[li],
-                                  v_scales=None if vs is None else vs[li],
-                                  head_major=mesh is not None,
-                                  use_kernel=use_kernel,
-                                  fuse_epilogue=fuse_mega)
-            if fuse_mega:
-                if kv_quant:
-                    y2, s, k_new, v_new, k_sc, v_sc = res
-                else:
-                    y2, s, k_new, v_new = res
-            else:
-                # mp > 1: the kernel emitted this shard's pre-psum
-                # output-GEMM partial; finish the epilogue with the
-                # per-op spelling (one psum, then bias/residual/LN2)
-                if kv_quant:
-                    y_part, k_new, v_new, k_sc, v_sc = res
-                else:
-                    y_part, k_new, v_new = res
-                s = xb + _srv_psum(y_part, axis) + p["bo"]
-                y2 = _srv_ln(s, p["ln2_g"], p["ln2_b"], eps)
-            if kv_quant:
-                # the kernel quantized inline — scatter the int8 payloads
-                # and their scale rows (the packed gather reads each
-                # token's row out of its lane block)
-                kp, ks = paged_write_packed_prequant(
-                    kp, ks, k_new[slot_c, off_c], k_sc[slot_c, off_c],
-                    *dest, layer=li, plan=plan)
-                vp, vs = paged_write_packed_prequant(
-                    vp, vs, v_new[slot_c, off_c], v_sc[slot_c, off_c],
-                    *dest, layer=li, plan=plan)
-            else:
-                kp = paged_write_packed(kp, k_new[slot_c, off_c], *dest,
-                                        layer=li, plan=plan)
-                vp = paged_write_packed(vp, v_new[slot_c, off_c], *dest,
-                                        layer=li, plan=plan)
-            if fuse_mega:
-                out = mega_mlp(y2.reshape(b * chunk, h),
-                               s.reshape(b * chunk, h), p,
-                               use_kernel=use_kernel, chunk=chunk)
-            else:
-                part = mega_mlp(y2.reshape(b * chunk, h), None, p,
-                                use_kernel=use_kernel,
-                                fuse_epilogue=False, chunk=chunk)
-                out = (s.reshape(b * chunk, h)
-                       + (_srv_psum(part, axis) + p["b2"]))
-            return (out.reshape(b, chunk, h),
-                    (kp, vp, ks, vs) if kv_quant else (kp, vp)), None
-
-        if mega:
-            # lane-block layout for the fused layers: packed tokens
-            # scatter into their [b, chunk] rows once, stay blocked
-            # through every layer, and gather back for the epilogue
-            x0 = jnp.zeros((b, chunk, x.shape[-1]), x.dtype
-                           ).at[scatter_b, off_c].set(x, mode="drop")
-            body = mega_block
-        else:
-            x0, body = x, block
         # the stacks the model's layers come in, in order: one for a uniform
         # model, the leading dense layers' and then the routed layers' for
         # one whose stack is not uniform (models/deepseek_v2.py). Each is
@@ -1466,7 +1147,7 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
                   if g in params]
         # the scans are scoped, so their own slicing of the stacked weights
         # falls under "layers" alone
-        carry, first, expert_rows = (x0, pools), 0, None
+        carry, first, expert_rows = (x, pools), 0, None
         with step_scope("layers"):
             for stack in groups:
                 n = jax.tree.leaves(stack)[0].shape[0]
@@ -1475,7 +1156,7 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
                 whole = {k: stack[k] for k in STACKED_BY_INDEX if k in stack}
                 within = jnp.arange(n, dtype=jnp.int32)
                 carry, rows = jax.lax.scan(
-                    functools.partial(body, whole=whole) if whole else body,
+                    functools.partial(block, whole=whole) if whole else block,
                     carry,
                     ({k: v for k, v in stack.items() if k not in whole},
                      first + within, within))
@@ -1485,8 +1166,6 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
                     # the layers in which it received any
                     expert_rows = rows.sum(axis=0)
         x, pools = carry
-        if mega:
-            x = x[slot_c, off_c]                     # back to packed [t]
         if spec_k:
             # -- speculative verify + fused accept epilogue --------------
             # rows last_idx .. last_idx+spec_k are the lane's verify rows
@@ -1657,35 +1336,21 @@ def _cfg_key(config: GPTConfig):
                  for f in dataclasses.fields(config))
 
 
-def _serving_fns(config: GPTConfig, page_size: int, use_kernel, mesh=None):
-    from ..distributed.mesh import mesh_signature
-
-    return _jit_cache_get(
-        ("legacy", _cfg_key(config), page_size, use_kernel,
-         mesh_signature(mesh)),
-        lambda: (build_prefill(config, page_size,
-                               use_kernel=use_kernel, mesh=mesh),
-                 build_decode_step(config, page_size,
-                                   use_kernel=use_kernel, mesh=mesh)))
-
-
 def _unified_fn(config: GPTConfig, page_size: int, chunk: int, use_kernel,
-                kv_quant=False, mesh=None, spec_k=0, mega=False):
+                kv_quant=False, mesh=None, spec_k=0):
     # the mesh SIGNATURE keys the cache (satellite of round 11): two mesh
     # sizes get two entries — neither collides with nor retraces the other.
     # spec_k is build GEOMETRY (the [b, k+1] output): two k values get two
     # executables, each compiled once; adaptive per-request k never keys.
-    # mega (round 16) keys too: the megakernelized decode build and the
-    # per-op build coexist — the scheduler routes rounds between them
     from ..distributed.mesh import mesh_signature
 
     return _jit_cache_get(
         ("unified", _cfg_key(config), page_size, chunk, use_kernel,
-         kv_quant, mesh_signature(mesh), spec_k, mega),
+         kv_quant, mesh_signature(mesh), spec_k),
         lambda: build_unified_step(config, page_size, chunk,
                                    use_kernel=use_kernel,
                                    kv_quant=kv_quant, mesh=mesh,
-                                   spec_k=spec_k, mega=mega))
+                                   spec_k=spec_k))
 
 
 # ---------------------------------------------------------------------------
@@ -1718,14 +1383,9 @@ def draft_config(config: GPTConfig, draft_layers: int) -> GPTConfig:
             f"spec_draft_layers {draft_layers} must be < num_layers "
             f"{config.num_layers} (a full-depth draft would run the "
             "target twice per token instead of a cheap proposer)")
-    # the draft stack serves plain decode only: no nested speculation.
-    # mega_decode clears here because the draft jits pick their kernel
-    # family EXPLICITLY — build_draft_step stays per-op (catch-up
-    # geometry), build_draft_chain takes a ``mega`` flag (round 22: the
-    # fused k-step chain runs the mega blocks when the parent does)
+    # the draft stack serves plain decode only: no nested speculation
     return dataclasses.replace(config, num_layers=draft_layers,
-                               spec_decode_k=0, spec_draft_layers=0,
-                               mega_decode=False)
+                               spec_decode_k=0, spec_draft_layers=0)
 
 
 def draft_serving_params(params, draft_layers: int):
@@ -1757,17 +1417,15 @@ def build_draft_step(config: GPTConfig, draft_layers: int, page_size: int,
 
 def build_draft_chain(config: GPTConfig, draft_layers: int, page_size: int,
                       k: int, use_kernel=None, kv_quant: bool = False,
-                      mesh=None, mega: bool = False):
+                      mesh=None):
     """The WHOLE k-step draft proposal chain as ONE jit (round 22).
 
     The round-19 engine launched the chunk-1 draft step k times per
     round, chaining tokens through the device feedback carry — k
     dispatches, k host pack loops. This builder rolls the chain into a
     single program: a ``lax.scan`` over the k chain steps, each step the
-    truncated stack at chunk-1 geometry (per-op blocks, or the round-16
-    mega blocks when ``mega=True`` — one persistent kernel pair per
-    layer per step, device-chained), so a speculative round costs ONE
-    draft dispatch + ONE verify dispatch.
+    truncated stack at chunk-1 geometry, device-chained, so a speculative
+    round costs ONE draft dispatch + ONE verify dispatch.
 
     Signature::
 
@@ -1791,7 +1449,6 @@ def build_draft_chain(config: GPTConfig, draft_layers: int, page_size: int,
     import jax.numpy as jnp
 
     from ..inference.kv_cache import (paged_write_packed,
-                                      paged_write_packed_prequant,
                                       paged_write_packed_quant)
     from ..ops.pallas.paged_attention import ragged_paged_attention
 
@@ -1803,16 +1460,6 @@ def build_draft_chain(config: GPTConfig, draft_layers: int, page_size: int,
     k = int(k)
     if k < 1:
         raise ValueError(f"draft chain length k must be >= 1, got {k}")
-    mega = bool(mega)
-    if mega:
-        from ..ops.pallas.mega_decode import (mega_attn_layer, mega_mlp,
-                                              validate_mega_config)
-
-        validate_mega_config(getattr(cfg, "weight_dtype", None),
-                             getattr(cfg, "weight_quant_group_size", -1),
-                             hd, mp,
-                             moe_experts=getattr(cfg, "moe_experts", 0))
-        fuse_mega = mp == 1
     n_pool = 4 if kv_quant else 2
 
     def _chain_inner(params, first_toks, steps, kv_lens0, *rest):
@@ -1881,76 +1528,15 @@ def build_draft_chain(config: GPTConfig, draft_layers: int, page_size: int,
                                  use_kernel, axis, valid=valid)[0]
                 return x, ((kp, vp, ks, vs) if kv_quant else (kp, vp))
 
-            def mega_block(xb, layer):
-                # the fused layer at chunk-1 geometry (round 16 blocks,
-                # round-22 mp composition via fuse_epilogue)
-                if kv_quant:
-                    p, kp, vp, ks, vs = layer
-                else:
-                    p, kp, vp = layer
-                    ks = vs = None
-                h = xb.shape[-1]
-                res = mega_attn_layer(xb, p, kp, vp, page_table, kv_lens,
-                                      q_lens, eps=eps, k_scales=ks,
-                                      v_scales=vs,
-                                      head_major=mesh is not None,
-                                      use_kernel=use_kernel,
-                                      fuse_epilogue=fuse_mega)
-                if fuse_mega:
-                    if kv_quant:
-                        y2, s, k_new, v_new, k_sc, v_sc = res
-                    else:
-                        y2, s, k_new, v_new = res
-                else:
-                    if kv_quant:
-                        y_part, k_new, v_new, k_sc, v_sc = res
-                    else:
-                        y_part, k_new, v_new = res
-                    s = xb + _srv_psum(y_part, axis) + p["bo"]
-                    y2 = _srv_ln(s, p["ln2_g"], p["ln2_b"], eps)
-                if kv_quant:
-                    kp, ks = paged_write_packed_prequant(
-                        kp, ks, k_new[slot_c, 0], k_sc[slot_c, 0],
-                        page_table, tok_slot, tok_pos, page_size)
-                    vp, vs = paged_write_packed_prequant(
-                        vp, vs, v_new[slot_c, 0], v_sc[slot_c, 0],
-                        page_table, tok_slot, tok_pos, page_size)
-                else:
-                    kp = paged_write_packed(kp, k_new[slot_c, 0],
-                                            page_table, tok_slot, tok_pos,
-                                            page_size)
-                    vp = paged_write_packed(vp, v_new[slot_c, 0],
-                                            page_table, tok_slot, tok_pos,
-                                            page_size)
-                if fuse_mega:
-                    out = mega_mlp(y2.reshape(b, h), s.reshape(b, h), p,
-                                   use_kernel=use_kernel, chunk=1)
-                else:
-                    part = mega_mlp(y2.reshape(b, h), None, p,
-                                    use_kernel=use_kernel,
-                                    fuse_epilogue=False, chunk=1)
-                    out = (s.reshape(b, h)
-                           + (_srv_psum(part, axis) + p["b2"]))
-                return (out.reshape(b, 1, h),
-                        ((kp, vp, ks, vs) if kv_quant else (kp, vp)))
-
-            if mega:
-                carry0 = jnp.zeros((b, 1, x.shape[-1]), x.dtype
-                                   ).at[scatter_b, 0].set(x, mode="drop")
-                body = mega_block
-            else:
-                carry0, body = x, block
             if kv_quant:
                 x, (k_pages, v_pages, k_scales, v_scales) = jax.lax.scan(
-                    body, carry0, (params["layers"], k_pages, v_pages,
-                                   k_scales, v_scales))
+                    block, x, (params["layers"], k_pages, v_pages,
+                               k_scales, v_scales))
                 pools = (k_pages, v_pages, k_scales, v_scales)
             else:
                 x, (k_pages, v_pages) = jax.lax.scan(
-                    body, carry0, (params["layers"], k_pages, v_pages))
+                    block, x, (params["layers"], k_pages, v_pages))
                 pools = (k_pages, v_pages)
-            if mega:
-                x = x[slot_c, 0]
             x = _srv_ln(x, params["lnf_g"], params["lnf_b"], eps)
             logits = _srv_logits(params, x).astype(jnp.float32)
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -1987,20 +1573,18 @@ def build_draft_chain(config: GPTConfig, draft_layers: int, page_size: int,
 
 
 def _draft_chain_fn(config: GPTConfig, draft_layers: int, page_size: int,
-                    k: int, use_kernel, kv_quant=False, mesh=None,
-                    mega=False):
+                    k: int, use_kernel, kv_quant=False, mesh=None):
     """Process-wide jit cache for :func:`build_draft_chain` (same policy
     as ``_unified_fn``: every predictor with the same draft geometry
-    replays one executable; ``k`` and ``mega`` are build geometry)."""
+    replays one executable; ``k`` is build geometry)."""
     from ..distributed.mesh import mesh_signature
 
     return _jit_cache_get(
         ("draft_chain", _cfg_key(draft_config(config, draft_layers)),
-         page_size, k, use_kernel, kv_quant, mesh_signature(mesh), mega),
+         page_size, k, use_kernel, kv_quant, mesh_signature(mesh)),
         lambda: build_draft_chain(config, draft_layers, page_size, k,
                                   use_kernel=use_kernel,
-                                  kv_quant=kv_quant, mesh=mesh,
-                                  mega=mega))
+                                  kv_quant=kv_quant, mesh=mesh))
 
 
 def generate_paged(model, input_ids, max_new_tokens=20, *, page_size=None,
@@ -2014,9 +1598,9 @@ def generate_paged(model, input_ids, max_new_tokens=20, *, page_size=None,
     ``chunk``-token ragged chunks (autotuned default), then every decode
     token replays the SAME fixed-shape program — no per-bucket prefill
     executables, no retrace after warmup. Greedy (``temperature == 0``,
-    the default) is bit-identical to the round-7 two-jit path and the
-    full-forward oracle. ``temperature > 0`` runs the fused seeded
-    temperature/top-k/top-p epilogue (``seed`` makes it reproducible).
+    the default) matches the full-forward oracle token for token.
+    ``temperature > 0`` runs the fused seeded temperature/top-k/top-p
+    epilogue (``seed`` makes it reproducible).
     With ``eos_token_id``, a row that stops early frees its cache pages,
     its lane goes inert, and its remaining columns pad with the eos id.
 
@@ -2108,14 +1692,8 @@ def generate_paged(model, input_ids, max_new_tokens=20, *, page_size=None,
         from ..inference.draft import DraftProposer
 
         proposers = [DraftProposer(spec_k) for _ in range(b)]
-    # round 22: with mega_decode on, the ONE unified program IS the
-    # megakernelized build — the fused kernels serve the mixed ragged-
-    # chunk geometry (any 1..chunk rows per lane), so prefill chunks and
-    # decode rounds alike run the same fixed-shape mega program (the
-    # round-16 per-op fallback + round-content router are gone)
     step = _unified_fn(cfg, mgr.page_size, chunk, use_kernel,
-                       kv_quant=kv_quant, mesh=mesh, spec_k=spec_k,
-                       mega=bool(getattr(cfg, "mega_decode", False)))
+                       kv_quant=kv_quant, mesh=mesh, spec_k=spec_k)
     traces_at_entry = step.trace_count[0]
     # token budget: every row can feed a full chunk each round (generate
     # drives all rows in lockstep; the budget-packed scheduler lives in
@@ -2261,8 +1839,7 @@ def generate_paged(model, input_ids, max_new_tokens=20, *, page_size=None,
                 if len(outs[i]) >= max_new_tokens:
                     done[i] = True
     # traces THIS call added: 1 on a cold shape, 0 when the cached jit
-    # already compiled it — never per-token (the no-retrace gate). With
-    # mega_decode on, the mega build IS the one program (round 22)
+    # already compiled it — never per-token (the no-retrace gate)
     generate_paged.last_decode_trace_count = (step.trace_count[0]
                                               - traces_at_entry)
     # rows that stopped early (eos) pad with the eos id, as before

@@ -4,7 +4,7 @@ The round-5 attribution (PERF_760M_r5_pre.json + mlp_roofline.py) showed the
 flagship step's MLP branch carries ~1.3 ms/layer of elementwise overhead
 (LN + gelu + residual HBM round-trips) over its pure-GEMM content — traffic
 XLA does not fully fuse into the matmul epilogues. These kernels fuse the
-ops XLA leaves unfused (the MPK "mega-kernelizing" lever):
+ops XLA leaves unfused (the kernel-fusion lever of MPK, PAPERS.md):
 
 - :func:`fused_layer_norm` — single-pass LayerNorm over the last axis:
   mean/var/normalize/scale/shift in ONE kernel, fp32 statistics regardless

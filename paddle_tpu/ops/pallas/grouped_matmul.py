@@ -319,7 +319,7 @@ def _blocks_for(e, m, k, n, bits, group_size, dtype):
     row occupancy). Without a tuned entry, float weights get tiles from
     the shape: a row tile that holds a mean group (so an expert's weights
     stream once, not once per 32 rows) and the whole contraction in one k
-    step where a ``[k, bn]`` weight tile stays near a megabyte."""
+    step where a ``[k, bn]`` weight tile stays near 1 MiB."""
     hit = _atc.lookup(_sig(e, k, n, bits, group_size, dtype))
     if hit and len(hit) == 3:
         pm, pn, pk = hit

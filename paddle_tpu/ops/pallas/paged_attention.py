@@ -1,55 +1,40 @@
-"""Paged decode attention — Pallas TPU kernel over a block-paged KV cache.
+"""Paged attention — the Pallas TPU kernel over a block-paged KV cache.
 
-The serving-side sibling of ``flash_attention.py``: one query token per
-sequence attends over that sequence's K/V prefix, which lives in a POOL of
+The serving-side sibling of ``flash_attention.py``: a sequence's query
+tokens attend over that sequence's K/V prefix, which lives in a POOL of
 fixed-size pages (``[num_pages, kv_heads, page_size, head_dim]`` — head-major
 inside a page, so one (page, head) block is a contiguous ``[page_size,
 head_dim]`` tile that satisfies Mosaic's (8, 128) rule on a block's last
 two dims) indexed by a per-sequence page table — the
 vLLM/Ragged-Paged-Attention memory layout (arxiv 2604.15464) that lets a
 continuous-batching scheduler admit/evict sequences without copying or
-fragmenting the cache.
+fragmenting the cache. The page table and the ragged per-sequence lengths
+ride in scalar-prefetch SMEM, and the K/V BlockSpec index maps read them to
+DMA exactly the pages each sequence owns — the page-table indirection costs
+no gather/materialization.
 
-Kernel shape (TPU-idiomatic, following the flash kernel's conventions):
+ONE kernel, :func:`ragged_paged_attention` (Ragged Paged Attention, arxiv
+2604.15464): each sequence contributes 1..chunk query tokens per step
+(decode lanes feed 1, prefill chunks feed up to ``chunk``), causal within
+the chunk, online softmax across that sequence's pages. Query rows for one
+(sequence, kv-head) pair are laid out ``[chunk * group, head_dim]``
+(chunk-major, GQA group minor) so one MXU dot serves the whole chunk; the
+per-row causal limit is ``kv_start + row // group + 1``. The per-step chunk
+size is a trace-time constant autotuned on the shared cache
+(:func:`preferred_chunk_size` / :func:`autotune_chunk_size`). ``length ==
+0`` marks an empty slot (output rows zero) — the scheduler parks evicted
+slots that way. The decode op :func:`paged_attention` (one query token per
+sequence; the Paddle surface ``incubate.nn.functional.paged_attention``) is
+a shape adapter onto it: a chunk of one row.
 
-- grid ``(batch, kv_heads, pages_per_seq)``; the page table and the ragged
-  per-sequence lengths ride in scalar-prefetch SMEM, and the K/V BlockSpec
-  index maps read them to DMA exactly the pages each sequence owns —
-  the page-table indirection costs no gather/materialization, and Pallas's
-  grid pipeline double-buffers the page fetches automatically.
-- GQA: q is viewed as ``[batch, kv_heads, group, head_dim]``; each program
-  computes all ``group`` q-heads sharing one kv head (group padded to >= 8
-  rows so the dot rides the MXU sublane tiling).
-- online softmax across pages: m/l and the running (normalized) output are
-  carried in outputs whose index maps ignore the page grid dim, so Mosaic
-  keeps them VMEM-resident across the inner steps (same revisiting pattern
-  as the flash backward's dq accumulator).
-- ragged occupancy: a sequence's page loop is masked by its length; pages
-  past the last valid one skip compute entirely (``pl.when``) and their DMA
-  is clamped onto the last valid page. ``length == 0`` marks an empty slot
-  (output rows zero) — the scheduler parks evicted slots that way.
-
-Decode is inference-only: no VJP (the op registers as non-differentiable).
+Inference-only: no VJP (the ops register as non-differentiable).
 Interpret-capable on CPU like the other Pallas kernels; the jnp
-gather-based :func:`paged_attention_reference` is both the numerical oracle
-and the non-TPU fallback. Page-size autotune rides the shared
-``autotune_cache`` (the page size IS the kernel's kv block size, fixed at
-cache construction — see :func:`autotune_page_size`).
+gather-based references are both the numerical oracles and the non-TPU
+fallback. Page-size autotune rides the shared ``autotune_cache`` (the page
+size IS the kernel's kv block size, fixed at cache construction — see
+:func:`autotune_page_size`).
 
-Round 9 adds the RAGGED sibling :func:`ragged_paged_attention` — the
-unified-step kernel (Ragged Paged Attention, arxiv 2604.15464): each
-sequence contributes 1..chunk query tokens per step (decode lanes feed 1,
-prefill chunks feed up to ``chunk``), causal within the chunk, online
-softmax across that sequence's pages. Query rows for one (sequence,
-kv-head) pair are laid out ``[chunk * group, head_dim]`` (chunk-major,
-GQA group minor) so one MXU dot serves the whole chunk; the per-row causal
-limit is ``kv_start + row // group + 1``. The per-step chunk size is a
-trace-time constant autotuned on the shared cache
-(:func:`preferred_chunk_size` / :func:`autotune_chunk_size`).
-
-The ragged kernel's grid (PR 29) pays for keys, not for page slots. The
-decode kernel above still visits every (sequence, head, page slot); the
-ragged one does not:
+The kernel's grid (PR 29) pays for keys, not for page slots:
 
 - one grid step serves ALL local KV heads of a lane (a page's block for
   every head is one contiguous ``[kv_heads, page_size, head_dim]`` tile;
@@ -121,96 +106,6 @@ def _dotf32(a, b, dims):
 
 
 # ---------------------------------------------------------------------------
-# kernel
-# ---------------------------------------------------------------------------
-
-
-def _decode_kernel(lens_ref, pt_ref, q_ref, k_ref, v_ref,
-                   o_ref, m_ref, l_ref, *, page_size, scale):
-    b = pl.program_id(0)
-    j = pl.program_id(2)
-    length = lens_ref[b]
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    @pl.when(j * page_size < length)
-    def _accumulate():
-        q = q_ref[...]           # [G8, d] input dtype (MXU wants bf16)
-        k = k_ref[...]           # [page_size, d] (None block dims dropped)
-        v = v_ref[...]
-        s = _dotf32(q, k, ((1,), (1,))) * scale          # [G8, ps] f32
-        col = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        s = jnp.where(col < length, s, NEG_INF)
-        m_prev = m_ref[...]                               # [G8, 1]
-        l_prev = l_ref[...]
-        m_next = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_next)
-        p = jnp.exp(s - m_next)
-        l_next = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        l_safe = jnp.where(l_next == 0.0, 1.0, l_next)
-        # running NORMALIZED output (jax paged-attention kernel recurrence):
-        # no final rescale pass needed after the last page
-        pv = _dotf32(p.astype(v.dtype), v, ((1,), (0,)))  # [G8, d]
-        o_ref[...] = ((o_ref[...] * (l_prev * alpha) + pv) / l_safe
-                      ).astype(o_ref.dtype)
-        m_ref[...] = m_next
-        l_ref[...] = l_next
-
-
-def _kernel_impl(q4, k_pages, v_pages, page_table, lengths, scale):
-    """q4: [b, kv_heads, G8, d] (group padded); returns [b, kv_heads, G8, d]
-    fp32."""
-    b, hkv, g8, d = q4.shape
-    num_pages, page_size = k_pages.shape[0], k_pages.shape[2]
-    pps = page_table.shape[1]
-    grid = (b, hkv, pps)
-
-    def kv_imap(bi, h, j, lens_ref, pt_ref):
-        # pages past the sequence's last valid one re-fetch the last valid
-        # page (their compute is skipped); empty slots / unallocated (-1)
-        # entries clamp to page 0. All-int32 arithmetic: weak python-int
-        # constants would promote to i64 under the framework's x64 mode.
-        ps = jnp.int32(page_size)
-        last = jnp.maximum(
-            jax.lax.div(lens_ref[bi] + ps - jnp.int32(1), ps) - jnp.int32(1),
-            jnp.int32(0))
-        page = pt_ref[bi, jnp.minimum(jnp.int32(j), last)]
-        return (jnp.clip(page, 0, num_pages - 1), h, 0, 0)
-
-    q_spec = pl.BlockSpec((None, None, g8, d), lambda bi, h, j, *_: (bi, h, 0, 0))
-    kv_spec = pl.BlockSpec((None, None, page_size, d), kv_imap)
-    o_spec = pl.BlockSpec((None, None, g8, d), lambda bi, h, j, *_: (bi, h, 0, 0))
-    ml_spec = pl.BlockSpec((None, None, g8, 1), lambda bi, h, j, *_: (bi, h, 0, 0))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=[q_spec, kv_spec, kv_spec],
-        out_specs=[o_spec, ml_spec, ml_spec],
-    )
-    out_shape = [
-        jax.ShapeDtypeStruct((b, hkv, g8, d), jnp.float32),
-        jax.ShapeDtypeStruct((b, hkv, g8, 1), jnp.float32),
-        jax.ShapeDtypeStruct((b, hkv, g8, 1), jnp.float32),
-    ]
-    kern = functools.partial(_decode_kernel, page_size=page_size, scale=scale)
-    with _atc.x64_off():
-        out, _, _ = pl.pallas_call(
-            kern, grid_spec=grid_spec, out_shape=out_shape,
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary", "arbitrary")),
-            interpret=_interpret(),
-        )(lengths.astype(jnp.int32), page_table.astype(jnp.int32),
-          q4, k_pages, v_pages)
-    return out
-
-
-# ---------------------------------------------------------------------------
 # jnp gather-based reference (oracle + non-TPU fallback + bench baseline)
 # ---------------------------------------------------------------------------
 
@@ -273,7 +168,8 @@ def use_kernel_default() -> bool:
 
 def paged_attention(q, k_pages, v_pages, page_table, lengths, scale=None,
                     use_kernel: bool | None = None):
-    """Decode attention over the paged KV cache.
+    """Decode attention over the paged KV cache: one query token per
+    sequence, as a one-row chunk of :func:`ragged_paged_attention`.
 
     ``use_kernel``: None = Pallas kernel on TPU, jnp reference elsewhere;
     True forces the kernel (interpret mode off-TPU — CPU tests); False
@@ -291,17 +187,12 @@ def paged_attention(q, k_pages, v_pages, page_table, lengths, scale=None,
     if not use_kernel:
         return paged_attention_reference(q, k_pages, v_pages, page_table,
                                          lengths, scale=scale)
-    group = hq // hkv
-    # pad the GQA group to >= 8 rows (MXU sublane tile); padded q rows are
-    # zeros — they compute garbage that the final slice drops
-    g8 = max(8, ((group + 7) // 8) * 8)
-    q4 = q.reshape(b, hkv, group, d)
-    if g8 != group:
-        q4 = jnp.pad(q4, ((0, 0), (0, 0), (0, g8 - group), (0, 0)))
-    out = _kernel_impl(q4, k_pages, v_pages, page_table, lengths,
-                       float(scale))
-    out = out[:, :, :group, :].reshape(b, hq, d)
-    return out.astype(q.dtype)
+    # a chunk of ONE query row a lane; an empty slot feeds none
+    lengths = lengths.astype(jnp.int32)
+    out = ragged_paged_attention(q[:, None], k_pages, v_pages, page_table,
+                                 lengths, (lengths > 0).astype(jnp.int32),
+                                 scale=scale, use_kernel=True)
+    return out[:, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -691,7 +582,7 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, kv_lens, q_lens,
     naming the layer to read: the kernel's block index maps lead with it, so
     no layer's pool is ever sliced out of the stack (151 MB a layer and pool
     at the 590M deployment). Without ``layer`` the pools are one layer's,
-    4-D, as the decode step, the draft chain and the autotuners pass them.
+    4-D, as the decode op, the draft chain and the autotuners pass them.
     """
     b, c, hq, d = q.shape
     hkv = k_pages.shape[-3]

@@ -67,7 +67,7 @@ class TestFindingsCore:
         from paddle_tpu.analysis import pass_of_fingerprint
 
         p = str(tmp_path / "baseline.json")
-        trace_fp = "JX005::serving-decode::arg3"
+        trace_fp = "JX005::serving-unified-step::arg11"
         src = Finding(rule="AL001", target="x.py", detail="f:key",
                       message="m")
         assert pass_of_fingerprint(trace_fp) == "trace"
@@ -87,7 +87,7 @@ class TestFindingsCore:
 
         p = tmp_path / "baseline.json"
         p.write_text(json.dumps(
-            {"findings": ["JX005::serving-decode::arg3"]}))
+            {"findings": ["JX005::serving-unified-step::arg11"]}))
         monkeypatch.setattr(fmod, "BASELINE_PATH", str(p))
         rc = cli.main(["--passes", "bench", "--json"])
         out = json.loads(capsys.readouterr().out)
@@ -837,16 +837,9 @@ class TestHazardRegressions:
 
         assert analyze_serving_unified() == []
 
-    def test_serving_jits_donate_consumed_buffers(self):
-        """The decode/prefill page-pool donation must keep aliasing outputs
-        (JX005 clean) — a silently wasted donation doubles cache memory."""
-        from paddle_tpu.analysis.targets import analyze_serving
-
-        assert [f for f in analyze_serving() if f.rule == "JX005"] == []
-
     def test_serving_quant_jits_are_clean_and_donate(self):
-        """The round-10 quantized serving jits (int8-weight prefill/decode
-        + int8-weight/int8-KV unified step): jaxpr walk — incl. JX001,
+        """The round-10 quantized serving step (int8-weight/int8-KV
+        unified step): jaxpr walk — incl. JX001,
         so per-group scales can never widen the compute to f64 — and the
         donation audit of pools AND scale planes come back with ZERO
         findings (the baseline stays empty)."""
@@ -887,20 +880,6 @@ class TestHazardRegressions:
         from paddle_tpu.analysis.targets import analyze_serving_tiered
 
         assert analyze_serving_tiered() == []
-
-    def test_serving_mega_mixed_is_clean_and_donates(self):
-        """The round-22 ragged megakernel pair: the unified mega step at
-        the MIXED packed geometry (chunk > 1, ragged q_lens — a decode
-        lane and a prefill-chunk lane in one dispatch) and the single-
-        dispatch draft chain, fp + int8w/int8kv — jaxpr walk (JX001
-        scale audit at the ragged rows) and the JX005 donation audit at
-        each program's own shifted pool positions come back with ZERO
-        findings (the baseline stays empty). A chain that stopped
-        aliasing its draft pools would double draft-cache memory every
-        speculative round."""
-        from paddle_tpu.analysis.targets import analyze_serving_mega_mixed
-
-        assert analyze_serving_mega_mixed() == []
 
 
 # ---------------------------------------------------------------------------
@@ -1064,7 +1043,7 @@ class TestCostModel:
                     replicated_weight_bytes=16 * self.H * 4,
                     num_layers=self.L, kv_heads=2, head_dim=4,
                     kv_itemsize=4, kv_quantized=False, act_itemsize=4,
-                    mp=1, batch=2, avg_ctx=8.0, mega=False)
+                    mp=1, batch=2, avg_ctx=8.0)
         base.update(kw)
         return ServingGeometry(**base)
 
@@ -1076,7 +1055,6 @@ class TestCostModel:
         rep = cost_model.static_hbm_report(closed, 2, pools,
                                            batch=2, avg_ctx=8.0)
         assert rep["num_layers"] == self.L and rep["hidden"] == self.H
-        assert rep["mega"] is False
         # wb = (layer/1 + repl)/2; kv = 2 pools x L*ctx*heads*hd*4;
         # act = 2 roundtrips x L x 17h x 4
         assert rep["weight_bytes_per_token"] == (512 + 512) // 2
@@ -1092,7 +1070,7 @@ class TestCostModel:
                                         0.02, "t")
         assert fs == []
 
-    def test_jx007_fires_on_drift_layer_count_and_regime(self):
+    def test_jx007_fires_on_drift_and_layer_count(self):
         from paddle_tpu.analysis import cost_model
 
         closed, pools = self._toy()
@@ -1102,10 +1080,6 @@ class TestCostModel:
         details = {f.detail for f in fs}
         assert {"layer-scan-length", "hbm-drift"} <= details
         assert all(f.rule == "JX007" for f in fs)
-        # geometry claims the mega activation regime: carry layout says no
-        fs = cost_model.check_hbm_model(closed, 2, pools,
-                                        self._geom(mega=True), 0.02, "t")
-        assert "activation-regime" in {f.detail for f in fs}
 
     def test_jx007_underivable_without_a_layer_scan(self):
         import jax.numpy as jnp
@@ -1121,7 +1095,7 @@ class TestCostModel:
 
 
 # ---------------------------------------------------------------------------
-# JX008 — pallas VMEM footprints + mega residency (round 23)
+# JX008 — pallas VMEM footprints (round 23)
 # ---------------------------------------------------------------------------
 
 
@@ -1144,63 +1118,10 @@ class TestVmem:
         # in + out blocks (full array, 4 KiB each), double-buffered
         want = vmem.LIVE_BUFFERS * 2 * 8 * 128 * 4
         assert fp["vmem_bytes"] == want
-        assert vmem.check_vmem(closed, want, False, "t") == []
-        fs = vmem.check_vmem(closed, want - 1, False, "t")
+        assert vmem.check_vmem(closed, want, "t") == []
+        fs = vmem.check_vmem(closed, want - 1, "t")
         assert [f.rule for f in fs] == ["JX008"]
         assert fs[0].detail.startswith("vmem-budget:")
-
-    def _mega_scan(self, leak):
-        import jax.numpy as jnp
-        from jax import lax
-
-        from paddle_tpu.analysis.jaxpr_checks import trace_callable
-
-        b, chunk, h, L = 2, 2, 16, 2
-        stack1 = jnp.ones((L, h, 4 * h), jnp.float32)
-        stack2 = jnp.ones((L, 4 * h, h), jnp.float32)
-        x = jnp.ones((b, chunk, h), jnp.float32)
-
-        def step(x, stack1, stack2):
-            def body(c, ws):
-                w1, w2 = ws
-                if leak:
-                    hid = c.reshape(b * chunk, h) @ w1    # [t, 4h] in HBM
-                    out = (hid @ w2).reshape(b, chunk, h)
-                else:
-                    bias = w1[0].reshape(1, 4 * h)        # param plumbing
-                    out = c + bias.sum()
-                return out, ()
-
-            y, _ = lax.scan(body, x, (stack1, stack2))
-            return y
-
-        return trace_callable(step, x, stack1, stack2)
-
-    def test_jx008_mega_residency_flags_token_wide_4h_values(self):
-        from paddle_tpu.analysis import vmem
-
-        fs = vmem.check_vmem(self._mega_scan(leak=True), None, True, "t")
-        assert fs and all(f.rule == "JX008" for f in fs)
-        assert fs[0].detail.startswith("mega-hbm-residency:")
-
-    def test_jx008_mega_residency_ignores_param_plumbing(self):
-        """A (1, 4h) bias reshape and the [h, 4h] weight tiles are
-        HBM-resident by design — only token-axis 4h values are leaks."""
-        from paddle_tpu.analysis import vmem
-
-        assert vmem.check_vmem(self._mega_scan(leak=False),
-                               None, True, "t") == []
-
-    def test_jx008_mega_residency_needs_a_layer_scan(self):
-        import jax.numpy as jnp
-
-        from paddle_tpu.analysis import vmem
-        from paddle_tpu.analysis.jaxpr_checks import trace_callable
-
-        closed = trace_callable(lambda x: x * 2.0,
-                                jnp.ones((4,), jnp.float32))
-        fs = vmem.check_vmem(closed, None, True, "t")
-        assert [f.detail for f in fs] == ["no-layer-scan"]
 
 
 # ---------------------------------------------------------------------------
@@ -1410,19 +1331,15 @@ class TestRepoGate:
             assert rid in RULES, f"rule {rid} missing from the catalog"
 
     def test_acceptance_targets_are_cost_contracted(self):
-        """The round-23 acceptance names serving-quant and the mixed mega
-        churn explicitly: their steps must carry a REAL hbm-drift contract
-        (the clean-run halves live in the hazard-regression tests — the
-        analyze fns now run cost_certify inline)."""
+        """The round-23 acceptance names the serving steps explicitly:
+        they must carry a REAL hbm-drift contract (the clean-run halves
+        live in the hazard-regression tests — the analyze fns now run
+        cost_certify inline) and keep the kernel VMEM budget armed."""
         from paddle_tpu.analysis.contracts import CONTRACTS
 
-        for key in ("serving-quant-unified-step", "serving-mega-mixed-step",
-                    "serving-mega-mixed-quant-step"):
+        for key in ("serving-unified-step", "serving-quant-unified-step"):
             assert CONTRACTS[key].hbm_tolerance is not None, key
-        # and the mega contracts keep the structural VMEM claims armed
-        assert CONTRACTS["serving-mega-mixed-step"].mega_vmem_resident
-        assert (CONTRACTS["serving-mega-mixed-step"].vmem_budget_bytes
-                or 0) > 0
+            assert (CONTRACTS[key].vmem_budget_bytes or 0) > 0, key
 
     def test_repo_is_clean_against_baseline(self):
         """The CI gate: every pass over the real tree + flagship callables;

@@ -1,11 +1,16 @@
 """Round-12 n-gram / prompt-lookup draft proposer (inference/draft.py):
 lookup edge cases (empty/short contexts, most-recent-match preference,
 chained copying), determinism across preemption replay, and adaptive-k
-backoff monotonicity. Host-only — no model, no jit.
+backoff monotonicity: host-only — no model, no jit. Then the round-22
+single-dispatch draft chain (``models/gpt.py build_draft_chain``) against k
+single-step dispatches, on a tiny model in interpret mode.
 """
 import numpy as np
 import pytest
 
+import jax.numpy as jnp
+
+import paddle_tpu  # noqa: F401  (framework config: x64 off, cpu)
 from paddle_tpu.inference.draft import DraftProposer
 
 
@@ -144,3 +149,167 @@ def test_model_draft_proposer_shares_adaptive_k_surface():
     assert p.k == 0                          # EMA backoff, inherited
     assert p.propose([5, 6, 7], 3) == []     # backed off: no engine call
     assert len(eng.calls) == 1
+
+
+# -- round 22: the single-dispatch draft chain ------------------------------
+
+H, HD = 32, 8                 # 4 heads — tiny but MXU-shaped
+PAGE = 8
+
+
+def _pools(rng, num_pages, nh, kv_quant):
+    if kv_quant:
+        kq = jnp.asarray(rng.randint(-127, 128,
+                                     (num_pages, nh, PAGE, HD)), jnp.int8)
+        vq = jnp.asarray(rng.randint(-127, 128,
+                                     (num_pages, nh, PAGE, HD)), jnp.int8)
+        ks = jnp.asarray(np.abs(rng.randn(num_pages, nh, PAGE)) * 0.01
+                         + 1e-3, jnp.float32)
+        vs = jnp.asarray(np.abs(rng.randn(num_pages, nh, PAGE)) * 0.01
+                         + 1e-3, jnp.float32)
+        return kq, vq, ks, vs
+    kq = jnp.asarray(rng.randn(num_pages, nh, PAGE, HD), jnp.float32)
+    vq = jnp.asarray(rng.randn(num_pages, nh, PAGE, HD), jnp.float32)
+    return kq, vq, None, None
+
+
+VOCAB = 97
+
+
+def _draft_cfg_params(draft_layers=1):
+    """A tiny 2-layer target model's serving params, sliced to the
+    truncated draft stack — the chain runs the SAME weights the engine
+    would."""
+    import paddle_tpu as paddle
+    from paddle_tpu.models.gpt import (GPTConfig, GPTForCausalLM,
+                                       draft_serving_params, serving_params)
+
+    paddle.seed(11)
+    cfg = GPTConfig(vocab_size=VOCAB, hidden_size=H, num_layers=2,
+                    num_heads=H // HD, max_seq_len=64)
+    model = GPTForCausalLM(cfg)
+    model.eval()
+    return cfg, model, draft_serving_params(serving_params(model),
+                                            draft_layers)
+
+
+def _chain_geometry(rng, b=3, pps=2, kv_quant=False):
+    """Per-lane draft-pool state: a mid-context lane, a deeper lane, an
+    idle lane (steps 0) — page capacity pre-reserved for kv0 + k like the
+    engine does."""
+    nh = H // HD
+    # serving pools carry a leading LAYER axis (the chain's inner scan
+    # runs over it); the truncated draft stack has 1 layer
+    pools = tuple(None if x is None else x[None]
+                  for x in _pools(rng, b * pps, nh, kv_quant))
+    pt = np.arange(b * pps, dtype=np.int32).reshape(b, pps)
+    kv0 = np.array([5, 9, 0][:b], np.int32)
+    first = rng.randint(0, VOCAB, (b,)).astype(np.int32)
+    return pools, jnp.asarray(pt), kv0, first
+
+
+@pytest.mark.parametrize("k", [1, 2, 4])
+def test_draft_chain_bit_identical_to_per_step_chain(rng, k):
+    """THE round-22 draft-chain contract: the fused k-step chain (one
+    dispatch, device-side scan) is BIT-identical — drafts AND pool
+    writes — to k separate single-step dispatches chained through the
+    host, at ragged per-lane depths (one lane a step behind, one idle)."""
+    from paddle_tpu.models.gpt import build_draft_chain
+
+    cfg, _, dparams = _draft_cfg_params()
+    (kp0, vp0, _, _), pt, kv0, first = _chain_geometry(rng)
+    steps = np.array([k, max(k - 1, 1), 0], np.int32)
+    kp_np, vp_np = np.asarray(kp0), np.asarray(vp0)
+
+    fused = build_draft_chain(cfg, 1, PAGE, k)
+    res = fused(dparams, jnp.asarray(first), jnp.asarray(steps),
+                jnp.asarray(kv0), jnp.asarray(kp_np), jnp.asarray(vp_np),
+                pt)
+    drafts_fused = np.asarray(res[0])
+
+    single = build_draft_chain(cfg, 1, PAGE, 1)
+    kp, vp = jnp.asarray(kp_np), jnp.asarray(vp_np)
+    ids = np.asarray(first)
+    per_step = []
+    for j in range(k):
+        active = steps > j
+        r = single(dparams, jnp.asarray(ids),
+                   jnp.asarray(active.astype(np.int32)),
+                   jnp.asarray(kv0 + j), kp, vp, pt)
+        d = np.asarray(r[0])[:, 0]
+        per_step.append(np.where(active, d, 0))
+        ids = np.where(active, d, ids).astype(np.int32)
+        kp, vp = r[1], r[2]
+    np.testing.assert_array_equal(drafts_fused, np.stack(per_step, 1))
+    np.testing.assert_array_equal(np.asarray(res[1]), np.asarray(kp))
+    np.testing.assert_array_equal(np.asarray(res[2]), np.asarray(vp))
+    # the idle lane proposed nothing and wrote nothing
+    assert not drafts_fused[2].any()
+    lane2 = np.asarray(pt)[2]
+    np.testing.assert_array_equal(np.asarray(res[1])[0][lane2],
+                                  kp_np[0][lane2])
+
+
+def test_draft_chain_int8kv_payloads_bit_identical(rng):
+    """The int8-KV chain: fused vs per-step single dispatches — the
+    quantized payloads AND scale rows land bit-identically (both sides
+    share the paged_write_packed_quant formula)."""
+    from paddle_tpu.models.gpt import build_draft_chain
+
+    cfg, _, dparams = _draft_cfg_params()
+    (kp0, vp0, ks0, vs0), pt, kv0, first = _chain_geometry(rng,
+                                                           kv_quant=True)
+    steps = np.array([2, 2, 0], np.int32)
+    raw = tuple(np.asarray(x) for x in (kp0, vp0, ks0, vs0))
+
+    fused = build_draft_chain(cfg, 1, PAGE, 2, kv_quant=True)
+    res = fused(dparams, jnp.asarray(first), jnp.asarray(steps),
+                jnp.asarray(kv0), *(jnp.asarray(x) for x in raw), pt)
+
+    single = build_draft_chain(cfg, 1, PAGE, 1, kv_quant=True)
+    pools = tuple(jnp.asarray(x) for x in raw)
+    ids = np.asarray(first)
+    for j in range(2):
+        active = steps > j
+        r = single(dparams, jnp.asarray(ids),
+                   jnp.asarray(active.astype(np.int32)),
+                   jnp.asarray(kv0 + j), *pools, pt)
+        ids = np.where(active, np.asarray(r[0])[:, 0], ids).astype(np.int32)
+        pools = r[1:]
+    for got, want in zip(res[1:], pools):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert res[1].dtype == jnp.int8
+
+
+def test_draft_chain_preemption_replay_self_heals(rng):
+    """The engine-level self-heal (round 22, fused chain): after a
+    proposal round, a DIVERGED continuation (the target rejected mid-
+    draft) and a SHORTER context (preemption replay) must both roll the
+    draft KV back to the longest common fed prefix and propose exactly
+    what a fresh engine proposes — no commit protocol, one comparison."""
+    from paddle_tpu.inference.draft import ModelDraftEngine
+    from paddle_tpu.models.gpt import serving_params
+
+    cfg, model, _ = _draft_cfg_params()
+    params = serving_params(model)
+    kw = dict(page_size=PAGE, chunk=4, max_batch=2, max_seq_len=64,
+              max_k=3)
+    eng = ModelDraftEngine(cfg, params, 1, **kw)
+    ctx = rng.randint(0, VOCAB, (9,)).tolist()
+    d1 = eng.propose({0: (7, ctx, 3)})[0]
+    assert len(d1) == 3
+
+    # diverged continuation: the target accepted d1[0] then emitted its
+    # own token — the fed tail past the fork must be rolled back
+    ctx2 = ctx + [int(d1[0]), (int(d1[1]) + 1) % VOCAB]
+    got = eng.propose({0: (7, ctx2, 3)})[0]
+    want = ModelDraftEngine(cfg, params, 1, **kw).propose(
+        {0: (7, ctx2, 3)})[0]
+    assert got == want and len(got) == 3
+
+    # preemption replay: the request returns with a SHORTER context
+    ctx3 = ctx[:5]
+    got = eng.propose({0: (7, ctx3, 2)})[0]
+    want = ModelDraftEngine(cfg, params, 1, **kw).propose(
+        {0: (7, ctx3, 2)})[0]
+    assert got == want and len(got) == 2

@@ -337,8 +337,7 @@ def test_the_tolerance_fails_a_wrong_page(model, prompt):
 
 @pytest.mark.parametrize("option", [
     dict(kv_cache_dtype="int8"), dict(mesh=1), dict(spec_decode_k=2),
-    dict(mega_decode=True), dict(host_tier_bytes=1 << 20),
-    dict(unified=False), dict(draft_layers=1)],
+    dict(host_tier_bytes=1 << 20), dict(draft_layers=1)],
     ids=lambda o: next(iter(o)))
 def test_unsupported_options_raise_for_the_latent_cache(model, option):
     with pytest.raises(NotImplementedError, match="latent"):

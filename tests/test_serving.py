@@ -48,22 +48,32 @@ def _oracle_greedy(model, ids_np, max_new_tokens):
 # -- generate: golden parity + jit-shape policy -----------------------------
 
 
-def test_generate_matches_full_forward_oracle(rng):
+# ``chunk4``: a prompt fed in several 4-token chunks over 8-token pages
+_CHUNKS = pytest.mark.parametrize(
+    "geometry", [dict(), dict(page_size=8, chunk=4)],
+    ids=["default", "chunk4"])
+
+
+@_CHUNKS
+def test_generate_matches_full_forward_oracle(rng, geometry):
     model = _tiny_model()
     ids = rng.randint(0, TINY["vocab_size"], (2, 11)).astype(np.int64)
     want = _oracle_greedy(model, ids, 8)
-    got = model.generate(paddle.to_tensor(ids), max_new_tokens=8).numpy()
+    got = model.generate(paddle.to_tensor(ids), max_new_tokens=8,
+                         **geometry).numpy()
     np.testing.assert_array_equal(got, want)
 
 
-def test_generate_kernel_leg_matches_oracle(rng):
+@_CHUNKS
+def test_generate_kernel_leg_matches_oracle(rng, geometry):
     """Same golden with the Pallas kernel forced (interpret mode on CPU) —
     the acceptance-criteria path."""
     model = _tiny_model()
     ids = rng.randint(0, TINY["vocab_size"], (2, 5)).astype(np.int64)
     want = _oracle_greedy(model, ids, 6)
     got = model.generate(paddle.to_tensor(ids), max_new_tokens=6,
-                         use_kernel=True, page_size=8).numpy()
+                         use_kernel=True,
+                         **{"page_size": 8, **geometry}).numpy()
     np.testing.assert_array_equal(got, want)
 
 
@@ -260,30 +270,6 @@ def test_request_done_logic():
 # -- round 9: unified step, prefix caching, fused sampling ------------------
 
 
-def test_unified_vs_legacy_token_for_token(rng):
-    """THE equivalence gate: the unified ragged step must reproduce the
-    round-7 two-jit path token-for-token on the same workload (greedy),
-    so the legacy path can be deleted in a later PR without losing the
-    oracle. Mixed prompt lengths exercise chunked prefill + decode packing
-    in the same steps."""
-    model = _tiny_model()
-    prompts = [rng.randint(0, TINY["vocab_size"], (n,)).tolist()
-               for n in (3, 19, 7, 1, 12)]
-    legacy = ServingPredictor(model, max_batch=3, max_seq_len=48,
-                              page_size=8, unified=False)
-    unified = ServingPredictor(model, max_batch=3, max_seq_len=48,
-                               page_size=8, unified=True, chunk=8)
-    want = legacy.generate(prompts, max_new_tokens=6)
-    got = unified.generate(prompts, max_new_tokens=6)
-    for p, w, g in zip(prompts, want, got):
-        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
-    # the unified path used ONE executable for everything; the legacy path
-    # needed its decode jit plus one prefill executable per bucket
-    assert unified.decode_trace_count == 1
-    assert unified.prefill_trace_count == 0
-    assert legacy.prefill_trace_count >= 1
-
-
 def test_unified_prefix_cache_hits_preserve_tokens(rng):
     """A repeated prompt must serve from the prefix cache (hit rate up,
     prefill work skipped) and still emit exactly the same greedy tokens."""
@@ -470,7 +456,7 @@ def test_unified_ttft_recorded(rng):
 def test_bench_serve_smoke_schema():
     """bench_serve.py --smoke must run green on CPU and emit bench.py's
     one-line JSON schema with the round-9 serving fields (TTFT, prefix
-    hit rate, prefill/decode retrace gates), the round-10 quantized
+    hit rate, the retrace gate), the round-10 quantized
     A/B legs (fp vs int8-weights vs int8-weights+int8-KV) with the
     hbm-bytes-per-token accounting, the round-11 mesh scaling leg
     (mp=1 vs mp=N unified step) with per-chip throughput, and the
@@ -486,16 +472,14 @@ def test_bench_serve_smoke_schema():
         _bench_serve_smoke_once()
 
 
-_SMOKE_LEGS = ("legacy-two-jit,unified-step,unified-async,unified-obs,"
+_SMOKE_LEGS = ("unified-step,unified-async,unified-obs,"
                "unified-spmd,unified-spec-base,unified-spec-k4,"
                "unified-int8w,unified-int8w-int8kv")
 
 
 def _bench_serve_smoke_once():
     # round 16: the tier-1 smoke runs its gated subset through the
-    # --legs selector (the round-16 mega leg has its own gated test —
-    # test_bench_serve_mega_leg_gates — so the pair's churn is not paid
-    # twice here)
+    # --legs selector
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     proc = subprocess.run(
         [sys.executable, "bench_serve.py", "--smoke", "--steps=6",
@@ -505,7 +489,7 @@ def _bench_serve_smoke_once():
         env={**os.environ, "JAX_PLATFORMS": "cpu"})
     assert proc.returncode == 0, proc.stderr[-2000:]
     lines = [l for l in proc.stdout.strip().splitlines() if l.strip()]
-    assert len(lines) == 9, proc.stdout
+    assert len(lines) == 8, proc.stdout
     for line, want_leg in zip(lines, _SMOKE_LEGS.split(",")):
         rec = json.loads(line)
         assert "error" not in rec, rec
@@ -520,18 +504,12 @@ def _bench_serve_smoke_once():
         assert rec["decode_retraces"] == 1  # the no-retrace gate
         assert "vs_baseline" in rec and "prefix_hit_rate" in rec
         assert rec["hbm_bytes_per_token"] > 0
-        # round 23: every unified leg carries the jaxpr-derived static
-        # HBM model next to the analytic one and the two agree within
-        # the JX007 contract tolerance; the legacy two-jit leg has no
-        # single traced step, so the keys are absent there (presence is
-        # asserted so a silent derivation failure fails here, not just
-        # in the tpulint gate)
-        if want_leg == "legacy-two-jit":
-            assert "hbm_bytes_per_token_static" not in rec
-            assert "hbm_model_drift_frac" not in rec
-        else:
-            assert rec["hbm_bytes_per_token_static"] > 0
-            assert abs(rec["hbm_model_drift_frac"]) <= 0.02
+        # round 23: every leg carries the jaxpr-derived static HBM model
+        # next to the analytic one and the two agree within the JX007
+        # contract tolerance (presence is asserted so a silent derivation
+        # failure fails here, not just in the tpulint gate)
+        assert rec["hbm_bytes_per_token_static"] > 0
+        assert abs(rec["hbm_model_drift_frac"]) <= 0.02
         # round 11: every leg stamps its mesh geometry
         assert rec["mesh_shape"] == f"mp{rec['mesh_chips']}"
         assert rec["tokens_per_s_per_chip"] == pytest.approx(
@@ -548,9 +526,8 @@ def _bench_serve_smoke_once():
         assert tel["serving_requests_admitted"] > 0
         assert tel["serving_ttft_ms_count"] > 0
         assert tel["kv_pages_free"] >= 0
-    (legacy, unified, uasync, uobs, spmd, specb, speck, int8w,
+    (unified, uasync, uobs, spmd, specb, speck, int8w,
      int8kv) = (json.loads(l) for l in lines)
-    assert "[legacy-two-jit]" in legacy["metric"]
     assert "[unified-step]" in unified["metric"]
     assert "[unified-async]" in uasync["metric"]
     assert "[unified-obs]" in uobs["metric"]
@@ -559,12 +536,6 @@ def _bench_serve_smoke_once():
     assert "[unified-spec-k4]" in speck["metric"]
     assert "[unified-int8w]" in int8w["metric"]
     assert "[unified-int8w-int8kv]" in int8kv["metric"]  # flagship LAST
-    # the retrace satellite gates: the legacy path's bucketed prefill
-    # compiles >= 1 executable (now visible); the unified step has NO
-    # prefill jit and exactly one executable for everything
-    assert legacy["prefill_retraces"] >= 1
-    for rec in (unified, uasync, uobs, spmd, specb, speck, int8w, int8kv):
-        assert rec["prefill_retraces"] == 0
     # the round-15 observability A/B, measured as an interleaved pair on
     # the same churn: vs_baseline is the paired-window median of traced/
     # untraced tokens/s. This end-to-end gate is the GROSS-regression
@@ -590,7 +561,7 @@ def _bench_serve_smoke_once():
     assert uasync["value"] > uasync["sync_tokens_per_s"]
     assert uasync["vs_baseline"] > 1.0
     assert uasync["async_emissions_match"] == 1.0
-    for rec in (legacy, unified, uasync):
+    for rec in (unified, uasync):
         assert 0.0 <= rec["step_gap_frac"] <= 1.0
         assert rec["host_ms_per_step"] >= 0.0
     # the round-12 speculation gates: the spec-off leg anchors exactly
@@ -601,9 +572,8 @@ def _bench_serve_smoke_once():
     assert specb["draft_acceptance_rate"] == 0.0
     assert speck["accepted_tokens_per_step"] > 1.0
     assert 0.0 < speck["draft_acceptance_rate"] <= 1.0
-    # prefix caching only exists on the unified legs, and the churn
-    # workload (repeated prompts) must actually hit it
-    assert legacy["prefix_hit_rate"] == 0.0
+    # the churn workload (repeated prompts) must actually hit the prefix
+    # cache
     assert unified["prefix_hit_rate"] > 0.0
     assert int8kv["prefix_hit_rate"] > 0.0
     # the round-11 mesh A/B: the spmd leg ran tensor-parallel (the test
@@ -663,20 +633,6 @@ def test_predictor_prefill_finished_request_never_decodes(rng):
     sp2 = ServingPredictor(model, max_batch=2, max_seq_len=32, page_size=8)
     got2 = sp2.generate([[5]], max_new_tokens=8, eos_token_id=eos)
     assert got2[0] == [eos]
-
-
-def test_predictor_bucket_rounding_capped_at_model_max(rng):
-    """Prompts near a max_seq_len that is not a bucket multiple must
-    prefill (bucket padding clamps to the model's position table)."""
-    model = _tiny_model(max_seq_len=90)
-    sp = ServingPredictor(model, max_batch=1, max_seq_len=90, page_size=8,
-                          prefill_bucket=16)
-    prompt = rng.randint(0, TINY["vocab_size"], (86,)).tolist()
-    got = sp.generate([prompt], max_new_tokens=3)
-    ids = np.asarray([prompt], np.int64)
-    want = model.generate(paddle.to_tensor(ids), max_new_tokens=3,
-                          page_size=8).numpy()[0]
-    np.testing.assert_array_equal(np.asarray(got[0]), want)
 
 
 def test_generate_eos_frees_pages_and_pads(rng):
@@ -750,20 +706,6 @@ def test_predictor_fails_never_admittable_request_individually(rng):
     flat = sp.telemetry()
     assert flat["serving_requests_failed"] == 1
     assert flat["serving_fail_reasons{reason=never_admittable}"] == 1
-    # the same contract on the legacy two-jit path (serving.py:679's
-    # other caller)
-    sp2 = ServingPredictor(model, max_batch=1, max_seq_len=32, page_size=4,
-                           num_pages=2, unified=False)
-    doomed2 = sp2.add_request(
-        list(rng.randint(0, TINY["vocab_size"], (20,))), max_new_tokens=4)
-    ok2 = sp2.add_request(list(rng.randint(0, TINY["vocab_size"], (4,))),
-                          max_new_tokens=3)
-    while sp2.has_work():
-        sp2.step()
-    assert doomed2.state == FAILED
-    assert doomed2.error["code"] == "never_admittable"
-    assert ok2.state == FINISHED and len(ok2.output_ids) == 3
-
 
 def test_generate_zero_budget_returns_empty(rng):
     model = _tiny_model()
@@ -857,17 +799,20 @@ def _token_match_rate(got, want):
     return float((got == want).mean())
 
 
-def test_quantized_generate_matches_fp_oracle(rng):
-    """The acceptance gate: generate_paged with int8 weights + int8 KV
-    matches the fp greedy oracle on >= 99% of tokens in the smoke config
-    (quantization noise may flip near-tie argmaxes — the explicit
-    tolerance), and the unified-step retrace gate is unchanged."""
+@pytest.mark.parametrize("group", [-1, 8], ids=["per-channel", "g8"])
+def test_quantized_generate_matches_fp_oracle(rng, group):
+    """The acceptance gate: generate_paged with int8 weights (a scale per
+    output channel, or per group of 8 inputs) + int8 KV matches the fp
+    greedy oracle on >= 99% of tokens in the smoke config (quantization
+    noise may flip near-tie argmaxes — the explicit tolerance), and the
+    unified-step retrace gate is unchanged."""
     from paddle_tpu.models.gpt import generate_paged
 
     model = _tiny_model()
     ids = rng.randint(0, TINY["vocab_size"], (2, 11)).astype(np.int64)
     want = _oracle_greedy(model, ids, 16)
     model.config.weight_dtype = "int8"
+    model.config.weight_quant_group_size = group
     model.config.kv_cache_dtype = "int8"
     try:
         got = model.generate(paddle.to_tensor(ids), max_new_tokens=16).numpy()
@@ -876,6 +821,7 @@ def test_quantized_generate_matches_fp_oracle(rng):
         assert generate_paged.last_decode_trace_count <= 1
     finally:
         model.config.weight_dtype = None
+        model.config.weight_quant_group_size = -1
         model.config.kv_cache_dtype = None
 
 
@@ -943,16 +889,6 @@ def test_quantized_predictor_matches_fp_and_no_retrace(rng):
         assert sp_q.decode_trace_count == 1
     finally:
         model.config.weight_dtype = None
-        model.config.kv_cache_dtype = None
-
-
-def test_quantized_kv_requires_unified_step(rng):
-    model = _tiny_model()
-    model.config.kv_cache_dtype = "int8"
-    try:
-        with pytest.raises(ValueError):
-            ServingPredictor(model, max_batch=2, unified=False)
-    finally:
         model.config.kv_cache_dtype = None
 
 
@@ -1024,24 +960,24 @@ def test_spmd_mesh1_token_identical_to_single_chip(rng):
     for w, g in zip(want, got):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
     assert mesh1.decode_trace_count == 1
-    assert mesh1.prefill_trace_count == 0
 
 
-def test_spmd_generate_mesh2_matches_oracle(rng):
-    """The acceptance gate: greedy generate over a 2-chip mp mesh matches
-    the full-forward oracle token-for-token, one trace per geometry, zero
-    on replay."""
+@pytest.mark.parametrize("mesh", [1, 2], ids=["mesh1", "mesh2"])
+def test_spmd_generate_matches_oracle(rng, mesh):
+    """The acceptance gate: greedy generate over an mp mesh (the sharded
+    program on one chip, and on two) matches the full-forward oracle
+    token-for-token, one trace per geometry, zero on replay."""
     from paddle_tpu.models.gpt import generate_paged
 
-    _need_devices(2)
+    _need_devices(mesh)
     model = _tiny_model()
     ids = rng.randint(0, TINY["vocab_size"], (2, 11)).astype(np.int64)
     want = _oracle_greedy(model, ids, 8)
     got = model.generate(paddle.to_tensor(ids), max_new_tokens=8,
-                         mesh=2).numpy()
+                         mesh=mesh).numpy()
     np.testing.assert_array_equal(got, want)
     assert generate_paged.last_decode_trace_count <= 1
-    model.generate(paddle.to_tensor(ids), max_new_tokens=8, mesh=2)
+    model.generate(paddle.to_tensor(ids), max_new_tokens=8, mesh=mesh)
     assert generate_paged.last_decode_trace_count == 0
 
 
@@ -1222,7 +1158,6 @@ def test_spec_predictor_matches_plain_and_counts_acceptance(rng):
     for w, g in zip(want, got):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
     assert spec.decode_trace_count == 1      # one executable for all of it
-    assert spec.prefill_trace_count == 0
     # the workload's greedy repetition must actually be captured
     assert spec.spec_proposed > 0
     assert spec.accepted_tokens_per_step > 1.0
@@ -1361,9 +1296,6 @@ def test_spec_composes_with_prefix_cache_and_preemption(rng):
 
 def test_spec_validation_errors(rng):
     model = _tiny_model()
-    with pytest.raises(ValueError, match="unified"):
-        ServingPredictor(model, max_batch=2, unified=False,
-                         spec_decode_k=2)
     with pytest.raises(ValueError, match="chunk"):
         ServingPredictor(model, max_batch=2, chunk=4, spec_decode_k=4)
     ids = rng.randint(0, TINY["vocab_size"], (1, 4)).astype(np.int64)
@@ -1919,23 +1851,14 @@ def test_async_steady_pack_cache_identity_greedy_and_sampled(rng):
         assert sp.steady_hits > 5
 
 
-def test_async_requires_unified():
-    model = _tiny_model()
-    with pytest.raises(ValueError, match="async"):
-        ServingPredictor(model, unified=False, async_engine=True)
-
-
 def test_async_engine_is_the_default(rng):
     """Round 14 (ROADMAP item-3 follow-up): the soaked PR-8 async engine
-    is the default on the unified path; the legacy two-jit path resolves
-    to sync (it has no feedback carry), and async_engine=False still
-    selects the sync oracle explicitly."""
+    is the default, and async_engine=False still selects the sync oracle
+    explicitly."""
     model = _tiny_model()
     assert ServingPredictor(model, max_batch=2).async_engine is True
     assert ServingPredictor(model, max_batch=2,
                             async_engine=False).async_engine is False
-    assert ServingPredictor(model, max_batch=2,
-                            unified=False).async_engine is False
     # the default engine still matches the explicit sync oracle
     prompts = [rng.randint(0, TINY["vocab_size"], (5,)).tolist()
                for _ in range(2)]
@@ -2015,234 +1938,7 @@ def test_async_step_returns_tokens_one_behind(rng):
         assert collected.get(r.req_id, []) == r.output_ids
 
 
-# -- round 16 (ragged since round 22): megakernelized hot loop --------------
-# GPTConfig.mega_decode routes EVERY serving round — mixed prefill+decode
-# included — through the fused per-layer Pallas megakernels
-# (ops/pallas/mega_decode) at the unified step's packed ragged geometry;
-# round 22 removed the round-16 round-content router (all-decode vs mixed)
-# and the second decode-geometry program with it. The gates here: greedy
-# mega == the full-forward oracle token-for-token, the mega-on engine emits
-# BIT-IDENTICAL greedy/sampled streams to mega-off (which is itself the
-# unchanged round-15 code path — the mega-off equivalence contract), and
-# the spec/quant/mesh/async compositions hold — now including mp=2.
-
-
-def test_mega_generate_matches_full_forward_oracle(rng):
-    """Greedy generate with mega_decode on == the no-cache full-forward
-    oracle token-for-token — reference path AND interpret-kernel leg."""
-    model = _tiny_model(mega_decode=True)
-    ids = rng.randint(0, TINY["vocab_size"], (2, 11)).astype(np.int64)
-    want = _oracle_greedy(model, ids, 8)
-    got = model.generate(paddle.to_tensor(ids), max_new_tokens=8,
-                         page_size=8, chunk=4).numpy()
-    np.testing.assert_array_equal(got, want)
-    # the interpret-kernel leg: the REAL megakernel bodies on CPU
-    got_k = model.generate(paddle.to_tensor(ids), max_new_tokens=8,
-                           page_size=8, chunk=4, use_kernel=True).numpy()
-    np.testing.assert_array_equal(got_k, want)
-
-
-def test_mega_generate_no_per_token_retrace(rng):
-    """Round 22: mega is a build flavor of the ONE unified program (the
-    round-16 second decode-geometry build is gone) — never a per-token
-    or per-round trace."""
-    from paddle_tpu.models.gpt import generate_paged
-
-    model = _tiny_model(mega_decode=True)
-    ids = rng.randint(0, TINY["vocab_size"], (2, 9)).astype(np.int64)
-    model.generate(paddle.to_tensor(ids), max_new_tokens=8, page_size=8,
-                   chunk=4)
-    assert generate_paged.last_decode_trace_count <= 1  # ONE program
-    model.generate(paddle.to_tensor(ids), max_new_tokens=8, page_size=8,
-                   chunk=4)
-    assert generate_paged.last_decode_trace_count == 0
-
-
-def test_mega_predictor_bit_identical_to_mega_off_async_churn(rng):
-    """THE round-16 equivalence gate: the mega-on predictor (async
-    engine, the production default) reproduces the mega-off predictor —
-    the UNCHANGED round-15 code path — token-for-token over a continuous
-    churn mixing admissions, chunked prefill, decode and retirement;
-    greedy and seeded-sampled streams alike."""
-    prompts = _churn_prompts(rng, 24)
-    for sampling in ({}, dict(temperature=0.8, top_k=12, seed=11)):
-        model = _tiny_model(mega_decode=True)
-        sp_on = ServingPredictor(model, max_batch=3, max_seq_len=96,
-                                 page_size=8, chunk=4)
-        on, _ = _drive_churn(sp_on, prompts, 6, **sampling)
-        model_off = _tiny_model()
-        sp_off = ServingPredictor(model_off, max_batch=3, max_seq_len=96,
-                                  page_size=8, chunk=4)
-        off, _ = _drive_churn(sp_off, prompts, 6, **sampling)
-        assert on == off
-    # round 22: ONE program either way — the mega build traced exactly
-    # once (no second decode-geometry executable, no content routing)
-    assert sp_on.decode_trace_count == 1
-    assert sp_off.decode_trace_count == 1
-
-
-def test_mega_spec_depth_zero_identical(rng):
-    """Speculative decoding composes: mega routes the 1 + k verify rows
-    through the fused kernel's in-register causal block — emissions match
-    the per-op speculative engine (which already reconciles depth-zero)
-    and the spec-off oracle stream."""
-    prompts = [np.tile(rng.randint(0, TINY["vocab_size"], (3,)), 6)
-               .tolist() for _ in range(6)]
-    model = _tiny_model(mega_decode=True)
-    sp_on = ServingPredictor(model, max_batch=3, max_seq_len=96,
-                             page_size=8, chunk=8, spec_decode_k=2)
-    on, _ = _drive_churn(sp_on, prompts, 6)
-    model_off = _tiny_model()
-    sp_off = ServingPredictor(model_off, max_batch=3, max_seq_len=96,
-                              page_size=8, chunk=8, spec_decode_k=2)
-    off, _ = _drive_churn(sp_off, prompts, 6)
-    assert on == off
-    # speculation actually accepted drafts on the mega route
-    assert sp_on.spec_accepted > 0
-
-
-def test_mega_quantized_int8w_int8kv_matches_mega_off(rng):
-    """The flagship quantized composition: int8 weights (grouped scales,
-    dequant fused tile-by-tile in the megakernel) + int8 KV (quantize-on-
-    write IN-KERNEL, scatter via paged_write_packed_prequant) — greedy
-    emissions identical to the mega-off int8w+int8kv path, and the pools
-    stay int8."""
-    quant = dict(weight_dtype="int8", weight_quant_group_size=8,
-                 kv_cache_dtype="int8")
-    prompts = _churn_prompts(rng, 12)
-    model = _tiny_model(mega_decode=True, **quant)
-    sp_on = ServingPredictor(model, max_batch=3, max_seq_len=96,
-                             page_size=8, chunk=4)
-    on, _ = _drive_churn(sp_on, prompts, 5)
-    model_off = _tiny_model(**quant)
-    sp_off = ServingPredictor(model_off, max_batch=3, max_seq_len=96,
-                              page_size=8, chunk=4)
-    off, _ = _drive_churn(sp_off, prompts, 5)
-    assert on == off
-    assert sp_on.cache.k_pages.dtype == jnp.int8
-    assert sp_on.cache.k_scales is not None
-
-
-def test_mega_mesh1_token_identical(rng):
-    """mesh=1 (the sharded program on one chip, head-major params) with
-    mega on is token-identical to mesh=None mega — and to plain."""
-    model = _tiny_model(mega_decode=True)
-    ids = rng.randint(0, TINY["vocab_size"], (2, 7)).astype(np.int64)
-    want = _oracle_greedy(model, ids, 6)
-    got = model.generate(paddle.to_tensor(ids), max_new_tokens=6,
-                         page_size=8, chunk=4, mesh=1).numpy()
-    np.testing.assert_array_equal(got, want)
-
-
-def test_mega_rejections_are_loud(rng):
-    """int4 weights cannot be served by the megakernel and the legacy
-    two-jit path refuses the flag: the predictor fails at CONSTRUCTION
-    with the real reason. (The round-16 mp > 1 rejection was LIFTED in
-    round 22 — test_mega_mesh2_token_identical is its replacement
-    equivalence gate.)"""
-    model = _tiny_model(mega_decode=True, weight_dtype="int4")
-    with pytest.raises(ValueError, match="int4"):
-        ServingPredictor(model, max_batch=2, max_seq_len=96, page_size=8)
-    model2 = _tiny_model(mega_decode=True)
-    with pytest.raises(ValueError, match="legacy"):
-        ServingPredictor(model2, max_batch=2, max_seq_len=96, page_size=8,
-                         unified=False)
-
-
-def test_mega_mesh2_token_identical(rng):
-    """THE round-22 mp gate (replaces round 16's loud mp=2 rejection):
-    mega inside the fully-manual shard_map at mesh=2 — the attn/mlp
-    kernels run with fuse_epilogue=False and the caller completes the
-    2·L row-parallel psums — is greedy token-identical to the
-    full-forward oracle, on the conftest-forced host devices."""
-    import jax
-
-    if len(jax.devices()) < 2:
-        pytest.skip("needs >= 2 (forced host) devices")
-    model = _tiny_model(mega_decode=True)
-    ids = rng.randint(0, TINY["vocab_size"], (2, 7)).astype(np.int64)
-    want = _oracle_greedy(model, ids, 6)
-    got = model.generate(paddle.to_tensor(ids), max_new_tokens=6,
-                         page_size=8, chunk=4, mesh=2).numpy()
-    np.testing.assert_array_equal(got, want)
-
-
-def test_bench_serve_mega_leg_gates():
-    """The round-16 bench acceptance (via --legs, the tier-1 smoke
-    subset selector): the int8w+int8kv mega leg's analytic
-    hbm_bytes_per_token sits STRICTLY below its interleaved mega-off
-    partner's (the per-op activation round-trips bought back), greedy
-    emissions are bit-identical across the pair, and the device-time
-    metric is live on the schema-checked line."""
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    proc = subprocess.run(
-        [sys.executable, "bench_serve.py", "--smoke", "--steps=6",
-         "--batch=2", "--prompt=8", "--gen-len=3",
-         "--legs=unified-mega"],
-        cwd=root, capture_output=True, text=True, timeout=600,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"})
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    lines = [l for l in proc.stdout.strip().splitlines() if l.strip()]
-    assert len(lines) == 1, proc.stdout
-    rec = json.loads(lines[0])
-    assert "error" not in rec, rec
-    assert rec["leg"] == "unified-mega"
-    assert rec["value"] > 0 and rec["mega_off_tokens_per_s"] > 0
-    assert rec["decode_retraces"] == 1            # both routed programs
-    assert rec["mega_emissions_match"] == 1.0
-    assert rec["device_ms_per_step"] > 0
-    assert rec["mega_off_device_ms_per_step"] > 0
-    # the acceptance criterion: the megakernel leg's per-token HBM bytes
-    # strictly below the per-op leg's on the same quantized churn
-    assert (rec["hbm_bytes_per_token"]
-            < rec["mega_off_hbm_bytes_per_token"])
-    # round 23: the jaxpr-derived static model agrees on the mega leg
-    # (the fused activation regime read off the blocked scan carry)
-    assert rec["hbm_bytes_per_token_static"] > 0
-    assert abs(rec["hbm_model_drift_frac"]) <= 0.02
-
-
-def test_bench_serve_mega_mixed_leg_gates():
-    """The round-22 bench acceptance (via --legs, the tier-1 smoke
-    subset selector): the MIXED-churn mega leg — ragged prefill+decode
-    rounds through the megakernels, the draft chain as one dispatch,
-    spec_k=4 model drafts riding int8w+int8kv — emits bit-identically
-    to its interleaved per-op partner, its analytic hbm_bytes_per_token
-    sits STRICTLY below the partner's, and the draft-overhead pair
-    (mega-on vs mega-off at the same accept rule) is live on the
-    schema-checked line."""
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    proc = subprocess.run(
-        [sys.executable, "bench_serve.py", "--smoke", "--steps=6",
-         "--batch=2", "--prompt=8", "--gen-len=3",
-         "--legs=unified-mega-mixed"],
-        cwd=root, capture_output=True, text=True, timeout=600,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"})
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    lines = [l for l in proc.stdout.strip().splitlines() if l.strip()]
-    assert len(lines) == 1, proc.stdout
-    rec = json.loads(lines[0])
-    assert "error" not in rec, rec
-    assert rec["leg"] == "unified-mega-mixed"
-    assert rec["value"] > 0 and rec["mega_off_tokens_per_s"] > 0
-    assert rec["decode_retraces"] == 1       # ONE program per leg
-    assert rec["mega_emissions_match"] == 1.0
-    assert rec["device_ms_per_step"] > 0
-    assert rec["mega_off_device_ms_per_step"] > 0
-    assert (rec["hbm_bytes_per_token"]
-            < rec["mega_off_hbm_bytes_per_token"])
-    # round 23: the static model agrees on the mixed mega churn too —
-    # the acceptance criterion names this leg explicitly
-    assert rec["hbm_bytes_per_token_static"] > 0
-    assert abs(rec["hbm_model_drift_frac"]) <= 0.02
-    # the draft-chain pair: overhead fractions live and sane on BOTH
-    # legs, acceptance stats riding the line for the equal-acceptance
-    # comparison (the smoke window is too short to gate the strict
-    # shrink — bench_serve's full run carries that criterion)
-    assert 0.0 < rec["draft_overhead_frac"] < 1.0
-    assert 0.0 < rec["mega_off_draft_overhead_frac"] < 1.0
-    assert rec["accepted_tokens_per_step"] > 0
-    assert rec["mega_off_accepted_tokens_per_step"] > 0
+# -- bench_serve.py legs, gated through --legs ------------------------------
 
 
 def test_bench_serve_overload_leg_gates():
@@ -2799,9 +2495,8 @@ def test_bench_serve_spec_model_leg_gates():
 
 
 # -- round 25: MoE serving -------------------------------------------------
-# The routed-expert FFN serves through the SAME unified step as dense
-# (per-op path; mega stays dense-only and rejects loudly). Greedy decode
-# must equal the no-cache full-forward oracle token-for-token — fp AND
+# The routed-expert FFN serves through the SAME unified step as dense.
+# Greedy decode must equal the no-cache full-forward oracle token-for-token — fp AND
 # int8w (the expert stacks quantize per expert; _oracle_greedy over a
 # dequantized-weights model is the int8w golden). Capacity drops are
 # deterministic, and the async engine stays stream-identical.
@@ -2907,23 +2602,6 @@ def test_moe_capacity_drop_determinism(rng):
         runs.append(ServingPredictor(model, **kw).generate(
             prompts, max_new_tokens=8))
     assert runs[0] == runs[1]
-
-
-def test_moe_mega_rejected_loudly():
-    """mega_decode stays dense-only: composing it with moe_experts fails
-    at build time with a message naming the conflict, not a silent
-    dense fallback."""
-    model = _tiny_model(**MOE, mega_decode=True)
-    with pytest.raises(ValueError, match="dense-only"):
-        ServingPredictor(model, max_batch=2, max_seq_len=64)
-
-
-def test_moe_legacy_two_jit_path_rejected():
-    """The pre-unified builders predate the MoE FFN path — they refuse
-    rather than serving a dense approximation."""
-    model = _tiny_model(**MOE)
-    with pytest.raises(ValueError, match="[Mm]oE|moe"):
-        ServingPredictor(model, max_batch=2, unified=False)
 
 
 def test_bench_serve_moe_leg_gates():

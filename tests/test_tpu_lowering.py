@@ -4,16 +4,14 @@ Interpret mode has no tiling rule, so a kernel that has only ever run here
 can be one Mosaic refuses (PR 21 found three). ``jax.jit(f).trace(*avals)
 .lower(lowering_platforms=("tpu",))`` reaches Pallas's Mosaic lowering
 checks without a chip: every ``pallas_call`` the two ``chip_smoke.py``
-phases reach, plus ``quant_matmul`` / ``grouped_matmul`` / ``mega_mlp`` /
-``fused_mlp``, is lowered at the smoke's real widths (GPT-3 760M: hidden
+phases reach, plus ``quant_matmul`` / ``grouped_matmul`` / ``fused_mlp``,
+is lowered at the smoke's real widths (GPT-3 760M: hidden
 1536, 12 heads of 128, ffn 6144; the serving defaults of 8 lanes, chunk 16,
 64-token pages) from abstract inputs — nothing is allocated. A lowering
 that passes is not a compile that passes (the fast-memory limit and
 Mosaic's layout inference are only met by the chip's compiler); this is the
 gate that stops an interpret-only kernel from reaching the chip again.
 """
-import functools
-
 import pytest
 
 import jax
@@ -227,41 +225,6 @@ def test_latent_moe_kernels_lower_at_deepseek_v2_lite_widths(what):
                              sds(2, 64, 2048, 2816),
                              sds(65, dtype=i32), sds(dtype=i32))
     assert calls == 1
-
-
-def _layer_weights():
-    return {"ln1_g": sds(H), "ln1_b": sds(H), "ln2_g": sds(H),
-            "ln2_b": sds(H), "wqkv": sds(H, 3 * H), "bqkv": sds(3 * H),
-            "wo": sds(H, H), "bo": sds(H), "w1": sds(H, FFN),
-            "b1": sds(FFN), "w2": sds(FFN, H), "b2": sds(H)}
-
-
-def test_mega_mlp_lowers():
-    from paddle_tpu.ops.pallas.mega_decode import mega_mlp
-
-    rows = sds(LANES * CHUNK, H)
-    assert mosaic_calls(functools.partial(mega_mlp, chunk=CHUNK), rows,
-                        rows, _layer_weights()) == 1
-
-
-@pytest.mark.xfail(
-    strict=True, raises=ValueError,
-    reason="mega_attn_layer does not lower: 'The Pallas TPU lowering "
-           "currently requires that the last two dimensions of your block "
-           "shape are divisible by 8 and 128 respectively, or be equal to "
-           "the respective dimensions of the overall array. Block spec for "
-           "args[8] in pallas_call _mega_attn_kernel ... has block shape "
-           "(Blocked(1536), Squeezed(), Squeezed(), Blocked(128)), array "
-           "shape (1536, 3, 12, 128)' — the per-head wqkv view squeezes "
-           "the second-minor dim (ROADMAP D3)")
-def test_mega_attn_layer_lowers():
-    from paddle_tpu.ops.pallas.mega_decode import mega_attn_layer
-
-    pool = sds(POOL, HEADS, PAGE, HD)
-    lens = sds(LANES, dtype=jnp.int32)
-    mosaic_calls(mega_attn_layer, sds(LANES, CHUNK, H), _layer_weights(),
-                 pool, pool, sds(LANES, PAGES_PER_SLOT, dtype=jnp.int32),
-                 lens, lens)
 
 
 def test_fused_mlp_fwd_bwd_lowers():
