@@ -390,9 +390,6 @@ class ServingPredictor:
         # expert (the fullest expert's share of the mean is max over mean of
         # these). A model without them reports neither.
         self._m_moe_rows = self._m_moe_expert_rows = self._m_moe_fed = None
-        # only a predictor whose step runs ``ragged_paged_attention`` counts
-        # that kernel's grid steps (set where the step is built)
-        self._attn_grid = self._m_attn_live = self._m_attn_grid = None
         self._moe_unread: list = []
         if getattr(cfg, "n_routed_experts", 0):
             self._m_moe_fed = self.metrics.counter(
@@ -510,10 +507,16 @@ class ServingPredictor:
         self._unified = build_unified_step(
             cfg, self.cache.page_size, self.chunk, use_kernel=use_kernel,
             kv_quant=self.kv_quant, mesh=self.mesh, spec_k=self.spec_k)
-        if not self.latent:
-            # the step's attention is ``ragged_paged_attention``: what a
-            # scheduled lane's context costs it in grid steps, by the
-            # kernel module's own function (per chip under a mesh)
+        # what a scheduled lane's rows and context cost the step's attention
+        # kernel in grid steps, by the kernel module's own function (per chip
+        # under a mesh)
+        if self.latent:
+            from ..ops.pallas.mla_paged_attention import tile_grid
+
+            self._attn_grid = tile_grid(
+                self.max_batch, self.token_budget, self.cache.pages_per_slot,
+                self.cache.page_size)
+        else:
             from ..ops.pallas.paged_attention import ragged_grid
 
             heads = cfg.num_heads // (self.mesh.shape["mp"]
@@ -522,14 +525,14 @@ class ServingPredictor:
                 self.max_batch, self.cache.pages_per_slot, self.chunk,
                 heads, heads, self.cache.page_size, cfg.head_dim,
                 "int8" if self.kv_quant else kv_dtype, kv_dtype)
-            self._m_attn_live = self.metrics.counter(
-                "serving_attn_blocks_live",
-                "grid steps of a ragged_paged_attention call that hold "
-                "a scheduled lane's keys, summed over dispatched steps")
-            self._m_attn_grid = self.metrics.counter(
-                "serving_attn_blocks_grid",
-                "grid steps a ragged_paged_attention call launches, "
-                "summed over dispatched steps")
+        self._m_attn_live = self.metrics.counter(
+            "serving_attn_blocks_live",
+            "grid steps of the step's paged attention kernel that hold "
+            "keys a scheduled lane's rows see, summed over dispatched steps")
+        self._m_attn_grid = self.metrics.counter(
+            "serving_attn_blocks_grid",
+            "grid steps a call of the step's paged attention kernel "
+            "launches, summed over dispatched steps")
         # round 19: the draft SOURCE behind spec_decode_k — "ngram" (the
         # round-12 prompt-lookup table) or "model" (the truncated-layer
         # self-draft: ModelDraftEngine runs the first draft_layers layers
@@ -2087,12 +2090,13 @@ class ServingPredictor:
         # rows while its context is known ahead (prompt chunks, replay), and
         # decode rows once it feeds one generated token (plus its drafts)
         now = None
-        contexts = []
+        contexts, fed = [], []
         for slot, n in sched.items():
             req = self.running[slot]
             written = cache.seq_len(slot)
             n_prompt = len(req.prompt_ids)
             contexts.append(written + n)
+            fed.append(n)
             if slot in decode_set and written >= n_prompt:
                 self._m_rows_decode.inc(n)
             else:
@@ -2107,10 +2111,9 @@ class ServingPredictor:
             # reconcile (their watermark is n_emit, a device value)
             if not spec_len[slot]:
                 cache.advance(slot, n)
-        if self._attn_grid is not None:
-            self._m_attn_live.inc(
-                sum(map(self._attn_grid.live_steps, contexts)))
-            self._m_attn_grid.inc(self._attn_grid.steps(contexts))
+        self._m_attn_live.inc(
+            sum(map(self._attn_grid.live_steps, contexts, fed)))
+        self._m_attn_grid.inc(self._attn_grid.steps(contexts, fed))
         spec_slots = [s for s in sched if spec_len[s]]
         # a speculating lane always completes, so a prefill-only round
         # (completing empty) carries nothing to materialize — the entry

@@ -960,7 +960,7 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
                 f"{', '.join(unsupported)} yet: its one pool has no scale "
                 "planes and no head axis to shard")
         from ..ops.pallas.mla_paged_attention import (
-            TILE_DEFAULT, mla_ragged_paged_attention, tile_plan)
+            mla_ragged_paged_attention, tile_plan)
         from .deepseek_v2 import (absorb_query, latent_qkv, softmax_scale,
                                   unabsorb_output)
     # argument layout (shared by the wrappers, shard_map specs and the
@@ -1054,8 +1054,11 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
                     else None)
         if latent:
             with step_scope("attn"):
-                # the latent kernel's tiled layout of this step's rows
-                tiles = (tile_plan(tok_slot, off, q_lens, TILE_DEFAULT)
+                # the latent kernel's tiled layout of this step's rows and
+                # its grid's work items: what every layer's call shares
+                tiles = (tile_plan(tok_slot, off, q_lens, ctx, page_table,
+                                   page_size=page_size,
+                                   num_pages=pools[0].shape[1])
                          if kernels else None)
 
         def mha(p, y, pools, li):
@@ -1109,8 +1112,7 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
                 o_lat = mla_ragged_paged_attention(
                     q_abs, pool, page_table, ctx, q_lens, tok_slot, off,
                     v_dim=cfg.kv_lora_rank, scale=softmax_scale(cfg),
-                    layer=li, use_kernel=use_kernel, plan=tiles,
-                    tile=TILE_DEFAULT)
+                    layer=li, use_kernel=use_kernel, plan=tiles)
                 with step_scope("attn_absorb"):
                     a = unabsorb_output(cfg, p, o_lat)
             return a, (pool,)

@@ -234,11 +234,12 @@ class RaggedGrid(NamedTuple):
         """Keys one grid step covers."""
         return self.pages * self.page_size
 
-    def live_steps(self, kv_len: int) -> int:
-        """Grid steps that hold keys of a scheduled lane's context."""
+    def live_steps(self, kv_len: int, q_len: int = 1) -> int:
+        """Grid steps that hold keys of a scheduled lane's context (however
+        many rows it feeds: the signature is ``TileGrid.live_steps``'s)."""
         return self.groups * -(-int(kv_len) // self.keys)
 
-    def steps(self, contexts) -> int:
+    def steps(self, contexts, q_lens=None) -> int:
         """Grid steps one call launches when the scheduled lanes' contexts
         are ``contexts`` (the lanes not named are idle)."""
         live = [max(self.groups, self.live_steps(n)) for n in contexts]
@@ -400,35 +401,73 @@ def _ragged_kernel(lens_ref, qlens_ref, lane_ref, blk_ref, last_ref, tbl_ref,
                       ).astype(o_ref.dtype)
 
 
-def _work_items(page_table, kv_lens, q_lens, plan, num_pages):
-    """The grid's second axis, item by item: every lane's live key blocks in
-    order, lane after lane (an idle or empty lane keeps ONE item, so its zero
-    rows are written). Returns ``total`` (the items this call runs: the
-    grid's dynamic bound), each item's ``lane`` and key ``block``, whether it
-    is its lane's ``last`` (``[n]``, n the static bound), and the page every
-    (item, operand) names (``[n * pages]``): a slot past the lane's context
-    names the page its operand named on the item BEFORE, across lanes too,
+def work_items(page_table, seen, fed, *, pages, page_size, blocks, num_pages,
+               keep, lane=None):
+    """The work-item axis both paged kernels' grids run over, item by item:
+    every query GROUP's live key blocks in order, group after group. A group
+    is what one output block serves: a lane for ``ragged_paged_attention``, a
+    tile of a lane's rows for ``mla_ragged_paged_attention``. ``seen [g]``:
+    the keys a group's rows see (a key block is ``pages`` pages of
+    ``page_size`` keys and live while it holds one of them); ``fed [g]``: the
+    rows it feeds (0: no key block is live); ``lane [g]``: the page-table row
+    it reads (None: group ``i`` reads row ``i``); ``keep``: whether a group
+    with no live block keeps ONE item (a lane does, so that its zero rows are
+    written; an unused tile, whose rows nobody reads, does not); ``blocks``:
+    the key blocks a full table gives a group, so ``g * blocks`` items bound
+    the axis. Returns ``total`` (the items this call runs: the grid's dynamic
+    bound, at least one), each item's ``group`` and key ``block``, whether it
+    is its group's ``last`` (``[n]``, n the static bound), and the page every
+    (item, operand) names (``[n * pages]``): a slot past what the group sees
+    names the page its operand named on the item BEFORE, across groups too,
     so the pipeline fetches nothing for it."""
-    b, pps = page_table.shape
-    pages, i32 = plan.pages, jnp.int32
-    n = b * plan.blocks
-    live_blocks = jnp.where(q_lens > 0, -(-kv_lens // i32(plan.keys)), 0)
-    per_lane = jnp.maximum(live_blocks, 1)
-    ends = jnp.cumsum(per_lane)
+    g, pps = seen.shape[0], page_table.shape[1]
+    i32 = jnp.int32
+    n = g * blocks
+    live_blocks = jnp.where(fed > 0, -(-seen // i32(pages * page_size)), 0)
+    per_group = jnp.maximum(live_blocks, 1) if keep else live_blocks
+    ends = jnp.cumsum(per_group)
     item = jnp.arange(n, dtype=i32)
-    lane = jnp.minimum(jnp.sum(item[:, None] >= ends[None, :], axis=1),
-                       b - 1).astype(i32)
-    block = item - (ends - per_lane)[lane]
-    last = (block == per_lane[lane] - 1).astype(i32)
-    slot = block[:, None] * pages + jnp.arange(pages, dtype=i32)[None, :]
-    live = ((slot * plan.page_size < kv_lens[lane][:, None])
-            & (q_lens[lane][:, None] > 0) & (slot < pps)
-            & (item[:, None] < ends[-1]))
-    page = jnp.clip(page_table, 0, num_pages - 1)[
-        lane[:, None], jnp.minimum(slot, pps - 1)]
-    last_live = jax.lax.cummax(jnp.where(live, item[:, None], 0), axis=0)
-    named = jnp.take_along_axis(page, last_live, axis=0)
-    return ends[-1].astype(i32), lane, block.astype(i32), last, named.reshape(-1)
+    group = jnp.minimum(jnp.sum(item[:, None] >= ends[None, :], axis=1),
+                        g - 1).astype(i32)
+    block = item - (ends - per_group)[group]
+    last = (block == per_group[group] - 1).astype(i32)
+    # operand k's slot of a group's key block j holds keys while j * pages +
+    # k is one of the group's live page slots: in its first ``reach[g, k]``
+    # blocks. Past them it names what it named in the last of them, or where
+    # the group has none, in the last group before that has one (``held``: a
+    # dense comparison over groups, not a scan over items; -1: nothing named
+    # yet, so what item 0 would name). Which page that is, is worked out per
+    # group and operand; the items only pick rows of these small tables and
+    # ``pages`` adjacent entries of the page table (an element-by-element
+    # gather over items x pages costs the chip 9 ns an element)
+    assert pages <= pps <= blocks * pages, (pages, pps, blocks)
+    k = jnp.arange(pages, dtype=i32)
+    slots = jnp.where(fed > 0, jnp.minimum(-(-seen // i32(page_size)), pps),
+                      0)
+    reach = jnp.maximum(-(-(slots[:, None] - k[None, :]) // i32(pages)), 0)
+    of = jnp.arange(g, dtype=i32)
+    held = jnp.max(jnp.where((reach > 0)[None] & (of[None, :] <= of[:, None]
+                                                 )[:, :, None],
+                             of[None, :, None], -1), axis=1)      # [g, pages]
+    table = jnp.pad(jnp.clip(page_table, 0, num_pages - 1),
+                    ((0, 0), (0, blocks * pages - pps))
+                    ).reshape(-1, blocks, pages)
+    rows = of if lane is None else lane
+    held_in = jnp.where(held < 0, group[0], held)
+    held_block = jnp.where(held < 0, 0, reach[held_in, k[None, :]] - 1)
+    held_page = table[rows[held_in], held_block, k[None, :]]      # [g, pages]
+    own = table[rows[group], jnp.minimum(block, blocks - 1)]      # [n, pages]
+    named = jnp.where(block[:, None] < reach[group], own, held_page[group])
+    total = ends[-1] if keep else jnp.maximum(ends[-1], 1)
+    return total.astype(i32), group, block.astype(i32), last, named.reshape(-1)
+
+
+def _work_items(page_table, kv_lens, q_lens, plan, num_pages):
+    """``ragged_paged_attention``'s items (:func:`work_items`): a group is a
+    lane, which sees its whole context and keeps one item when idle."""
+    return work_items(page_table, kv_lens, q_lens, pages=plan.pages,
+                      page_size=plan.page_size, blocks=plan.blocks,
+                      num_pages=num_pages, keep=True)
 
 
 def _ragged_kernel_impl(q4, k_pages, v_pages, page_table, kv_lens, q_lens,

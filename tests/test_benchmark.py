@@ -7,8 +7,10 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from benchmark.tests.test_benchmark import *  # noqa: E402,F401,F403
 from benchmark.tests.test_scope_trace import *  # noqa: E402,F401,F403
+# ``test_a_latent_predictor_has_neither_counter`` of that file is not
+# collected: PR 33 gave the latent predictor both counters (their test is in
+# ``tests/test_latent_moe_serving.py``); the file is a later benchmark PR's
 from benchmark.tests.test_ragged_grid import (  # noqa: E402,F401
-    test_a_latent_predictor_has_neither_counter,
     test_live_block_share_on_a_hand_made_run,
     test_live_block_share_reads_nothing_where_the_counters_are_absent,
     test_scheduler_counts_the_kernels_grid_steps_of_known_lanes)

@@ -236,6 +236,37 @@ def test_chunked_prefill_then_decode_agree_with_the_reference(
                if k.startswith("serving_moe_expert_rows{")) == fed * 4
 
 
+def test_scheduler_counts_the_latent_kernels_grid_steps_of_known_lanes(model):
+    """PR 33: the latent predictor counts its kernel's grid steps as the GPT
+    one does, by the kernel module's own ``tile_grid`` for the deployment:
+    64 keys a grid step here (8 pages of 8), tiles of 64 tokens, so a lane's
+    8-row chunk or one decode row is one tile, live over ceil(context / 64)
+    key blocks; the grid launches no other step."""
+    from paddle_tpu.ops.pallas.mla_paged_attention import tile_grid
+
+    sp = ServingPredictor(model, use_kernel=False, async_engine=False,
+                          **SERVE)
+    grid = tile_grid(3, 24, 16, 8)
+    assert (grid.keys, grid.tiles, grid.blocks) == (64, 3, 2)
+    long = np.random.default_rng(9).integers(0, 256, 100).tolist()
+    sp.add_request(long, max_new_tokens=3)
+    sp.add_request(long[:5], max_new_tokens=3)
+    while sp.has_work():
+        sp.step()
+    t = sp.telemetry()
+    # lane A: twelve 8-row chunks, the last 4 rows, two decode rows; lane B:
+    # 5 rows, two decode rows
+    lanes = ([(8 * k, 8) for k in range(1, 13)] + [(100, 4), (101, 1),
+                                                   (102, 1)]
+             + [(5, 5), (6, 1), (7, 1)])
+    assert t["serving_rows_prefill"] + t["serving_rows_decode"] == sum(
+        q for _, q in lanes)
+    live = sum(grid.live_steps(kv, q) for kv, q in lanes)
+    assert live == 8 + 2 * 7 + 3
+    assert t["serving_attn_blocks_live"] == live
+    assert t["serving_attn_blocks_grid"] == live
+
+
 def test_a_gpt_predictor_reports_no_expert_counters():
     from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
 

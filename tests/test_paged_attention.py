@@ -590,6 +590,89 @@ def test_work_items_visit_live_key_blocks_and_name_the_page_before():
                                   [[5, 6], [7, 6], [7, 6], [8, 6]])
 
 
+def _work_items_before(page_table, kv_lens, q_lens, plan, num_pages):
+    """``_work_items`` as PR 29 wrote it, before PR 33 made it the lanes' case
+    of the planner both paged kernels share: the oracle of the test below."""
+    b, pps = page_table.shape
+    pages, i32 = plan.pages, jnp.int32
+    n = b * plan.blocks
+    live_blocks = jnp.where(q_lens > 0, -(-kv_lens // i32(plan.keys)), 0)
+    per_lane = jnp.maximum(live_blocks, 1)
+    ends = jnp.cumsum(per_lane)
+    item = jnp.arange(n, dtype=i32)
+    lane = jnp.minimum(jnp.sum(item[:, None] >= ends[None, :], axis=1),
+                       b - 1).astype(i32)
+    block = item - (ends - per_lane)[lane]
+    last = (block == per_lane[lane] - 1).astype(i32)
+    slot = block[:, None] * pages + jnp.arange(pages, dtype=i32)[None, :]
+    live = ((slot * plan.page_size < kv_lens[lane][:, None])
+            & (q_lens[lane][:, None] > 0) & (slot < pps)
+            & (item[:, None] < ends[-1]))
+    page = jnp.clip(page_table, 0, num_pages - 1)[
+        lane[:, None], jnp.minimum(slot, pps - 1)]
+    last_live = jax.lax.cummax(jnp.where(live, item[:, None], 0), axis=0)
+    named = jnp.take_along_axis(page, last_live, axis=0)
+    return (ends[-1].astype(i32), lane, block.astype(i32), last,
+            named.reshape(-1))
+
+
+# name: (ragged_grid's arguments, kv_lens, q_lens); None: drawn per lane
+_PLANNER_CASES = {
+    "590m-chat-like": ((24, 32, 64, 12, 12, 64, 128, jnp.bfloat16,
+                        jnp.bfloat16), None, "decode"),
+    "590m-doc-sat-like": ((24, 32, 64, 12, 12, 64, 128, jnp.bfloat16,
+                           jnp.bfloat16), None, "mixed"),
+    "590m-int8": ((24, 32, 64, 12, 12, 64, 128, jnp.int8, jnp.bfloat16),
+                  None, "mixed"),
+    "590m-all-idle": ((24, 32, 64, 12, 12, 64, 128, jnp.bfloat16,
+                       jnp.bfloat16), None, "idle"),
+    "590m-full-table": ((24, 32, 64, 12, 12, 64, 128, jnp.bfloat16,
+                         jnp.bfloat16), [2048] * 24, [64] * 24),
+    "dead-lanes-between-live": ((4, 12, 4, 4, 4, 8, 16, jnp.float32,
+                                 jnp.float32), [70, 30, 91, 0], [4, 0, 1, 0]),
+    "key-block-edges": ((4, 20, 8, 4, 4, 8, 16, jnp.float32, jnp.float32),
+                        [31, 32, 33, 160], [1, 8, 8, 1]),
+    "slots-not-a-multiple": ((2, 9, 4, 4, 2, 8, 16, jnp.float32,
+                              jnp.float32), [72, 67], [4, 1]),
+    "three-lanes-two-pages": ((3, 4, 1, 1, 1, 4, 16, jnp.float32,
+                               jnp.float32), [9, 30, 3], [1, 0, 1]),
+}
+
+
+@pytest.mark.parametrize("name", list(_PLANNER_CASES))
+def test_shared_planner_for_lanes_is_work_items_as_before(rng, name):
+    """PR 33: ``_work_items`` is ``work_items`` over lanes (a lane sees its
+    whole context, reads its own page-table row and keeps one item when
+    idle): every array it hands the kernel equals what PR 29's function
+    gave, value for value, at the 590M cells' shapes and on the small ragged
+    cases of this file."""
+    shape, kv_lens, q_lens = _PLANNER_CASES[name]
+    plan = pa.ragged_grid(*shape)
+    b, pps, chunk, page_size = shape[0], shape[1], shape[2], shape[5]
+    num_pages = b * pps // 2 + 3
+    # a table with entries past the pool and below zero, as a scheduler's
+    # unset slots are: both functions clip
+    pt = jnp.asarray(rng.randint(-1, num_pages + 2, (b, pps)), jnp.int32)
+    if kv_lens is None:
+        top = pps * page_size
+        kv_lens = rng.randint(1, top + 1, b)
+        q_lens = {"decode": np.ones(b, int),
+                  "mixed": np.where(np.arange(b) % 4 == 0, chunk, 1),
+                  "idle": np.zeros(b, int)}[q_lens]
+        q_lens[3::7] = 0                       # idle lanes with stale contexts
+        kv_lens = np.maximum(kv_lens, q_lens)
+    kv_lens = jnp.asarray(kv_lens, jnp.int32)
+    q_lens = jnp.asarray(q_lens, jnp.int32)
+    want = _work_items_before(pt, kv_lens, q_lens, plan, num_pages)
+    got = pa._work_items(pt, kv_lens, q_lens, plan, num_pages)
+    assert len(got) == len(want) == 5
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    scheduled = [int(n) for n, q in zip(kv_lens, q_lens) if q]
+    assert int(got[0]) == plan.steps(scheduled)
+
+
 @pytest.mark.parametrize("budget,heads", [(12 << 20, 4), (70_000, 2),
                                           (30_000, 1)])
 def test_ragged_fewer_heads_a_step_than_kv_heads(rng, monkeypatch, budget,

@@ -124,6 +124,8 @@ def test_compiled_latent_step_keeps_its_one_pool_in_one_buffer(one_chip,
     import paddle_tpu  # noqa: F401  framework config (matmul precision)
     from paddle_tpu.models.deepseek_v2 import DeepseekV2Config
     from paddle_tpu.models.gpt import build_unified_step
+    from paddle_tpu.ops.pallas.mla_paged_attention import (MLA_KERNEL_NAME,
+                                                           tile_grid)
 
     sys.path.insert(0, REPO)
     try:
@@ -144,10 +146,34 @@ def test_compiled_latent_step_keeps_its_one_pool_in_one_buffer(one_chip,
                    "grouped_matmul"):
         assert any(_is_mosaic_call(line, kernel)
                    for line in hlo.splitlines()), kernel
+    # PR 33: exactly one call of the latent kernel in a layer scan's body
+    # (the dense layer's scan and the routed layer's: two in the program);
+    # the benchmark's roofline reader multiplies by the calls of that name
+    assert sum(_is_mosaic_call(line, MLA_KERNEL_NAME)
+               for line in hlo.splitlines()) == 2
     pool = avals[11]
     assert pool.shape == (2, 512, 1, 64, 640)
     assert pool_copies(hlo, pool.shape) == []
     assert compiled.memory_analysis().temp_size_in_bytes < 0.3e9
+    # the grid at the cell's shapes (32 lanes, 256 page slots, budget 1,024),
+    # by the function the kernel and the scheduler's counters are built from:
+    # never more than the static grid it replaces (48 tiles x 32 key blocks
+    # of 8 pages = 1,536 steps a call), and the live (tile, key block) pairs
+    # alone: a decode lane at 2,048 reads 2,048 keys, a 256-row chunk's tiles
+    # each up to their own last row; no lane scheduled, one step
+    grid = tile_grid(32, 1024, 256, 64)
+    assert grid.tiles == 32 + 1024 // grid.tile
+    assert grid.tiles * -(-256 // 8) == 1536
+    tiles = 256 // grid.tile
+    assert grid.steps([16384] * 32, [1] * 32) == 32 * grid.blocks
+    assert (grid.steps([16384] * 32, [1] * 28 + [256] * 4)
+            <= grid.tiles * grid.blocks <= 1536)
+    assert grid.steps([2048] * 32, [1] * 32) == 32 * -(-2048 // grid.keys)
+    assert grid.steps([2048] * 32, [1] * 31 + [256]) == (
+        31 * -(-2048 // grid.keys)
+        + sum(-(-(1792 + (k + 1) * grid.tile) // grid.keys)
+              for k in range(tiles)))
+    assert grid.steps([], []) == 1
 
 
 @pytest.mark.parametrize("kv_quant", [False, True], ids=["fp", "int8"])
