@@ -367,7 +367,7 @@ class KVCacheManager:
                  max_batch, max_seq_len, page_size=None, num_q_heads=None,
                  dtype=jnp.float32, enable_prefix_cache=False,
                  quantize_kv=False, mesh=None, metrics=None,
-                 host_tier_bytes=0, latent=False):
+                 host_tier_bytes=0, latent=False, index_plane=None):
         from ..ops.pallas.paged_attention import preferred_page_size
 
         # a LATENT cache (multi-head latent attention): ONE pool, one row
@@ -410,6 +410,21 @@ class KVCacheManager:
         pool_dtype = jnp.int8 if self.quantize_kv else dtype
         self.k_pages = jnp.zeros(shape, pool_dtype)
         self.v_pages = None if self.latent else jnp.zeros(shape, pool_dtype)
+        # a latent cache whose model has a learned INDEXER (learned sparse
+        # attention, models/glm_moe_dsa.py): ``index_plane=(layers, width)``,
+        # a second plane ``[layers, num_pages, 1, page_size, width]`` for the
+        # indexer layers' keys alone. It is keyed by the SAME page ids, so a
+        # page's index keys are allocated, freed, shared by the prefix cache
+        # and copied on write with the page they belong to (the step copies
+        # every pool it is handed); nothing below knows of it
+        self.index_pages = None
+        if index_plane is not None:
+            if not self.latent:
+                raise NotImplementedError(
+                    "an index plane belongs to a latent cache")
+            self.index_pages = jnp.zeros(
+                (int(index_plane[0]), self.num_pages, 1, self.page_size,
+                 int(index_plane[1])), pool_dtype)
         if self.quantize_kv:
             sshape = shape[:4]
             self.k_scales = jnp.zeros(sshape, jnp.float32)
@@ -1445,7 +1460,9 @@ class KVCacheManager:
     def pools(self) -> tuple:
         """The donated pools in the order the unified step takes and
         returns them: ``(k, v[, k_scales, v_scales])``, or the one latent
-        pool."""
+        pool [and its index plane]."""
+        if self.index_pages is not None:
+            return (self.k_pages, self.index_pages)
         if self.latent:
             return (self.k_pages,)
         if self.quantize_kv:
@@ -1457,7 +1474,9 @@ class KVCacheManager:
         """Adopt the pools returned by a jitted serving step (scale
         planes too on the int8-KV path; a latent cache's one pool)."""
         self.k_pages = k_pages
-        if v_pages is not None:
+        if self.index_pages is not None:
+            self.index_pages = v_pages   # the latent pool's second plane
+        elif v_pages is not None:
             self.v_pages = v_pages
         if k_scales is not None:
             self.k_scales = k_scales

@@ -390,7 +390,18 @@ class ServingPredictor:
         # expert (the fullest expert's share of the mean is max over mean of
         # these). A model without them reports neither.
         self._m_moe_rows = self._m_moe_expert_rows = self._m_moe_fed = None
+        self._m_moe_elsewhere = None
         self._moe_unread: list = []
+        self._moe_pairs_unread: list = []
+        if getattr(cfg, "experts_held", None):
+            # a chip's share of the experts: the counters below count the
+            # HELD experts and their rows; the rest of a row's choices went
+            # to experts on other chips
+            self._m_moe_elsewhere = self.metrics.counter(
+                "serving_moe_rows_elsewhere",
+                "token-expert pairs whose expert is not held here")
+            self._moe_pairs_per_row = (cfg.num_moe_layers
+                                       * cfg.num_experts_per_tok)
         if getattr(cfg, "n_routed_experts", 0):
             self._m_moe_fed = self.metrics.counter(
                 "serving_moe_experts_fed",
@@ -476,8 +487,12 @@ class ServingPredictor:
         kv_heads, kv_width = ((1, -(-cfg.latent_dim // 128) * 128)
                               if self.latent
                               else (cfg.num_heads, cfg.head_dim))
+        # learned sparse attention: the indexer layers' keys, a second plane
+        self.sparse = self.latent and bool(getattr(cfg, "index_topk", 0))
         self.cache = KVCacheManager(
             cfg.num_layers, kv_heads, kv_width, latent=self.latent,
+            **({"index_plane": (cfg.num_index_layers, cfg.index_head_dim)}
+               if self.sparse else {}),
             num_pages=num_pages, max_batch=self.max_batch,
             max_seq_len=self.max_seq_len, page_size=page_size,
             num_q_heads=cfg.num_heads, dtype=kv_dtype,
@@ -511,11 +526,12 @@ class ServingPredictor:
         # kernel in grid steps, by the kernel module's own function (per chip
         # under a mesh)
         if self.latent:
-            from ..ops.pallas.mla_paged_attention import tile_grid
+            from ..ops.pallas.mla_paged_attention import (tile_for_heads,
+                                                          tile_grid)
 
             self._attn_grid = tile_grid(
                 self.max_batch, self.token_budget, self.cache.pages_per_slot,
-                self.cache.page_size)
+                self.cache.page_size, tile_for_heads(cfg.num_heads))
         else:
             from ..ops.pallas.paged_attention import ragged_grid
 
@@ -533,6 +549,27 @@ class ServingPredictor:
             "serving_attn_blocks_grid",
             "grid steps a call of the step's paged attention kernel "
             "launches, summed over dispatched steps")
+        # learned sparse attention: per scheduled row, the keys its indexer
+        # scored, the keys its attention read and the keys it would have
+        # read without a selection, each summed over the layers that do it
+        # (by the kernels' own count, ``dsa_index.keys_of``)
+        self.last_selected = None
+        if self.sparse:
+            from ..ops.pallas.dsa_index import keys_of
+
+            self._dsa_keys_of = keys_of
+            self._m_dsa_scored = self.metrics.counter(
+                "serving_dsa_keys_scored",
+                "keys the indexer scored: per scheduled row and layer with "
+                "an indexer, the row's context")
+            self._m_dsa_selected = self.metrics.counter(
+                "serving_dsa_keys_selected",
+                "keys attention read: per scheduled row and attention "
+                "layer, min(context, index_topk)")
+            self._m_dsa_context = self.metrics.counter(
+                "serving_dsa_keys_context",
+                "keys attention would read with no selection: per "
+                "scheduled row and attention layer, its context")
         # round 19: the draft SOURCE behind spec_decode_k — "ngram" (the
         # round-12 prompt-lookup table) or "model" (the truncated-layer
         # self-draft: ModelDraftEngine runs the first draft_layers layers
@@ -1436,6 +1473,11 @@ class ServingPredictor:
         rows, fed = np.sum([np.asarray(a) for a in self._moe_unread[:ready]],
                            axis=0)
         del self._moe_unread[:ready]
+        if self._m_moe_elsewhere is not None:
+            # what the steps' rows chose in all, less what was held here
+            self._m_moe_elsewhere.inc(
+                sum(self._moe_pairs_unread[:ready]) - int(rows.sum()))
+            del self._moe_pairs_unread[:ready]
         self._m_moe_rows.inc(int(rows.sum()))
         self._m_moe_fed.inc(int(fed.sum()))
         for expert in np.flatnonzero(rows):
@@ -2076,6 +2118,13 @@ class ServingPredictor:
             # It stays on the device until a reconcile materializes tokens
             # anyway (no sync of its own)
             self._moe_unread.append(res[2 + len(pools)])
+            if self._m_moe_elsewhere is not None:
+                self._moe_pairs_unread.append(
+                    sum(sched.values()) * self._moe_pairs_per_row)
+        if self.sparse:
+            # [layers with an indexer, lanes, key slots] bool: the keys each
+            # lane's last row read; left on the device
+            self.last_selected = res[3 + len(pools)]
         self._carry = carry
         # charge the dispatched-unmaterialized token per completing lane
         # only once the launch SUCCEEDED (round 17: a failed launch must
@@ -2114,6 +2163,14 @@ class ServingPredictor:
         self._m_attn_live.inc(
             sum(map(self._attn_grid.live_steps, contexts, fed)))
         self._m_attn_grid.inc(self._attn_grid.steps(contexts, fed))
+        if self.sparse:
+            cfg = self.config
+            seen, read = map(sum, zip(*(
+                self._dsa_keys_of(c, n, cfg.index_topk)
+                for c, n in zip(contexts, fed)))) if fed else (0, 0)
+            self._m_dsa_scored.inc(cfg.num_index_layers * seen)
+            self._m_dsa_context.inc(cfg.num_layers * seen)
+            self._m_dsa_selected.inc(cfg.num_layers * read)
         spec_slots = [s for s in sched if spec_len[s]]
         # a speculating lane always completes, so a prefill-only round
         # (completing empty) carries nothing to materialize — the entry

@@ -204,23 +204,33 @@ def gated_mlp(y, w_gu, w_d):
     return (jax.nn.silu(gu[..., :half]) * gu[..., half:]) @ w_d
 
 
-def latent_qkv(config, p, y, positions):
+def latent_qkv(config, p, y, positions, with_query_latent=False):
     """The projections every attention form starts from, for rows ``y [...,
     h]`` at integer ``positions [...]``: ``(q_nope [..., nh, nope], q_pe
     [..., nh, rope]`` rotated, ``latent [..., r + rope])`` where ``latent``
-    is the cached row ``[rms_norm(c) | rope(k_pe)]``."""
+    is the cached row ``[rms_norm(c) | rope(k_pe)]``. Where the layer holds a
+    LOW-RANK query (``wq_a``, ``q_ln_g``, ``wq_b``: ``q_lora_rank``) the
+    query is ``rms_norm(y W_q_a) W_q_b``; ``with_query_latent`` appends that
+    normalised query latent ``[..., q_lora_rank]`` (what a learned indexer
+    projects its own queries from; None for a full-rank query)."""
     import jax.numpy as jnp
 
     cfg = config
     nh, nope, r = cfg.num_heads, cfg.qk_nope_head_dim, cfg.kv_lora_rank
     lead = y.shape[:-1]
-    q = (y @ p["wq"]).reshape(*lead, nh, cfg.head_dim)
+    cq = None
+    if "wq_a" in p:
+        cq = rms_norm(y @ p["wq_a"], p["q_ln_g"], cfg.rms_norm_eps)
+        q = (cq @ p["wq_b"]).reshape(*lead, nh, cfg.head_dim)
+    else:
+        q = (y @ p["wq"]).reshape(*lead, nh, cfg.head_dim)
     ckv = y @ p["wkv_a"]
     cos, sin = rope_cos_sin(cfg, positions)
     c = rms_norm(ckv[..., :r], p["kv_ln_g"], cfg.rms_norm_eps)
     k_pe = apply_rope(ckv[..., r:], cos, sin)
     q_pe = apply_rope(q[..., nope:], cos[..., None, :], sin[..., None, :])
-    return q[..., :nope], q_pe, jnp.concatenate([c, k_pe], axis=-1)
+    out = (q[..., :nope], q_pe, jnp.concatenate([c, k_pe], axis=-1))
+    return out + (cq,) if with_query_latent else out
 
 
 def _wkv_b_heads(config, p):
@@ -251,9 +261,11 @@ def unabsorb_output(config, p, o_lat):
     return o.reshape(*o.shape[:-2], -1)
 
 
-def attention_expanded(config, p, q_nope, q_pe, latent):
+def attention_expanded(config, p, q_nope, q_pe, latent, selected=None):
     """Causal attention over one sequence ``[s, ...]`` with every head's K
-    and V expanded from the latent rows: ``[s, nh * v]``."""
+    and V expanded from the latent rows: ``[s, nh * v]``. ``selected [s, s]``
+    (bool): the keys each row may read, where something chose them
+    (``models/glm_moe_dsa.py``); causal itself."""
     import jax
     import jax.numpy as jnp
 
@@ -266,7 +278,8 @@ def attention_expanded(config, p, q_nope, q_pe, latent):
     scores = (jnp.einsum("qhd,khd->hqk", q_nope, k_nope)
               + jnp.einsum("qhd,kd->hqk", q_pe, k_pe)).astype(jnp.float32)
     s = scores.shape[-1]
-    causal = jnp.tril(jnp.ones((s, s), bool))
+    causal = (jnp.tril(jnp.ones((s, s), bool)) if selected is None
+              else selected)
     scores = jnp.where(causal, scores * softmax_scale(cfg), -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
     return jnp.einsum("hqk,khd->qhd", probs, v).reshape(s, -1)
@@ -309,12 +322,21 @@ def routed_ffn(config, p, y, use_kernel=None, valid=None, with_counts=False,
     from .moe import moe_ffn
 
     cfg = config
+    # what a configuration of this family may state beyond DeepSeek-V2's:
+    # sigmoid scores chosen by score plus a correction bias (``moe_bias``),
+    # and a chip's SHARE of the experts (``experts_held``)
+    more = {}
+    if getattr(cfg, "scoring_func", "softmax") != "softmax":
+        more.update(scoring=cfg.scoring_func, route_bias=p.get("moe_bias"))
+    if getattr(cfg, "experts_held", None):
+        more.update(experts_held=cfg.experts_held)
     out, _, stats = moe_ffn(
         y, p["moe_gate"], p["moe_w_gu"], None, p["moe_w_d"], None,
         top_k=cfg.num_experts_per_tok, capacity_factor=None,
         renormalize=bool(cfg.norm_topk_prob), gated=True,
         gate_scale=float(cfg.routed_scaling_factor),
-        use_kernel=use_kernel, valid=valid, with_stats=True, layer=layer)
+        use_kernel=use_kernel, valid=valid, with_stats=True, layer=layer,
+        **more)
     with step_scope("moe_shared"):
         out = out + gated_mlp(y, p["sh_w_gu"], p["sh_w_d"])
     if not with_counts:
@@ -373,25 +395,28 @@ def param_shapes(config, dtype=None):
     return tree
 
 
-def init_params(config, seed: int, dtype=None):
+def init_params(config, seed: int, dtype=None, shapes=None, std_share=None):
     """Seeded weights (normal, std ``initializer_range``; norm weights one),
     made leaf by leaf ON the device in ``dtype``: each stacked leaf is filled
     a layer at a time, so the largest temporary is one layer's leaf and no
     float32 copy of the model ever exists. The bits come from the device's
     own generator (``impl="rbg"``): four billion values from threefry took
     85 s of a v5e, and a seed need only give the same weights on the same
-    backend."""
+    backend. ``shapes``: another family's tree over the same rule;
+    ``std_share``: ``{leaf name: share of std}`` for leaves that are seeded
+    narrower."""
     import jax
     import jax.numpy as jnp
 
-    std = float(config.initializer_range)
-    shapes = param_shapes(config, dtype)
+    if shapes is None:
+        shapes = param_shapes(config, dtype)
 
     def leaf(path, s, key):
         name = path[-1].key
         if name.endswith("_g"):                  # a norm's weight
             return jnp.ones(s.shape, s.dtype)
-        if len(path) == 1:
+        std = float(config.initializer_range) * (std_share or {}).get(name, 1)
+        if len(path) == 1:       # not a stack of layers: one piece
             return (jax.random.normal(key, s.shape, jnp.float32) * std
                     ).astype(s.dtype)
         keys = jax.random.split(key, s.shape[0])
@@ -405,6 +430,16 @@ def init_params(config, seed: int, dtype=None):
         leaves = [jax.jit(lambda k, path=path, s=s: leaf(path, s, k))(
             jax.random.fold_in(root, i)) for i, (path, s) in enumerate(flat)]
     return jax.tree_util.tree_unflatten(treedef, leaves)
+
+
+def layer_stacks(params):
+    """The stacks of equal layers the serving step scans, in order: the
+    leading dense layers' and the routed layers', or, where the layers come
+    in more kinds than two, the tree's own ``stacks`` (a tuple, one stack per
+    run of equal layers: ``models/glm_moe_dsa.py``)."""
+    if "stacks" in params:
+        return list(params["stacks"])
+    return [params[g] for g in ("dense_layers", "layers") if g in params]
 
 
 def layer_groups(params):

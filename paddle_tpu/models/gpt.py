@@ -929,6 +929,23 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
         fn(params, ...the same 11 arrays..., latent_pages, page_table, ...)
         -> (next_toks, logits, latent_pages, expert_rows)
 
+    A model whose layers come in more kinds than two brings ``stacks``, one
+    stack per RUN of equal layers (``deepseek_v2.layer_stacks``), each one
+    scan. LEARNED SPARSE attention over the latent cache (``config.
+    index_topk``; ``models/glm_moe_dsa.py``, PR 34) adds a second donated
+    pool, the indexer layers' keys ``[indexer layers, num_pages, 1,
+    page_size, index_head_dim]`` under the same page ids, and carries the
+    SELECTION through the scans beside ``x`` and the pools: a layer with an
+    indexer writes its index keys, scores every key a row sees and keeps the
+    exact top ``index_topk`` (``ops/pallas/dsa_index.py``), the layers after
+    it read through that selection (``mla_ragged_paged_attention(
+    selected=)``) until the next such layer. A last result hands out the keys
+    each lane's last row read::
+
+        fn(params, ..., latent_pages, index_pages, page_table, ...)
+        -> (next_toks, logits, latent_pages, index_pages, expert_rows,
+            selected[indexer layers, b, key slots] bool)
+
     ``kv_quant``, ``mesh`` and ``spec_k`` are not extended to the latent
     cache and raise ``NotImplementedError`` here.
     """
@@ -941,7 +958,7 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
     from ..observability.tracing import step_scope
     from ..ops.pallas.paged_attention import (ragged_paged_attention,
                                               use_kernel_default)
-    from .deepseek_v2 import STACKED_BY_INDEX
+    from .deepseek_v2 import STACKED_BY_INDEX, layer_stacks
 
     cfg = config
     trace_count = [0]
@@ -960,16 +977,25 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
                 f"{', '.join(unsupported)} yet: its one pool has no scale "
                 "planes and no head axis to shard")
         from ..ops.pallas.mla_paged_attention import (
-            mla_ragged_paged_attention, tile_plan)
+            MLA_KERNEL_NAME, SPARSE_MLA_KERNEL_NAME,
+            mla_ragged_paged_attention, tile_for_heads, tile_grid, tile_plan)
         from .deepseek_v2 import (absorb_query, latent_qkv, softmax_scale,
                                   unabsorb_output)
+    # LEARNED SPARSE attention over the latent cache (``index_topk``;
+    # models/glm_moe_dsa.py): a second donated pool, the indexer's keys, and
+    # the selection a layer with an indexer makes is carried to the layers
+    # after it
+    sparse = latent and bool(getattr(cfg, "index_topk", 0))
+    if sparse:
+        from ..ops.pallas import dsa_index as dsa
+        from .glm_moe_dsa import indexer_qkw
     # argument layout (shared by the wrappers, shard_map specs and the
     # donation indices): params + 6 packed/lane arrays [+ spec_len] + the
     # 4 feedback arrays (feedback mask, prev_toks carry, emit_mask,
     # produced), then the donated pools [+ scale planes], then the
     # 7-array tail
     n_lead = 12 if spec_k else 11
-    n_pool = 1 if latent else 4 if kv_quant else 2
+    n_pool = 2 if sparse else 1 if latent else 4 if kv_quant else 2
     n_out_lead = 4 if spec_k else 2
 
     def _body(*args):
@@ -1056,10 +1082,27 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
             with step_scope("attn"):
                 # the latent kernel's tiled layout of this step's rows and
                 # its grid's work items: what every layer's call shares
+                mla_tile = tile_for_heads(cfg.num_heads)
                 tiles = (tile_plan(tok_slot, off, q_lens, ctx, page_table,
                                    page_size=page_size,
-                                   num_pages=pools[0].shape[1])
+                                   num_pages=pools[0].shape[1],
+                                   tile=mla_tile)
                          if kernels else None)
+        if sparse:
+            # the selection as the attention part reads it: where the
+            # kernels run, a 0/1 mask in the plan's tiled layout; else bool
+            # over the packed rows. Until a layer with an indexer fills it,
+            # nothing
+            slots = page_table.shape[1] * page_size
+            last_c = jnp.clip(last_idx, 0, t - 1)
+            if kernels:
+                tgrid = tile_grid(b, t, page_table.shape[1], page_size,
+                                  mla_tile)
+                no_selection = jnp.zeros(
+                    (tgrid.tiles * tgrid.tile, tgrid.blocks * tgrid.keys),
+                    jnp.bfloat16)
+            else:
+                no_selection = jnp.zeros((t, slots), bool)
 
         def mha(p, y, pools, li):
             """Multi-head attention over per-head K and V pools: packed
@@ -1092,42 +1135,80 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
             return (a.reshape(t, nh_l * hd),
                     (kp, vp, ks, vs) if kv_quant else (kp, vp))
 
-        def mla(p, y, pools, li):
+        def select(p, y, cq, ipool, fi):
+            """A layer's INDEXER (``models/glm_moe_dsa.py``): its keys
+            written into layer ``fi`` of the index plane, every row's seen
+            keys scored, and the ``index_topk`` best of each row kept.
+            Returns the plane, the selection as the attention part reads it
+            and, ``[b, slots]`` bool, the selection of each lane's last
+            row."""
+            with step_scope("attn_index"):
+                q_idx, k_idx, w = indexer_qkw(cfg, p, y, cq, tok_pos)
+                ipool = paged_write_packed(ipool, k_idx[:, None, :], *dest,
+                                           layer=fi, plan=plan)
+                scores = (
+                    dsa.index_scores(q_idx, w, ipool, tiles, fi, grid=tgrid)
+                    if kernels else dsa.index_scores_reference(
+                        q_idx, w, ipool, page_table, ctx, q_lens, tok_slot,
+                        off, layer=fi))
+            with step_scope("attn_select"):
+                if kernels:
+                    sel = dsa.select_mask(scores, tiles, grid=tgrid,
+                                          k=cfg.index_topk)
+                    mine = jnp.minimum(tiles.dest[last_c], sel.shape[0] - 1)
+                    return ipool, sel, sel[mine][:, :slots] > 0
+                sel = dsa.select_topk(scores, scores > -jnp.inf,
+                                      cfg.index_topk)
+                return ipool, sel, sel[last_c]
+
+        def mla(p, y, pools, li, sel=None, fi=None):
             """Latent attention: ONE row ``[c | k_pe]`` written per token,
             read back in the absorbed form by every row of the step (a
             decode row and a prefill chunk's rows alike: all of them read
-            the paged context, see ``models/deepseek_v2.py``)."""
-            (pool,) = pools
+            the paged context, see ``models/deepseek_v2.py``). With an
+            indexer anywhere in the model (``sparse``): over the selected
+            keys ``sel`` alone, which a layer that has an indexer makes anew
+            (:func:`select`; it then also returns the selection and its
+            last rows)."""
+            pool = pools[0]
             pad = pool.shape[-1] - cfg.latent_dim    # lanes to a whole tile
             with step_scope("qkv"):
-                q_nope, q_pe, row = latent_qkv(cfg, p, y, tok_pos)
+                q_nope, q_pe, row, *cq = latent_qkv(
+                    cfg, p, y, tok_pos, with_query_latent=sparse)
                 row = jnp.pad(row, ((0, 0), (0, pad)))
             with step_scope("kv_write"):
                 pool = paged_write_packed(pool, row[:, None, :], *dest,
                                           layer=li, plan=plan)
+            pools, last_rows = (pool,) + tuple(pools[1:]), None
             with step_scope("attn"):
+                if "idx_wq" in p:
+                    ipool, sel, last_rows = select(p, y, cq[0], pools[1], fi)
+                    pools = (pool, ipool)
                 with step_scope("attn_absorb"):
                     q_abs = jnp.pad(absorb_query(cfg, p, q_nope, q_pe),
                                     ((0, 0), (0, 0), (0, pad)))
                 o_lat = mla_ragged_paged_attention(
                     q_abs, pool, page_table, ctx, q_lens, tok_slot, off,
                     v_dim=cfg.kv_lora_rank, scale=softmax_scale(cfg),
-                    layer=li, use_kernel=use_kernel, plan=tiles)
+                    layer=li, use_kernel=use_kernel, plan=tiles,
+                    tile=mla_tile, selected=sel,
+                    name=SPARSE_MLA_KERNEL_NAME if sparse
+                    else MLA_KERNEL_NAME)
                 with step_scope("attn_absorb"):
                     a = unabsorb_output(cfg, p, o_lat)
-            return a, (pool,)
+            return (a, pools, sel, last_rows) if sparse else (a, pools)
 
         attention = mla if latent else mha
 
         def block(carry, layer, whole=None):
-            x, pools = carry
-            p, li, lj = layer
+            x, pools, *sel = carry
+            p, li, lj, *fi = layer
             if whole:
                 # stacks read by index inside their kernel: layer lj of them
                 p = dict(p, **whole)
             with step_scope("ln"):
                 y = _srv_norm(cfg, x, p, "ln1")
-            a, pools = attention(p, y, pools, li)
+            a, pools, *chosen = attention(p, y, pools, li, *sel, *fi)
             with step_scope("attn_out"):
                 x = x + _srv_psum(_srv_mm(a, p["wo"], use_kernel), axis)
                 if "bo" in p:
@@ -1138,6 +1219,10 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
                 f, rows = _srv_ffn(cfg, p, y, use_kernel, axis, valid=valid,
                                    layer=lj if whole else None)
                 x = x + f
+            if sparse:
+                # the selection goes on to the next layer; its last rows
+                # (None from a layer that shares) come out with the counts
+                return (x, pools, chosen[0]), (rows, chosen[1])
             return (x, pools), rows
 
         # the stacks the model's layers come in, in order: one for a uniform
@@ -1145,11 +1230,14 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
         # one whose stack is not uniform (models/deepseek_v2.py). Each is
         # one scan; the layer index counts through them all, since the pool
         # stack is [all layers, pages, ...]
-        groups = [params[g] for g in ("dense_layers", "layers")
-                  if g in params]
+        # (a model of more kinds of layer brings one stack per run of equal
+        # layers, ``stacks``: models/glm_moe_dsa.py)
+        groups = layer_stacks(params)
         # the scans are scoped, so their own slicing of the stacked weights
         # falls under "layers" alone
         carry, first, expert_rows = (x, pools), 0, None
+        if sparse:
+            carry, indexed, selected = carry + (no_selection,), 0, []
         with step_scope("layers"):
             for stack in groups:
                 n = jax.tree.leaves(stack)[0].shape[0]
@@ -1157,17 +1245,28 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
                 # slices (none for a GPT block)
                 whole = {k: stack[k] for k in STACKED_BY_INDEX if k in stack}
                 within = jnp.arange(n, dtype=jnp.int32)
+                counters = (first + within, within)
+                if sparse:
+                    # a layer with an indexer: which layer of the index plane
+                    counters += (indexed + within,)
+                    indexed += n if "idx_wq" in stack else 0
                 carry, rows = jax.lax.scan(
                     functools.partial(block, whole=whole) if whole else block,
                     carry,
                     ({k: v for k, v in stack.items() if k not in whole},
-                     first + within, within))
+                     *counters))
                 first += n
+                if sparse:
+                    rows, last_rows = rows
+                    if last_rows is not None:
+                        selected.append(last_rows)
                 if rows is not None:
                     # [2, E] int32: the rows every expert received, and
                     # the layers in which it received any
-                    expert_rows = rows.sum(axis=0)
-        x, pools = carry
+                    rows = rows.sum(axis=0)
+                    expert_rows = (rows if expert_rows is None
+                                   else expert_rows + rows)
+        x, pools = carry[:2]
         if spec_k:
             # -- speculative verify + fused accept epilogue --------------
             # rows last_idx .. last_idx+spec_k are the lane's verify rows
@@ -1243,6 +1342,11 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
             # the previous token through (a lane skipped by the budget
             # still feeds its latest token through feedback next step)
             next_toks = jnp.where(emit_mask > 0, next_ids, prev_toks)
+        if sparse:
+            # last: [layers with an indexer, b, key slots] bool, the keys
+            # each lane's last row read
+            return (next_toks, logits, *pools, expert_rows,
+                    jnp.concatenate(selected))
         if expert_rows is not None:
             return (next_toks, logits, *pools, expert_rows)
         return (next_toks, logits, *pools)

@@ -47,7 +47,8 @@ def moe_capacity(n_tokens: int, num_experts: int, top_k: int,
                    / int(num_experts)) * int(top_k), 4)
 
 
-def route_topk(logits, top_k: int, renormalize: bool = True):
+def route_topk(logits, top_k: int, renormalize: bool = True,
+               scoring: str = "softmax", bias=None):
     """Deterministic top-k routing over router ``logits [N, E]``.
 
     Returns ``(gates [N, k] fp32, idx [N, k] int32, probs [N, E] fp32,
@@ -55,18 +56,30 @@ def route_topk(logits, top_k: int, renormalize: bool = True):
     unless ``renormalize`` is off, when they are the k softmax scores as
     they are (``norm_topk_prob: false``); ``masks`` the per-choice one-hot
     ``[N, E]`` list. ``jnp.argmax`` breaks ties to the lowest index, and
-    the iterative masking keeps the k experts distinct."""
+    the iterative masking keeps the k experts distinct.
+
+    ``scoring="sigmoid"`` with ``bias [E]`` is DeepSeek-V3's ``noaux_tc``
+    choice: the scores are ``sigmoid(logits)``, the k experts are those with
+    the largest ``score + bias`` (``e_score_correction_bias``), and the
+    gates are the UNBIASED scores of the chosen."""
     n, e = logits.shape
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    p = probs
+    if scoring == "softmax" and bias is None:
+        probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+        p, taken = probs, None
+    else:
+        probs = (jax.nn.sigmoid(logits.astype(jnp.float32))
+                 if scoring == "sigmoid"
+                 else jax.nn.softmax(logits.astype(jnp.float32), axis=-1))
+        p = probs if bias is None else probs + bias.astype(jnp.float32)
+        taken = -jnp.inf      # a biased score may be negative: mask, not zero
     idxs, raw, masks = [], [], []
     for _ in range(int(top_k)):
         i = jnp.argmax(p, axis=-1)
         m = jax.nn.one_hot(i, e, dtype=jnp.float32)
         idxs.append(i.astype(jnp.int32))
-        raw.append((p * m).sum(axis=-1))
+        raw.append(((p if taken is None else probs) * m).sum(axis=-1))
         masks.append(m)
-        p = p * (1.0 - m)
+        p = p * (1.0 - m) if taken is None else jnp.where(m > 0, taken, p)
     gates = jnp.stack(raw, axis=1)                       # [N, k]
     if renormalize:
         gates = gates / jnp.maximum(gates.sum(axis=1, keepdims=True), 1e-9)
@@ -144,7 +157,8 @@ def _expert_bias(b, eids):
 def moe_ffn(x, gate_w, w1, b1, w2, b2, *, top_k: int,
             capacity_factor: float | None, use_kernel=None, valid=None,
             with_stats: bool = False, renormalize: bool = True,
-            gated: bool = False, gate_scale: float = 1.0, layer=None):
+            gated: bool = False, gate_scale: float = 1.0, layer=None,
+            scoring: str = "softmax", route_bias=None, experts_held=None):
     """The MoE FFN over 2D tokens ``x [N, d]``.
 
     gate_w ``[d, E]``; w1 ``[E, d, f]`` / w2 ``[E, f, d]`` (fp stacks or
@@ -163,7 +177,14 @@ def moe_ffn(x, gate_w, w1, b1, w2, b2, *, top_k: int,
     grouped GEMM, ``(silu(x W_g) * x W_u) W_d``, instead of the biased
     GELU pair. ``layer`` (a traced int32 scalar): ``w1`` / ``w2`` are the
     whole model's stacks ``[L, E, ...]`` and this call is layer ``layer``
-    of them (``grouped_matmul(layer=)``).
+    of them (``grouped_matmul(layer=)``). ``scoring`` / ``route_bias`` as
+    :func:`route_topk`. ``experts_held=(first, count)``: this chip's SHARE
+    of an expert-parallel layer. The router keeps its full width ``E`` and
+    its k; ``w1`` / ``w2`` hold the ``count`` experts ``first ..`` alone;
+    only the pairs whose choice is held (and whose row is valid) are sorted
+    into the grouped layout and computed, and what the absent experts would
+    have added is left out: no stand-in. ``rows`` then counts the held
+    experts' rows, ``[count]``.
 
     Returns ``(out [N, d], aux_loss)`` — plus a stats dict (``load [E]``
     kept-pair fraction per expert, ``drop_rate``, ``rows [E]`` int32 kept
@@ -176,7 +197,8 @@ def moe_ffn(x, gate_w, w1, b1, w2, b2, *, top_k: int,
     k = int(top_k)
     with step_scope("moe_route"):
         logits = x.astype(jnp.float32) @ gate_w.astype(jnp.float32)
-        gates, idx, probs, masks = route_topk(logits, k, renormalize)
+        gates, idx, probs, masks = route_topk(
+            logits, k, renormalize, scoring=scoring, bias=route_bias)
         aux = load_balance_aux(probs, masks[0], valid=valid)
         if capacity_factor is None:
             cap = n
@@ -197,13 +219,28 @@ def moe_ffn(x, gate_w, w1, b1, w2, b2, *, top_k: int,
         # scatter that inverts the permutation and a running sum down the
         # pairs each cost about 3 ms a step of seven layers: PERF.md, PR 28.)
         eid = idx.reshape(-1)                                     # [N*k]
+        if experts_held is not None:
+            # this chip's share: a pair is sorted in only where its expert
+            # is one of the held ones and its row is real; every other
+            # pair has no place (dropped by the scatter, gate zero)
+            first, e = int(experts_held[0]), int(experts_held[1])
+            keep = keep & (idx >= first) & (idx < first + e)
+            gates = gates * keep.astype(gates.dtype)
+            eid = jnp.where(keep.reshape(-1), eid - first, e)
         chose = eid[:, None] == jnp.arange(e, dtype=eid.dtype)[None]
         offsets = jnp.concatenate(
             [jnp.zeros((1,), jnp.int32),
              jnp.cumsum(chose.sum(axis=0, dtype=jnp.int32))])
-        place = offsets[eid] + _earlier_same_choice(chose)  # pair -> row
-        xs = jnp.zeros((n * k, d), x.dtype).at[place].set(
-            jnp.repeat(x, k, axis=0))                             # [N*k, d]
+        if experts_held is None:
+            place = offsets[eid] + _earlier_same_choice(chose)  # pair -> row
+            xs = jnp.zeros((n * k, d), x.dtype).at[place].set(
+                jnp.repeat(x, k, axis=0))                         # [N*k, d]
+        else:
+            place = jnp.where(
+                eid < e, offsets[jnp.minimum(eid, e - 1)]
+                + _earlier_same_choice(chose), n * k)
+            xs = jnp.zeros((n * k, d), x.dtype).at[place].set(
+                jnp.repeat(x, k, axis=0), mode="drop")
         if b1 is not None or b2 is not None:
             from ..ops.pallas.grouped_matmul import token_group_ids
 
@@ -225,7 +262,12 @@ def moe_ffn(x, gate_w, w1, b1, w2, b2, *, top_k: int,
     with step_scope("moe_route"):
         # each token gathers its k experts' rows and sums them by its gates
         # (a gather and a small product, not a scatter-add over N*k rows)
-        mine = jnp.take(y, place, axis=0).reshape(n, k, d)
+        if experts_held is None:
+            mine = jnp.take(y, place, axis=0).reshape(n, k, d)
+        else:
+            # rows past the held pairs are whatever the grouped GEMM left
+            mine = jnp.where(keep[:, :, None], jnp.take(
+                y, place, axis=0, mode="clip").reshape(n, k, d), 0)
         out = jnp.einsum("nkd,nk->nd", mine.astype(jnp.float32),
                          gates.astype(jnp.float32)).astype(x.dtype)
     if not with_stats:

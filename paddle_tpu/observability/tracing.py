@@ -172,10 +172,13 @@ STEP_SCOPES = (
 #: order, the weighted sum back), ``moe_experts`` (the grouped GEMMs and the
 #: activation between them) and ``moe_shared`` (the shared experts); inside
 #: ``attn``, a latent cache's ``attn_absorb`` (``W_kv_b``'s key half on the
-#: query, its value half behind the softmax).
+#: query, its value half behind the softmax) and, where a learned indexer
+#: chooses the keys attention reads (``models/glm_moe_dsa.py``), ``attn_index``
+#: (the indexer's projections, the index-key write, the score kernel) and
+#: ``attn_select`` (the exact top-k that turns scores into the selection).
 STEP_SUBSCOPES = {
     "moe_route": "mlp", "moe_experts": "mlp", "moe_shared": "mlp",
-    "attn_absorb": "attn",
+    "attn_absorb": "attn", "attn_index": "attn", "attn_select": "attn",
 }
 
 

@@ -71,6 +71,10 @@ from .paged_attention import (NEG_INF, _dotf32, _interpret, gather_pages,
 # stable pallas_call name (survives into the compiled HLO and the device
 # trace): how a check or a trace reduction finds the kernel
 MLA_KERNEL_NAME = "mla_ragged_paged_attention"
+# the same kernel reading a SELECTION of the keys (``selected=``; learned
+# sparse attention, ``dsa_index.py``) runs under a name of its own: what is
+# counted for one is never applied to the other
+SPARSE_MLA_KERNEL_NAME = "sparse_mla_paged_attention"
 
 # Chosen on the v5e at the DeepSeek-V2-Lite cell's shapes with no dead grid
 # step in the way (PERF.md, PR 33: microseconds a call of the kernel alone over
@@ -85,6 +89,14 @@ MLA_KERNEL_NAME = "mla_ragged_paged_attention"
 TILE_DEFAULT = 64           # query tokens per tile
 PAGES_PER_STEP_DEFAULT = 8  # pages one grid step reads
 FEW_TOKENS = 1              # a tile with no more real tokens runs the few-rows form
+
+
+def tile_for_heads(heads: int) -> int:
+    """Query tokens a tile holds for ``heads`` query heads: the tile's rows
+    (tokens x heads) are what the kernel keeps in fast memory, 1,024 of them
+    at the widths it was tuned for (64 tokens of 16 heads); never under the
+    16 rows a bf16 tile of a selection mask has."""
+    return max(16, min(TILE_DEFAULT, TILE_DEFAULT * 16 // int(heads)))
 
 
 class TileGrid(NamedTuple):
@@ -212,15 +224,18 @@ def tile_plan(tok_slot, tok_off, q_lens, kv_lens, page_table, *, page_size,
 
 def _mla_kernel(tile_ref, blk_ref, last_ref, tbl_ref, first_ref, ctx_ref,
                 rows_ref, full_ref, layer_ref, *refs, page_size, pages, heads,
-                v_dim, scale, few_tokens, few_rows):
+                v_dim, scale, few_tokens, few_rows, selected=False):
     """One work item: ``pages`` pages of one tile's context. The tables are
     :class:`TilePlan`'s (``tbl_ref``, ``full_ref`` and ``layer_ref`` are the
     index maps' business alone). refs: the tile's whole query block, [its
-    first ``few_rows`` rows: a second view of the same buffer], the pages,
+    first ``few_rows`` rows: a second view of the same buffer], [``selected``:
+    the item's block ``[tile, keys]`` of the selection mask], the pages,
     the output (left where it lies: a tile's rows go there by a copy of the
     kernel's own), then scratch: the softmax state m, l, acc, the output
     rows on their way out and the copy's semaphore."""
     q_refs, refs = refs[:2 if few_rows else 1], refs[2 if few_rows else 1:]
+    if selected:
+        sel_ref, refs = refs[0], refs[1:]
     page_refs = refs[:pages]
     o_hbm, m_ref, l_ref, acc_ref, out_ref, sem = refs[pages:]
     it = pl.program_id(0)
@@ -265,6 +280,13 @@ def _mla_kernel(tile_ref, blk_ref, last_ref, tbl_ref, first_ref, ctx_ref,
         limit = jnp.minimum(first + tok + jnp.int32(1), ctx)
         col0 = jax.lax.broadcasted_iota(jnp.int32, (r, keys), 1)
         m_prev = m_ref[:r, :]                             # [r, 1]
+        if selected:
+            # row i reads its token's row of the mask: a one-hot product
+            # spreads the tile's ``[tokens, keys]`` over its ``[r, keys]``
+            tokens = sel_ref.shape[0]
+            mine = (jax.lax.broadcasted_iota(jnp.int32, (r, tokens), 0)
+                    // heads == jax.lax.broadcasted_iota(
+                        jnp.int32, (r, tokens), 1)).astype(sel_ref.dtype)
         blocks, scores, m_next = [], [], m_prev
         for k in range(0, pages, pair):
             block = (page_refs[k][...] if pair == 1 else jnp.concatenate(
@@ -272,6 +294,11 @@ def _mla_kernel(tile_ref, blk_ref, last_ref, tbl_ref, first_ref, ctx_ref,
             s = _dotf32(q, block, ((1,), (1,))) * scale   # [r, keys]
             col = (j * pages + k) * page_size + col0
             s = jnp.where(col < limit, s, NEG_INF)
+            if selected:
+                chosen = _dotf32(
+                    mine, sel_ref[:, k * page_size:k * page_size + keys],
+                    ((1,), (0,)))
+                s = jnp.where(chosen > 0.5, s, NEG_INF)
             blocks.append(block)
             scores.append(s)
             m_next = jnp.maximum(m_next, jnp.max(s, axis=-1, keepdims=True))
@@ -304,14 +331,17 @@ def _mla_kernel(tile_ref, blk_ref, last_ref, tbl_ref, first_ref, ctx_ref,
     by_form(last_ref[it] == 1, finish)
 
 
-def _kernel_impl(q3, pool, plan, layer, *, grid, heads, v_dim, scale):
+def _kernel_impl(q3, pool, plan, layer, *, grid, heads, v_dim, scale,
+                 selected=None, name=MLA_KERNEL_NAME):
     """q3: ``[n, tile * heads, row]`` tiled queries; returns ``[n, tile *
     heads, v_dim]`` in q3's dtype, of which only a tile's real tokens' rows
     are written (the first ``grid.few`` tokens' of a few-rows tile, none of
     an unused tile). The grid is ``plan.total`` work items; every block
     index is one lookup in the plan's tables. The pool is passed
     ``grid.pages`` times, each operand with its own block index map, so one
-    item's pages are fetched together."""
+    item's pages are fetched together. ``selected [n * tile, blocks * keys]``
+    (0/1; ``dsa_index.select_mask``): the keys each tiled row may read
+    besides being causal; ``name``: the call's name in the trace."""
     n, r, row = q3.shape
     page_size, pages = pool.shape[3], grid.pages
     # the few-rows form's rows: whole float32 sublane tiles
@@ -332,13 +362,16 @@ def _kernel_impl(q3, pool, plan, layer, *, grid, heads, v_dim, scale):
     q_specs = [pl.BlockSpec((None, r, row), whole_imap)]
     if few_rows:
         q_specs.append(pl.BlockSpec((None, few_rows, row), few_imap))
+    sel_specs = [] if selected is None else [pl.BlockSpec(
+        (grid.tile, grid.keys),
+        lambda it, tile_ref, blk_ref, *_: (tile_ref[it], blk_ref[it]))]
     page_specs = [pl.BlockSpec((None, None, None, page_size, row),
                                functools.partial(page_imap, k))
                   for k in range(pages)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=9,
         grid=(plan.total,),
-        in_specs=q_specs + page_specs,
+        in_specs=q_specs + sel_specs + page_specs,
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[pltpu.VMEM((r, 1), jnp.float32),
                         pltpu.VMEM((r, 1), jnp.float32),
@@ -348,25 +381,28 @@ def _kernel_impl(q3, pool, plan, layer, *, grid, heads, v_dim, scale):
     )
     kern = functools.partial(_mla_kernel, page_size=page_size, pages=pages,
                              heads=heads, v_dim=v_dim, scale=scale,
-                             few_tokens=grid.few, few_rows=few_rows)
+                             few_tokens=grid.few, few_rows=few_rows,
+                             selected=selected is not None)
     with _atc.x64_off():
         return pl.pallas_call(
             kern, grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((n, r, v_dim), q3.dtype),
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",)),
-            interpret=_interpret(), name=MLA_KERNEL_NAME,
+            interpret=_interpret(), name=name,
         )(plan.tile, plan.block, plan.last, plan.page, plan.first, plan.ctx,
           plan.rows, plan.full, jnp.asarray(layer, i32).reshape(1),
-          *([q3] * len(q_specs)), *([pool] * pages))
+          *([q3] * len(q_specs)), *([selected] * len(sel_specs)),
+          *([pool] * pages))
 
 
 def mla_ragged_paged_attention_reference(q, pool, page_table, kv_lens,
                                          q_lens, tok_slot, tok_off, *,
-                                         v_dim, scale, layer):
+                                         v_dim, scale, layer, selected=None):
     """Gather-based oracle (and the non-TPU path): every lane's pages
     gathered into one contiguous view, masked causal softmax per packed
-    row. Shapes as :func:`mla_ragged_paged_attention`."""
+    row. Shapes as :func:`mla_ragged_paged_attention`; ``selected [t, S]``
+    (bool) here names the keys each PACKED row may read."""
     b = q_lens.shape[0]
     num_pages = pool.shape[1]
     pt = jnp.clip(page_table, 0, num_pages - 1)
@@ -378,6 +414,8 @@ def mla_ragged_paged_attention_reference(q, pool, page_table, kv_lens,
     pos = ctx - q_lens[slot_c] + tok_off                   # [t]
     col = jnp.arange(mine.shape[1])[None, :]
     seen = (col <= pos[:, None]) & (col < ctx[:, None])    # [t, S]
+    if selected is not None:
+        seen = seen & selected
     s = jnp.where(seen[:, None, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     live = (tok_slot >= 0) & (tok_off >= 0) & (tok_off < q_lens[slot_c])
@@ -391,7 +429,8 @@ def mla_ragged_paged_attention(q, pool, page_table, kv_lens, q_lens,
                                layer, use_kernel: bool | None = None,
                                plan: TilePlan | None = None,
                                tile: int = TILE_DEFAULT,
-                               pages_per_step: int = PAGES_PER_STEP_DEFAULT):
+                               pages_per_step: int = PAGES_PER_STEP_DEFAULT,
+                               selected=None, name: str = MLA_KERNEL_NAME):
     """Absorbed latent attention of one serving step's packed rows.
 
     q: ``[t, heads, row]`` absorbed queries of the packed token stream;
@@ -407,6 +446,12 @@ def mla_ragged_paged_attention(q, pool, page_table, kv_lens, q_lens,
     the same ``tok_slot``, ``tok_off``, ``q_lens``, ``kv_lens``,
     ``page_table``, ``tile`` and ``pages_per_step``, where the caller made it
     once for all layers. ``use_kernel`` as in ``paged_attention``.
+    ``selected``: each row reads only the keys it names, of those it sees
+    (learned sparse attention, ``ops/pallas/dsa_index.py``): where the
+    kernel runs, the 0/1 mask in the tiled layout ``[tiles * tile, blocks *
+    keys]`` (``select_mask``); on the jnp path, ``[t, S]`` bool over packed
+    rows. ``name``: the kernel call's name in the trace (the selected form
+    runs under its own).
     """
     t, heads, row = q.shape
     assert pool.ndim == 5 and pool.shape[2] == 1 and pool.shape[4] == row, (
@@ -416,7 +461,7 @@ def mla_ragged_paged_attention(q, pool, page_table, kv_lens, q_lens,
     if not use_kernel:
         return mla_ragged_paged_attention_reference(
             q, pool, page_table, kv_lens, q_lens, tok_slot, tok_off,
-            v_dim=v_dim, scale=scale, layer=layer)
+            v_dim=v_dim, scale=scale, layer=layer, selected=selected)
     if plan is None:
         plan = tile_plan(tok_slot, tok_off, q_lens, kv_lens, page_table,
                          page_size=pool.shape[3], num_pages=pool.shape[1],
@@ -432,7 +477,7 @@ def mla_ragged_paged_attention(q, pool, page_table, kv_lens, q_lens,
                       ).at[plan.dest].set(q, mode="drop")
     out = _kernel_impl(tiled.reshape(n, tile * heads, row), pool, plan,
                        layer, grid=grid, heads=heads, v_dim=v_dim,
-                       scale=float(scale))
+                       scale=float(scale), selected=selected, name=name)
     out = out.reshape(n * tile, heads, v_dim)
     # a padding row has no place in the tiles (and what holds no real token's
     # row is never written): zero, as the reference gives

@@ -658,8 +658,12 @@ class TestStepScopes:
         with pytest.raises(ValueError, match="STEP_SCOPES"):
             step_scope("layer_norm")
         # PR 28: parts of a part, each inside a scope of the closed list
+        # (PR 34: the learned indexer's two, inside "attn")
         assert set(STEP_SUBSCOPES) == {"moe_route", "moe_experts",
-                                       "moe_shared", "attn_absorb"}
+                                       "moe_shared", "attn_absorb",
+                                       "attn_index", "attn_select"}
+        assert STEP_SUBSCOPES["attn_index"] == "attn" \
+            == STEP_SUBSCOPES["attn_select"]
         assert set(STEP_SUBSCOPES.values()) <= set(STEP_SCOPES)
         assert not set(STEP_SUBSCOPES) & set(STEP_SCOPES)
         with step_scope("moe_route"):
@@ -693,7 +697,7 @@ class TestStepScopes:
                                 os.path.join("models", "gpt_spmd.py"),
                                 os.path.join("models", "moe.py")]
         assert used[os.path.join("models", "gpt.py")] == \
-            set(SERVE_SCOPES) | {"attn_absorb"}
+            set(SERVE_SCOPES) | {"attn_absorb", "attn_index", "attn_select"}
         assert used[os.path.join("models", "gpt_spmd.py")] == \
             set(TRAIN_SCOPES)
         # a routed layer's parts, inside the serving step's "mlp"

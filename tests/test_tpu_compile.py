@@ -217,3 +217,57 @@ def test_compiled_step_at_the_590m_cells_serving_widths(one_chip, monkeypatch,
     assert grid.heads == HEADS and grid.pages > 1
     assert grid.steps([SEQ] * lanes) == lanes * -(-slots // grid.pages) == 192
     assert grid.steps([]) == lanes
+
+
+def test_compiled_sparse_latent_step_keeps_both_planes_in_place(one_chip,
+                                                                monkeypatch):
+    """PR 34: GLM-5.2's block through the same step at the cell's widths (64
+    heads, a low-rank query, 32 index heads, top-2048, 16 of 256 experts
+    held), three layers in three runs: dense with an indexer, routed sharing
+    its selection, routed with an indexer. The score kernel, the selection
+    kernel and the selected form of the latent kernel are Mosaic calls of the
+    program (the dense form's name is NOT in it: what is counted for one is
+    never applied to the other); neither the latent pool nor the index plane
+    is copied; the program's temporaries (scores and selection of 384 tiled
+    rows over 49,152 key slots) stay far under one layer's experts."""
+    import paddle_tpu  # noqa: F401  framework config (matmul precision)
+    from paddle_tpu.models.glm_moe_dsa import GlmMoeDsaConfig
+    from paddle_tpu.models.gpt import build_unified_step
+
+    sys.path.insert(0, REPO)
+    try:
+        from chip_smoke import _is_mosaic_call, pool_copies
+        from benchmark.tools.compile_serve_sparse_latent_moe_for_v5e import (
+            step_avals)
+    finally:
+        sys.path.remove(REPO)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = GlmMoeDsaConfig(
+        vocab_size=19360, num_layers=3, first_k_dense_replace=1,
+        n_routed_experts=16, max_seq_len=49152,
+        indexer_types=("full", "shared", "full"))
+    dep = dict(token_budget=256, max_batch=8, max_seq_len=49152, page_size=64)
+    step = build_unified_step(cfg, 64, 128)
+    avals, (pool, index) = step_avals(cfg, dep, 512, one_chip, jnp.bfloat16)
+    compiled = step.lower(*avals).compile()
+    lines = compiled.as_text().splitlines()
+
+    def calls(kernel):
+        # by the call's own name: a line also names its operands, and the
+        # selection kernel's operand is the score kernel's result
+        return sum(_is_mosaic_call(line, f"/{kernel}/pallas_call")
+                   for line in lines)
+
+    # one call a layer of the attention kernel, one a layer with an indexer
+    # of the other two: the roofline readers multiply by the calls they find
+    assert calls("sparse_mla_paged_attention") == 3
+    assert calls("dsa_index_scores") == calls("dsa_topk_select") == 2
+    assert calls("grouped_matmul") == 4 and calls("paged_kv_write") == 5
+    assert calls("mla_ragged_paged_attention") == 0
+    assert pool.shape == (3, 512, 1, 64, 640)
+    assert index.shape == (2, 512, 1, 64, 128)
+    hlo = "\n".join(lines)
+    assert pool_copies(hlo, pool.shape) == []
+    assert pool_copies(hlo, index.shape) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.4e9

@@ -227,6 +227,55 @@ def test_latent_moe_kernels_lower_at_deepseek_v2_lite_widths(what):
     assert calls == 1
 
 
+@pytest.mark.parametrize("what", ["index scores", "selection",
+                                  "selected latent attention"])
+def test_sparse_latent_kernels_lower_at_glm_widths(what):
+    """PR 34's kernels at the GLM-5.2 cell's widths: 32 index heads of 128
+    over an index plane of 128-value keys, exact top-2048 over 768 page
+    slots of 64, 64 query heads over the 640-wide latent row in tiles of 16
+    tokens, all from one tile plan."""
+    from paddle_tpu.ops.pallas import dsa_index as dsa
+    from paddle_tpu.ops.pallas import mla_paged_attention as mla
+
+    pages, lanes, budget, slots, heads = 512, 16, 256, 768, 64
+    i32, tile = jnp.int32, mla.tile_for_heads(heads)
+    grid = mla.tile_grid(lanes, budget, slots, PAGE, tile)
+    table = sds(lanes, slots, dtype=i32)
+    tok, lane = sds(budget, dtype=i32), sds(lanes, dtype=i32)
+
+    def planned(fn):
+        def with_plan(table, ctx, q_lens, slot, off, *rest):
+            plan = mla.tile_plan(slot, off, q_lens, ctx, table,
+                                 page_size=PAGE, num_pages=pages, tile=tile)
+            return fn(plan, table, ctx, q_lens, slot, off, *rest)
+        return with_plan
+
+    scores = sds(grid.tiles * tile, grid.blocks * grid.keys,
+                 dtype=jnp.float32)
+    if what == "index scores":
+        calls = mosaic_calls(
+            planned(lambda plan, table, ctx, q_lens, slot, off, q, w, pool:
+                    dsa.index_scores(q, w, pool, plan, 1, grid=grid)),
+            table, lane, lane, tok, tok, sds(budget, 32, 128),
+            sds(budget, 32, dtype=jnp.float32), sds(2, pages, 1, PAGE, 128))
+    elif what == "selection":
+        calls = mosaic_calls(
+            planned(lambda plan, table, ctx, q_lens, slot, off, x:
+                    dsa.select_mask(x, plan, grid=grid, k=2048)),
+            table, lane, lane, tok, tok, scores)
+    else:
+        calls = mosaic_calls(
+            planned(lambda plan, table, ctx, q_lens, slot, off, q, pool, sel:
+                    mla.mla_ragged_paged_attention(
+                        q, pool, table, ctx, q_lens, slot, off, v_dim=512,
+                        scale=0.06, layer=2, plan=plan, tile=tile,
+                        selected=sel, name=mla.SPARSE_MLA_KERNEL_NAME)),
+            table, lane, lane, tok, tok, sds(budget, heads, 640),
+            sds(5, pages, 1, PAGE, 640),
+            sds(*scores.shape, dtype=jnp.bfloat16))
+    assert calls == 1
+
+
 def test_fused_mlp_fwd_bwd_lowers():
     """``bench.py --fused-mlp``'s kernels at the train step's shapes (the
     gelu backward's row block is sized by the fast-memory budget)."""
