@@ -122,8 +122,12 @@ accounting in lockstep at every drain.
 
 Knobs: ``max_batch`` (lanes), ``num_pages``/``page_size`` (pool geometry),
 ``max_seq_len`` (page-table width), ``chunk`` (per-slot prefill chunk,
-autotuned default), ``token_budget`` (tokens per step, default
-``max_batch * (1 + spec_k) + chunk``), ``prefix_cache`` (on by
+autotuned default), ``token_budget`` (the MOST rows a step packs, default
+``max_batch * (1 + spec_k) + chunk``: a ceiling on rows and on the step's
+memory, not what a step costs — the one step program computes over the
+smallest rung of ``models/gpt.py step_row_ladder`` that holds the rows
+packed, so a decode-only step of a large budget runs its decode rows;
+``serving_rows_run`` counts the rungs taken), ``prefix_cache`` (on by
 default), ``spec_decode_k`` (speculation build geometry, default
 ``config.spec_decode_k``), ``async_engine`` (the round-13 pipelined
 engine) + ``max_inflight_steps`` (deferral bound for steps that cannot
@@ -365,7 +369,8 @@ class ServingPredictor:
                  host_tier_bytes=0):
         from ..distributed.mesh import as_serving_mesh
         from ..models.gpt import (_serving_params_cached, build_unified_step,
-                                  serving_params, shard_serving_params)
+                                  serving_params, shard_serving_params,
+                                  step_row_ladder)
 
         gpt = model.gpt if hasattr(model, "gpt") else model
         self.config = gpt.config
@@ -522,6 +527,10 @@ class ServingPredictor:
         self._unified = build_unified_step(
             cfg, self.cache.page_size, self.chunk, use_kernel=use_kernel,
             kv_quant=self.kv_quant, mesh=self.mesh, spec_k=self.spec_k)
+        # the row counts that one program runs at: it takes the smallest that
+        # holds the rows a step packs, by this same function
+        self._row_ladder = step_row_ladder(
+            self.max_batch, self.spec_k, self.chunk, self.token_budget)
         # what a scheduled lane's rows and context cost the step's attention
         # kernel in grid steps, by the kernel module's own function (per chip
         # under a mesh)
@@ -746,6 +755,11 @@ class ServingPredictor:
         self._m_rows_decode = m.counter(
             "serving_rows_decode",
             "real rows fed by decode lanes (drafts included)")
+        self._m_rows_run = m.counter(
+            "serving_rows_run",
+            "rows the step program ran: per dispatched step the rung of its "
+            "row ladder that holds the rows packed, by rung",
+            labels=("rung",))
         self._m_queue_wait = m.histogram(
             "serving_queue_wait_ms", "submit -> first admission",
             buckets=(1, 10, 100, 1000, 10000, 100000))
@@ -1934,6 +1948,8 @@ class ServingPredictor:
             return None
         import jax
 
+        from ..models.gpt import step_row_rung
+
         b = self.max_batch
         decode_set = set(decode_slots)
         t = self.token_budget
@@ -2160,6 +2176,8 @@ class ServingPredictor:
             # reconcile (their watermark is n_emit, a device value)
             if not spec_len[slot]:
                 cache.advance(slot, n)
+        rung = self._row_ladder[step_row_rung(self._row_ladder, sum(fed))]
+        self._m_rows_run.labels(rung=str(rung)).inc(rung)
         self._m_attn_live.inc(
             sum(map(self._attn_grid.live_steps, contexts, fed)))
         self._m_attn_grid.inc(self._attn_grid.steps(contexts, fed))

@@ -780,6 +780,35 @@ def _sample_epilogue(logits, keys, temperature, top_k, top_p):
     return sampled.astype(jnp.int32)
 
 
+#: chunks beside the decode rows at the rungs under the budget: each rung is
+#: the whole step traced, lowered and compiled once more (set-up time), so
+#: there are two, the decode-only step's and one for a step with a few chunks
+STEP_ROW_RUNG_CHUNKS = (0, 2)
+
+
+def step_row_ladder(lanes: int, spec_k: int, chunk: int, budget: int):
+    """The row counts ONE serving step program runs at (``build_unified_step``
+    picks a rung per step, inside the program, from ``q_lens``;
+    ``ServingPredictor`` counts the rung the same way): ascending, the last
+    one ``budget``. The rungs stand where the scheduler's steps land: a
+    decode-only step holds at most ``lanes * (1 + spec_k)`` rows (rounded up
+    to the 16 rows of a bf16 tile), and every lane still feeding its prompt
+    adds at most ``chunk`` rows in place of its decode rows. So a rung is
+    the decode rows plus ``k`` chunks, ``k`` of ``STEP_ROW_RUNG_CHUNKS``,
+    where that stays under the budget. One rung (a budget no larger than the
+    decode rows): a program without a conditional."""
+    decode = -(-lanes * (1 + spec_k) // 16) * 16
+    rungs = [decode + k * chunk for k in STEP_ROW_RUNG_CHUNKS]
+    return tuple(r for r in rungs if r < budget) + (budget,)
+
+
+def step_row_rung(ladder, rows):
+    """Index of the smallest rung of ``ladder`` that holds ``rows`` rows (an
+    ``int`` on the host, a traced scalar inside the program: one spelling
+    for both)."""
+    return sum((rows > r) * 1 for r in ladder[:-1])
+
+
 def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
                        use_kernel: bool | None = None,
                        kv_quant: bool = False, mesh=None,
@@ -820,7 +849,15 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
     (``temperature == 0``) take the argmax of the logits; sampling lanes
     run the fused seeded epilogue.
     Every array argument keeps its shape step over step: one trace, one
-    executable (``fn.trace_count[0]`` is the gate).
+    executable (``fn.trace_count[0]`` is the gate) — and several ROW COUNTS
+    inside it (PR 35): the budget ``t`` is a ceiling, and a step computes
+    over the first ``R`` rows alone, ``R`` the smallest rung of
+    :func:`step_row_ladder` that holds the rows the host packed
+    (``q_lens.sum()``, from row 0 on). The program picks the rung itself from
+    ``q_lens``; embedding, projections, attention's operands, FFN, router
+    and the write plan all run over ``R`` rows, and what they did for the
+    rows left out (``tok_slot == -1``) nothing read. A budget no larger than
+    the decode rows has one rung and no conditional (``_at_rung``).
 
     WHERE THE POOLS LIVE (PR 27): ``k_pages`` / ``v_pages`` (and, int8, the
     scale planes) are stacked ``[num_layers, num_pages, kv_heads, page_size,
@@ -905,8 +942,9 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
     for a completing lane). Rejected drafts' K/V sits above the advanced
     watermark — the scheduler rolls their pages back host-side
     (``KVCacheManager.trim_pages``). ``spec_k`` is geometry: one trace
-    per (budget, batch, spec_k), composing with ``kv_quant`` and ``mesh``
-    (the epilogue replicates; donation covers the same pools).
+    per (budget, batch, spec_k), its row ladder inside it, composing with
+    ``kv_quant`` and ``mesh`` (the epilogue replicates; donation covers the
+    same pools).
 
     WHAT THE CONFIGURATION CHOOSES (PR 28). The step's plumbing — packed
     stream, feedback, CoW lanes, the pool-carrying scan, head, sampling — is
@@ -951,6 +989,7 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
     """
     import jax
     import jax.numpy as jnp
+    from jax.extend.core import jaxpr_as_fun
 
     from ..inference.kv_cache import (packed_write_plan, paged_copy_pages,
                                       paged_write_packed,
@@ -1009,8 +1048,67 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
                            emit_mask, produced, pools, page_table, cow_src,
                            cow_dst, base_keys, temperature, top_k, top_p)
 
+    def _at_rung(rows_of, tok_ids, tok_slot, tok_pos, feedback, q_lens,
+                 pools):
+        """What of a step depends on its packed rows (``rows_of``: embedding,
+        the layers, the final norm and the rows the head reads), over the
+        rows the step HOLDS. The host packs a step's rows from row 0 on
+        (``q_lens.sum()`` of them; every row after them has ``tok_slot ==
+        -1`` and a result nothing reads), so it runs over the first ``R``
+        rows alone, ``R`` the smallest rung of :func:`step_row_ladder` that
+        holds them. Pools and results have one shape at every rung.
+
+        One conditional a rung, in sequence, each "this rung, or hand the
+        pools and results on as they are"; exactly one runs. Not one
+        ``lax.switch`` over the rungs: from its third branch on XLA:TPU
+        copies every pool at the branch's edge (two branches updating one
+        donated buffer in place it accepts, a third it does not, nested
+        ``cond``s the same), and a pool-sized copy a step is what PR 27
+        removed. In this form the pools stay one buffer from argument to
+        result (``tests/test_tpu_compile.py``)."""
+        def at(rows, pools):
+            return rows_of(tok_ids[:rows], tok_slot[:rows], tok_pos[:rows],
+                           feedback[:rows], pools)
+
+        ladder = step_row_ladder(q_lens.shape[0], spec_k, chunk,
+                                 tok_ids.shape[0])
+        if len(ladder) == 1:
+            return at(ladder[0], pools)
+        # before any rung ran: the pools as they came, among results of the
+        # shapes a rung gives. The first rung is traced here, for those
+        # shapes, and its conditional replays the equations (a trace of the
+        # step is set-up time: no rung is traced twice)
+        first, shapes = jax.make_jaxpr(
+            functools.partial(at, ladder[0]), return_shape=True)(pools)
+        res = tuple(jnp.zeros(s.shape, s.dtype) for s in shapes)
+        res = tuple(pools) + res[n_pool:]
+        rung = step_row_rung(ladder, q_lens.sum())
+        for i, rows in enumerate(ladder):
+            res = jax.lax.cond(
+                rung == i,
+                (lambda res: tuple(jaxpr_as_fun(first)(*res[:n_pool])))
+                if i == 0 else
+                lambda res, rows=rows: tuple(at(rows, res[:n_pool])),
+                lambda res: res, res)
+        return res
+
+    replays = {}
+
+    def traced_once(fn, *args):
+        """``fn(*args)``: traced when it first meets arguments of these
+        shapes, replayed from its equations after that, within one trace of
+        the step (``fn`` takes every array it reads as an argument)."""
+        flat, tree = jax.tree.flatten(args)
+        key = (tree, tuple(map(jax.typeof, flat)))
+        if key not in replays:
+            replays[key] = jax.make_jaxpr(
+                lambda *flat: fn(*jax.tree.unflatten(tree, flat)))(*flat)
+        out, = jaxpr_as_fun(replays[key])(*flat)
+        return out
+
     def step(*args):
         trace_count[0] += 1
+        replays.clear()
         body = _body
         if mesh is not None:
             from jax.sharding import PartitionSpec as P
@@ -1035,8 +1133,6 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
                     last_idx, spec_len, feedback, prev_toks, emit_mask,
                     produced, pools, page_table, cow_src, cow_dst, base_keys,
                     temperature, top_k, top_p):
-        t = tok_ids.shape[0]
-        b = q_lens.shape[0]
         # copy-on-write BEFORE any write: diverging lanes get a private
         # copy of their shared tail page across every layer (scale planes
         # are page-keyed, so they ride the same copy lanes)
@@ -1044,6 +1140,23 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
             pools = tuple(paged_copy_pages(pool, cow_src, cow_dst,
                                            lane_by_lane=latent)
                           for pool in pools)
+        # what depends on the packed rows runs at a rung of the row ladder
+        # (``_at_rung``); the head and the sampling after it are per lane
+        out = _at_rung(
+            functools.partial(_rows_part, params, q_lens, kv_lens, last_idx,
+                              prev_toks, page_table),
+            tok_ids, tok_slot, tok_pos, feedback, q_lens, pools)
+        return _lanes_part(params, out[:n_pool], out[n_pool],
+                           out[n_pool + 1:], spec_len, prev_toks, emit_mask,
+                           produced, base_keys, temperature, top_k, top_p)
+
+    def _rows_part(params, q_lens, kv_lens, last_idx, prev_toks, page_table,
+                   tok_ids, tok_slot, tok_pos, feedback, pools):
+        """Embedding, the layers and the final norm over the packed rows
+        given, and of them the rows the head reads: ``(*pools, h_rows[,
+        drafts][, expert_rows][, selected])``."""
+        t = tok_ids.shape[0]
+        b = q_lens.shape[0]
         valid = tok_slot >= 0
         slot_c = jnp.clip(tok_slot, 0, b - 1)
         with step_scope("embed"):
@@ -1127,10 +1240,13 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
             with step_scope("attn"):
                 qb = jnp.zeros((b, chunk, nh_l, hd), q.dtype
                                ).at[scatter_b, off_c].set(q, mode="drop")
-                ab = ragged_paged_attention(qb, kp, vp, page_table, ctx,
-                                            q_lens, use_kernel=use_kernel,
-                                            k_scales=ks, v_scales=vs,
-                                            layer=li)
+                # the kernel's operands have one shape at every rung: its
+                # body (a second to trace) is traced for the first
+                ab = traced_once(
+                    lambda *ops: ragged_paged_attention(
+                        *ops[:6], use_kernel=use_kernel, k_scales=ops[6],
+                        v_scales=ops[7], layer=ops[8]),
+                    qb, kp, vp, page_table, ctx, q_lens, ks, vs, li)
                 a = ab[slot_c, off_c]                # back to packed [t]
             return (a.reshape(t, nh_l * hd),
                     (kp, vp, ks, vs) if kv_quant else (kp, vp))
@@ -1187,13 +1303,18 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
                 with step_scope("attn_absorb"):
                     q_abs = jnp.pad(absorb_query(cfg, p, q_nope, q_pe),
                                     ((0, 0), (0, 0), (0, pad)))
-                o_lat = mla_ragged_paged_attention(
-                    q_abs, pool, page_table, ctx, q_lens, tok_slot, off,
-                    v_dim=cfg.kv_lora_rank, scale=softmax_scale(cfg),
-                    layer=li, use_kernel=use_kernel, plan=tiles,
-                    tile=mla_tile, selected=sel,
-                    name=SPARSE_MLA_KERNEL_NAME if sparse
-                    else MLA_KERNEL_NAME)
+                # one shape in every scan of a rung: the kernel's body is
+                # traced for the first
+                o_lat = traced_once(
+                    lambda *ops: mla_ragged_paged_attention(
+                        *ops[:7], v_dim=cfg.kv_lora_rank,
+                        scale=softmax_scale(cfg), layer=ops[7],
+                        use_kernel=use_kernel, plan=ops[8], tile=mla_tile,
+                        selected=ops[9],
+                        name=SPARSE_MLA_KERNEL_NAME if sparse
+                        else MLA_KERNEL_NAME),
+                    q_abs, pool, page_table, ctx, q_lens, tok_slot, off, li,
+                    tiles, sel)
                 with step_scope("attn_absorb"):
                     a = unabsorb_output(cfg, p, o_lat)
             return (a, pools, sel, last_rows) if sparse else (a, pools)
@@ -1267,17 +1388,39 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
                     expert_rows = (rows if expert_rows is None
                                    else expert_rows + rows)
         x, pools = carry[:2]
+        # the rows the head reads: each slot's LAST packed token yields its
+        # next-token decision; speculating, rows last_idx .. last_idx+spec_k
+        # are the lane's verify rows (its last context token, then its
+        # packed draft tokens; a non-speculating lane has spec_len 0 and
+        # only row 0 matters)
+        rows = (last_idx[:, None] + jnp.arange(spec_k + 1)[None] if spec_k
+                else last_idx)
+        with step_scope("head"):
+            x = _srv_norm(cfg, x, params, "lnf")
+            out = (*pools, x[jnp.clip(rows, 0, t - 1)])  # [b, (k1,) h]
+        if spec_k:
+            # drafts ride the packed token stream: [b, k]
+            out += (tok_ids[jnp.clip(rows[:, 1:], 0, t - 1)],)
+        if expert_rows is not None:
+            out += (expert_rows,)
+        if sparse:
+            # per scan with an indexer: [its layers, b, key slots] bool, the
+            # keys each lane's last row read
+            out += tuple(selected)
+        return out
+
+    def _lanes_part(params, pools, h_rows, extras, spec_len, prev_toks,
+                    emit_mask, produced, base_keys, temperature, top_k,
+                    top_p):
+        """Head and sampling over the rows ``_rows_part`` picked, and the
+        step's results in their order (``extras``: its results after the
+        rows, as they came)."""
+        b = prev_toks.shape[0]
         if spec_k:
             # -- speculative verify + fused accept epilogue --------------
-            # rows last_idx .. last_idx+spec_k are the lane's verify rows
-            # (its last context token, then its packed draft tokens); a
-            # non-speculating lane has spec_len 0 and only row 0 matters
             k1 = spec_k + 1
-            rows = last_idx[:, None] + jnp.arange(k1)[None]     # [b, k1]
-            rows_c = jnp.clip(rows, 0, t - 1)
+            drafts, *extras = extras
             with step_scope("head"):
-                x = _srv_norm(cfg, x, params, "lnf")
-                h_rows = x[rows_c]                              # [b,k1,h]
                 logits_rows = _srv_logits(params,
                                           h_rows).astype(jnp.float32)
             v = logits_rows.shape[-1]
@@ -1305,9 +1448,7 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
                 out_ids = jnp.where((temperature > 0.0)[:, None], sampled,
                                     greedy)
                 # accept while draft i matches the token the model
-                # actually emits at its position: drafts ride the packed
-                # token stream
-                drafts = tok_ids[jnp.clip(rows[:, 1:], 0, t - 1)]  # [b, k]
+                # actually emits at its position
                 ok = ((drafts == out_ids[:, :spec_k])
                       & (jnp.arange(spec_k)[None] < spec_len[:, None]))
                 n_emit = (1 + jnp.cumprod(ok.astype(jnp.int32),
@@ -1317,12 +1458,10 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
                     out_ids, jnp.maximum(n_emit - 1, 0)[:, None],
                     axis=1)[:, 0]
                 next_toks = jnp.where(emit_mask > 0, last_emit, prev_toks)
-            return (out_ids, n_emit, next_toks, logits_rows[:, 0], *pools)
+            return (out_ids, n_emit, next_toks, logits_rows[:, 0], *pools,
+                    *extras)
         with step_scope("head"):
-            x = _srv_norm(cfg, x, params, "lnf")
-            # each slot's LAST packed token yields its next-token decision
-            h_last = x[jnp.clip(last_idx, 0, t - 1)]              # [b, h]
-            logits = _srv_logits(params, h_last).astype(jnp.float32)
+            logits = _srv_logits(params, h_rows).astype(jnp.float32)
 
         # the epilogue's [b, vocab] sort/softmax/cumsum (and the key
         # folds) only EXECUTE on steps where some lane actually samples —
@@ -1342,14 +1481,13 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
             # the previous token through (a lane skipped by the budget
             # still feeds its latest token through feedback next step)
             next_toks = jnp.where(emit_mask > 0, next_ids, prev_toks)
+        # after the pools: ``expert_rows`` where experts are routed, then,
+        # where the model has an indexer, the selected keys, last:
+        # [layers with an indexer, b, key slots] bool
         if sparse:
-            # last: [layers with an indexer, b, key slots] bool, the keys
-            # each lane's last row read
-            return (next_toks, logits, *pools, expert_rows,
-                    jnp.concatenate(selected))
-        if expert_rows is not None:
-            return (next_toks, logits, *pools, expert_rows)
-        return (next_toks, logits, *pools)
+            extras = [e for e in extras if e.dtype != jnp.bool_] + [
+                jnp.concatenate([e for e in extras if e.dtype == jnp.bool_])]
+        return (next_toks, logits, *pools, *extras)
 
     jitted = jit32(step,
                    donate_argnums=tuple(range(n_lead, n_lead + n_pool)))

@@ -7,6 +7,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from benchmark.tests.test_benchmark import *  # noqa: E402,F401,F403
 from benchmark.tests.test_scope_trace import *  # noqa: E402,F401,F403
+from benchmark.tests.test_step_row_fill_share import *  # noqa: E402,F401,F403
 # ``test_a_latent_predictor_has_neither_counter`` of that file is not
 # collected: PR 33 gave the latent predictor both counters (their test is in
 # ``tests/test_latent_moe_serving.py``); the file is a later benchmark PR's

@@ -123,7 +123,7 @@ def test_compiled_latent_step_keeps_its_one_pool_in_one_buffer(one_chip,
     layer's experts (1.1 GB)."""
     import paddle_tpu  # noqa: F401  framework config (matmul precision)
     from paddle_tpu.models.deepseek_v2 import DeepseekV2Config
-    from paddle_tpu.models.gpt import build_unified_step
+    from paddle_tpu.models.gpt import build_unified_step, step_row_ladder
     from paddle_tpu.ops.pallas.mla_paged_attention import (MLA_KERNEL_NAME,
                                                            tile_grid)
 
@@ -147,10 +147,13 @@ def test_compiled_latent_step_keeps_its_one_pool_in_one_buffer(one_chip,
         assert any(_is_mosaic_call(line, kernel)
                    for line in hlo.splitlines()), kernel
     # PR 33: exactly one call of the latent kernel in a layer scan's body
-    # (the dense layer's scan and the routed layer's: two in the program);
-    # the benchmark's roofline reader multiplies by the calls of that name
+    # (the dense layer's scan and the routed layer's: two a step); PR 35: the
+    # program holds the step once a rung of its row ladder and runs one
+    rungs = len(step_row_ladder(dep["max_batch"], 0, 64,
+                                dep["token_budget"]))
+    assert rungs == 3
     assert sum(_is_mosaic_call(line, MLA_KERNEL_NAME)
-               for line in hlo.splitlines()) == 2
+               for line in hlo.splitlines()) == 2 * rungs
     pool = avals[11]
     assert pool.shape == (2, 512, 1, 64, 640)
     assert pool_copies(hlo, pool.shape) == []
@@ -188,7 +191,8 @@ def test_compiled_step_at_the_590m_cells_serving_widths(one_chip, monkeypatch,
     pages a step) grid steps (every lane at the table's end; one a lane when
     all are idle), by the function the kernel module exports."""
     import paddle_tpu  # noqa: F401  framework config (matmul precision)
-    from paddle_tpu.models.gpt import GPTConfig, build_unified_step
+    from paddle_tpu.models.gpt import (GPTConfig, build_unified_step,
+                                       step_row_ladder)
     from paddle_tpu.ops.pallas.paged_attention import (RAGGED_KERNEL_NAME,
                                                        ragged_grid)
 
@@ -207,8 +211,11 @@ def test_compiled_step_at_the_590m_cells_serving_widths(one_chip, monkeypatch,
                               BUDGET=512)
     compiled = step.lower(*avals).compile()
     hlo = compiled.as_text()
+    # one call a rung of the row ladder (PR 35: 32 / 160 / 512 rows), one
+    # rung a step; the pools stay one buffer through the conditionals
+    assert step_row_ladder(lanes, 0, CHUNK, 512) == (32, 160, 512)
     assert sum(_is_mosaic_call(line, RAGGED_KERNEL_NAME)
-               for line in hlo.splitlines()) == 1
+               for line in hlo.splitlines()) == 3
     assert pool_copies(hlo, pool.shape) == []
     assert compiled.memory_analysis().temp_size_in_bytes < (
         pool.size * pool.dtype.itemsize)
@@ -232,7 +239,7 @@ def test_compiled_sparse_latent_step_keeps_both_planes_in_place(one_chip,
     rows over 49,152 key slots) stay far under one layer's experts."""
     import paddle_tpu  # noqa: F401  framework config (matmul precision)
     from paddle_tpu.models.glm_moe_dsa import GlmMoeDsaConfig
-    from paddle_tpu.models.gpt import build_unified_step
+    from paddle_tpu.models.gpt import build_unified_step, step_row_ladder
 
     sys.path.insert(0, REPO)
     try:
@@ -260,10 +267,15 @@ def test_compiled_sparse_latent_step_keeps_both_planes_in_place(one_chip,
                    for line in lines)
 
     # one call a layer of the attention kernel, one a layer with an indexer
-    # of the other two: the roofline readers multiply by the calls they find
-    assert calls("sparse_mla_paged_attention") == 3
-    assert calls("dsa_index_scores") == calls("dsa_topk_select") == 2
-    assert calls("grouped_matmul") == 4 and calls("paged_kv_write") == 5
+    # of the other two, in each rung of the row ladder (PR 35; a step runs
+    # one rung): the roofline readers count the calls the trace shows
+    rungs = len(step_row_ladder(dep["max_batch"], 0, 128,
+                                dep["token_budget"]))
+    assert rungs == 2
+    assert calls("sparse_mla_paged_attention") == 3 * rungs
+    assert calls("dsa_index_scores") == calls("dsa_topk_select") == 2 * rungs
+    assert calls("grouped_matmul") == 4 * rungs
+    assert calls("paged_kv_write") == 5 * rungs
     assert calls("mla_ragged_paged_attention") == 0
     assert pool.shape == (3, 512, 1, 64, 640)
     assert index.shape == (2, 512, 1, 64, 128)
