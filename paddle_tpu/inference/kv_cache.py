@@ -353,6 +353,113 @@ def _payload_crc(planes: dict) -> int:
 # ---------------------------------------------------------------------------
 
 
+class _WindowGroup:
+    """The cache GROUP of the layers that attend a WINDOW (sliding-window
+    attention): their K and V pools ``[layers, num_pages, kv_heads,
+    page_size, head_dim]``, a free list and a page table of their own. A lane
+    holds only the pages its next rows can still see: before a step that
+    feeds ``n <= chunk`` rows from position ``written`` the scheduler releases
+    the pages wholly behind ``written - tokens + 1`` (the lower edge of the
+    step's first row) and grows the lane to ``written + n``, so a lane never
+    holds more than ``pages_per_slot`` pages, whatever its context. Row ``k``
+    of a lane's table is the page of positions ``(first + k) * page_size
+    ..``: the table starts at the lane's first HELD page, and the step is
+    handed ``first * page_size`` beside it (:meth:`device`), the position its
+    coordinates count from.
+
+    A released page goes back to the free list at once, while steps
+    dispatched before may still be in flight, and a row of such a step (its
+    lower edge lies further back) may still READ the page. What keeps that
+    safe is order, not visibility: every step was dispatched with a private
+    copy of the table as it stood, the device runs steps in dispatch order
+    (each takes the pools the one before returns), and a page is REWRITTEN
+    only by a step dispatched after the release (the earliest is the step
+    being packed, whose own rows no longer see it), so a new owner's first
+    write lands after every read of the old rows."""
+
+    def __init__(self, layers, tokens, chunk, *, max_batch, num_kv_heads,
+                 head_dim, page_size, dtype):
+        self.layers, self.tokens = int(layers), int(tokens)
+        self.page_size = int(page_size)
+        # tokens - 1 positions behind the first new row, chunk new rows, at
+        # any alignment to the pages
+        self.pages_per_slot = -(-(self.tokens + int(chunk) - 1)
+                                // self.page_size) + 1
+        self.num_pages = int(max_batch) * self.pages_per_slot
+        shape = (self.layers, self.num_pages, num_kv_heads, self.page_size,
+                 head_dim)
+        self.k_pages = jnp.zeros(shape, dtype)
+        self.v_pages = jnp.zeros(shape, dtype)
+        self.table = np.full((max_batch, self.pages_per_slot), -1, np.int32)
+        self.first = np.zeros((max_batch,), np.int32)
+        self.free = list(range(self.num_pages - 1, -1, -1))   # pop()
+        self.released = 0
+        self._rev = 0
+        self._dev = (-1, None)
+
+    def held(self, slot=None) -> int:
+        """Pages a lane holds (None: all lanes)."""
+        if slot is None:
+            return self.num_pages - len(self.free)
+        return int((self.table[slot] >= 0).sum())
+
+    def release_behind(self, slot: int, written: int) -> int:
+        """Release ``slot``'s pages wholly behind the lower edge of a row at
+        position ``written``. Returns how many."""
+        edge = max(0, int(written) - self.tokens + 1) // self.page_size
+        drop = min(edge - int(self.first[slot]), self.pages_per_slot)
+        if drop <= 0:
+            return 0
+        gone = [int(p) for p in self.table[slot, :drop] if p >= 0]
+        self.free.extend(reversed(gone))
+        self.table[slot] = np.concatenate(
+            [self.table[slot, drop:], np.full((drop,), -1, np.int32)])
+        self.first[slot] = edge
+        self.released += len(gone)
+        self._rev += 1
+        return len(gone)
+
+    def need(self, slot: int, new_len: int) -> int:
+        """Pages ``slot`` still has to claim to hold ``new_len`` tokens."""
+        want = pages_needed(new_len, self.page_size) - int(self.first[slot])
+        return max(0, want - self.held(slot))
+
+    def grow(self, slot: int, new_len: int) -> bool:
+        """Claim the pages ``slot`` lacks to hold ``new_len`` tokens; whether
+        it claimed any."""
+        have = self.held(slot)
+        want = pages_needed(new_len, self.page_size) - int(self.first[slot])
+        if want > self.pages_per_slot:
+            raise RuntimeError(
+                f"slot {slot}: {want} window pages for {new_len} tokens from "
+                f"page {int(self.first[slot])}, over the lane's bound "
+                f"{self.pages_per_slot}: release_behind was not called, or "
+                "the step feeds more rows than the group was built for")
+        for k in range(have, want):
+            self.table[slot, k] = self.free.pop()
+        if want > have:
+            self._rev += 1
+        return want > have
+
+    def free_slot(self, slot: int) -> None:
+        gone = [int(p) for p in self.table[slot] if p >= 0]
+        self.free.extend(reversed(gone))
+        self.table[slot] = -1
+        self.first[slot] = 0
+        self._rev += 1
+
+    def device(self):
+        """``(table [batch, pages_per_slot], base [batch])`` on the device:
+        the group's page table and the position each lane's table starts at.
+        Uploaded from private copies when stale (as the full table)."""
+        rev, dev = self._dev
+        if rev != self._rev:
+            dev = (jnp.asarray(self.table.copy()),
+                   jnp.asarray(self.first * np.int32(self.page_size)))
+            self._dev = (self._rev, dev)
+        return dev
+
+
 class KVCacheManager:
     """Owns the page pool + page table + free lists for one model.
 
@@ -367,8 +474,29 @@ class KVCacheManager:
                  max_batch, max_seq_len, page_size=None, num_q_heads=None,
                  dtype=jnp.float32, enable_prefix_cache=False,
                  quantize_kv=False, mesh=None, metrics=None,
-                 host_tier_bytes=0, latent=False, index_plane=None):
+                 host_tier_bytes=0, latent=False, index_plane=None,
+                 window=None):
         from ..ops.pallas.paged_attention import preferred_page_size
+
+        # layers that attend a WINDOW: ``window=(layers, tokens, chunk)``,
+        # ``layers`` of the ``num_layers`` keep their last ``tokens``
+        # positions alone (a step feeds a lane at most ``chunk`` rows). They
+        # are a cache GROUP of their own (:class:`_WindowGroup`); the pools,
+        # table and free list below are the full layers'. What is not built
+        # over two groups fails here.
+        if window is not None:
+            unsupported = [name for name, on in (
+                ("enable_prefix_cache (a hit needs the last window's pages "
+                 "alive in the window group)", enable_prefix_cache),
+                ("quantize_kv", quantize_kv), ("mesh", mesh is not None),
+                ("host_tier_bytes", host_tier_bytes),
+                ("latent", latent)) if on]
+            if unsupported or not 0 < int(window[0]) < num_layers:
+                raise NotImplementedError(
+                    f"a cache with a window group ({window[0]} of "
+                    f"{num_layers} layers) does not take "
+                    f"{', '.join(unsupported) or 'that split of layers'} "
+                    "yet")
 
         # a LATENT cache (multi-head latent attention): ONE pool, one row
         # per token shared by every head — ``num_kv_heads`` 1, ``head_dim``
@@ -392,6 +520,11 @@ class KVCacheManager:
             raise ValueError(
                 f"the mp mesh size {int(mesh.shape['mp'])} must divide "
                 f"kv heads {num_kv_heads} (pages shard by whole head)")
+        self.window = None if window is None else _WindowGroup(
+            *window, max_batch=max_batch, num_kv_heads=num_kv_heads,
+            head_dim=head_dim, page_size=page_size, dtype=dtype)
+        if self.window is not None:
+            num_layers -= self.window.layers     # the full group's
         self.num_layers = num_layers
         self.num_kv_heads = num_kv_heads
         self.head_dim = head_dim
@@ -553,6 +686,15 @@ class KVCacheManager:
         self._m_restore_scatters = m.counter(
             "kv_tier_restore_device_calls", "device scatter calls issued "
             "by batched imports (one per plane per round)")
+        if self.window is not None:
+            self._m_window_held = m.gauge(
+                "kv_window_pages_held", "pages the window group's lanes "
+                "hold (each over the group's layers)")
+            self._m_window_released = m.counter(
+                "kv_window_pages_released", "window-group pages released "
+                "behind a lane's window")
+            self._m_full_held = m.gauge(
+                "kv_full_pages_held", "pages the full group's lanes hold")
         self._note_occupancy()
 
     def _note_occupancy(self) -> None:
@@ -565,6 +707,12 @@ class KVCacheManager:
         self._m_withheld.set(len(self._withheld))
         self._m_tier_pages.set(len(self._host_tier))
         self._m_tier_bytes.set(self._host_tier_nbytes)
+        if self.window is not None:
+            # (no page of such a cache is shared or registered: what is
+            # not free or withheld is held by one lane)
+            self._m_window_held.set(self.window.held())
+            self._m_full_held.set(self.num_pages - self.available_page_count
+                                  - len(self._withheld))
 
     # -- back-compat metric reads (pre-round-15 attribute surface) ---------
 
@@ -844,12 +992,20 @@ class KVCacheManager:
         scheduler then evicts or stalls the sequence."""
         if new_len > self.max_seq_len:
             return False
+        if self.window is not None:
+            # both groups or neither (the window group is sized for every
+            # lane's bound, so after release_window it has the pages)
+            if self.window.need(slot, new_len) > len(self.window.free):
+                return False
         have = int((self._page_table[slot] >= 0).sum())
         need = self.pages_needed(new_len)
-        if need <= have:
-            return True
         if need - have > self.available_page_count:
             return False
+        if self.window is not None and self.window.grow(slot, new_len) \
+                and need <= have:
+            self._note_occupancy()
+        if need <= have:
+            return True
         for i in range(have, need):
             page = self._alloc_page()
             self._page_table[slot, i] = page
@@ -857,6 +1013,18 @@ class KVCacheManager:
         self._pt_rev += 1
         self._note_occupancy()
         return True
+
+    def release_window(self, slot: int) -> int:
+        """Return to the window group's free list the pages of ``slot`` that
+        no row from its written length on can see (a cache without a window
+        group: nothing). The scheduler calls it when it packs a step, before
+        it grows the lane. Returns the pages released."""
+        if self.window is None:
+            return 0
+        n = self.window.release_behind(slot, int(self._seq_lens[slot]))
+        if n:
+            self._m_window_released.inc(n)
+        return n
 
     def advance(self, slot: int, n: int = 1) -> None:
         self._seq_lens[slot] += n
@@ -992,6 +1160,8 @@ class KVCacheManager:
             if pg >= 0:
                 self._release_page(pg)
             self._page_table[slot, i] = -1
+        if self.window is not None:
+            self.window.free_slot(slot)
         self._seq_lens[slot] = 0
         self._pt_rev += 1
         self._sl_rev += 1
@@ -1203,11 +1373,11 @@ class KVCacheManager:
 
     def _planes(self) -> dict:
         """Payload plane name -> this manager's pool array."""
-        if self.latent:
+        if self.latent or self.window is not None:
             raise NotImplementedError(
                 "page payloads (kv_transfer frames, host-tier entries, "
                 "imported prefix pages) are not defined for a latent cache "
-                "yet")
+                "or for a cache with a window group yet")
         return {name: getattr(self, attr)
                 for name, attr in _PLANE_ATTRS.items()
                 if self.quantize_kv or name in ("k", "v")}
@@ -1465,14 +1635,23 @@ class KVCacheManager:
             return (self.k_pages, self.index_pages)
         if self.latent:
             return (self.k_pages,)
+        if self.window is not None:
+            return (self.k_pages, self.v_pages, self.window.k_pages,
+                    self.window.v_pages)
         if self.quantize_kv:
             return (self.k_pages, self.v_pages, self.k_scales, self.v_scales)
         return (self.k_pages, self.v_pages)
 
-    def update_pages(self, k_pages, v_pages=None, k_scales=None,
-                     v_scales=None) -> None:
-        """Adopt the pools returned by a jitted serving step (scale
-        planes too on the int8-KV path; a latent cache's one pool)."""
+    def update_pages(self, *pools) -> None:
+        """Adopt the pools returned by a jitted serving step, in
+        :meth:`pools`'s order (scale planes too on the int8-KV path; a latent
+        cache's one pool [and its index plane]; the full group's pair and
+        then the window group's)."""
+        if self.window is not None:
+            (self.k_pages, self.v_pages, self.window.k_pages,
+             self.window.v_pages) = pools
+            return
+        k_pages, v_pages, k_scales, v_scales = (pools + (None,) * 3)[:4]
         self.k_pages = k_pages
         if self.index_pages is not None:
             self.index_pages = v_pages   # the latent pool's second plane

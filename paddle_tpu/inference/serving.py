@@ -427,17 +427,24 @@ class ServingPredictor:
         # scans it, in its serving dtype, on the device: nothing is extracted
         # or copied. What this path does not extend to it yet fails here.
         self.latent = bool(getattr(cfg, "kv_lora_rank", 0))
-        if self.latent:
+        # a model whose layers retain different things (window layers keep
+        # their last ``sliding_window`` positions alone; models/
+        # cohere2_moe.py): a second cache group, and the same refusals
+        self.windowed = not self.latent and bool(
+            getattr(cfg, "sliding_window", 0))
+        if self.latent or self.windowed:
             unsupported = [name for name, on in (
                 ("kv_cache_dtype", kv_cache_dtype), ("mesh", mesh),
                 ("spec_decode_k", spec_decode_k),
                 ("host_tier_bytes", host_tier_bytes),
                 ("draft_source='model'", draft_source == "model"),
-                ("draft_layers", draft_layers)) if on]
+                ("draft_layers", draft_layers),
+                ("prefix_cache", self.windowed and prefix_cache)) if on]
             if unsupported:
                 raise NotImplementedError(
-                    f"not supported for a latent (MLA) cache yet: "
-                    f"{', '.join(unsupported)}")
+                    f"not supported for a "
+                    f"{'latent (MLA) cache' if self.latent else 'cache with a window group'}"
+                    f" yet: {', '.join(unsupported)}")
             import jax
 
             self.params = (model.params if dtype is None else jax.tree.map(
@@ -485,19 +492,25 @@ class ServingPredictor:
                 cfg.num_heads, cfg.num_heads, cfg.head_dim, kv_dtype)
             num_pages = self.max_batch * pages_needed(self.max_seq_len, ps)
         if prefix_cache is None:
-            prefix_cache = True
+            # (a hit needs the last window's pages alive in a window group)
+            prefix_cache = not self.windowed
         # a latent cache: one pool of one row per token, the row padded to
         # whole 128-lane tiles (compiled for the chip, an unpadded 576-wide
         # row costs a copy of the whole pool at every kernel call)
         kv_heads, kv_width = ((1, -(-cfg.latent_dim // 128) * 128)
                               if self.latent
-                              else (cfg.num_heads, cfg.head_dim))
+                              else (getattr(cfg, "num_kv_heads", None)
+                                    or cfg.num_heads, cfg.head_dim))
+        self.chunk = int(chunk or preferred_chunk_size(
+            cfg.num_heads, cfg.num_heads, cfg.head_dim, kv_dtype))
         # learned sparse attention: the indexer layers' keys, a second plane
         self.sparse = self.latent and bool(getattr(cfg, "index_topk", 0))
         self.cache = KVCacheManager(
             cfg.num_layers, kv_heads, kv_width, latent=self.latent,
             **({"index_plane": (cfg.num_index_layers, cfg.index_head_dim)}
                if self.sparse else {}),
+            **({"window": (cfg.num_window_layers, cfg.sliding_window,
+                           self.chunk)} if self.windowed else {}),
             num_pages=num_pages, max_batch=self.max_batch,
             max_seq_len=self.max_seq_len, page_size=page_size,
             num_q_heads=cfg.num_heads, dtype=kv_dtype,
@@ -506,8 +519,6 @@ class ServingPredictor:
             # round 21: the host-DRAM spill tier under the HBM pool
             # (0 disables — evictions drop exactly like pre-21)
             host_tier_bytes=host_tier_bytes)
-        self.chunk = int(chunk or preferred_chunk_size(
-            cfg.num_heads, cfg.num_heads, cfg.head_dim, kv_dtype))
         # round 12: speculative decoding — build geometry for the verify
         # rows ([b, k+1] outputs); per-request adaptive k only varies the
         # spec_len values, so one executable serves every k <= spec_k
@@ -542,14 +553,15 @@ class ServingPredictor:
                 self.max_batch, self.token_budget, self.cache.pages_per_slot,
                 self.cache.page_size, tile_for_heads(cfg.num_heads))
         else:
-            from ..ops.pallas.paged_attention import ragged_grid
+            from ..ops.pallas.paged_attention import (lane_block_rows,
+                                                      ragged_grid)
 
-            heads = cfg.num_heads // (self.mesh.shape["mp"]
-                                      if self.mesh is not None else 1)
+            mp = self.mesh.shape["mp"] if self.mesh is not None else 1
             self._attn_grid = ragged_grid(
                 self.max_batch, self.cache.pages_per_slot, self.chunk,
-                heads, heads, self.cache.page_size, cfg.head_dim,
-                "int8" if self.kv_quant else kv_dtype, kv_dtype)
+                cfg.num_heads // mp, kv_heads // mp, self.cache.page_size,
+                cfg.head_dim, "int8" if self.kv_quant else kv_dtype,
+                kv_dtype)
         self._m_attn_live = self.metrics.counter(
             "serving_attn_blocks_live",
             "grid steps of the step's paged attention kernel that hold "
@@ -558,6 +570,33 @@ class ServingPredictor:
             "serving_attn_blocks_grid",
             "grid steps a call of the step's paged attention kernel "
             "launches, summed over dispatched steps")
+        if self.windowed:
+            # a call a layer, of two kinds: the counters above then sum a
+            # step's calls, a window layer's steps from its first live block
+            # (in its own table's coordinates)
+            # (``lane_block_rows``: a grid pair a rung)
+            def grids(rung):
+                rows = lane_block_rows(self.chunk, rung, cfg.num_heads,
+                                       kv_heads)
+                return tuple(ragged_grid(
+                    self.max_batch, pps, rows, cfg.num_heads, kv_heads,
+                    self.cache.page_size, cfg.head_dim, kv_dtype, kv_dtype)
+                    for pps in (self.cache.pages_per_slot,
+                                self.cache.window.pages_per_slot))
+
+            self._attn_grids = {rung: grids(rung)
+                                for rung in self._row_ladder}
+            self._attn_grid, self._attn_grid_window = grids(
+                self._row_ladder[-1])
+            self._m_window_read = self.metrics.counter(
+                "serving_window_keys_read",
+                "keys attention read: per scheduled row and attention layer, "
+                "the keys its mask admits (a window layer: at most the "
+                "window)")
+            self._m_window_context = self.metrics.counter(
+                "serving_window_keys_context",
+                "keys attention would read with no window: per scheduled row "
+                "and attention layer, its context")
         # learned sparse attention: per scheduled row, the keys its indexer
         # scored, the keys its attention read and the keys it would have
         # read without a selection, each summed over the layers that do it
@@ -1498,6 +1537,32 @@ class ServingPredictor:
             self._m_moe_expert_rows.labels(expert=str(expert)).inc(
                 int(rows[expert]))
 
+    def _note_window_step(self, slots, contexts, fed, rung) -> None:
+        """A dispatched step's attention over two kinds of layer, counted on
+        the host from the lanes' lengths: the kernel's live and launched
+        grid steps summed over the step's calls (a window layer's from its
+        first live block, in its own table's coordinates), and the keys the
+        scheduled rows read against those they would read with no window."""
+        cfg, win = self.config, self.cache.window
+        full_layers = cfg.num_layers - win.layers
+        base = [int(win.first[s]) * win.page_size for s in slots]
+        rel = [c - b for c, b in zip(contexts, base)]
+        (grid, wgrid), w = self._attn_grids[rung], win.tokens
+        self._m_attn_live.inc(
+            full_layers * sum(map(grid.live_steps, contexts, fed))
+            + win.layers * sum(wgrid.live_steps(c, n, w)
+                               for c, n in zip(rel, fed)))
+        self._m_attn_grid.inc(
+            full_layers * grid.steps(contexts, fed)
+            + win.layers * wgrid.steps(rel, fed, w))
+        seen = read = 0
+        for c, n in zip(contexts, fed):
+            # rows at positions c - n .. c - 1 see p + 1 keys each
+            seen += n * (c - n) + n * (n + 1) // 2
+            read += sum(min(p + 1, w) for p in range(c - n, c))
+        self._m_window_context.inc(cfg.num_layers * seen)
+        self._m_window_read.inc(full_layers * seen + win.layers * read)
+
     def _note_first_token(self, req: Request) -> None:
         req.first_token_time = monotonic()
         self._m_ttft.observe((req.first_token_time - req.submit_time) * 1e3)
@@ -1893,6 +1958,8 @@ class ServingPredictor:
                 self._finish(req)
                 continue
             n = min(sched[slot], self.max_seq_len - written)
+            # a window group's pages that no row from here on can see
+            cache.release_window(slot)
             if slot in drafts:
                 # AUTHORITATIVE draft clamp, at claim time: earlier slots
                 # in this loop may have consumed the free pages counted
@@ -2107,6 +2174,8 @@ class ServingPredictor:
                 self._put_cached("temp", temp),
                 self._put_cached("top_k", top_k),
                 self._put_cached("top_p", top_p))
+        if self.windowed:
+            tail += cache.window.device()
         pools = cache.pools()
         # per-lane trace instants on the request lanes (tracing only):
         # what kind of work each scheduled request got this step
@@ -2178,9 +2247,12 @@ class ServingPredictor:
                 cache.advance(slot, n)
         rung = self._row_ladder[step_row_rung(self._row_ladder, sum(fed))]
         self._m_rows_run.labels(rung=str(rung)).inc(rung)
-        self._m_attn_live.inc(
-            sum(map(self._attn_grid.live_steps, contexts, fed)))
-        self._m_attn_grid.inc(self._attn_grid.steps(contexts, fed))
+        if self.windowed:
+            self._note_window_step(list(sched), contexts, fed, rung)
+        else:
+            self._m_attn_live.inc(
+                sum(map(self._attn_grid.live_steps, contexts, fed)))
+            self._m_attn_grid.inc(self._attn_grid.steps(contexts, fed))
         if self.sparse:
             cfg = self.config
             seen, read = map(sum, zip(*(

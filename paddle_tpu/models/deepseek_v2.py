@@ -323,8 +323,9 @@ def routed_ffn(config, p, y, use_kernel=None, valid=None, with_counts=False,
 
     cfg = config
     # what a configuration of this family may state beyond DeepSeek-V2's:
-    # sigmoid scores chosen by score plus a correction bias (``moe_bias``),
-    # and a chip's SHARE of the experts (``experts_held``)
+    # sigmoid scores chosen by score plus a correction bias (``moe_bias``;
+    # none where the layer holds none), a chip's SHARE of the experts
+    # (``experts_held``), and shared experts that are averaged
     more = {}
     if getattr(cfg, "scoring_func", "softmax") != "softmax":
         more.update(scoring=cfg.scoring_func, route_bias=p.get("moe_bias"))
@@ -338,7 +339,12 @@ def routed_ffn(config, p, y, use_kernel=None, valid=None, with_counts=False,
         use_kernel=use_kernel, valid=valid, with_stats=True, layer=layer,
         **more)
     with step_scope("moe_shared"):
-        out = out + gated_mlp(y, p["sh_w_gu"], p["sh_w_d"])
+        shared = gated_mlp(y, p["sh_w_gu"], p["sh_w_d"])
+        # shared experts that are AVERAGED, not summed (``models/
+        # cohere2_moe.py``): their one summed MLP over their number
+        scale = getattr(cfg, "shared_expert_scale", None)
+        out = out + (shared if scale is None
+                     else (shared * scale).astype(shared.dtype))
     if not with_counts:
         return out
     import jax.numpy as jnp

@@ -14,6 +14,7 @@ mp_layers.py:47,:333,:540) where GSPMD emits the collectives.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import math
 from dataclasses import dataclass
@@ -515,18 +516,22 @@ def _srv_ln(x, g, b, eps):
     xf = x.astype(jnp.float32)
     mu = xf.mean(-1, keepdims=True)
     var = xf.var(-1, keepdims=True)
-    return (((xf - mu) * jax.lax.rsqrt(var + eps)) * g + b).astype(x.dtype)
+    out = ((xf - mu) * jax.lax.rsqrt(var + eps)) * g
+    # ``b`` None: a LayerNorm with a weight and no bias (Cohere's)
+    return (out if b is None else out + b).astype(x.dtype)
 
 
 def _srv_norm(config, x, p, name):
     """The block's norm ``name`` (``ln1`` / ``ln2`` / ``lnf``) over the
     weights ``p``, as the configuration has it: RMSNorm with a weight alone
-    where it states ``rms_norm_eps``, else the biased LayerNorm."""
+    where it states ``rms_norm_eps``, else LayerNorm, biased where the
+    weights hold a bias."""
     if hasattr(config, "rms_norm_eps"):
         from .deepseek_v2 import rms_norm
 
         return rms_norm(x, p[name + "_g"], config.rms_norm_eps)
-    return _srv_ln(x, p[name + "_g"], p[name + "_b"], config.layer_norm_eps)
+    return _srv_ln(x, p[name + "_g"], p.get(name + "_b"),
+                   config.layer_norm_eps)
 
 
 def _srv_logits(params, h):
@@ -616,13 +621,22 @@ def _srv_ffn(config, p, y, use_kernel=None, axis=None, valid=None,
     return _srv_mlp(p, y, use_kernel, axis), None
 
 
-def _split_qkv(qkv, nh, hd, head_major):
+def _split_qkv(qkv, nh, hd, head_major, nkv=None):
     """[..., 3*nh*hd] -> (q, k, v) each [..., nh, hd]. The eager layout
     orders the fused projection's columns [3, nh, hd]; the mesh layout is
     HEAD-MAJOR [nh, 3, hd] (``shard_serving_params`` permutes the columns)
     so a contiguous mp shard owns whole heads. Both splits read the same
-    dot products — bit-identical outputs, only column order moves."""
+    dot products — bit-identical outputs, only column order moves.
+    GROUPED queries (``nkv`` key-value heads, fewer than ``nh``): the
+    columns are ``[q: nh, hd | k: nkv, hd | v: nkv, hd]`` and k and v come
+    back ``[..., nkv, hd]``."""
     lead = qkv.shape[:-1]
+    if nkv is not None and nkv != nh:
+        assert not head_major, "grouped queries have no head-major layout"
+        q, k, v = (qkv[..., :nh * hd], qkv[..., nh * hd:(nh + nkv) * hd],
+                   qkv[..., (nh + nkv) * hd:])
+        return (q.reshape(*lead, nh, hd), k.reshape(*lead, nkv, hd),
+                v.reshape(*lead, nkv, hd))
     if head_major:
         q4 = qkv.reshape(*lead, nh, 3, hd)
         return q4[..., 0, :], q4[..., 1, :], q4[..., 2, :]
@@ -700,6 +714,13 @@ def shard_serving_params(params, mesh, config):
 
     mp = int(mesh.shape["mp"])
     nh, hd = config.num_heads, config.head_dim
+    nkv = getattr(config, "num_kv_heads", None) or nh
+    if nkv != nh:
+        raise NotImplementedError(
+            f"grouped queries ({nh} query heads over {nkv} key-value heads) "
+            "are not laid out over the mp mesh yet: the head-major "
+            "permutation of wqkv assumes as many key-value heads as query "
+            "heads")
     if nh % mp:
         raise ValueError(
             f"the mp mesh size {mp} must divide num_heads {nh} "
@@ -986,6 +1007,30 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
 
     ``kv_quant``, ``mesh`` and ``spec_k`` are not extended to the latent
     cache and raise ``NotImplementedError`` here.
+
+    ``mha`` is ONE function for every model with per-head K and V pools: the
+    GPT block is it with as many key-value heads as query heads, no rotary
+    and no window. GROUPED queries (``config.num_kv_heads``) give the pools
+    fewer heads than the queries; a layer KIND (``models/cohere2_moe.py
+    attention_kind``) may rotate q and k by ``tok_pos`` and may see a WINDOW.
+    A model whose layers retain different things (``config.sliding_window``:
+    window layers keep their last positions alone) has a pool pair per cache
+    GROUP, the full layers' ``[full layers, num_pages, ...]`` then the window
+    layers' ``[window layers, window pages, ...]``, all four donated, and
+    after the seven-array tail the window group's own page table and, per
+    lane, the position of its first held page's first row (``inference/
+    kv_cache.py``)::
+
+        fn(params, ...the same 11 arrays..., k_pages, v_pages, k_window,
+           v_window, page_table, ...the tail..., window_table[b, pps_w],
+           window_base[b])
+        -> (next_toks, logits, k_pages, v_pages, k_window, v_window,
+            expert_rows)
+
+    The block of such a model may be PARALLEL (``config.parallel_block``: one
+    norm feeds attention and the FFN, both added to ``x``), and a run of ONE
+    layer brings its weights unstacked and is not scanned. ``kv_quant``,
+    ``mesh`` and ``spec_k`` are not extended to a window group either.
     """
     import jax
     import jax.numpy as jnp
@@ -995,7 +1040,8 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
                                       paged_write_packed,
                                       paged_write_packed_quant)
     from ..observability.tracing import step_scope
-    from ..ops.pallas.paged_attention import (ragged_paged_attention,
+    from ..ops.pallas.paged_attention import (lane_block_rows,
+                                              ragged_paged_attention,
                                               use_kernel_default)
     from .deepseek_v2 import STACKED_BY_INDEX, layer_stacks
 
@@ -1003,6 +1049,7 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
     trace_count = [0]
     mp, axis = _mesh_mp(mesh)
     nh_l, hd = cfg.num_heads // mp, cfg.head_dim
+    nkv_l = (getattr(cfg, "num_kv_heads", None) or cfg.num_heads) // mp
     # a LATENT cache (multi-head latent attention, models/deepseek_v2.py):
     # one pool whose row is [c | k_pe], shared by every head
     latent = bool(getattr(cfg, "kv_lora_rank", 0))
@@ -1028,25 +1075,42 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
     if sparse:
         from ..ops.pallas import dsa_index as dsa
         from .glm_moe_dsa import indexer_qkw
+    # layers that retain different things (``sliding_window``; models/
+    # cohere2_moe.py): a pool pair per cache group, the window group with a
+    # page table of its own
+    windowed = not latent and bool(getattr(cfg, "sliding_window", 0))
+    if windowed:
+        unsupported = [name for name, on in (
+            ("kv_cache_dtype='int8'", kv_quant), ("mesh", mesh is not None),
+            ("spec_decode_k", spec_k)) if on]
+        if unsupported:
+            raise NotImplementedError(
+                f"a cache with a window group does not serve "
+                f"{', '.join(unsupported)} yet: its second pool pair has no "
+                "scale planes, no sharding rule and no rollback")
+        from .cohere2_moe import (WINDOW, attention_kind, rope_interleaved,
+                                  stack_runs)
     # argument layout (shared by the wrappers, shard_map specs and the
     # donation indices): params + 6 packed/lane arrays [+ spec_len] + the
     # 4 feedback arrays (feedback mask, prev_toks carry, emit_mask,
     # produced), then the donated pools [+ scale planes], then the
-    # 7-array tail
+    # 7-array tail [+ the window group's table and bases]
     n_lead = 12 if spec_k else 11
-    n_pool = 2 if sparse else 1 if latent else 4 if kv_quant else 2
+    n_pool = (2 if sparse else 1 if latent
+              else 4 if kv_quant or windowed else 2)
     n_out_lead = 4 if spec_k else 2
 
     def _body(*args):
         lead = args[:n_lead]
         pools = tuple(args[n_lead:n_lead + n_pool])
         (page_table, cow_src, cow_dst, base_keys, temperature, top_k,
-         top_p) = args[n_lead + n_pool:]
+         top_p, *win) = args[n_lead + n_pool:]
         spec_len = lead[7] if spec_k else None
         feedback, prev_toks, emit_mask, produced = lead[n_lead - 4:]
         return _step_inner(*lead[:7], spec_len, feedback, prev_toks,
                            emit_mask, produced, pools, page_table, cow_src,
-                           cow_dst, base_keys, temperature, top_k, top_p)
+                           cow_dst, base_keys, temperature, top_k, top_p,
+                           tuple(win))
 
     def _at_rung(rows_of, tok_ids, tok_slot, tok_pos, feedback, q_lens,
                  pools):
@@ -1094,12 +1158,13 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
 
     replays = {}
 
-    def traced_once(fn, *args):
+    def traced_once(fn, *args, kind=None):
         """``fn(*args)``: traced when it first meets arguments of these
         shapes, replayed from its equations after that, within one trace of
-        the step (``fn`` takes every array it reads as an argument)."""
+        the step (``fn`` takes every array it reads as an argument; ``kind``:
+        what else tells two such functions apart)."""
         flat, tree = jax.tree.flatten(args)
-        key = (tree, tuple(map(jax.typeof, flat)))
+        key = (kind, tree, tuple(map(jax.typeof, flat)))
         if key not in replays:
             replays[key] = jax.make_jaxpr(
                 lambda *flat: fn(*jax.tree.unflatten(tree, flat)))(*flat)
@@ -1132,26 +1197,28 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
     def _step_inner(params, tok_ids, tok_slot, tok_pos, q_lens, kv_lens,
                     last_idx, spec_len, feedback, prev_toks, emit_mask,
                     produced, pools, page_table, cow_src, cow_dst, base_keys,
-                    temperature, top_k, top_p):
+                    temperature, top_k, top_p, win=()):
         # copy-on-write BEFORE any write: diverging lanes get a private
         # copy of their shared tail page across every layer (scale planes
-        # are page-keyed, so they ride the same copy lanes)
+        # are page-keyed, so they ride the same copy lanes; a window group's
+        # pages are never shared, and the page ids are the full group's)
+        n_cow = 2 if windowed else n_pool
         with step_scope("cow"):
             pools = tuple(paged_copy_pages(pool, cow_src, cow_dst,
                                            lane_by_lane=latent)
-                          for pool in pools)
+                          for pool in pools[:n_cow]) + tuple(pools[n_cow:])
         # what depends on the packed rows runs at a rung of the row ladder
         # (``_at_rung``); the head and the sampling after it are per lane
         out = _at_rung(
             functools.partial(_rows_part, params, q_lens, kv_lens, last_idx,
-                              prev_toks, page_table),
+                              prev_toks, page_table, win),
             tok_ids, tok_slot, tok_pos, feedback, q_lens, pools)
         return _lanes_part(params, out[:n_pool], out[n_pool],
                            out[n_pool + 1:], spec_len, prev_toks, emit_mask,
                            produced, base_keys, temperature, top_k, top_p)
 
     def _rows_part(params, q_lens, kv_lens, last_idx, prev_toks, page_table,
-                   tok_ids, tok_slot, tok_pos, feedback, pools):
+                   win, tok_ids, tok_slot, tok_pos, feedback, pools):
         """Embedding, the layers and the final norm over the packed rows
         given, and of them the rows the head reads: ``(*pools, h_rows[,
         drafts][, expert_rows][, selected])``."""
@@ -1191,6 +1258,17 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
         with step_scope("kv_write"):
             plan = (packed_write_plan(*dest, pools[0].shape[1]) if kernels
                     else None)
+            if windowed:
+                # the window group's table starts at each lane's first held
+                # page: its positions count from that page's first row, for
+                # the write and for attention (causality and the window are
+                # both differences of positions)
+                win_table, win_base = win
+                dest_w = (win_table, tok_slot, tok_pos - win_base[slot_c],
+                          page_size)
+                plan_w = (packed_write_plan(*dest_w, pools[2].shape[1])
+                          if kernels else None)
+                ctx_w = ctx - win_base.astype(jnp.int32)
         if latent:
             with step_scope("attn"):
                 # the latent kernel's tiled layout of this step's rows and
@@ -1217,15 +1295,35 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
             else:
                 no_selection = jnp.zeros((t, slots), bool)
 
-        def mha(p, y, pools, li):
+        def mha(p, y, pools, li, kind=None):
             """Multi-head attention over per-head K and V pools: packed
-            rows ``y [t, h]`` to ``[t, heads * head_dim]``."""
-            kp, vp, *scales = pools
+            rows ``y [t, h]`` to ``[t, heads * head_dim]``. ``kind``: the
+            layer's kind where the model has several (a window layer rotates
+            q and k by position, sees its window and lives in the window
+            group's pools; ``models/cohere2_moe.py``); None: the GPT block's,
+            no rotary, no window, the one group."""
+            window = theta = None
+            sub = contextlib.nullcontext()
+            to, at, kv_ctx, tbl, group_plan = dest, 0, ctx, page_table, plan
+            if kind is not None:
+                window, theta = attention_kind(cfg, kind)
+                sub = step_scope("attn_full")
+                if kind == WINDOW:
+                    sub = step_scope("attn_window")
+                    to, at, kv_ctx, tbl, group_plan = (dest_w, 2, ctx_w,
+                                                       win_table, plan_w)
+            kp, vp, *scales = pools[at:at + 2] if windowed else pools
             ks, vs = scales or (None, None)
             with step_scope("qkv"):
-                qkv = _srv_mm(y, p["wqkv"], use_kernel) + p["bqkv"]
+                qkv = _srv_mm(y, p["wqkv"], use_kernel)
+                if "bqkv" in p:
+                    qkv = qkv + p["bqkv"]
                 q, k_t, v_t = _split_qkv(qkv, nh_l, hd,
-                                         head_major=mesh is not None)
+                                         head_major=mesh is not None,
+                                         nkv=nkv_l)
+                if theta is not None:
+                    q = rope_interleaved(q, tok_pos, theta)
+                    k_t = rope_interleaved(k_t, tok_pos, theta)
             with step_scope("kv_write"):
                 if kv_quant:
                     kp, ks = paged_write_packed_quant(kp, ks, k_t, *dest,
@@ -1233,21 +1331,25 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
                     vp, vs = paged_write_packed_quant(vp, vs, v_t, *dest,
                                                       layer=li, plan=plan)
                 else:
-                    kp = paged_write_packed(kp, k_t, *dest, layer=li,
-                                            plan=plan)
-                    vp = paged_write_packed(vp, v_t, *dest, layer=li,
-                                            plan=plan)
-            with step_scope("attn"):
-                qb = jnp.zeros((b, chunk, nh_l, hd), q.dtype
-                               ).at[scatter_b, off_c].set(q, mode="drop")
-                # the kernel's operands have one shape at every rung: its
-                # body (a second to trace) is traced for the first
+                    kp = paged_write_packed(kp, k_t, *to, layer=li,
+                                            plan=group_plan)
+                    vp = paged_write_packed(vp, v_t, *to, layer=li,
+                                            plan=group_plan)
+            c = lane_block_rows(chunk, t, nh_l, nkv_l)
+            row = off_c if c == chunk else jnp.minimum(off_c, c - 1)
+            with step_scope("attn"), sub:
+                qb = jnp.zeros((b, c, nh_l, hd), q.dtype
+                               ).at[scatter_b, row].set(q, mode="drop")
                 ab = traced_once(
                     lambda *ops: ragged_paged_attention(
                         *ops[:6], use_kernel=use_kernel, k_scales=ops[6],
-                        v_scales=ops[7], layer=ops[8]),
-                    qb, kp, vp, page_table, ctx, q_lens, ks, vs, li)
-                a = ab[slot_c, off_c]                # back to packed [t]
+                        v_scales=ops[7], layer=ops[8], window=window),
+                    qb, kp, vp, tbl, kv_ctx, q_lens, ks, vs, li,
+                    kind=window)
+                a = ab[slot_c, row]                  # back to packed [t]
+            if windowed:
+                pools = pools[:at] + (kp, vp) + pools[at + 2:]
+                return a.reshape(t, nh_l * hd), pools
             return (a.reshape(t, nh_l * hd),
                     (kp, vp, ks, vs) if kv_quant else (kp, vp))
 
@@ -1321,7 +1423,7 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
 
         attention = mla if latent else mha
 
-        def block(carry, layer, whole=None):
+        def block(carry, layer, whole=None, kind=None):
             x, pools, *sel = carry
             p, li, lj, *fi = layer
             if whole:
@@ -1329,13 +1431,17 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
                 p = dict(p, **whole)
             with step_scope("ln"):
                 y = _srv_norm(cfg, x, p, "ln1")
-            a, pools, *chosen = attention(p, y, pools, li, *sel, *fi)
+            a, pools, *chosen = attention(
+                p, y, pools, li, *sel, *fi,
+                **({} if kind is None else {"kind": kind}))
             with step_scope("attn_out"):
                 x = x + _srv_psum(_srv_mm(a, p["wo"], use_kernel), axis)
                 if "bo" in p:
                     x = x + p["bo"]
-            with step_scope("ln"):
-                y = _srv_norm(cfg, x, p, "ln2")
+            if not getattr(cfg, "parallel_block", False):
+                # (a PARALLEL block's one norm feeds the FFN too)
+                with step_scope("ln"):
+                    y = _srv_norm(cfg, x, p, "ln2")
             with step_scope("mlp"):
                 f, rows = _srv_ffn(cfg, p, y, use_kernel, axis, valid=valid,
                                    layer=lj if whole else None)
@@ -1354,13 +1460,32 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
         # (a model of more kinds of layer brings one stack per run of equal
         # layers, ``stacks``: models/glm_moe_dsa.py)
         groups = layer_stacks(params)
+        # a model of several attention kinds: the kind of each run, and the
+        # layers of each cache group counted apart (a group's pool stack is
+        # [its layers, its pages, ...])
+        kinds = ([k for k, _ in stack_runs(cfg)] if windowed
+                 else [None] * len(groups))
+        layers_run = {}      # cache group (the window's?) -> its layers so far
         # the scans are scoped, so their own slicing of the stacked weights
         # falls under "layers" alone
-        carry, first, expert_rows = (x, pools), 0, None
+        carry, expert_rows = (x, pools), None
         if sparse:
             carry, indexed, selected = carry + (no_selection,), 0, []
         with step_scope("layers"):
-            for stack in groups:
+            for stack, kind in zip(groups, kinds):
+                group = windowed and kind == WINDOW
+                first = layers_run.get(group, 0)
+                if stack["ln1_g"].ndim == 1:
+                    # a run of ONE layer, its weights unstacked: no scan,
+                    # and no copy of a weight out of a stack of one
+                    carry, rows = block(
+                        carry, (stack, jnp.int32(first), jnp.int32(0)),
+                        kind=kind)
+                    layers_run[group] = first + 1
+                    if rows is not None:
+                        expert_rows = (rows if expert_rows is None
+                                       else expert_rows + rows)
+                    continue
                 n = jax.tree.leaves(stack)[0].shape[0]
                 # what a kernel reads by layer index stays out of the scanned
                 # slices (none for a GPT block)
@@ -1372,11 +1497,12 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
                     counters += (indexed + within,)
                     indexed += n if "idx_wq" in stack else 0
                 carry, rows = jax.lax.scan(
-                    functools.partial(block, whole=whole) if whole else block,
+                    functools.partial(block, whole=whole, kind=kind)
+                    if whole or kind else block,
                     carry,
                     ({k: v for k, v in stack.items() if k not in whole},
                      *counters))
-                first += n
+                layers_run[group] = first + n
                 if sparse:
                     rows, last_rows = rows
                     if last_rows is not None:

@@ -175,10 +175,14 @@ STEP_SCOPES = (
 #: query, its value half behind the softmax) and, where a learned indexer
 #: chooses the keys attention reads (``models/glm_moe_dsa.py``), ``attn_index``
 #: (the indexer's projections, the index-key write, the score kernel) and
-#: ``attn_select`` (the exact top-k that turns scores into the selection).
+#: ``attn_select`` (the exact top-k that turns scores into the selection);
+#: where a model's attention layers come in kinds (``models/cohere2_moe.py``),
+#: ``attn_window`` and ``attn_full``, so that the paged kernel's time divides
+#: by layer kind.
 STEP_SUBSCOPES = {
     "moe_route": "mlp", "moe_experts": "mlp", "moe_shared": "mlp",
     "attn_absorb": "attn", "attn_index": "attn", "attn_select": "attn",
+    "attn_window": "attn", "attn_full": "attn",
 }
 
 

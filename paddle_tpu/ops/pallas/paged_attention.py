@@ -228,22 +228,59 @@ class RaggedGrid(NamedTuple):
     groups: int
     lanes: int
     blocks: int
+    #: fast memory one grid step's blocks and scratch take where that is
+    #: more than :data:`VMEM_BUDGET` even with one head a step (a wide
+    #: query group's whole chunk), else 0: the call then asks Mosaic for it
+    vmem: int = 0
 
     @property
     def keys(self) -> int:
         """Keys one grid step covers."""
         return self.pages * self.page_size
 
-    def live_steps(self, kv_len: int, q_len: int = 1) -> int:
+    def live_steps(self, kv_len: int, q_len: int = 1, window=None) -> int:
         """Grid steps that hold keys of a scheduled lane's context (however
-        many rows it feeds: the signature is ``TileGrid.live_steps``'s)."""
-        return self.groups * -(-int(kv_len) // self.keys)
+        many rows it feeds: the signature is ``TileGrid.live_steps``'s).
+        ``window``: the layer's rows see their last ``window`` positions
+        alone, and the steps are listed from the block of the first key the
+        lane's first row sees (:func:`first_live_key`; ``kv_len`` in the
+        page table's own coordinates)."""
+        first = first_live_key(int(kv_len), int(q_len), window)
+        return self.groups * (-(-int(kv_len) // self.keys)
+                              - first // self.keys)
 
-    def steps(self, contexts, q_lens=None) -> int:
+    def steps(self, contexts, q_lens=None, window=None) -> int:
         """Grid steps one call launches when the scheduled lanes' contexts
         are ``contexts`` (the lanes not named are idle)."""
-        live = [max(self.groups, self.live_steps(n)) for n in contexts]
+        fed = q_lens if q_lens is not None else [1] * len(contexts)
+        live = [max(self.groups, self.live_steps(n, q, window))
+                for n, q in zip(contexts, fed)]
         return self.groups * (self.lanes - len(live)) + sum(live)
+
+
+def first_live_key(kv_len, q_len, window):
+    """The first key position any of a lane's ``q_len`` new rows sees, its
+    context ``kv_len`` long with them: 0, or under a ``window`` the lower
+    edge of its FIRST new row, ``(kv_len - q_len) - window + 1`` (a row at
+    position p sees keys ``p - window + 1 .. p``, itself counted). One
+    spelling for the host's count, the planner and the kernel (ints or
+    traced int32)."""
+    if window is None:
+        return 0
+    lo = kv_len - q_len - (int(window) - 1)
+    return max(lo, 0) if isinstance(lo, int) else jnp.maximum(lo, 0)
+
+
+def lane_block_rows(chunk, rung, hq, hkv) -> int:
+    """Query rows of a lane's block (the ``chunk`` :func:`ragged_grid` and
+    the kernel are handed) in a step of ``rung`` rows. A lane feeds at most
+    the rung's rows. Where a KV head serves a GROUP of query heads the lanes'
+    blocks are that many times a row, and are cut to what the rung can hold;
+    at one query head a KV head they have one shape at every rung (the
+    kernel's body, a second to trace, is traced for the first). One spelling
+    for the step (``models/gpt.py`` ``mha``) and the scheduler's count of its
+    grid steps (``inference/serving.py``)."""
+    return chunk if hkv == hq else min(chunk, rung)
 
 
 def ragged_grid(lanes, pps, chunk, hq, hkv, page_size, head_dim, kv_dtype,
@@ -276,14 +313,19 @@ def ragged_grid(lanes, pps, chunk, hq, hkv, page_size, head_dim, kv_dtype,
     heads = max([h for h in range(1, hkv + 1) if hkv % h == 0
                  and fixed + h * per_head <= VMEM_BUDGET] or [1])
     few = max(FEW_ROWS, -(-group // 8) * 8)
+    # one head's blocks alone can be past the budget (16 query heads a KV
+    # head x a chunk's 256 rows): the call then asks Mosaic for what they
+    # take, twice over for the compiler's own values (scores, exponentials)
+    over = fixed + heads * per_head > VMEM_BUDGET
     return RaggedGrid(heads=heads, pages=pages, pair=pair, rows=rows,
                       few_rows=few if few < rows else 0, page_size=page_size,
                       groups=hkv // heads, lanes=lanes,
-                      blocks=-(-pps // pages))
+                      blocks=-(-pps // pages),
+                      vmem=2 * (fixed + heads * per_head) if over else 0)
 
 
 def _ragged_kernel(lens_ref, qlens_ref, lane_ref, blk_ref, last_ref, tbl_ref,
-                   *refs, plan, group, scale, quant, stacked):
+                   *refs, plan, group, scale, quant, stacked, window=None):
     """One grid step: ``plan.heads`` KV heads of one lane over ``plan.pages``
     pages. refs: [layer (stacked pools only: the index maps' business alone)]
     q, K pages x P, V pages x P, [K scale rows x P, V scale rows x P], o,
@@ -295,7 +337,13 @@ def _ragged_kernel(lens_ref, qlens_ref, lane_ref, blk_ref, last_ref, tbl_ref,
     values are exact in the compute dtype, so the per-token scales fold into
     the two dots' fp32 sides as [1, page_size] lane vectors: ``(q . kq) *
     ks`` and ``(p * vs) . vq``; the online-softmax recurrence is IDENTICAL
-    (one body, so the paths cannot drift)."""
+    (one body, so the paths cannot drift).
+
+    ``window``: a row sees its last ``window`` positions alone. The lane's
+    items then start at the block of the first key its first row sees
+    (``blk_ref`` counts from that block), and the scores have a lower edge
+    beside the causal one; blocks and edge in the page table's own
+    coordinates (a window group's table starts at its first held page)."""
     pages, pair, page_size = plan.pages, plan.pair, plan.page_size
     refs = refs[1:] if stacked else refs
     q_ref, refs = refs[0], refs[1:]
@@ -312,6 +360,9 @@ def _ragged_kernel(lens_ref, qlens_ref, lane_ref, blk_ref, last_ref, tbl_ref,
     q_len = qlens_ref[b]     # valid query tokens this step (0 = idle lane)
     keys = pair * page_size
     first_key = j * plan.keys
+    if window is not None:
+        first_key += (first_live_key(kv_len, q_len, window)
+                      // jnp.int32(plan.keys)) * jnp.int32(plan.keys)
 
     @pl.when(j == 0)
     def _init():
@@ -337,9 +388,14 @@ def _ragged_kernel(lens_ref, qlens_ref, lane_ref, blk_ref, last_ref, tbl_ref,
         qi = jax.lax.broadcasted_iota(jnp.int32, (rows, keys), 0) // group
         limit = jnp.minimum((kv_len - q_len) + qi + jnp.int32(1), kv_len)
         col = first_key + jax.lax.broadcasted_iota(jnp.int32, (rows, keys), 1)
+        if window is not None:
+            # the row at position p sees keys p - window + 1 .. p
+            lower = (kv_len - q_len) + qi + jnp.int32(1 - window)
 
         def scores(c):
             seen = col + jnp.int32(c * keys) < limit
+            if window is not None:
+                seen &= col + jnp.int32(c * keys) >= lower
             for h in range(plan.heads):
                 q = q_ref[h, :rows, :]                       # [rows, d]
                 k = tile(k_refs, c, h)
@@ -402,7 +458,7 @@ def _ragged_kernel(lens_ref, qlens_ref, lane_ref, blk_ref, last_ref, tbl_ref,
 
 
 def work_items(page_table, seen, fed, *, pages, page_size, blocks, num_pages,
-               keep, lane=None):
+               keep, lane=None, first=None):
     """The work-item axis both paged kernels' grids run over, item by item:
     every query GROUP's live key blocks in order, group after group. A group
     is what one output block serves: a lane for ``ragged_paged_attention``, a
@@ -414,7 +470,9 @@ def work_items(page_table, seen, fed, *, pages, page_size, blocks, num_pages,
     with no live block keeps ONE item (a lane does, so that its zero rows are
     written; an unused tile, whose rows nobody reads, does not); ``blocks``:
     the key blocks a full table gives a group, so ``g * blocks`` items bound
-    the axis. Returns ``total`` (the items this call runs: the grid's dynamic
+    the axis; ``first [g]``: the first key position a group's rows see (None:
+    0), its items then start at that key's block and ``block`` counts from
+    it. Returns ``total`` (the items this call runs: the grid's dynamic
     bound, at least one), each item's ``group`` and key ``block``, whether it
     is its group's ``last`` (``[n]``, n the static bound), and the page every
     (item, operand) names (``[n * pages]``): a slot past what the group sees
@@ -424,6 +482,10 @@ def work_items(page_table, seen, fed, *, pages, page_size, blocks, num_pages,
     i32 = jnp.int32
     n = g * blocks
     live_blocks = jnp.where(fed > 0, -(-seen // i32(pages * page_size)), 0)
+    if first is not None:
+        # the blocks before the first live key's are not listed
+        before = jnp.where(fed > 0, first // i32(pages * page_size), 0)
+        live_blocks = live_blocks - before
     per_group = jnp.maximum(live_blocks, 1) if keep else live_blocks
     ends = jnp.cumsum(per_group)
     item = jnp.arange(n, dtype=i32)
@@ -446,8 +508,10 @@ def work_items(page_table, seen, fed, *, pages, page_size, blocks, num_pages,
                       0)
     reach = jnp.maximum(-(-(slots[:, None] - k[None, :]) // i32(pages)), 0)
     of = jnp.arange(g, dtype=i32)
-    held = jnp.max(jnp.where((reach > 0)[None] & (of[None, :] <= of[:, None]
-                                                 )[:, :, None],
+    # an operand holds keys in one of a group's LISTED blocks
+    has = reach > 0 if first is None else reach > before[:, None]
+    held = jnp.max(jnp.where(has[None] & (of[None, :] <= of[:, None]
+                                          )[:, :, None],
                              of[None, :, None], -1), axis=1)      # [g, pages]
     table = jnp.pad(jnp.clip(page_table, 0, num_pages - 1),
                     ((0, 0), (0, blocks * pages - pps))
@@ -456,23 +520,28 @@ def work_items(page_table, seen, fed, *, pages, page_size, blocks, num_pages,
     held_in = jnp.where(held < 0, group[0], held)
     held_block = jnp.where(held < 0, 0, reach[held_in, k[None, :]] - 1)
     held_page = table[rows[held_in], held_block, k[None, :]]      # [g, pages]
-    own = table[rows[group], jnp.minimum(block, blocks - 1)]      # [n, pages]
-    named = jnp.where(block[:, None] < reach[group], own, held_page[group])
+    # an item's key block among the table's: counted from the group's first
+    at = block if first is None else block + before[group]
+    own = table[rows[group], jnp.minimum(at, blocks - 1)]         # [n, pages]
+    named = jnp.where(at[:, None] < reach[group], own, held_page[group])
     total = ends[-1] if keep else jnp.maximum(ends[-1], 1)
     return total.astype(i32), group, block.astype(i32), last, named.reshape(-1)
 
 
-def _work_items(page_table, kv_lens, q_lens, plan, num_pages):
+def _work_items(page_table, kv_lens, q_lens, plan, num_pages, window=None):
     """``ragged_paged_attention``'s items (:func:`work_items`): a group is a
-    lane, which sees its whole context and keeps one item when idle."""
+    lane, which sees its whole context (under a ``window``: from the first
+    key its first row sees) and keeps one item when idle."""
     return work_items(page_table, kv_lens, q_lens, pages=plan.pages,
                       page_size=plan.page_size, blocks=plan.blocks,
-                      num_pages=num_pages, keep=True)
+                      num_pages=num_pages, keep=True,
+                      first=None if window is None
+                      else first_live_key(kv_lens, q_lens, window))
 
 
 def _ragged_kernel_impl(q4, k_pages, v_pages, page_table, kv_lens, q_lens,
                         plan, group, scale, k_scales=None, v_scales=None,
-                        layer=None):
+                        layer=None, window=None):
     """q4: [b, kv_heads, R, d] with R = ``plan.rows``; returns [b, kv_heads,
     R, d] fp32. ``k_scales``/``v_scales`` ([num_pages, kv_heads, page_size]
     or None) flip the int8-KV kernel. ``layer`` (an int32 scalar, or None):
@@ -522,12 +591,13 @@ def _ragged_kernel_impl(q4, k_pages, v_pages, page_table, kv_lens, q_lens,
                  + [v_scales.astype(jnp.float32)] * pages)
     kv_lens, q_lens = kv_lens.astype(i32), q_lens.astype(i32)
     total, *items = _work_items(page_table.astype(i32), kv_lens, q_lens,
-                                plan, num_pages)
+                                plan, num_pages, window)
     prefetch = [kv_lens, q_lens, *items]
     if stacked:
         prefetch.append(jnp.asarray(layer, i32).reshape(1))
     kern = functools.partial(_ragged_kernel, plan=plan, group=group,
-                             scale=scale, quant=quant, stacked=stacked)
+                             scale=scale, quant=quant, stacked=stacked,
+                             window=window)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
         grid=(plan.groups, total),
@@ -545,7 +615,8 @@ def _ragged_kernel_impl(q4, k_pages, v_pages, page_table, kv_lens, q_lens,
             kern, grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((b, hkv, rows, d), jnp.float32),
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary")),
+                dimension_semantics=("parallel", "arbitrary"),
+                **({"vmem_limit_bytes": plan.vmem} if plan.vmem else {})),
             interpret=_interpret(), name=RAGGED_KERNEL_NAME,
         )(*prefetch, *args)
 
@@ -553,7 +624,7 @@ def _ragged_kernel_impl(q4, k_pages, v_pages, page_table, kv_lens, q_lens,
 def ragged_paged_attention_reference(q, k_pages, v_pages, page_table,
                                      kv_lens, q_lens, scale=None,
                                      k_scales=None, v_scales=None,
-                                     layer=None):
+                                     layer=None, window=None):
     """Gather-based oracle for the ragged kernel (and the non-TPU path).
 
     q: [b, chunk, num_q_heads, d] right-padded query chunks; kv_lens: [b]
@@ -563,7 +634,9 @@ def ragged_paged_attention_reference(q, k_pages, v_pages, page_table,
     all keys at positions <= its own. With ``k_scales``/``v_scales``
     ([num_pages, kv_heads, page_size]) the pages are int8 and dequantize
     after the gather. ``layer``: the pools and scale planes are stacked
-    ``[num_layers, ...]`` and the gather reads that layer of them. Returns
+    ``[num_layers, ...]`` and the gather reads that layer of them.
+    ``window``: a token at position p attends keys ``p - window + 1 .. p``
+    alone (positions in the page table's own coordinates). Returns
     [b, chunk, num_q_heads, d].
     """
     b, c, hq, d = q.shape
@@ -588,6 +661,8 @@ def ragged_paged_attention_reference(q, k_pages, v_pages, page_table,
     col = jnp.arange(pps * page_size).reshape(1, 1, -1)
     valid = ((col < jnp.minimum(limit, kv_lens.reshape(-1, 1, 1)))
              & (jnp.arange(c).reshape(1, -1, 1) < q_lens.reshape(-1, 1, 1)))
+    if window is not None:
+        valid &= col >= limit - int(window)
     s = jnp.where(valid[:, None, None], s, NEG_INF)              # [b,h,g,c,s]
     p = jax.nn.softmax(s, axis=-1)
     # fully-masked rows (idle lanes / padding past q_lens): softmax is
@@ -600,7 +675,8 @@ def ragged_paged_attention_reference(q, k_pages, v_pages, page_table,
 
 def ragged_paged_attention(q, k_pages, v_pages, page_table, kv_lens, q_lens,
                            scale=None, use_kernel: bool | None = None,
-                           k_scales=None, v_scales=None, layer=None):
+                           k_scales=None, v_scales=None, layer=None,
+                           window=None):
     """Ragged prefill+decode attention over the paged KV cache.
 
     The unified-step entry: each slot contributes ``q_lens[b]`` (0..chunk)
@@ -622,6 +698,15 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, kv_lens, q_lens,
     no layer's pool is ever sliced out of the stack (151 MB a layer and pool
     at the 590M deployment). Without ``layer`` the pools are one layer's,
     4-D, as the decode op, the draft chain and the autotuners pass them.
+
+    ``window`` (a static int): sliding-window attention, a token at position
+    p attends keys ``p - window + 1 .. p``. The grid lists a lane's key
+    blocks from the first one any of its rows sees and the scores get a lower
+    edge. Positions are those of the page table handed in: a cache group that
+    keeps a window's pages alone hands in a table that starts at its first
+    held page and ``kv_lens`` counted from that page's first position
+    (causality and the window are both differences of positions). With no
+    window the plan and the kernel are what they were.
     """
     b, c, hq, d = q.shape
     hkv = k_pages.shape[-3]
@@ -641,7 +726,7 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, kv_lens, q_lens,
     if not use_kernel:
         return ragged_paged_attention_reference(
             q, k_pages, v_pages, page_table, kv_lens, q_lens, scale=scale,
-            k_scales=k_scales, v_scales=v_scales, layer=layer)
+            k_scales=k_scales, v_scales=v_scales, layer=layer, window=window)
     group = hq // hkv
     plan = ragged_grid(b, page_table.shape[1], c, hq, hkv,
                        k_pages.shape[-2], d, k_pages.dtype, q.dtype)
@@ -654,7 +739,7 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, kv_lens, q_lens,
     out = _ragged_kernel_impl(q4, k_pages, v_pages, page_table, kv_lens,
                               q_lens, plan, group, float(scale),
                               k_scales=k_scales, v_scales=v_scales,
-                              layer=layer)
+                              layer=layer, window=window)
     out = out[:, :, :c * group, :].reshape(b, hkv, c, group, d)
     out = out.transpose(0, 2, 1, 3, 4).reshape(b, c, hq, d)
     return out.astype(q.dtype)

@@ -658,10 +658,14 @@ class TestStepScopes:
         with pytest.raises(ValueError, match="STEP_SCOPES"):
             step_scope("layer_norm")
         # PR 28: parts of a part, each inside a scope of the closed list
-        # (PR 34: the learned indexer's two, inside "attn")
+        # (PR 34: the learned indexer's two, inside "attn"; PR 36: the
+        # paged kernel's time by layer kind, inside "attn")
         assert set(STEP_SUBSCOPES) == {"moe_route", "moe_experts",
                                        "moe_shared", "attn_absorb",
-                                       "attn_index", "attn_select"}
+                                       "attn_index", "attn_select",
+                                       "attn_window", "attn_full"}
+        assert STEP_SUBSCOPES["attn_window"] == "attn" \
+            == STEP_SUBSCOPES["attn_full"]
         assert STEP_SUBSCOPES["attn_index"] == "attn" \
             == STEP_SUBSCOPES["attn_select"]
         assert set(STEP_SUBSCOPES.values()) <= set(STEP_SCOPES)
@@ -697,7 +701,8 @@ class TestStepScopes:
                                 os.path.join("models", "gpt_spmd.py"),
                                 os.path.join("models", "moe.py")]
         assert used[os.path.join("models", "gpt.py")] == \
-            set(SERVE_SCOPES) | {"attn_absorb", "attn_index", "attn_select"}
+            set(SERVE_SCOPES) | {"attn_absorb", "attn_index", "attn_select",
+                                 "attn_window", "attn_full"}
         assert used[os.path.join("models", "gpt_spmd.py")] == \
             set(TRAIN_SCOPES)
         # a routed layer's parts, inside the serving step's "mlp"

@@ -283,3 +283,58 @@ def test_compiled_sparse_latent_step_keeps_both_planes_in_place(one_chip,
     assert pool_copies(hlo, pool.shape) == []
     assert pool_copies(hlo, index.shape) == []
     assert compiled.memory_analysis().temp_size_in_bytes < 0.4e9
+
+
+def test_compiled_window_step_keeps_both_groups_in_place(one_chip,
+                                                         monkeypatch):
+    """PR 36: Command A+'s block through the same step at the cell's widths
+    (128 query heads over 8 key-value heads of 128, window 4,096, 16 of 128
+    experts held, four shared experts as one MLP), two layers in two runs of
+    ONE (a window layer, a full layer: both unstacked, neither scanned), 8
+    lanes. At 16 query heads a key-value head the chunk's block is 4,096 rows
+    a head and the kernel asks Mosaic for its fast memory; the decode rung's
+    block is cut to the rung. Neither group's pools are copied, and both
+    kinds of call are Mosaic calls under their own sub-scope."""
+    import paddle_tpu  # noqa: F401  framework config (matmul precision)
+    from paddle_tpu.models.cohere2_moe import (FULL, WINDOW,
+                                               Cohere2MoeConfig)
+    from paddle_tpu.models.gpt import build_unified_step, step_row_ladder
+
+    sys.path.insert(0, REPO)
+    try:
+        from chip_smoke import _is_mosaic_call, pool_copies
+        from benchmark.tools.compile_serve_window_moe_for_v5e import (
+            step_avals)
+    finally:
+        sys.path.remove(REPO)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = Cohere2MoeConfig(vocab_size=32768, num_layers=2,
+                           n_routed_experts=16, max_seq_len=57344,
+                           layer_types=(WINDOW, FULL))
+    dep = dict(token_budget=512, max_batch=8, max_seq_len=57344,
+               page_size=64, chunk=256)
+    step = build_unified_step(cfg, 64, 256)
+    avals, (full, win) = step_avals(cfg, dep, 512, one_chip, jnp.bfloat16)
+    compiled = step.lower(*avals).compile()
+    lines = compiled.as_text().splitlines()
+
+    def calls(kernel, under=""):
+        return sum(_is_mosaic_call(line, f"/{kernel}/pallas_call")
+                   and under in line for line in lines)
+
+    rungs = len(step_row_ladder(8, 0, 256, 512))
+    assert rungs == 2
+    assert calls("ragged_paged_attention") == 2 * rungs
+    assert calls("ragged_paged_attention", "attn_window") == rungs
+    assert calls("ragged_paged_attention", "attn_full") == rungs
+    assert calls("grouped_matmul") == 4 * rungs
+    assert calls("paged_kv_write") == 4 * rungs
+    assert full.shape == (1, 512, 8, 64, 128)
+    assert win.shape == (1, 8 * 69, 8, 64, 128)
+    hlo = "\n".join(lines)
+    assert pool_copies(hlo, full.shape) == []
+    assert pool_copies(hlo, win.shape) == []
+    # the prefill rung's query blocks ([8, 256, 128, 128] and the kernel's
+    # float32 result) are the temporaries; far under one layer's weights
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
