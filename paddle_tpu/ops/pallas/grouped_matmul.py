@@ -18,14 +18,24 @@ blocking discipline as ``ragged_paged_attention``:
 - each grid step DMAs that group's ``[bk, bn]`` weight tile into VMEM:
   empty experts stream ZERO weight bytes, and a group's weights are
   fetched only for its own row tiles;
+- the float forward (what the serving cells run) moves 1-2 MiB of weights
+  a grid step at every contraction width (``_float_fwd_tile``: the tile
+  follows ``(k, n, dtype)``; a quarter of a megabyte does not cover a grid
+  step's own cost), runs the row tiles that hold rows and no others (the
+  grid's first bound is their count, a dynamic grid dimension), and writes
+  its output in the rows' dtype: at once where the whole contraction is one
+  step, from a float32 scratch on the last of several;
 - the int8/int4 tile-dequant scale-row machinery is lifted verbatim from
   ``quant_matmul.py`` — one scale row per k tile, widened and applied on
   the way into the MXU, fp32 accumulation across k tiles.
 
 The jnp segment-matmul reference (:func:`grouped_matmul_reference`) is
 the numerical oracle and the non-TPU fallback; interpret mode runs the
-real kernel on CPU for the tests. Tile autotune rides the shared
-``autotune_cache`` (signatures ``gmm:{E}x{K}x{N}:{bits}b:g{gs}:{dtype}``).
+real kernel on CPU for the tests. The tiles come from the shape
+(``_blocks_for``); :func:`autotune_grouped_matmul` is the sweep behind the
+float forward's rule (``PERF.md`` section 6, PR 37) and can still pin a
+signature's tiles on the shared ``autotune_cache``
+(``gmm:{E}x{K}x{N}:{bits}b:g{gs}:{dtype}``; no packaged entry).
 
 Backward (custom VJP): ``dx`` runs the same grouped tile-dequant
 structure with the contraction transposed (weights stay quantized in
@@ -197,26 +207,36 @@ def grouped_matmul_reference(x, weights, group_offsets, scales=None):
 # ---------------------------------------------------------------------------
 
 
-def _gmm_kernel(gid_ref, meta_ref, x_ref, w_ref, o_ref):
-    """One [bm, bn] output tile of ONE group, accumulating over k tiles:
-    the weight tile is this tile's group's ``[bk, bn]`` slab (index map
-    reads the prefetched group id). ``meta_ref`` is ``[live tiles, layer]``:
-    a dead tile (past the ``meta_ref[0]`` tiles that hold rows) computes nothing, and its index maps name the
-    blocks the step before it named, so it streams nothing either."""
-    del gid_ref  # consumed by the index maps
+def _gmm_kernel(gid_ref, layer_ref, x_ref, w_ref, o_ref, *acc):
+    """One [bm, bn] output tile of ONE group: the weight tile is this row
+    tile's group's ``[bk, bn]`` slab (the index map reads the prefetched
+    group id, and the layer of a stack). With the whole contraction in one
+    step the product is written as it comes, rounded once to the block's
+    dtype; over several k steps a float32 scratch holds the partial sums and
+    the last step writes. The grid runs the row tiles that hold rows and no
+    others (its first bound is their count)."""
+    del gid_ref, layer_ref  # consumed by the index maps
+    x = x_ref[...]
+    part = jax.lax.dot_general(
+        x, w_ref[0].astype(x.dtype), (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32, precision=_MXU)
+    if not acc:
+        o_ref[...] = part.astype(o_ref.dtype)
+        return
+    acc_ref, = acc
     kstep = pl.program_id(2)
 
-    @pl.when(pl.program_id(0) < meta_ref[0])
-    def _live():
-        @pl.when(kstep == 0)
-        def _init():
-            o_ref[...] = jnp.zeros_like(o_ref)
+    @pl.when(kstep == 0)
+    def _first():
+        acc_ref[...] = part
 
-        x = x_ref[...]
-        w = w_ref[0].astype(x.dtype)
-        o_ref[...] += jax.lax.dot_general(
-            x, w, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32, precision=_MXU)
+    @pl.when(kstep > 0)
+    def _rest():
+        acc_ref[...] += part
+
+    @pl.when(kstep == pl.num_programs(2) - 1)
+    def _write():
+        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
 def _gmm_q_kernel(gid_ref, x_ref, w_ref, s_ref, o_ref):
@@ -299,6 +319,12 @@ def _gmm_q_bwd_kernel(gid_ref, dy_ref, w_ref, s_ref, dx_ref):
 BM_DEFAULT = 32
 BN_DEFAULT = 256
 BK_DEFAULT = 512
+# the float forward's weight tile, a grid step's DMA: the most bytes, and the
+# output columns it starts from (the sweep on the chip: PERF.md section 6,
+# PR 37). Mosaic's default scoped fast memory is what the tiles must fit.
+W_TILE_BYTES = 2 << 20
+BN_FLOAT = 512
+VMEM_DEFAULT_BYTES = 16 << 20
 
 
 def _sig(e, k, n, bits, group, dtype) -> str:
@@ -312,24 +338,68 @@ def _div_pick(pref: int, dim: int) -> int:
     return max(b, 1)
 
 
-def _blocks_for(e, m, k, n, bits, group_size, dtype):
+def _row_tile(e, m):
+    """A row tile that holds a mean group, 32 to 128 rows: an expert's
+    weights stream once, not once per 32 rows."""
+    bm = BM_DEFAULT
+    while bm < 128 and bm * e < m:
+        bm *= 2
+    return bm
+
+
+def _whole_k_step(k):
+    """The whole contraction in one k step where ``[k, 256]`` stays near
+    1 MiB (the ``dx`` backward's, and the forward's before PR 37)."""
+    return k if k <= 2048 and k % 128 == 0 else BK_DEFAULT
+
+
+def _float_fwd_tile(k, n, dtype):
+    """(bn, bk) of the float forward from the shape: the widest weight tile
+    ``[bk, bn]`` within ``W_TILE_BYTES`` whatever ``k`` is. ``bk`` is the
+    largest divisor of ``k`` that is a multiple of 128 lanes and fits beside
+    ``BN_FLOAT`` columns (the whole contraction where that fits); a narrow
+    contraction then widens ``bn`` while the tile stays within the bytes."""
+    isz = jnp.dtype(dtype).itemsize
+    bn = _div_pick(BN_FLOAT, n)
+    fits = [d for d in range(128, k + 1, 128)
+            if k % d == 0 and d * bn * isz <= W_TILE_BYTES]
+    bk = max(fits) if fits else _div_pick(BK_DEFAULT, k)
+    while n % (2 * bn) == 0 and 2 * bn * bk * isz <= W_TILE_BYTES:
+        bn *= 2
+    return bn, bk
+
+
+def fwd_vmem_bytes(bm, bn, bk, k, dtype):
+    """Fast memory the float forward's pipeline holds: two buffers each of
+    the weight tile, the row tile and the output block, and the float32
+    scratch where the contraction takes several steps."""
+    isz = jnp.dtype(dtype).itemsize
+    return (2 * isz * (bk * bn + bm * bk + bm * bn)
+            + (bm * bn * 4 if bk < k else 0))
+
+
+def _blocks_for(e, m, k, n, bits, group_size, dtype, which="fwd"):
     """(bm, bn, bk): bn/bk honor divisibility + scale-group alignment
     exactly like ``quant_matmul``; bm is free because the pack pads every
     group to a bm multiple (it only trades padding waste against MXU
     row occupancy). Without a tuned entry, float weights get tiles from
-    the shape: a row tile that holds a mean group (so an expert's weights
-    stream once, not once per 32 rows) and the whole contraction in one k
-    step where a ``[k, bn]`` weight tile stays near 1 MiB."""
+    the shape: a row tile that holds a mean group, and for the forward
+    (``which="fwd"``) a weight tile of 1-2 MiB at every contraction width
+    (:func:`_float_fwd_tile`); the ``dx`` backward (``which="bwd"``) keeps
+    the whole contraction in one step where ``[k, 256]`` stays near 1 MiB,
+    and quantized weights keep the defaults their scale groups tie them
+    to."""
     hit = _atc.lookup(_sig(e, k, n, bits, group_size, dtype))
     if hit and len(hit) == 3:
         pm, pn, pk = hit
     else:
         pm, pn, pk = BM_DEFAULT, BN_DEFAULT, BK_DEFAULT
         if bits == 0:
-            while pm < 128 and pm * e < m:
-                pm *= 2
-            if k <= 2048 and k % 128 == 0:
-                pk = k
+            pm = _row_tile(e, m)
+            if which == "fwd":
+                pn, pk = _float_fwd_tile(k, n, dtype)
+            else:
+                pk = _whole_k_step(k)
     bm = max(8, _div_pick(pm, 1024))          # pow2 row tile >= sublane min
     bn = _div_pick(pn, n)
     k_ext = k // 2 if bits == 4 else k
@@ -346,26 +416,67 @@ def _shape_ok(k, n, bits) -> bool:
     return n % 128 == 0 and k_ext % (32 if bits else 8) == 0
 
 
+def fwd_candidates(e, m, k, n, dtype):
+    """The (bm, bn, bk) the float forward's rule ranges over at this shape:
+    weight tiles ``[bk, bn]`` of 512 KiB to 4 MiB (``bn`` 256 to 2048 by
+    doubling and the widest divisor of ``n`` under that; ``bk`` 512 up by
+    doubling and ``k``, ``k / 2``, ``k / 3``) under the rule's row tile; the
+    rule's own tile under the row tiles beside its own; and what the rule
+    was before PR 37 (``bn`` 256; ``bk`` the whole ``k`` up to 2048, else
+    512); all within the default fast memory."""
+    isz = jnp.dtype(dtype).itemsize
+    bm = _row_tile(e, m)
+    widest = max((d for d in range(128, min(n, 2048) + 1, 128)
+                  if n % d == 0), default=_div_pick(BN_DEFAULT, n))
+    bns = sorted({b for b in (256, 512, 1024, 2048) if n % b == 0}
+                 | {widest})
+    bks = sorted({b for b in (512, 1024, 2048, 4096) if k % b == 0}
+                 | {k // d for d in (1, 2, 3) if k % (128 * d) == 0})
+    out = [(bm, BN_DEFAULT, _div_pick(_whole_k_step(k), k))]
+    out += [(bm, bn, bk) for bn in bns for bk in bks
+            if (512 << 10) <= bk * bn * isz <= (4 << 20)]
+    out += [(r, *_float_fwd_tile(k, n, dtype))
+            for r in (bm // 2, bm, bm * 2) if 16 <= r <= 256]
+    return tuple(c for c in dict.fromkeys(out)
+                 if fwd_vmem_bytes(*c, k, dtype) <= VMEM_DEFAULT_BYTES)
+
+
 def autotune_grouped_matmul(e, m, k, n, bits=8, group_size=-1,
-                            dtype=jnp.float32,
-                            candidates=((32, 256, 512), (8, 256, 512),
-                                        (128, 256, 512), (32, 512, 256),
-                                        (16, 256, 1024)),
-                            iters=10):
-    """Sweep (bm, bn, bk) for this grouped-GEMM signature (uniform groups,
-    ``m`` total rows) and persist the winner on the shared cache. No-op
-    off-TPU."""
+                            dtype=jnp.float32, candidates=None, iters=10,
+                            fed=None, layers=None, timings=None):
+    """Sweep (bm, bn, bk) for this grouped-GEMM signature and persist the
+    winner on the shared cache. No-op off-TPU. ``candidates``: by default
+    what the float forward's rule ranges over (:func:`fwd_candidates`), and
+    for quantized weights the tiles their scale groups allow. ``fed=(experts,
+    rows)``: ragged offsets like a decode step's, ``experts`` of the ``e``
+    (evenly spread) hold ``rows`` rows each and the other rows of ``m`` lie
+    past the last offset, as a chip's share of an expert-parallel layer
+    leaves them; None: ``m // e`` rows in every group. ``layers`` (float
+    weights): the stacked ``layer=`` form over that many layers, one after
+    the other. ``timings``: a dict filled with ``{candidate: seconds a
+    call}`` (a candidate the compiler refuses is left out)."""
     from ...observability import monotonic
 
     gs = k if group_size in (-1, None, 0) else int(group_size)
     if _interpret():
         return _blocks_for(e, m, k, n, bits, gs, dtype)
+    if candidates is None:
+        candidates = (fwd_candidates(e, m, k, n, dtype) if bits == 0 else
+                      ((32, 256, 512), (8, 256, 512), (128, 256, 512),
+                       (32, 512, 256), (16, 256, 1024)))
     _atc.load()
     sig = _sig(e, k, n, bits, gs, dtype)
     kx, kq, kf = jax.random.split(jax.random.PRNGKey(0), 3)
     x = jax.random.normal(kx, (m, k), dtype)
-    offs = jnp.arange(e + 1, dtype=jnp.int32) * (m // e)
-    offs = offs.at[-1].set(m)
+    if fed is None:
+        offs = jnp.arange(e + 1, dtype=jnp.int32) * (m // e)
+        offs = offs.at[-1].set(m)
+    else:
+        experts, rows = fed
+        holds = jnp.zeros((e,), jnp.int32).at[
+            (jnp.arange(experts) * e) // experts].set(rows)
+        offs = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                                jnp.cumsum(holds)]).astype(jnp.int32)
     scales = None
     if bits:
         kext = k // 2 if bits == 4 else k
@@ -373,7 +484,10 @@ def autotune_grouped_matmul(e, m, k, n, bits=8, group_size=-1,
                                8 if bits == 4 else 128, jnp.int8)
         scales = jnp.ones((e, k // gs, n), jnp.float32)
     else:
-        w = jax.random.normal(kf, (e, k, n), dtype)
+        # one expert's values for every expert: a sweep times, it does not
+        # compare, and a gigabyte of normal variates takes its own minute
+        w = jnp.broadcast_to(jax.random.normal(kf, (k, n), dtype),
+                             ((layers,) if layers else ()) + (e, k, n))
     saved = _atc.CACHE.get(sig)
     best, best_t = None, float("inf")
     for cand in candidates:
@@ -381,14 +495,18 @@ def autotune_grouped_matmul(e, m, k, n, bits=8, group_size=-1,
         try:
             step = jax.jit(functools.partial(grouped_matmul,
                                              use_kernel=True))
-            step(x, w, offs, scales).block_until_ready()
+            calls = [dict(layer=jnp.int32(i % layers)) if layers else {}
+                     for i in range(iters)]
+            step(x, w, offs, scales, **calls[0]).block_until_ready()
             t0 = monotonic()
-            for _ in range(iters):
-                out = step(x, w, offs, scales)
+            for kw in calls:
+                out = step(x, w, offs, scales, **kw)
             out.block_until_ready()
             t = monotonic() - t0
         except Exception:
             continue
+        if timings is not None:
+            timings[tuple(cand)] = t / iters
         if t < best_t:
             best, best_t = list(cand), t
     if best is not None:
@@ -419,41 +537,36 @@ def _fwd_impl(x2, weights, scales3d, group_offsets, k, bits, group_size,
     semantics = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))
     if bits == 0:
-        nj, nk = n // bn, k // bk
-
-        # scalar prefetch: the tiles' groups, and meta = [live tiles, layer]
-        def dead(i, meta):
-            return i >= meta[0]
-
-        # a dead tile names the LAST blocks the last live tile named: the
-        # pipeline fetches nothing for a block index that did not change
-        def x_imap(i, j, kk, g, meta):
-            d = dead(i, meta)
-            return (jnp.where(d, jnp.maximum(meta[0] - 1, 0), i),
-                    jnp.where(d, nk - 1, kk))
-
-        def w_imap(i, j, kk, g, meta):
-            d = dead(i, meta)
+        # scalar prefetch: the tiles' groups and this call's layer. The
+        # first grid bound is the count of row tiles that hold rows (a
+        # dynamic grid dimension): the tiles past the ragged end are never
+        # visited, and their output rows, which nothing gathers back, stay
+        # unwritten
+        def w_imap(i, j, kk, g, lay):
             # stacked weights [L, E, K, N]: the leading block index is this
             # call's layer
-            return (() if layer is None else (meta[1],)) + (
-                g[i], jnp.where(d, nk - 1, kk), jnp.where(d, nj - 1, j))
+            return (() if layer is None else (lay[0],)) + (g[i], kk, j)
 
         w_block = (1, bk, bn) if layer is None else (None, 1, bk, bn)
+        nk = k // bk
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(mp // bm, nj, nk),
-            in_specs=[pl.BlockSpec((bm, bk), x_imap),
+            num_scalar_prefetch=2, grid=(n_live, n // bn, nk),
+            in_specs=[pl.BlockSpec((bm, bk), lambda i, j, kk, g, lay: (i, kk)),
                       pl.BlockSpec(w_block, w_imap)],
-            out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk, g, meta: (i, j)))
-        meta = jnp.stack([n_live.astype(jnp.int32),
-                          jnp.asarray(0 if layer is None else layer,
-                                      jnp.int32)])
+            out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk, g, lay: (i, j)),
+            scratch_shapes=([pltpu.VMEM((bm, bn), jnp.float32)]
+                            if nk > 1 else []))
         with _atc.x64_off():
             out = pl.pallas_call(
-                _gmm_kernel, grid_spec=grid_spec, out_shape=out_shape,
-                compiler_params=semantics, interpret=_interpret(),
-                name=GROUPED_KERNEL_NAME,
-            )(tile_gid, meta, x_pad, weights)
+                _gmm_kernel, grid_spec=grid_spec,
+                out_shape=jax.ShapeDtypeStruct((mp, n), x2.dtype),
+                compiler_params=pltpu.CompilerParams(
+                    dimension_semantics=("arbitrary", "parallel",
+                                         "arbitrary")),
+                interpret=_interpret(), name=GROUPED_KERNEL_NAME,
+            )(tile_gid,
+              jnp.asarray(0 if layer is None else layer, jnp.int32).reshape(1),
+              x_pad, weights)
         return out[dest]
     if bits == 8:
         grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -519,7 +632,8 @@ def _bwd_dx_impl(dy, weights, scales3d, group_offsets, k, bits, group_size,
         return jnp.take_along_axis(
             dxs, gid[None, :, None].astype(jnp.int32), axis=0)[0].astype(
                 x_dtype)
-    bm, bn, bk = _blocks_for(e, m, k, n, bits, group_size, x_dtype)
+    bm, bn, bk = _blocks_for(e, m, k, n, bits, group_size, x_dtype,
+                             which="bwd")
     dest, tile_gid, mp, _ = _pack_layout(group_offsets, m, e, bm)
     dy_pad = jnp.zeros((mp, n), x_dtype).at[dest].set(dy.astype(x_dtype))
     out_shape = jax.ShapeDtypeStruct((mp, k), jnp.float32)

@@ -338,3 +338,53 @@ def test_compiled_window_step_keeps_both_groups_in_place(one_chip,
     # the prefill rung's query blocks ([8, 256, 128, 128] and the kernel's
     # float32 result) are the temporaries; far under one layer's weights
     assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
+
+
+@pytest.mark.parametrize("e,m,k,n", [
+    pytest.param(16, 256, 4096, 8192, id="cmdaplus-gate-up"),
+    pytest.param(16, 256, 4096, 4096, id="cmdaplus-down"),
+    pytest.param(16, 128, 6144, 4096, id="glm-gate-up"),
+    pytest.param(16, 128, 2048, 6144, id="glm-down"),
+    pytest.param(64, 192, 2048, 2816, id="dsv2-gate-up"),
+    pytest.param(64, 192, 1408, 2048, id="dsv2-down"),
+    pytest.param(64, 6144, 2048, 2816, id="dsv2-gate-up-top-rung"),
+    pytest.param(64, 6144, 1408, 2048, id="dsv2-down-top-rung"),
+])
+def test_grouped_matmul_compiles_alone_at_the_routed_cells_shapes(
+        one_chip, monkeypatch, e, m, k, n):
+    """PR 37: the KERNEL alone (seconds each; the whole steps above hold the
+    call counts) at the three routed cells' six ``(k, n)`` pairs, stacked
+    and read by layer index as their scans do: the float forward's tile
+    (1-2 MiB of weights a grid step, two buffers of it, the row tile, the
+    output block and the float32 scratch) fits Mosaic's default fast memory,
+    the grid's dynamic first bound lowers, and the call hands back bf16."""
+    import paddle_tpu  # noqa: F401  framework config (matmul precision)
+    from paddle_tpu.ops.pallas.grouped_matmul import (
+        GROUPED_KERNEL_NAME, VMEM_DEFAULT_BYTES, _blocks_for, fwd_vmem_bytes,
+        grouped_matmul)
+
+    sys.path.insert(0, REPO)
+    try:
+        from chip_smoke import _is_mosaic_call
+    finally:
+        sys.path.remove(REPO)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    bf = jnp.bfloat16
+
+    def sds(*shape, dtype=bf):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def fn(x, w, offsets, layer):
+        return grouped_matmul(x, w, offsets, layer=layer)
+
+    with jax.enable_x64(False):
+        compiled = jax.jit(fn).lower(
+            sds(m, k), sds(2, e, k, n), sds(e + 1, dtype=jnp.int32),
+            sds(dtype=jnp.int32)).compile()
+    calls = [line for line in compiled.as_text().splitlines()
+             if _is_mosaic_call(line, f"/{GROUPED_KERNEL_NAME}/pallas_call")]
+    assert len(calls) == 1
+    assert calls[0].split("=")[1].strip().startswith("bf16[")
+    bm, bn, bk = _blocks_for(e, m, k, n, 0, k, bf)
+    assert fwd_vmem_bytes(bm, bn, bk, k, bf) <= VMEM_DEFAULT_BYTES
