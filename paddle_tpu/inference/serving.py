@@ -142,9 +142,10 @@ import numpy as np
 
 import jax.numpy as jnp
 
-from ..observability import (MetricsRegistry, monotonic,
-                             request_begin, request_end, request_event,
-                             span, tracing_active)
+from ..observability import (MetricsRegistry, merge_snapshots, monotonic,
+                             phase, process_registry, request_begin,
+                             request_end, request_event, span,
+                             tracing_active)
 from ..profiler.record import recorder as _recorder
 from .faults import InjectedFault, fault_point
 from .kv_cache import KVCacheManager, kv_cache_quantized, pages_needed
@@ -445,37 +446,41 @@ class ServingPredictor:
                     f"not supported for a "
                     f"{'latent (MLA) cache' if self.latent else 'cache with a window group'}"
                     f" yet: {', '.join(unsupported)}")
-            import jax
+        with phase("weights.place"):
+            if self.latent or self.windowed:
+                import jax
 
-            self.params = (model.params if dtype is None else jax.tree.map(
-                lambda a: a if a.dtype == dtype else a.astype(dtype),
-                model.params))
-        elif dtype is None:
-            # share the weak-keyed extraction with generate() — a second
-            # predictor (or generate call) on one model reuses the stacks
-            # (quantized per cfg.weight_dtype, sharded per mesh signature,
-            # inside the cache)
-            self.params = _serving_params_cached(model, mesh=self.mesh)
-            # the round-19 draft engine slices its truncated stacks off
-            # the UNSHARDED extraction (it re-shards with its own config)
-            params_unsharded = (self.params if self.mesh is None
-                                else _serving_params_cached(model,
-                                                            mesh=None))
-        else:
-            import jax
+                self.params = (
+                    model.params if dtype is None else jax.tree.map(
+                        lambda a: a if a.dtype == dtype else a.astype(dtype),
+                        model.params))
+            elif dtype is None:
+                # share the weak-keyed extraction with generate() — a
+                # second predictor (or generate call) on one model reuses
+                # the stacks (quantized per cfg.weight_dtype, sharded per
+                # mesh signature, inside the cache)
+                self.params = _serving_params_cached(model, mesh=self.mesh)
+                # the round-19 draft engine slices its truncated stacks
+                # off the UNSHARDED extraction (it re-shards with its own
+                # config)
+                params_unsharded = (self.params if self.mesh is None
+                                    else _serving_params_cached(model,
+                                                                mesh=None))
+            else:
+                import jax
 
-            self.params = jax.tree.map(lambda a: a.astype(dtype),
-                                       serving_params(model))
-            if cfg.weight_dtype is not None:
-                from .quantize import quantize_serving_params
+                self.params = jax.tree.map(lambda a: a.astype(dtype),
+                                           serving_params(model))
+                if cfg.weight_dtype is not None:
+                    from .quantize import quantize_serving_params
 
-                self.params = quantize_serving_params(
-                    self.params, cfg.weight_dtype,
-                    cfg.weight_quant_group_size)
-            params_unsharded = self.params
-            if self.mesh is not None:
-                self.params = shard_serving_params(self.params, self.mesh,
-                                                   cfg)
+                    self.params = quantize_serving_params(
+                        self.params, cfg.weight_dtype,
+                        cfg.weight_quant_group_size)
+                params_unsharded = self.params
+                if self.mesh is not None:
+                    self.params = shard_serving_params(
+                        self.params, self.mesh, cfg)
         # the model's position table bounds every context
         self.max_seq_len = min(int(max_seq_len or cfg.max_seq_len),
                                cfg.max_seq_len)
@@ -505,20 +510,21 @@ class ServingPredictor:
             cfg.num_heads, cfg.num_heads, cfg.head_dim, kv_dtype))
         # learned sparse attention: the indexer layers' keys, a second plane
         self.sparse = self.latent and bool(getattr(cfg, "index_topk", 0))
-        self.cache = KVCacheManager(
-            cfg.num_layers, kv_heads, kv_width, latent=self.latent,
-            **({"index_plane": (cfg.num_index_layers, cfg.index_head_dim)}
-               if self.sparse else {}),
-            **({"window": (cfg.num_window_layers, cfg.sliding_window,
-                           self.chunk)} if self.windowed else {}),
-            num_pages=num_pages, max_batch=self.max_batch,
-            max_seq_len=self.max_seq_len, page_size=page_size,
-            num_q_heads=cfg.num_heads, dtype=kv_dtype,
-            enable_prefix_cache=prefix_cache, quantize_kv=self.kv_quant,
-            mesh=self.mesh, metrics=self.metrics,
-            # round 21: the host-DRAM spill tier under the HBM pool
-            # (0 disables — evictions drop exactly like pre-21)
-            host_tier_bytes=host_tier_bytes)
+        with phase("kv.pools"):
+            self.cache = KVCacheManager(
+                cfg.num_layers, kv_heads, kv_width, latent=self.latent,
+                **({"index_plane": (cfg.num_index_layers, cfg.index_head_dim)}
+                   if self.sparse else {}),
+                **({"window": (cfg.num_window_layers, cfg.sliding_window,
+                               self.chunk)} if self.windowed else {}),
+                num_pages=num_pages, max_batch=self.max_batch,
+                max_seq_len=self.max_seq_len, page_size=page_size,
+                num_q_heads=cfg.num_heads, dtype=kv_dtype,
+                enable_prefix_cache=prefix_cache, quantize_kv=self.kv_quant,
+                mesh=self.mesh, metrics=self.metrics,
+                # round 21: the host-DRAM spill tier under the HBM pool
+                # (0 disables — evictions drop exactly like pre-21)
+                host_tier_bytes=host_tier_bytes)
         # round 12: speculative decoding — build geometry for the verify
         # rows ([b, k+1] outputs); per-request adaptive k only varies the
         # spec_len values, so one executable serves every k <= spec_k
@@ -535,9 +541,10 @@ class ServingPredictor:
         self.token_budget = int(
             token_budget
             or (self.max_batch * (1 + self.spec_k) + self.chunk))
-        self._unified = build_unified_step(
-            cfg, self.cache.page_size, self.chunk, use_kernel=use_kernel,
-            kv_quant=self.kv_quant, mesh=self.mesh, spec_k=self.spec_k)
+        with phase("step.build"):
+            self._unified = build_unified_step(
+                cfg, self.cache.page_size, self.chunk, use_kernel=use_kernel,
+                kv_quant=self.kv_quant, mesh=self.mesh, spec_k=self.spec_k)
         # the row counts that one program runs at: it takes the smallest that
         # holds the rows a step packs, by this same function
         self._row_ladder = step_row_ladder(
@@ -895,8 +902,12 @@ class ServingPredictor:
     def telemetry(self) -> dict[str, float]:
         """Flat snapshot of the serving-stack registry (predictor + KV
         cache instruments) — the ``telemetry`` sub-object bench_serve
-        rides on its JSON lines."""
-        return self.metrics.snapshot_flat()
+        rides on its JSON lines — merged with the process registry's
+        (``observability.process_registry``): time to ready by set-up phase,
+        and jax's traces, lowerings and compiles by function, so that
+        ``jax_lowerings`` read after ready is a recompile alarm."""
+        return merge_snapshots(self.metrics.snapshot_flat(),
+                               process_registry.snapshot_flat())
 
     # -- queue API ---------------------------------------------------------
 

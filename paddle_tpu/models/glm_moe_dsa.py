@@ -292,8 +292,13 @@ class GlmMoeDsaForCausalLM:
     def __init__(self, config: GlmMoeDsaConfig, *, seed: int = 0,
                  dtype=None, params=None):
         self.config = config
-        self.params = (params if params is not None
-                       else init_params(config, seed, dtype))
+        self.params = params
+        if params is None:
+            from ..observability.tracing import phase
+
+            with phase("weights.make") as made:
+                self.params = init_params(config, seed, dtype)
+                made.end_when_ready(self.params)
 
     def eval(self):
         return self

@@ -26,6 +26,7 @@ from ..nn.initializer import Normal
 from ..nn.layer.common import Dropout, Embedding, Linear
 from ..nn.layer.container import LayerList
 from ..nn.layer.norm import LayerNorm
+from ..observability.tracing import phase
 from ..tensor.creation import arange
 from ..tensor.manipulation import concat, reshape
 from ..tensor.math import matmul
@@ -371,12 +372,14 @@ class GPTForCausalLM(Layer):
     def __init__(self, config: GPTConfig):
         super().__init__()
         self.config = config
-        self.gpt = GPTModel(config)
-        if not config.tie_word_embeddings:
-            self.lm_head = Linear(
-                config.hidden_size, config.vocab_size,
-                weight_attr=_w(config), bias_attr=False,
-            )
+        with phase("weights.make") as made:
+            self.gpt = GPTModel(config)
+            if not config.tie_word_embeddings:
+                self.lm_head = Linear(
+                    config.hidden_size, config.vocab_size,
+                    weight_attr=_w(config), bias_attr=False,
+                )
+            made.end_when_ready([p._data for p in self.parameters()])
         self.criterion = GPTPretrainingCriterion()
 
     def _logits(self, hidden):
@@ -1131,8 +1134,10 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
         removed. In this form the pools stay one buffer from argument to
         result (``tests/test_tpu_compile.py``)."""
         def at(rows, pools):
-            return rows_of(tok_ids[:rows], tok_slot[:rows], tok_pos[:rows],
-                           feedback[:rows], pools)
+            # runs once a rung, while jax traces the step
+            with phase(f"step.rung.{rows}"):
+                return rows_of(tok_ids[:rows], tok_slot[:rows],
+                               tok_pos[:rows], feedback[:rows], pools)
 
         ladder = step_row_ladder(q_lens.shape[0], spec_k, chunk,
                                  tok_ids.shape[0])

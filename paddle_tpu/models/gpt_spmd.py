@@ -35,7 +35,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..framework.jit32 import jit32
-from ..observability.tracing import step_scope
+from ..observability.tracing import phase, step_scope
 from .gpt import GPTConfig
 
 
@@ -666,13 +666,16 @@ def build_spmd_train_step(
             raise ValueError(
                 "fused_mlp has no MoE path — the fused MLP kernels are "
                 "dense-only (disable fused_mlp for moe_experts > 0)")
-    params = init_params(config, mesh, dtype=dtype)
+    with phase("weights.make") as made:
+        params = init_params(config, mesh, dtype=dtype)
+        made.end_when_ready(params)
     if zero_stage:
         p_shard, m_shard = zero_shardings(params, mesh, zero_stage)
     else:
         p_shard = m_shard = param_shardings(mesh, params)
-    params = jax.device_put(params, p_shard)
-    mom = jax.device_put(sgd_init(params), m_shard)
+    with phase("weights.place"):
+        params = jax.device_put(params, p_shard)
+        mom = jax.device_put(sgd_init(params), m_shard)
     data_shard = NamedSharding(mesh, P("dp", None))
 
     def sync_grads(params, ids, labels):
@@ -709,12 +712,13 @@ def build_spmd_train_step(
             params2 = jax.tree.map(lambda p, m: p - lr * m, params, mom2)
         return params2, mom2, loss
 
-    jitted_inner = jit32(
-        step,
-        in_shardings=(p_shard, m_shard, data_shard, data_shard),
-        out_shardings=(p_shard, m_shard, NamedSharding(mesh, P())),
-        donate_argnums=(0, 1),
-    )
+    with phase("step.build"):
+        jitted_inner = jit32(
+            step,
+            in_shardings=(p_shard, m_shard, data_shard, data_shard),
+            out_shardings=(p_shard, m_shard, NamedSharding(mesh, P())),
+            donate_argnums=(0, 1),
+        )
 
     # round-15 telemetry on the library-wide registry (off by default;
     # observability.enable_metrics() turns it on): step counter, host

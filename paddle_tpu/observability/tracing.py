@@ -20,6 +20,11 @@ buffer (``profiler/record.py``):
   step programs, or of :data:`STEP_SUBSCOPES`, parts of those parts. The name rides every HLO operation's ``op_name`` path
   into the device trace, where ``benchmark/scope_trace.py`` charges each
   operation's own time to the innermost such name.
+- :func:`phase` — a named SET-UP range (``with phase("weights.make"):``):
+  what :func:`span` records while a window is open, and always, window or
+  not, an entry of ``setup_record`` and the seconds on the process
+  registry's ``setup_seconds{phase=}`` (``observability/startup.py``).
+  A phase runs once per build, never on a step's call path.
 - :func:`monotonic` / :func:`monotonic_ns` — THE timing clock for
   ``paddle_tpu/inference`` and ``paddle_tpu/distributed`` (tpulint AL006
   flags raw ``time.perf_counter()`` there; timing belongs to this layer
@@ -33,12 +38,15 @@ serving spans and the request lanes together.
 from __future__ import annotations
 
 import contextlib
+import threading
 import time
 
 from ..profiler.record import now_ns, recorder
+from .startup import (SetupEntry, process_registry, record_seconds,
+                      setup_record)
 
 __all__ = [
-    "span", "request_begin", "request_event", "request_end",
+    "span", "phase", "request_begin", "request_event", "request_end",
     "tracing_active", "monotonic", "monotonic_ns",
     "device_annotation", "set_device_tracing", "STEP_SCOPES",
     "STEP_SUBSCOPES", "step_scope",
@@ -100,13 +108,16 @@ class _Span:
     def __exit__(self, *exc):
         end = now_ns()
         if self._start is not None:
-            recorder.record(self.name, self._start, end,
-                            category=self.category)
+            self._ended(self._start, end)
             self._start = None
         ann, self._ann = self._ann, None
         if ann is not None:
             ann.__exit__(*exc)
         return False
+
+    def _ended(self, start_ns, end_ns):
+        # a no-op unless a record window is open
+        recorder.record(self.name, start_ns, end_ns, category=self.category)
 
 
 def span(name: str, category: str = "serving"):
@@ -116,6 +127,74 @@ def span(name: str, category: str = "serving"):
     if not recorder.enabled:
         return _NULL
     return _Span(name, category)
+
+
+# -- set-up phases ---------------------------------------------------------
+
+_SETUP_SECONDS = process_registry.counter(
+    "setup_seconds", "seconds of each set-up phase (observability.phase)",
+    labels=("phase",))
+
+#: the phases open on each thread, innermost last: a phase's parent
+_OPEN = threading.local()
+
+
+class _Phase(_Span):
+    __slots__ = ("_parent", "_arrays")
+
+    def __init__(self, name):
+        super().__init__(name, "setup")
+        self._parent = self._arrays = None
+
+    def __enter__(self):
+        stack = _OPEN.__dict__.setdefault("stack", [])
+        self._parent = stack[-1] if stack else None
+        stack.append(self.name)
+        return super().__enter__()
+
+    def end_when_ready(self, tree):
+        """End this phase when the device holds the arrays of ``tree``, not
+        when its block does, and without waiting for them here: the device
+        work the block dispatched then counts to it, and what follows the
+        block still overlaps that work
+        (:meth:`~.startup.SetupRecord.end_when_ready`)."""
+        import jax
+
+        self._arrays = [a for a in jax.tree.leaves(tree)
+                        if isinstance(a, jax.Array)]
+
+    def __exit__(self, *exc):
+        _OPEN.stack.pop()
+        return super().__exit__(*exc)
+
+    def _ended(self, start_ns, end_ns):
+        super()._ended(start_ns, end_ns)
+        t0 = monotonic()
+        counter = _SETUP_SECONDS.labels(phase=self.name)
+        start = start_ns * 1e-9
+        if self._arrays is not None:
+            setup_record.end_when_ready(self.name, start, self._parent,
+                                        self._arrays, counter)
+            self._arrays = None
+        else:
+            end = end_ns * 1e-9
+            setup_record.add(SetupEntry(self.name, start, end, self._parent))
+            counter.inc(end - start)
+        record_seconds.inc(monotonic() - t0)
+
+
+def phase(name: str) -> _Phase:
+    """A named set-up range: weights made (``weights.make``) or placed
+    (``weights.place``), the KV pools (``kv.pools``), the host side of a
+    step program's build (``step.build``), a rung of its row ladder while
+    jax traces it (``step.rung.<rows>``). Always recorded, in
+    ``setup_record`` on :func:`monotonic` (with the phase open around it on
+    its thread as ``parent``) and as ``setup_seconds{phase=name}`` on the
+    process registry; as a Chrome ``X`` event and a TraceAnnotation too
+    while a record window or capture is open, like :func:`span`. Never on a
+    step's call path: a phase inside a traced function runs only while jax
+    traces it."""
+    return _Phase(name)
 
 
 # -- per-request async lanes -------------------------------------------------
