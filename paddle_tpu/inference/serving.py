@@ -567,6 +567,7 @@ class ServingPredictor:
                                                       ragged_grid)
 
             mp = self.mesh.shape["mp"] if self.mesh is not None else 1
+            self._q_heads = cfg.num_heads // mp      # a chip's query heads
             self._attn_grid = ragged_grid(
                 self.max_batch, self.cache.pages_per_slot, self.chunk,
                 cfg.num_heads // mp, kv_heads // mp, self.cache.page_size,
@@ -580,6 +581,20 @@ class ServingPredictor:
             "serving_attn_blocks_grid",
             "grid steps a call of the step's paged attention kernel "
             "launches, summed over dispatched steps")
+        if not self.latent:
+            # what the kernel's query operand holds against what it is fed:
+            # a lane's block is ``lane_block_rows`` rows a query head at the
+            # step's rung (padded to whole sublane tiles), however few rows
+            # the lane feeds
+            self._m_query_rows = self.metrics.counter(
+                "serving_attn_query_rows",
+                "query rows the step's paged attention kernel is fed, times "
+                "their query heads: per scheduled row and attention layer")
+            self._m_query_block_rows = self.metrics.counter(
+                "serving_attn_query_block_rows",
+                "rows of the query blocks the step builds for that kernel: "
+                "per attention layer, lanes x key-value heads x a lane's "
+                "rows a head at the step's rung")
         if self.windowed:
             # a call a layer, of two kinds: the counters above then sum a
             # step's calls, a window layer's steps from its first live block
@@ -2273,6 +2288,13 @@ class ServingPredictor:
             self._m_attn_live.inc(
                 sum(map(self._attn_grid.live_steps, contexts, fed)))
             self._m_attn_grid.inc(self._attn_grid.steps(contexts, fed))
+        if not self.latent:
+            grid = (self._attn_grids[rung][0] if self.windowed
+                    else self._attn_grid)
+            layers = self.config.num_layers
+            self._m_query_rows.inc(layers * sum(fed) * self._q_heads)
+            self._m_query_block_rows.inc(
+                layers * grid.lanes * grid.groups * grid.heads * grid.rows)
         if self.sparse:
             cfg = self.config
             seen, read = map(sum, zip(*(

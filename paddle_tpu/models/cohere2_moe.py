@@ -43,10 +43,10 @@ from __future__ import annotations
 import math
 from dataclasses import dataclass
 
-from .deepseek_v2 import init_params as _init_params
 from .deepseek_v2 import routed_ffn
-
-WINDOW, FULL = "sliding_attention", "full_attention"
+from .layer_kinds import (FULL, WINDOW, attention, rope_interleaved,
+                          run_layers, stack_runs, stacked_shapes)
+from .layer_kinds import CausalLM, init_params as _init_runs
 
 
 @dataclass
@@ -116,22 +116,16 @@ class Cohere2MoeConfig:
     def num_window_layers(self) -> int:
         return sum(k == WINDOW for k in self.layer_types)
 
+    @property
+    def rotary(self):
+        """The window layers' rotary pairing: interleaved (``rope_gptj``)."""
+        return rope_interleaved
+
     def num_params(self) -> int:
         import jax
 
         return sum(int(math.prod(s.shape))
                    for s in jax.tree.leaves(param_shapes(self)))
-
-
-def stack_runs(config):
-    """``[(kind, layers)]``: the runs of equal layers, in order."""
-    runs = []
-    for kind in config.layer_types:
-        if runs and runs[-1][0] == kind:
-            runs[-1] = (kind, runs[-1][1] + 1)
-        else:
-            runs.append((kind, 1))
-    return runs
 
 
 def _layer_shapes(config):
@@ -157,36 +151,16 @@ def param_shapes(config, dtype=None):
 
     cfg = config
     dt = jnp.dtype(dtype or jnp.float32)
-
-    def sds(*shape):
-        return jax.ShapeDtypeStruct(shape, dt)
-
-    return {"tok_emb": sds(cfg.vocab_size, cfg.hidden_size),
-            "lnf_g": sds(cfg.hidden_size),
-            "stacks": tuple(
-                {k: sds(*((n,) if n > 1 else ()), *s)
-                 for k, s in _layer_shapes(cfg).items()}
-                for _, n in stack_runs(cfg))}
+    return {"tok_emb": jax.ShapeDtypeStruct((cfg.vocab_size, cfg.hidden_size),
+                                            dt),
+            "lnf_g": jax.ShapeDtypeStruct((cfg.hidden_size,), dt),
+            "stacks": stacked_shapes(cfg, _layer_shapes(cfg), dt)}
 
 
 def init_params(config, seed: int, dtype=None):
-    """Seeded weights on the device in ``dtype``
-    (``deepseek_v2.init_params`` over this family's tree). That rule fills a
-    leaf of a stack one layer at a time; an unstacked run's matrices are
-    made as a stack of one and handed over without it (filled by their
-    first dimension they would be 4,096 draws of one row each)."""
-    import jax
-
-    shapes = param_shapes(config, dtype)
-    lifted = [{k for k, s in run.items() if n == 1 and s.ndim == 2}
-              for run, (_, n) in zip(shapes["stacks"], stack_runs(config))]
-    tree = _init_params(config, seed, dtype, shapes=dict(shapes, stacks=tuple(
-        {k: jax.ShapeDtypeStruct((1,) + s.shape, s.dtype) if k in up else s
-         for k, s in run.items()}
-        for run, up in zip(shapes["stacks"], lifted))))
-    return dict(tree, stacks=tuple(
-        {k: v[0] if k in up else v for k, v in run.items()}
-        for run, up in zip(tree["stacks"], lifted)))
+    """Seeded weights on the device in ``dtype`` (``layer_kinds.init_params``
+    over this family's tree)."""
+    return _init_runs(config, seed, param_shapes(config, dtype), dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -201,75 +175,6 @@ def layer_norm(x, g, eps):
     from .gpt import _srv_ln
 
     return _srv_ln(x, g, None, eps)
-
-
-def rope_interleaved(x, positions, theta):
-    """Rotate ``x [t, heads, d]`` by its row's integer position over the whole
-    head, INTERLEAVED pairs ``(x[2j], x[2j+1])`` at frequency ``theta ** (-2j
-    / d)`` (``rope_gptj``; float32 inside, ``x``'s dtype out). The pair's
-    partner comes by a roll along the head, not by a reshape to pairs: the
-    values stay in whole 128-lane rows."""
-    import jax.numpy as jnp
-    import numpy as np
-
-    d = x.shape[-1]
-    inv = float(theta) ** (-np.arange(0, d, 2, dtype=np.float64) / d)
-    ang = (positions.astype(jnp.float32)[:, None]
-           * jnp.asarray(np.repeat(inv, 2), jnp.float32))[:, None, :]
-    xf = x.astype(jnp.float32)
-    even = (jnp.arange(d) % 2) == 0
-    partner = jnp.where(even, -jnp.roll(xf, -1, axis=-1),
-                        jnp.roll(xf, 1, axis=-1))
-    return (xf * jnp.cos(ang) + partner * jnp.sin(ang)).astype(x.dtype)
-
-
-def split_qkv(config, qkv):
-    """``[..., (nh + 2 nkv) * hd]`` to ``q [..., nh, hd]``, ``k`` and ``v
-    [..., nkv, hd]``."""
-    from .gpt import _split_qkv
-
-    return _split_qkv(qkv, config.num_heads, config.head_dim, False,
-                      nkv=config.num_kv_heads)
-
-
-def attention_kind(config, kind):
-    """``(window, rope theta)`` of a layer kind: what ``mha`` does beyond the
-    GPT block's (``models/gpt.py``): a window layer rotates and sees its
-    window; a full layer has neither."""
-    if kind == WINDOW:
-        return config.sliding_window, config.rope_theta
-    return None, None
-
-
-def attention(config, p, y, positions, kind):
-    """Causal grouped-query attention over ONE sequence ``y [s, h]``: ``[s,
-    nh * hd]`` (plain products; the serving step reads the paged cache
-    through its kernel instead)."""
-    import jax
-    import jax.numpy as jnp
-
-    cfg = config
-    window, theta = attention_kind(cfg, kind)
-    q, k, v = split_qkv(cfg, y @ p["wqkv"])
-    if theta is not None:
-        q = rope_interleaved(q, positions, theta)
-        k = rope_interleaved(k, positions, theta)
-    s, group = y.shape[0], cfg.num_heads // cfg.num_kv_heads
-    qg = q.reshape(s, cfg.num_kv_heads, group, cfg.head_dim)
-    scores = jnp.einsum("qhgd,khd->hgqk", qg, k).astype(jnp.float32)
-    seen = positions[:, None] >= positions[None, :]
-    if window is not None:
-        seen &= positions[:, None] - positions[None, :] < window
-    scores = jnp.where(seen, scores * cfg.head_dim ** -0.5, -jnp.inf)
-    probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
-    return jnp.einsum("hgqk,khd->qhgd", probs, v).reshape(s, -1)
-
-
-def run_layers(stack, n):
-    """The ``n`` layers of one run, each as its own unstacked weights."""
-    if n == 1:
-        return [stack]
-    return [{k: v[i] for k, v in stack.items()} for i in range(n)]
 
 
 def forward(config, params, ids):
@@ -294,30 +199,9 @@ def forward(config, params, ids):
     return jnp.einsum("bsh,vh->bsv", h, params["tok_emb"]) * cfg.logit_scale
 
 
-class Cohere2MoeForCausalLM:
-    """The model as ``ServingPredictor`` takes it: ``config`` and the weight
-    tree (:func:`init_params`), in ``dtype``, on the device. Inference only;
-    ``__call__`` is the eager full forward."""
+class Cohere2MoeForCausalLM(CausalLM):
+    """The model as ``ServingPredictor`` takes it (``layer_kinds.CausalLM``):
+    ``Cohere2MoeForCausalLM(Cohere2MoeConfig(...), seed=, dtype=)``."""
 
-    def __init__(self, config: Cohere2MoeConfig, *, seed: int = 0,
-                 dtype=None, params=None):
-        self.config = config
-        self.params = params
-        if params is None:
-            from ..observability.tracing import phase
-
-            with phase("weights.make") as made:
-                self.params = init_params(config, seed, dtype)
-                made.end_when_ready(self.params)
-
-    def eval(self):
-        return self
-
-    def __call__(self, input_ids):
-        import jax.numpy as jnp
-
-        from ..tensor.tensor import Tensor
-
-        ids = input_ids._data if isinstance(input_ids, Tensor) else input_ids
-        return Tensor(forward(self.config, self.params,
-                              jnp.asarray(ids, jnp.int32)))
+    init_params = staticmethod(init_params)
+    forward = staticmethod(forward)

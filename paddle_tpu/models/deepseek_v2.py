@@ -309,15 +309,18 @@ STACKED_BY_INDEX = ("moe_w_gu", "moe_w_d")
 
 
 def routed_ffn(config, p, y, use_kernel=None, valid=None, with_counts=False,
-               layer=None):
+               layer=None, route=None):
     """Routed experts plus the shared ones over rows ``y [n, h]``: dropless
     top-k over the softmax scores, gates as they are (``norm_topk_prob``
-    decides), gated SiLU experts through the grouped GEMM
-    (``models/moe.py moe_ffn``), and the shared gated MLP for every row.
+    decides), gated experts through the grouped GEMM (``models/moe.py
+    moe_ffn``; SiLU, or the configuration's ``hidden_act``), and the shared
+    gated MLP for every row where the layer holds one (``sh_w_gu``).
     ``with_counts``: also ``[2, E]`` int32, the rows each expert received
     and whether it received any. ``layer``: ``p``'s expert weights
     (:data:`STACKED_BY_INDEX`) are the whole stacks ``[L, E, ...]`` and this
-    is layer ``layer`` of them."""
+    is layer ``layer`` of them. ``route``: the rows the router reads, where
+    the configuration routes from other rows than the experts read (None:
+    ``y``)."""
     from ..observability.tracing import step_scope
     from .moe import moe_ffn
 
@@ -337,14 +340,15 @@ def routed_ffn(config, p, y, use_kernel=None, valid=None, with_counts=False,
         renormalize=bool(cfg.norm_topk_prob), gated=True,
         gate_scale=float(cfg.routed_scaling_factor),
         use_kernel=use_kernel, valid=valid, with_stats=True, layer=layer,
-        **more)
-    with step_scope("moe_shared"):
-        shared = gated_mlp(y, p["sh_w_gu"], p["sh_w_d"])
-        # shared experts that are AVERAGED, not summed (``models/
-        # cohere2_moe.py``): their one summed MLP over their number
-        scale = getattr(cfg, "shared_expert_scale", None)
-        out = out + (shared if scale is None
-                     else (shared * scale).astype(shared.dtype))
+        act=getattr(cfg, "hidden_act", "silu"), route_x=route, **more)
+    if "sh_w_gu" in p:
+        with step_scope("moe_shared"):
+            shared = gated_mlp(y, p["sh_w_gu"], p["sh_w_d"])
+            # shared experts that are AVERAGED, not summed (``models/
+            # cohere2_moe.py``): their one summed MLP over their number
+            scale = getattr(cfg, "shared_expert_scale", None)
+            out = out + (shared if scale is None
+                         else (shared * scale).astype(shared.dtype))
     if not with_counts:
         return out
     import jax.numpy as jnp

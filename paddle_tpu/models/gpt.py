@@ -600,7 +600,7 @@ def _srv_moe(config, p, y, use_kernel=None, valid=None):
 
 
 def _srv_ffn(config, p, y, use_kernel=None, axis=None, valid=None,
-             layer=None):
+             layer=None, route=None):
     """Block FFN dispatch — the ONE switch every serving builder goes
     through. Returns ``(out, rows)``: ``rows [2, E]`` int32, how many rows
     each expert of a dropless routed layer received and whether it received
@@ -609,12 +609,13 @@ def _srv_ffn(config, p, y, use_kernel=None, axis=None, valid=None,
     (``moe_w_gu``; ``models/deepseek_v2.py``), a dense gated SiLU MLP
     (``w_gu``), the GShard-style ``_srv_moe``, or the biased GELU
     ``_srv_mlp``. ``layer``: the expert stacks in ``p`` are whole, and this
-    is layer ``layer`` of them (``deepseek_v2.STACKED_BY_INDEX``)."""
+    is layer ``layer`` of them (``deepseek_v2.STACKED_BY_INDEX``).
+    ``route``: the rows the router reads where they are not ``y``."""
     if "moe_w_gu" in p:
         from .deepseek_v2 import routed_ffn
 
         return routed_ffn(config, p, y, use_kernel, valid=valid,
-                          with_counts=True, layer=layer)
+                          with_counts=True, layer=layer, route=route)
     if "w_gu" in p:
         from .deepseek_v2 import gated_mlp
 
@@ -1098,8 +1099,7 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
                 f"a cache with a window group does not serve "
                 f"{', '.join(unsupported)} yet: its second pool pair has no "
                 "scale planes, no sharding rule and no rollback")
-        from .cohere2_moe import (WINDOW, attention_kind, rope_interleaved,
-                                  stack_runs)
+        from .layer_kinds import WINDOW, attention_kind, stack_runs
     # argument layout (shared by the wrappers, shard_map specs and the
     # donation indices): params + 6 packed/lane arrays [+ spec_len] + the
     # 4 feedback arrays (feedback mask, prev_toks carry, emit_mask,
@@ -1342,8 +1342,9 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
                                          head_major=mesh is not None,
                                          nkv=nkv_l)
                 if theta is not None:
-                    q = rope_interleaved(q, tok_pos, theta)
-                    k_t = rope_interleaved(k_t, tok_pos, theta)
+                    # in the configuration's pairing (``models/layer_kinds``)
+                    q = cfg.rotary(q, tok_pos, theta)
+                    k_t = cfg.rotary(k_t, tok_pos, theta)
             with step_scope("kv_write"):
                 if kv_quant:
                     kp, ks = paged_write_packed_quant(kp, ks, k_t, *dest,
@@ -1498,6 +1499,10 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
                 p = dict(p, **whole)
             with step_scope("ln"):
                 y = _srv_norm(cfg, x, p, "ln1")
+            # a router placed BEFORE attention reads this norm, not the FFN's
+            # (``models/smallthinker.py``)
+            route = (y if getattr(cfg, "router_before_attention", False)
+                     else None)
             a, pools, *chosen = attention(
                 p, y, pools, li, *sel, *fi,
                 **({} if kind is None else {"kind": kind}))
@@ -1511,7 +1516,7 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
                     y = _srv_norm(cfg, x, p, "ln2")
             with step_scope("mlp"):
                 f, rows = _srv_ffn(cfg, p, y, use_kernel, axis, valid=valid,
-                                   layer=lj if whole else None)
+                                   layer=lj if whole else None, route=route)
                 x = x + f
             if sparse:
                 # the selection goes on to the next layer; its last rows

@@ -149,6 +149,10 @@ def _grouped_mm(xs, w, offsets, use_kernel, layer=None):
     return grouped_matmul(xs, w, offsets, use_kernel=use_kernel, layer=layer)
 
 
+#: what a gated expert's gate half goes through, by the configuration's name
+_GATE_ACT = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
 def _expert_bias(b, eids):
     """Per-row bias gather from an ``[E, F]`` stack."""
     return jnp.take(b, eids, axis=0)
@@ -158,7 +162,8 @@ def moe_ffn(x, gate_w, w1, b1, w2, b2, *, top_k: int,
             capacity_factor: float | None, use_kernel=None, valid=None,
             with_stats: bool = False, renormalize: bool = True,
             gated: bool = False, gate_scale: float = 1.0, layer=None,
-            scoring: str = "softmax", route_bias=None, experts_held=None):
+            scoring: str = "softmax", route_bias=None, experts_held=None,
+            act: str = "silu", route_x=None):
     """The MoE FFN over 2D tokens ``x [N, d]``.
 
     gate_w ``[d, E]``; w1 ``[E, d, f]`` / w2 ``[E, f, d]`` (fp stacks or
@@ -173,11 +178,13 @@ def moe_ffn(x, gate_w, w1, b1, w2, b2, *, top_k: int,
     DROPLESS (no clamp: every choice of every valid token is computed);
     ``renormalize`` as in :func:`route_topk`; ``gate_scale`` multiplies
     the gates (``routed_scaling_factor``); ``gated``: ``w1`` is ``[E, d,
-    2f]``, the gate and up projections of a gated SiLU expert in ONE
-    grouped GEMM, ``(silu(x W_g) * x W_u) W_d``, instead of the biased
-    GELU pair. ``layer`` (a traced int32 scalar): ``w1`` / ``w2`` are the
-    whole model's stacks ``[L, E, ...]`` and this call is layer ``layer``
-    of them (``grouped_matmul(layer=)``). ``scoring`` / ``route_bias`` as
+    2f]``, the gate and up projections of a gated expert in ONE grouped
+    GEMM, ``(act(x W_g) * x W_u) W_d``, instead of the biased GELU pair;
+    ``act`` is the configuration's: ``"silu"``, or ``"relu"`` (ReGLU).
+    ``route_x [N, d']``: the rows the router reads, where they are not
+    ``x`` (a router placed before attention reads that norm's rows).
+    ``layer`` (a traced int32 scalar): ``w1`` / ``w2`` are the whole
+    model's stacks ``[L, E, ...]`` and this call is layer ``layer`` of them (``grouped_matmul(layer=)``). ``scoring`` / ``route_bias`` as
     :func:`route_topk`. ``experts_held=(first, count)``: this chip's SHARE
     of an expert-parallel layer. The router keeps its full width ``E`` and
     its k; ``w1`` / ``w2`` hold the ``count`` experts ``first ..`` alone;
@@ -196,7 +203,8 @@ def moe_ffn(x, gate_w, w1, b1, w2, b2, *, top_k: int,
     e = gate_w.shape[-1]
     k = int(top_k)
     with step_scope("moe_route"):
-        logits = x.astype(jnp.float32) @ gate_w.astype(jnp.float32)
+        logits = (x if route_x is None else route_x).astype(
+            jnp.float32) @ gate_w.astype(jnp.float32)
         gates, idx, probs, masks = route_topk(
             logits, k, renormalize, scoring=scoring, bias=route_bias)
         aux = load_balance_aux(probs, masks[0], valid=valid)
@@ -252,7 +260,7 @@ def moe_ffn(x, gate_w, w1, b1, w2, b2, *, top_k: int,
             h = h + _expert_bias(b1, eid_sorted).astype(h.dtype)
         if gated:
             f = h.shape[-1] // 2
-            h = jax.nn.silu(h[:, :f]) * h[:, f:]
+            h = _GATE_ACT[act](h[:, :f]) * h[:, f:]
         else:
             h = jax.nn.gelu(h, approximate=True)
         y = _grouped_mm(h.astype(x.dtype), w2, offsets, use_kernel, layer)

@@ -361,8 +361,8 @@ def test_a_released_page_is_claimed_again_under_steps_in_flight(tiny):
 
 # ---- the kernel and its planner ----------------------------------------------------
 
-@pytest.mark.parametrize("hq, hkv", [(16, 1), (4, 4)], ids=["group16",
-                                                           "group1"])
+@pytest.mark.parametrize("hq, hkv", [(16, 1), (4, 4), (28, 4)],
+                         ids=["group16", "group1", "group7"])
 @pytest.mark.parametrize("window", [16, 9, None])
 def test_windowed_kernel_is_the_gather_reference(hq, hkv, window):
     rng = np.random.default_rng(0)

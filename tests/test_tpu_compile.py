@@ -365,6 +365,8 @@ def test_compiled_window_step_keeps_both_groups_in_place(one_chip,
     pytest.param(64, 192, 1408, 2048, id="dsv2-down"),
     pytest.param(64, 6144, 2048, 2816, id="dsv2-gate-up-top-rung"),
     pytest.param(64, 6144, 1408, 2048, id="dsv2-down-top-rung"),
+    pytest.param(64, 192, 2560, 1536, id="smallthinker-gate-up"),
+    pytest.param(64, 6144, 768, 2560, id="smallthinker-down-top-rung"),
 ])
 def test_grouped_matmul_compiles_alone_at_the_routed_cells_shapes(
         one_chip, monkeypatch, e, m, k, n):
@@ -404,3 +406,51 @@ def test_grouped_matmul_compiles_alone_at_the_routed_cells_shapes(
     assert calls[0].split("=")[1].strip().startswith("bf16[")
     bm, bn, bk = _blocks_for(e, m, k, n, 0, k, bf)
     assert fwd_vmem_bytes(bm, bn, bk, k, bf) <= VMEM_DEFAULT_BYTES
+
+
+@pytest.mark.parametrize("window, rows", [
+    (4096, 32), (4096, 256), (None, 32), (None, 256)],
+    ids=["window-decode-rung", "window-chunk", "full-decode-rung",
+         "full-chunk"])
+def test_paged_kernel_compiles_alone_at_seven_query_heads_a_kv_head(
+        one_chip, monkeypatch, window, rows):
+    """PR 40: ``ragged_paged_attention`` alone at SmallThinker's widths (28
+    query heads over 4 key-value heads of 128: a group of 7, never a power
+    of two) in both forms, at the decode rung's 32-row lane blocks and at a
+    chunk's 256: the group's 7 rows a token lie side by side (224 and 1,792
+    rows a head, whole sublane tiles), the few-rows form takes 8, and the
+    call fits Mosaic's default fast memory (no request of its own)."""
+    import paddle_tpu  # noqa: F401  framework config (matmul precision)
+    from paddle_tpu.ops.pallas.paged_attention import (RAGGED_KERNEL_NAME,
+                                                       ragged_grid,
+                                                       ragged_paged_attention)
+
+    sys.path.insert(0, REPO)
+    try:
+        from chip_smoke import _is_mosaic_call
+    finally:
+        sys.path.remove(REPO)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    bf, i32 = jnp.bfloat16, jnp.int32
+    lanes, pps = 32, (69 if window else 256)
+    pages = lanes * pps
+
+    def sds(*shape, dtype=bf):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def fn(q, kp, vp, table, kv, ql, layer):
+        return ragged_paged_attention(q, kp, vp, table, kv, ql, layer=layer,
+                                      window=window)
+
+    with jax.enable_x64(False):
+        compiled = jax.jit(fn).lower(
+            sds(lanes, rows, 28, 128), sds(2, pages, 4, 64, 128),
+            sds(2, pages, 4, 64, 128), sds(lanes, pps, dtype=i32),
+            sds(lanes, dtype=i32), sds(lanes, dtype=i32),
+            sds(dtype=i32)).compile()
+    calls = [line for line in compiled.as_text().splitlines()
+             if _is_mosaic_call(line, f"/{RAGGED_KERNEL_NAME}/pallas_call")]
+    assert len(calls) == 1
+    plan = ragged_grid(lanes, pps, rows, 28, 4, 64, 128, bf, bf)
+    assert plan.rows == rows * 7 and plan.few_rows == 8 and plan.vmem == 0
