@@ -595,6 +595,17 @@ class ServingPredictor:
                 "rows of the query blocks the step builds for that kernel: "
                 "per attention layer, lanes x key-value heads x a lane's "
                 "rows a head at the step's rung")
+            # how many times the kernel walks a scheduled lane's keys: once
+            # a group of key-value heads of its plan at the step's rung
+            self._m_kv_passes = self.metrics.counter(
+                "serving_attn_kv_passes",
+                "passes the step's paged attention kernel makes over a "
+                "lane's keys (its plan's head groups): per scheduled lane "
+                "and attention layer")
+            self._m_lane_layers = self.metrics.counter(
+                "serving_attn_lane_layers",
+                "scheduled lanes x attention layers, summed over dispatched "
+                "steps")
         if self.windowed:
             # a call a layer, of two kinds: the counters above then sum a
             # step's calls, a window layer's steps from its first live block
@@ -2295,6 +2306,13 @@ class ServingPredictor:
             self._m_query_rows.inc(layers * sum(fed) * self._q_heads)
             self._m_query_block_rows.inc(
                 layers * grid.lanes * grid.groups * grid.heads * grid.rows)
+            passes = layers * grid.groups
+            if self.windowed:
+                win_layers = self.cache.window.layers
+                passes += win_layers * (self._attn_grids[rung][1].groups
+                                        - grid.groups)
+            self._m_kv_passes.inc(len(fed) * passes)
+            self._m_lane_layers.inc(len(fed) * layers)
         if self.sparse:
             cfg = self.config
             seen, read = map(sum, zip(*(

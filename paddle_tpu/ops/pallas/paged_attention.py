@@ -38,9 +38,12 @@ The kernel's grid (PR 29) pays for keys, not for page slots:
 
 - one grid step serves ALL local KV heads of a lane (a page's block for
   every head is one contiguous ``[kv_heads, page_size, head_dim]`` tile;
-  where that does not fit the fast-memory budget, the largest divisor of the
-  head count that does, and the head groups lead the grid) over SEVERAL
-  pages: the pool is passed once per page a step reads, each operand with
+  where that does not fit the chip's fast memory, 128 MiB a v5e core, with
+  room for the compiler's own values, the largest divisor of the head count
+  that does, and the head groups lead the grid: each group walks the lane's
+  keys again) over SEVERAL pages. A step past Mosaic's 16 MiB scoped
+  default asks for what it takes; one under it asks for nothing and lowers
+  as before. The pool is passed once per page a step reads, each operand with
   its own block index map, so the pipeline fetches them together. Two
   64-key pages side by side make a 128-key score tile; the accumulator is
   rescaled once a grid step and divided by ``l`` once a lane; ``m``, ``l``
@@ -203,12 +206,35 @@ def paged_attention(q, k_pages, v_pages, page_table, lengths, scale=None,
 # What one grid step of the ragged kernel covers (measured on the v5e at the
 # 590M serving cells, PERF.md, PR 29: with no dead grid steps 2, 4, 6 and 8
 # pages a step are within 4% of each other; 4 needs half the operands of 8):
-# the pages one step reads, the fast memory its blocks may take of Mosaic's
-# 16 MiB scoped default (double buffers counted; the rest is the compiler's
-# own), and the query rows of the few-rows form (one f32 sublane tile).
+# the pages one step reads and the query rows of the few-rows form (one f32
+# sublane tile).
 PAGES_PER_STEP = 4
-VMEM_BUDGET = 12 << 20
 FEW_ROWS = 8
+# Fast memory. A grid step whose blocks and scratch (double buffers counted)
+# take at most VMEM_DEFAULT, Mosaic's 16 MiB scoped default less headroom,
+# asks for nothing. Past it the call asks for them plus room for the
+# compiler's own values (a key block's scores, exponentials and masks):
+# VMEM_ROOM_TILES float32 score tiles of one head (``[rows, keys]``, a score
+# tile's keys rounded up to 128 lanes). Compiled alone for a v5e at the
+# serving cells' plans, the kernel took at most 4.4 such tiles beyond its
+# blocks (PERF.md). A step takes as many heads as fit the chip's fast memory
+# so counted (:func:`vmem_capacity`).
+VMEM_DEFAULT = 12 << 20
+VMEM_ROOM_TILES = 5
+V5E_VMEM = 128 << 20
+
+
+def vmem_capacity() -> int:
+    """A core's fast memory, read from the attached TPU
+    (``pltpu.get_tpu_info()``); where none is attached (the CPU, interpret
+    mode, a compile for a described chip) a v5e's, so that the host's counts
+    and the kernel's plan always agree."""
+    if jax.default_backend() == "tpu":
+        try:
+            return int(pltpu.get_tpu_info().vmem_capacity_bytes)
+        except ValueError:      # a device kind the table does not know
+            pass
+    return V5E_VMEM
 
 
 class RaggedGrid(NamedTuple):
@@ -228,9 +254,9 @@ class RaggedGrid(NamedTuple):
     groups: int
     lanes: int
     blocks: int
-    #: fast memory one grid step's blocks and scratch take where that is
-    #: more than :data:`VMEM_BUDGET` even with one head a step (a wide
-    #: query group's whole chunk), else 0: the call then asks Mosaic for it
+    #: fast memory the call asks Mosaic for where one grid step's blocks and
+    #: scratch take more than :data:`VMEM_DEFAULT` (with the room for its
+    #: own values), else 0
     vmem: int = 0
 
     @property
@@ -290,10 +316,12 @@ def ragged_grid(lanes, pps, chunk, hq, hkv, page_size, head_dim, kv_dtype,
     grid steps (``inference/serving.py``) cannot drift from the kernel.
 
     A grid step reads ``min(PAGES_PER_STEP, pps)`` pages of as many local KV
-    heads as fit :data:`VMEM_BUDGET` (the largest divisor of ``hkv``): K and
-    V blocks double-buffered, int8 pools with their scale planes (whose block
-    is all heads of a page whatever the step takes), the query block, the
-    float32 output block and the softmax state in scratch."""
+    heads as fit the chip's fast memory (the largest divisor of ``hkv``;
+    :func:`vmem_capacity`): K and V blocks double-buffered, int8 pools with
+    their scale planes (whose block is all heads of a page whatever the step
+    takes), the query block, the float32 output block and the softmax state
+    in scratch, and room for the compiler's own values. A step past
+    :data:`VMEM_DEFAULT` asks Mosaic for that much."""
     group = hq // hkv
     rows = max(8, -(-chunk * group // 8) * 8)
     kv_bytes, q_bytes = jnp.dtype(kv_dtype).itemsize, jnp.dtype(q_dtype).itemsize
@@ -310,18 +338,17 @@ def ragged_grid(lanes, pps, chunk, hq, hkv, page_size, head_dim, kv_dtype,
                 + 3 * rows * 128 * 4                                # m, l, top
                 + pages // pair * rows * score_lanes * 4)           # scores
     fixed = 2 * 2 * pages * hkv * page_size * 4 if quant else 0
+    room = VMEM_ROOM_TILES * rows * score_lanes * 4
+    capacity = vmem_capacity()
     heads = max([h for h in range(1, hkv + 1) if hkv % h == 0
-                 and fixed + h * per_head <= VMEM_BUDGET] or [1])
+                 and fixed + h * per_head + room <= capacity] or [1])
     few = max(FEW_ROWS, -(-group // 8) * 8)
-    # one head's blocks alone can be past the budget (16 query heads a KV
-    # head x a chunk's 256 rows): the call then asks Mosaic for what they
-    # take, twice over for the compiler's own values (scores, exponentials)
-    over = fixed + heads * per_head > VMEM_BUDGET
+    step = fixed + heads * per_head
     return RaggedGrid(heads=heads, pages=pages, pair=pair, rows=rows,
                       few_rows=few if few < rows else 0, page_size=page_size,
                       groups=hkv // heads, lanes=lanes,
                       blocks=-(-pps // pages),
-                      vmem=2 * (fixed + heads * per_head) if over else 0)
+                      vmem=step + room if step > VMEM_DEFAULT else 0)
 
 
 def _ragged_kernel(lens_ref, qlens_ref, lane_ref, blk_ref, last_ref, tbl_ref,

@@ -485,19 +485,20 @@ def test_planner_starts_a_window_lane_at_its_first_live_block():
 
 def test_a_chunk_past_the_budget_asks_for_its_fast_memory():
     # 16 query heads a key-value head x 256 rows: one head's blocks are past
-    # the budget, and the call asks Mosaic for what it takes
+    # Mosaic's scoped default; the chip's fast memory holds 4 of the 8, and
+    # the call asks Mosaic for what they take
     plan = pa.ragged_grid(32, 896, 256, 128, 8, 64, 128, jnp.bfloat16,
                           jnp.bfloat16)
-    assert plan.heads == 1 and plan.rows == 4096 and plan.few_rows == 16
-    assert pa.VMEM_BUDGET < plan.vmem < 64 << 20
-    # the decode rung's block is cut to the rung's 32 rows, 512 a head: the
-    # budget takes 4 of the 8 heads a grid step, and nothing is asked for
+    assert plan.heads == 4 and plan.rows == 4096 and plan.few_rows == 16
+    assert pa.VMEM_DEFAULT < plan.vmem <= pa.V5E_VMEM
+    # the decode rung's block is cut to the rung's 32 rows, 512 a head: a
+    # grid step takes all 8 heads, past the default, and asks for them
     assert pa.lane_block_rows(256, 32, 128, 8) == 32
     assert pa.lane_block_rows(256, 544, 128, 8) == 256
     small = pa.ragged_grid(32, 896, 32, 128, 8, 64, 128, jnp.bfloat16,
                            jnp.bfloat16)
-    assert (small.rows, small.heads, small.groups, small.vmem) == (
-        512, 4, 2, 0)
+    assert (small.rows, small.heads, small.groups) == (512, 8, 1)
+    assert pa.VMEM_DEFAULT < small.vmem < 24 << 20
     # one query head a key-value head: one block shape at every rung
     assert pa.lane_block_rows(64, 24, 12, 12) == 64
     gpt = pa.ragged_grid(24, 32, 64, 12, 12, 64, 128, jnp.bfloat16,

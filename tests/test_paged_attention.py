@@ -673,16 +673,16 @@ def test_shared_planner_for_lanes_is_work_items_as_before(rng, name):
     assert int(got[0]) == plan.steps(scheduled)
 
 
-@pytest.mark.parametrize("budget,heads", [(12 << 20, 4), (70_000, 2),
+@pytest.mark.parametrize("budget,heads", [(12 << 20, 4), (80_000, 2),
                                           (30_000, 1)])
 def test_ragged_fewer_heads_a_step_than_kv_heads(rng, monkeypatch, budget,
                                                  heads):
-    """Where a page of every head does not fit the fast-memory budget the
+    """Where a page of every head does not fit the chip's fast memory the
     grid takes the largest divisor of the KV heads that does: the head
     groups become the grid's first axis."""
     b, c, hq, hkv, d, page_size, pps = 2, 4, 8, 4, 16, 8, 10
     q, kp, vp, pt = _ragged_case(rng, b, c, hq, hkv, d, page_size, pps)
-    monkeypatch.setattr(pa, "VMEM_BUDGET", budget)
+    monkeypatch.setattr(pa, "vmem_capacity", lambda: budget)
     plan = _plan_of(q, kp, pt)
     assert (plan.heads, plan.groups, plan.blocks) == (
         heads, hkv // heads, 3)
@@ -713,14 +713,106 @@ def test_ragged_grid_at_the_serving_cells_widths():
     int8 = pa.ragged_grid(24, 32, 64, 12, 12, 64, 128, jnp.int8,
                           jnp.bfloat16)
     assert (int8.heads, int8.pair, int8.blocks) == (12, 1, 8)
-    # 32 heads do not fit the fast-memory budget whole: two groups of 16
+    # 32 heads are past Mosaic's scoped default whole but fit the chip's
+    # fast memory: one group of 32, and the call asks for what it takes
     wide = pa.ragged_grid(24, 32, 64, 32, 32, 64, 128, jnp.bfloat16,
                           jnp.bfloat16)
-    assert (wide.heads, wide.groups) == (16, 2)
-    assert wide.steps([2048] * 24) == 2 * 192 and wide.live_steps(257) == 4
+    assert (wide.heads, wide.groups) == (32, 1)
+    assert pa.VMEM_DEFAULT < wide.vmem < 20 << 20
+    assert wide.steps([2048] * 24) == 192 and wide.live_steps(257) == 2
     # the draft chain's chunk of one: no few-rows form beside the 8-row block
     assert pa.ragged_grid(24, 32, 1, 12, 12, 64, 128, jnp.bfloat16,
                           jnp.bfloat16).few_rows == 0
+
+
+# -- the key-value heads a grid step takes, at the serving cells' shapes ------
+
+#: cell: lanes, page slots of each cache group (full, then window), chunk,
+#: query heads, key-value heads, the row ladder's rungs
+_CELL_SHAPES = {
+    "smallthinker": (32, (256, 69), 256, 28, 4, (32, 544, 1024)),
+    "command-a-plus": (32, (896, 69), 256, 128, 8, (32, 544, 1024)),
+    "cerebras-gpt-590m": (24, (32,), 64, 12, 12, (32, 160, 512)),
+}
+
+
+def _cell_plans(name):
+    """``{(rung, page slots): plan}`` as the step and the scheduler build
+    them (``lane_block_rows`` at the rung, bf16 pools, 64-token pages)."""
+    lanes, slots, chunk, hq, hkv, ladder = _CELL_SHAPES[name]
+    bf = jnp.bfloat16
+    return {(rung, pps): pa.ragged_grid(
+        lanes, pps, pa.lane_block_rows(chunk, rung, hq, hkv), hq, hkv, 64,
+        128, bf, bf) for rung in ladder for pps in slots}
+
+
+@pytest.mark.parametrize("name", list(_CELL_SHAPES))
+def test_a_step_takes_the_most_kv_heads_the_chips_fast_memory_holds(name):
+    """Every plan takes the largest divisor of the key-value heads whose
+    blocks, with the room for Mosaic's own values, fit a v5e core's 128 MiB;
+    it asks for fast memory only where its blocks are past the scoped
+    default."""
+    hkv = _CELL_SHAPES[name][4]
+    assert pa.vmem_capacity() == pa.V5E_VMEM == 128 << 20    # no TPU here
+    for (rung, pps), plan in _cell_plans(name).items():
+        room = pa.VMEM_ROOM_TILES * plan.rows * 128 * 4
+        assert plan.groups * plan.heads == hkv
+        if plan.vmem:
+            step = plan.vmem - room                  # the blocks alone
+            assert pa.VMEM_DEFAULT < step and plan.vmem <= pa.V5E_VMEM
+            bigger = [h for h in range(plan.heads + 1, hkv + 1)
+                      if hkv % h == 0]
+            # the next divisor's blocks would not fit
+            assert not bigger or (step // plan.heads * bigger[0] + room
+                                  > pa.V5E_VMEM), (rung, pps)
+        else:
+            # within the default: every head, as the parent's plan took
+            assert plan.heads == hkv, (rung, pps)
+    got = {(rung, pps): (p.rows, p.heads, p.groups, bool(p.vmem))
+           for (rung, pps), p in _cell_plans(name).items()}
+    want = {
+        # the decode rung's 224-row blocks fit the default whole; a chunk
+        # rung's 1,792 rows take all 4 heads (was 1: four passes)
+        "smallthinker": {(32, 256): (224, 4, 1, False),
+                         (32, 69): (224, 4, 1, False),
+                         **{(r, s): (1792, 4, 1, True) for r in (544, 1024)
+                            for s in (256, 69)}},
+        # the decode rung takes all 8 heads (was 4); a chunk rung's 4,096
+        # rows a head take 4 of 8 (was 1)
+        "command-a-plus": {**{(32, s): (512, 8, 1, True) for s in (896, 69)},
+                           **{(r, s): (4096, 4, 2, True) for r in (544, 1024)
+                              for s in (896, 69)}},
+        # unchanged: 12 heads, nothing asked
+        "cerebras-gpt-590m": {(r, 32): (64, 12, 1, False)
+                              for r in (32, 160, 512)},
+    }[name]
+    assert got == want
+
+
+@pytest.mark.parametrize("hq, hkv, chunk, window", [
+    (28, 4, 256, None), (28, 4, 256, 300), (128, 8, 32, None),
+    (128, 8, 32, 300)], ids=["group7-chunk", "group7-chunk-window",
+                             "group16-decode-rung", "group16-decode-rung-window"])
+def test_every_head_a_grid_step_beside_decode_lanes(rng, hq, hkv, chunk,
+                                                    window):
+    """The kernel at the cells' widths under the plan that takes every
+    key-value head a grid step (SmallThinker's chunk rung, 1,792 rows a
+    head; Command A+'s decode rung, 512), a chunk lane beside two decode
+    lanes and an idle one, against the gather reference."""
+    b, d, page_size, pps = 4, 128, 64, 12
+    q, kp, vp, pt = _ragged_case(rng, b, chunk, hq, hkv, d, page_size, pps)
+    plan = _plan_of(q, kp, pt)
+    assert (plan.heads, plan.groups, plan.rows) == (hkv, 1,
+                                                    chunk * hq // hkv)
+    assert plan.vmem > pa.VMEM_DEFAULT
+    kv_lens = jnp.asarray([600, 700, 90, 0], jnp.int32)
+    q_lens = jnp.asarray([chunk, 1, 1, 0], jnp.int32)
+    ref = pa.ragged_paged_attention_reference(q, kp, vp, pt, kv_lens, q_lens,
+                                              window=window)
+    out = pa.ragged_paged_attention(q, kp, vp, pt, kv_lens, q_lens,
+                                    use_kernel=True, window=window)
+    _assert_valid_rows_match(out, ref, q_lens)
+    assert not np.asarray(out)[3].any()
 
 
 @pytest.mark.parametrize("kv", ["fp", "int8"])

@@ -291,3 +291,58 @@ def test_counters_against_a_hand_count(tiny):
         "layer_metrics", "attn_query_block_fill_share").read(
             {"counters": t})
     assert share == pytest.approx(100 * fed * 14 / (steps * 2 * 2 * 56))
+
+
+def test_kv_passes_follow_the_kernels_plan_at_each_rung(tiny, monkeypatch):
+    """``serving_attn_kv_passes`` over ``serving_attn_lane_layers`` is, step
+    by step, the head groups of the kernel's plan at the rung the step ran.
+    A fast memory (patched small) that holds both key-value heads of a
+    decode rung's 112-row lane blocks but one head of a chunk rung's 224
+    makes a chunk step walk every lane's keys twice, the decode lanes beside
+    the chunk lane included."""
+    from paddle_tpu.ops.pallas import paged_attention as pa
+
+    monkeypatch.setattr(pa, "vmem_capacity", lambda: 1_000_000)
+    cfg, m = tiny
+    sp = ServingPredictor(m, max_batch=4, max_seq_len=128, page_size=4,
+                          num_pages=96, token_budget=64, chunk=32,
+                          async_engine=False)
+    ladder = sp._row_ladder
+    groups = {rung: [g.groups for g in sp._attn_grids[rung]]
+              for rung in ladder}
+    assert groups == {ladder[0]: [1, 1],
+                      **{rung: [2, 2] for rung in ladder[1:]}}
+    rng = np.random.default_rng(11)
+    for n in (3, 4, 5, 70):
+        sp.add_request(rng.integers(0, 128, n).tolist(), max_new_tokens=6)
+    passes = layers = 0
+    chunk_beside_decode = False
+    while sp.has_work():
+        before = sp.telemetry()
+        sp.step()
+        after = sp.telemetry()
+        ran = [int(k.split("=")[1][:-1]) for k in after
+               if k.startswith("serving_rows_run{")
+               and after[k] != before.get(k, 0)]
+        if not ran:
+            continue
+        (rung,) = ran
+        lanes = (after["serving_attn_lane_layers"]
+                 - before.get("serving_attn_lane_layers", 0)) // 8
+        step_passes = (after["serving_attn_kv_passes"]
+                       - before.get("serving_attn_kv_passes", 0))
+        assert step_passes == lanes * 8 * groups[rung][0], rung
+        decoded = (after["serving_rows_decode"]
+                   - before.get("serving_rows_decode", 0))
+        chunk_beside_decode |= rung != ladder[0] and decoded >= 2
+        passes, layers = passes + step_passes, layers + lanes * 8
+    assert chunk_beside_decode
+    t = sp.telemetry()
+    assert (t["serving_attn_kv_passes"], t["serving_attn_lane_layers"]) == (
+        passes, layers)
+    from benchmark import run
+
+    ratio, note = run.reader_for(
+        "layer_metrics", "attn_kv_passes_per_lane").read({"counters": t})
+    assert 1.0 < ratio < 2.0 and ratio == passes / layers
+    assert note == {"kv_passes": passes, "lane_layers": layers}

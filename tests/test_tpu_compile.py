@@ -408,18 +408,11 @@ def test_grouped_matmul_compiles_alone_at_the_routed_cells_shapes(
     assert fwd_vmem_bytes(bm, bn, bk, k, bf) <= VMEM_DEFAULT_BYTES
 
 
-@pytest.mark.parametrize("window, rows", [
-    (4096, 32), (4096, 256), (None, 32), (None, 256)],
-    ids=["window-decode-rung", "window-chunk", "full-decode-rung",
-         "full-chunk"])
-def test_paged_kernel_compiles_alone_at_seven_query_heads_a_kv_head(
-        one_chip, monkeypatch, window, rows):
-    """PR 40: ``ragged_paged_attention`` alone at SmallThinker's widths (28
-    query heads over 4 key-value heads of 128: a group of 7, never a power
-    of two) in both forms, at the decode rung's 32-row lane blocks and at a
-    chunk's 256: the group's 7 rows a token lie side by side (224 and 1,792
-    rows a head, whole sublane tiles), the few-rows form takes 8, and the
-    call fits Mosaic's default fast memory (no request of its own)."""
+def _paged_kernel_alone(dev, monkeypatch, lanes, pps, rows, hq, hkv,
+                        window):
+    """``ragged_paged_attention`` alone over stacked bf16 pools of two
+    layers (64-token pages, heads of 128) compiled for ``dev``: the Mosaic
+    calls of the compiled program and the plan the call was built from."""
     import paddle_tpu  # noqa: F401  framework config (matmul precision)
     from paddle_tpu.ops.pallas.paged_attention import (RAGGED_KERNEL_NAME,
                                                        ragged_grid,
@@ -433,11 +426,10 @@ def test_paged_kernel_compiles_alone_at_seven_query_heads_a_kv_head(
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     bf, i32 = jnp.bfloat16, jnp.int32
-    lanes, pps = 32, (69 if window else 256)
     pages = lanes * pps
 
     def sds(*shape, dtype=bf):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=dev)
 
     def fn(q, kp, vp, table, kv, ql, layer):
         return ragged_paged_attention(q, kp, vp, table, kv, ql, layer=layer,
@@ -445,12 +437,57 @@ def test_paged_kernel_compiles_alone_at_seven_query_heads_a_kv_head(
 
     with jax.enable_x64(False):
         compiled = jax.jit(fn).lower(
-            sds(lanes, rows, 28, 128), sds(2, pages, 4, 64, 128),
-            sds(2, pages, 4, 64, 128), sds(lanes, pps, dtype=i32),
+            sds(lanes, rows, hq, 128), sds(2, pages, hkv, 64, 128),
+            sds(2, pages, hkv, 64, 128), sds(lanes, pps, dtype=i32),
             sds(lanes, dtype=i32), sds(lanes, dtype=i32),
             sds(dtype=i32)).compile()
     calls = [line for line in compiled.as_text().splitlines()
              if _is_mosaic_call(line, f"/{RAGGED_KERNEL_NAME}/pallas_call")]
+    return calls, ragged_grid(lanes, pps, rows, hq, hkv, 64, 128, bf, bf)
+
+
+@pytest.mark.parametrize("window, rows", [
+    (4096, 32), (4096, 256), (None, 32), (None, 256)],
+    ids=["window-decode-rung", "window-chunk", "full-decode-rung",
+         "full-chunk"])
+def test_paged_kernel_compiles_alone_at_seven_query_heads_a_kv_head(
+        one_chip, monkeypatch, window, rows):
+    """``ragged_paged_attention`` alone at SmallThinker's widths (28 query
+    heads over 4 key-value heads of 128: a group of 7, never a power
+    of two) in both forms, at the decode rung's 32-row lane blocks and at a
+    chunk's 256: the group's 7 rows a token lie side by side (224 and 1,792
+    rows a head, whole sublane tiles), the few-rows form takes 8, and every
+    head is taken a grid step: the decode rung's within Mosaic's default
+    fast memory (no request of its own), a chunk's past it, asking for what
+    its plan reckons."""
+    from paddle_tpu.ops.pallas.paged_attention import VMEM_DEFAULT
+
+    calls, plan = _paged_kernel_alone(one_chip, monkeypatch, 32,
+                                      69 if window else 256, rows, 28, 4,
+                                      window)
     assert len(calls) == 1
-    plan = ragged_grid(lanes, pps, rows, 28, 4, 64, 128, bf, bf)
-    assert plan.rows == rows * 7 and plan.few_rows == 8 and plan.vmem == 0
+    assert plan.rows == rows * 7 and plan.few_rows == 8 and plan.heads == 4
+    assert plan.vmem == 0 if rows == 32 else plan.vmem > VMEM_DEFAULT
+
+
+@pytest.mark.parametrize("hq, hkv, pps, rows, window, heads", [
+    (128, 8, 69, 32, 4096, 8), (128, 8, 896, 32, None, 8),
+    (128, 8, 896, 256, None, 4)],
+    ids=["command-a-plus-decode-rung-window",
+         "command-a-plus-decode-rung-full", "command-a-plus-chunk-rung"])
+def test_paged_kernel_fits_the_fast_memory_its_plan_asks_for(
+        one_chip, monkeypatch, hq, hkv, pps, rows, window, heads):
+    """The plans that take more key-value heads a grid step than Mosaic's
+    16 MiB scoped default holds compile for a v5e within what the plan asks
+    for (its blocks and the room for the compiler's own values): Mosaic
+    refuses a call whose scoped allocations pass ``vmem_limit_bytes``. The
+    request stays under a v5e core's 128 MiB. (SmallThinker's chunk rung:
+    the test above.)"""
+    from paddle_tpu.ops.pallas.paged_attention import (V5E_VMEM,
+                                                       VMEM_DEFAULT)
+
+    calls, plan = _paged_kernel_alone(one_chip, monkeypatch, 32, pps, rows,
+                                      hq, hkv, window)
+    assert len(calls) == 1
+    assert plan.heads == heads and plan.rows == rows * hq // hkv
+    assert VMEM_DEFAULT < plan.vmem <= V5E_VMEM
