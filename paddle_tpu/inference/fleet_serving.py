@@ -274,12 +274,15 @@ class FleetRouter:
         # front of the admission, so latency-sensitive small fleets can
         # keep the pre-21 place-and-recompute behavior.
         self.prefix_pulls = bool(prefix_pulls)
-        if (getattr(getattr(model, "config", None), "kv_lora_rank", 0)
-                and (self.prefill_replicas or self.prefix_pulls)):
+        cfg = getattr(model, "config", None)
+        kind = ("a latent (MLA) cache" if getattr(cfg, "kv_lora_rank", 0)
+                else "a looped stack" if getattr(cfg, "total_ut_steps", 0)
+                else None)
+        if kind and (self.prefill_replicas or self.prefix_pulls):
             raise NotImplementedError(
                 "kv_transfer (prefill_replicas, prefix_pulls) is not "
-                "supported for a latent (MLA) cache yet: its pages have no "
-                "payload format")
+                f"supported for {kind} yet: its pages have no payload "
+                "format")
         self.max_failovers = int(max_failovers)
         if self.max_failovers < 0:
             raise ValueError(f"max_failovers must be >= 0, "

@@ -475,7 +475,7 @@ class KVCacheManager:
                  dtype=jnp.float32, enable_prefix_cache=False,
                  quantize_kv=False, mesh=None, metrics=None,
                  host_tier_bytes=0, latent=False, index_plane=None,
-                 window=None):
+                 window=None, loops=1):
         from ..ops.pallas.paged_attention import preferred_page_size
 
         # layers that attend a WINDOW: ``window=(layers, tokens, chunk)``,
@@ -497,6 +497,24 @@ class KVCacheManager:
                     f"{num_layers} layers) does not take "
                     f"{', '.join(unsupported) or 'that split of layers'} "
                     "yet")
+
+        # a LOOPED stack (models/ouro.py): each layer runs ``loops`` times a
+        # token and keeps each loop's keys and values apart, so the pools
+        # hold ``num_layers * loops`` planes, loop t of layer l at plane ``t
+        # * num_layers + l``, all under the ONE page table: a page covers
+        # every loop of every layer. What is not built over it fails here.
+        self.loops = int(loops)
+        if self.loops != 1:
+            unsupported = [name for name, on in (
+                ("enable_prefix_cache", enable_prefix_cache),
+                ("quantize_kv", quantize_kv), ("mesh", mesh is not None),
+                ("host_tier_bytes", host_tier_bytes), ("latent", latent),
+                ("window", window is not None)) if on]
+            if unsupported or self.loops < 1:
+                raise NotImplementedError(
+                    f"a cache of {loops} loops does not take "
+                    f"{', '.join(unsupported) or 'that count'} yet")
+            num_layers *= self.loops
 
         # a LATENT cache (multi-head latent attention): ONE pool, one row
         # per token shared by every head — ``num_kv_heads`` 1, ``head_dim``
@@ -686,6 +704,10 @@ class KVCacheManager:
         self._m_restore_scatters = m.counter(
             "kv_tier_restore_device_calls", "device scatter calls issued "
             "by batched imports (one per plane per round)")
+        if self.loops != 1:
+            m.gauge("kv_pool_planes",
+                    "planes of the pool stack: layers x loops").set(
+                        self.num_layers)
         if self.window is not None:
             self._m_window_held = m.gauge(
                 "kv_window_pages_held", "pages the window group's lanes "

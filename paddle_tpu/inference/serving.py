@@ -433,21 +433,29 @@ class ServingPredictor:
         # cohere2_moe.py): a second cache group, and the same refusals
         self.windowed = not self.latent and bool(
             getattr(cfg, "sliding_window", 0))
-        if self.latent or self.windowed:
+        # a LOOPED stack (models/ouro.py): the layers run ``total_ut_steps``
+        # times a token, each (loop, layer) with its own plane of the pools
+        self.looped = not self.latent and bool(
+            getattr(cfg, "total_ut_steps", 0))
+        self._loops = cfg.total_ut_steps if self.looped else 1
+        if self.latent or self.windowed or self.looped:
             unsupported = [name for name, on in (
                 ("kv_cache_dtype", kv_cache_dtype), ("mesh", mesh),
                 ("spec_decode_k", spec_decode_k),
                 ("host_tier_bytes", host_tier_bytes),
                 ("draft_source='model'", draft_source == "model"),
                 ("draft_layers", draft_layers),
-                ("prefix_cache", self.windowed and prefix_cache)) if on]
+                ("prefix_cache", (self.windowed or self.looped)
+                 and prefix_cache)) if on]
             if unsupported:
+                kind = ("latent (MLA) cache" if self.latent
+                        else "cache with a window group" if self.windowed
+                        else "looped stack")
                 raise NotImplementedError(
-                    f"not supported for a "
-                    f"{'latent (MLA) cache' if self.latent else 'cache with a window group'}"
-                    f" yet: {', '.join(unsupported)}")
+                    f"not supported for a {kind} yet: "
+                    f"{', '.join(unsupported)}")
         with phase("weights.place"):
-            if self.latent or self.windowed:
+            if self.latent or self.windowed or self.looped:
                 import jax
 
                 self.params = (
@@ -497,8 +505,10 @@ class ServingPredictor:
                 cfg.num_heads, cfg.num_heads, cfg.head_dim, kv_dtype)
             num_pages = self.max_batch * pages_needed(self.max_seq_len, ps)
         if prefix_cache is None:
-            # (a hit needs the last window's pages alive in a window group)
-            prefix_cache = not self.windowed
+            # (a hit needs the last window's pages alive in a window group;
+            # a looped stack's shared pages are not shown to carry every
+            # loop's planes)
+            prefix_cache = not (self.windowed or self.looped)
         # a latent cache: one pool of one row per token, the row padded to
         # whole 128-lane tiles (compiled for the chip, an unpadded 576-wide
         # row costs a copy of the whole pool at every kernel call)
@@ -517,6 +527,7 @@ class ServingPredictor:
                    if self.sparse else {}),
                 **({"window": (cfg.num_window_layers, cfg.sliding_window,
                                self.chunk)} if self.windowed else {}),
+                **({"loops": self._loops} if self.looped else {}),
                 num_pages=num_pages, max_batch=self.max_batch,
                 max_seq_len=self.max_seq_len, page_size=page_size,
                 num_q_heads=cfg.num_heads, dtype=kv_dtype,
@@ -633,6 +644,12 @@ class ServingPredictor:
                 "serving_window_keys_context",
                 "keys attention would read with no window: per scheduled row "
                 "and attention layer, its context")
+        if self.looped:
+            self._m_loop_passes = self.metrics.counter(
+                "serving_loop_passes",
+                "rows that ran a loop of a looped stack, times the layers "
+                "they ran in it, summed over dispatched steps",
+                labels=("loop",))
         # learned sparse attention: per scheduled row, the keys its indexer
         # scored, the keys its attention read and the keys it would have
         # read without a selection, each summed over the layers that do it
@@ -2302,7 +2319,7 @@ class ServingPredictor:
         if not self.latent:
             grid = (self._attn_grids[rung][0] if self.windowed
                     else self._attn_grid)
-            layers = self.config.num_layers
+            layers = self.config.num_layers * self._loops
             self._m_query_rows.inc(layers * sum(fed) * self._q_heads)
             self._m_query_block_rows.inc(
                 layers * grid.lanes * grid.groups * grid.heads * grid.rows)
@@ -2313,6 +2330,11 @@ class ServingPredictor:
                                         - grid.groups)
             self._m_kv_passes.inc(len(fed) * passes)
             self._m_lane_layers.inc(len(fed) * layers)
+        if self.looped:
+            # every scheduled row runs every loop, over the whole stack
+            for loop in range(self._loops):
+                self._m_loop_passes.labels(loop=str(loop)).inc(
+                    sum(fed) * self.config.num_layers)
         if self.sparse:
             cfg = self.config
             seen, read = map(sum, zip(*(

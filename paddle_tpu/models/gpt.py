@@ -1038,6 +1038,16 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
     norm feeds attention and the FFN, both added to ``x``), and a run of ONE
     layer brings its weights unstacked and is not scanned. ``kv_quant``,
     ``mesh`` and ``spec_k`` are not extended to a window group either.
+
+    A LOOPED model (``config.total_ut_steps``; ``models/ouro.py``) runs its
+    one stack once a loop: a loop around the layer scan, one traced body for
+    all loops, the final norm after each loop (the next loop's input, and
+    after the last the head's). Its pools hold a plane per (loop, layer),
+    ``[num_layers * loops, num_pages, ...]`` under the one page table, loop t
+    of layer l at plane ``config.cache_plane(t, l)``; its full layers may
+    rotate (``rope_full_layers``) and its block may norm what each sublayer
+    adds (``ln1b_g`` / ``ln2b_g``). The signature is the GPT block's.
+    ``kv_quant``, ``mesh`` and ``spec_k`` are not extended to it.
     """
     import jax
     import jax.numpy as jnp
@@ -1090,14 +1100,18 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
     # cohere2_moe.py): a pool pair per cache group, the window group with a
     # page table of its own
     windowed = not latent and bool(getattr(cfg, "sliding_window", 0))
-    if windowed:
+    # a LOOPED stack (``total_ut_steps``; models/ouro.py): the layers run once
+    # a loop, each (loop, layer) with its own plane of the pools
+    looped = not latent and bool(getattr(cfg, "total_ut_steps", 0))
+    if windowed or looped:
         unsupported = [name for name, on in (
             ("kv_cache_dtype='int8'", kv_quant), ("mesh", mesh is not None),
             ("spec_decode_k", spec_k)) if on]
         if unsupported:
             raise NotImplementedError(
-                f"a cache with a window group does not serve "
-                f"{', '.join(unsupported)} yet: its second pool pair has no "
+                f"a cache with "
+                f"{'a window group' if windowed else 'a plane a loop'} does "
+                f"not serve {', '.join(unsupported)} yet: its pools have no "
                 "scale planes, no sharding rule and no rollback")
         from .layer_kinds import WINDOW, attention_kind, stack_runs
     # argument layout (shared by the wrappers, shard_map specs and the
@@ -1327,7 +1341,8 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
             to, at, kv_ctx, tbl, group_plan = dest, 0, ctx, page_table, plan
             if kind is not None:
                 window, theta = attention_kind(cfg, kind)
-                sub = step_scope("attn_full")
+                if windowed:
+                    sub = step_scope("attn_full")
                 if kind == WINDOW:
                     sub = step_scope("attn_window")
                     to, at, kv_ctx, tbl, group_plan = (dest_w, 2, ctx_w,
@@ -1507,7 +1522,13 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
                 p, y, pools, li, *sel, *fi,
                 **({} if kind is None else {"kind": kind}))
             with step_scope("attn_out"):
-                x = x + _srv_psum(_srv_mm(a, p["wo"], use_kernel), axis)
+                o = _srv_psum(_srv_mm(a, p["wo"], use_kernel), axis)
+                if "ln1b_g" in p:
+                    # a SANDWICH block norms what each sublayer adds too
+                    # (``models/ouro.py``)
+                    with step_scope("ln"):
+                        o = _srv_norm(cfg, o, p, "ln1b")
+                x = x + o
                 if "bo" in p:
                     x = x + p["bo"]
             if not getattr(cfg, "parallel_block", False):
@@ -1517,12 +1538,35 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
             with step_scope("mlp"):
                 f, rows = _srv_ffn(cfg, p, y, use_kernel, axis, valid=valid,
                                    layer=lj if whole else None, route=route)
+                if "ln2b_g" in p:
+                    with step_scope("ln"):
+                        f = _srv_norm(cfg, f, p, "ln2b")
                 x = x + f
             if sparse:
                 # the selection goes on to the next layer; its last rows
                 # (None from a layer that shares) come out with the counts
                 return (x, pools, chosen[0]), (rows, chosen[1])
             return (x, pools), rows
+
+        def loops_over(carry, stack, kind):
+            """A LOOPED stack: the whole stack once a loop, ONE traced body
+            for every loop (a loop around the layer scan), loop t of layer
+            l writing and reading plane ``cfg.cache_plane(t, l)`` of the
+            pools (which the carry holds throughout), and the final norm
+            after each loop: the next loop's input, and after the last
+            loop the head's."""
+            n = stack["ln1_g"].shape[0]
+            within = jnp.arange(n, dtype=jnp.int32)
+
+            def one_loop(i, carry):
+                carry, _ = jax.lax.scan(
+                    functools.partial(block, kind=kind), carry,
+                    (stack, cfg.cache_plane(i, within), within))
+                with step_scope("ln"):
+                    x = _srv_norm(cfg, carry[0], params, "lnf")
+                return (x, *carry[1:])
+
+            return jax.lax.fori_loop(0, cfg.total_ut_steps, one_loop, carry)
 
         # the stacks the model's layers come in, in order: one for a uniform
         # model, the leading dense layers' and then the routed layers' for
@@ -1535,7 +1579,7 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
         # a model of several attention kinds: the kind of each run, and the
         # layers of each cache group counted apart (a group's pool stack is
         # [its layers, its pages, ...])
-        kinds = ([k for k, _ in stack_runs(cfg)] if windowed
+        kinds = ([k for k, _ in stack_runs(cfg)] if windowed or looped
                  else [None] * len(groups))
         layers_run = {}      # cache group (the window's?) -> its layers so far
         # the scans are scoped, so their own slicing of the stacked weights
@@ -1545,6 +1589,9 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
             carry, indexed, selected = carry + (no_selection,), 0, []
         with step_scope("layers"):
             for stack, kind in zip(groups, kinds):
+                if looped:
+                    carry = loops_over(carry, stack, kind)
+                    continue
                 group = windowed and kind == WINDOW
                 first = layers_run.get(group, 0)
                 if stack["ln1_g"].ndim == 1:
@@ -1594,7 +1641,8 @@ def build_unified_step(config: GPTConfig, page_size: int, chunk: int,
         rows = (last_idx[:, None] + jnp.arange(spec_k + 1)[None] if spec_k
                 else last_idx)
         with step_scope("head"):
-            x = _srv_norm(cfg, x, params, "lnf")
+            if not looped:      # (a looped stack's last loop normed it)
+                x = _srv_norm(cfg, x, params, "lnf")
             out = (*pools, x[jnp.clip(rows, 0, t - 1)])  # [b, (k1,) h]
         if spec_k:
             # drafts ride the packed token stream: [b, k]

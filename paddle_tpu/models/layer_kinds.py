@@ -34,10 +34,12 @@ def stack_runs(config):
 def attention_kind(config, kind):
     """``(window, rope theta)`` of a layer kind: what ``mha`` does beyond the
     GPT block's (``models/gpt.py``): a window layer rotates and sees its
-    window; a full layer has neither."""
+    window; a full layer sees every causal key and rotates only where the
+    configuration says so (``rope_full_layers``: ``models/ouro.py``)."""
     if kind == WINDOW:
         return config.sliding_window, config.rope_theta
-    return None, None
+    return None, (config.rope_theta
+                  if getattr(config, "rope_full_layers", False) else None)
 
 
 def _angles(positions, theta, d, repeat):

@@ -491,3 +491,44 @@ def test_paged_kernel_fits_the_fast_memory_its_plan_asks_for(
     assert len(calls) == 1
     assert plan.heads == heads and plan.rows == rows * hq // hkv
     assert VMEM_DEFAULT < plan.vmem <= V5E_VMEM
+
+
+def test_compiled_looped_step_keeps_every_loops_planes_in_place(one_chip,
+                                                               monkeypatch):
+    """Ouro's block through the same step at the cell's widths (16 heads of
+    128, hidden 2,048, SwiGLU at 5,632, the whole vocabulary), two layers
+    run four times a token: the pools hold eight planes, the loop around the
+    layer scan carries them, and no copy of a pool is made at the loop's
+    edge or anywhere outside ``cow``. One traced layer body: one call of
+    each kernel a rung, however many loops."""
+    import paddle_tpu  # noqa: F401  framework config (matmul precision)
+    from paddle_tpu.models.gpt import build_unified_step, step_row_ladder
+    from paddle_tpu.models.ouro import OuroConfig
+
+    sys.path.insert(0, REPO)
+    try:
+        from chip_smoke import _is_mosaic_call, pool_copies
+        from benchmark.tools.compile_serve_looped_for_v5e import step_avals
+    finally:
+        sys.path.remove(REPO)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = OuroConfig(num_layers=2, max_seq_len=2048)
+    dep = dict(token_budget=512, max_batch=16, max_seq_len=2048,
+               page_size=64, chunk=256)
+    step = build_unified_step(cfg, 64, 256)
+    avals, pool = step_avals(cfg, dep, 64, one_chip, jnp.bfloat16)
+    compiled = step.lower(*avals).compile()
+    lines = compiled.as_text().splitlines()
+
+    def calls(kernel):
+        return sum(_is_mosaic_call(line, f"/{kernel}/pallas_call")
+                   for line in lines)
+
+    rungs = len(step_row_ladder(16, 0, 256, 512))
+    assert rungs == 2
+    assert calls("ragged_paged_attention") == rungs
+    assert pool.shape == (8, 64, 16, 64, 128)
+    assert pool_copies("\n".join(lines), pool.shape) == []
+    # the prefill rung's query blocks and the kernel's float32 result
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.4e9
